@@ -14,7 +14,7 @@ Prints ONE json line: {"metric", "value", "unit", "vs_baseline"}.
 ``vs_baseline`` divides by ASSUMED_FLINK_EVENTS_PER_SEC: single-node
 Apache Flink with HeapKeyedStateBackend on Nexmark Q5 sustains roughly
 2M events/s (order of magnitude from public Nexmark runs; the reference
-repo publishes no numbers — BASELINE.md). The north-star target is 20x.
+repo publishes no numbers). The north-star target is 20x.
 """
 from __future__ import annotations
 
@@ -30,16 +30,16 @@ WINDOW_MS = 10_000
 SLIDE_MS = 1_000
 
 # Every bench env spreads this in: submit-time plan analysis is OFF so
-# the measured clocks contain zero analyzer cost (BASELINE.md states
-# analysis overhead is excluded from bench timings; the tier-1 dogfood
-# gate separately keeps these pipelines/configs at zero findings).
+# the measured clocks contain zero analyzer cost (analysis overhead is
+# excluded from bench timings; the tier-1 dogfood gate separately keeps
+# these pipelines/configs at zero findings).
 BENCH_CONF = {"analysis.fail-on": "off"}
 
 # CLI A/B axes (--fire-gate on|off, --readiness piggyback|probe):
 # merged into every run's conf AFTER the per-config builders, so the
 # COMMITTED confs (job_confs/--dump-confs, exercised with no overrides)
 # stay byte-stable while a measurement run can flip the control-plane
-# knobs without editing code (PROFILE.md §12's before/after axis).
+# knobs without editing code (the before/after axis).
 CONTROL_OVERRIDES: dict = {}
 
 def _phase_summary(metrics: dict, wall_s: float) -> dict:
@@ -47,8 +47,7 @@ def _phase_summary(metrics: dict, wall_s: float) -> dict:
     profile.phase.* keys — driver.phase_breakdown() is the ONE shared
     accounting, so the artifact mirrors whatever phases it emits
     (hardcoding the list here would silently drop a future phase) —
-    plus the throttle-wait share of batch wall, the §8.3 attribution
-    line the §12 acceptance bar reads."""
+    plus the throttle-wait share of batch wall."""
     pref = "profile.phase."
     ph = {k[len(pref):]: round(float(v), 3)
           for k, v in sorted(metrics.items()) if k.startswith(pref)}
@@ -205,7 +204,7 @@ def run_q5(batch_size: int, n_batches: int, *, shards: int, slots: int,
     cfg = NexmarkConfig(
         batch_size=batch_size, n_batches=n_batches,
         events_per_ms=100, num_active_auctions=10_000, hot_ratio=4)
-    # sub-batch fire/emit decoupling (PROFILE.md §8.6): fires reach
+    # sub-batch fire/emit decoupling: fires reach
     # the host at ~batch_wall/K cadence instead of riding the drain
     # behind one full logical-batch device step
     conf = {**_q5_conf(batch_size, shards, slots, sub_batches),
@@ -231,7 +230,7 @@ def run_q5(batch_size: int, n_batches: int, *, shards: int, slots: int,
 # THE default sub-batch config of the headline (and of the acceptance
 # bar): 2^22-record logical batches executed as 4 chained 2^20
 # sub-batch device programs — logical-batch ingest amortization with
-# fire visibility at sub-batch cadence (PROFILE.md §8.6).
+# fire visibility at sub-batch cadence.
 HEADLINE_BATCH = 1 << 22
 HEADLINE_SUB_BATCHES = 4
 
@@ -256,7 +255,7 @@ def _q5_trial(batch, n_meas, sub_batches, profile_dir=""):
         "p99_latency_ms": round(metrics.get("driver.emit_latency_ms.p99", 0.0), 1),
         "max_latency_ms": round(metrics.get("driver.emit_latency_ms.max", 0.0), 1),
         # per-phase wall attribution (dispatch/throttle/drain/advance/
-        # fire) — the win is attributed, not asserted (PROFILE.md §12)
+        # fire) — the win is attributed, not asserted
         "phase_breakdown": _phase_summary(metrics, elapsed),
     }
     return trial, metrics
@@ -265,7 +264,7 @@ def _q5_trial(batch, n_meas, sub_batches, profile_dir=""):
 def _profile_top_ops(batch, sub_batches, n_batches=16):
     """One short PROFILED Q5 run (pipeline.profile-dir): returns the
     per-op device-time summary so the bench ARTIFACT itself names the
-    expensive ops (the §8.5 anomaly hunt) — never fails the bench."""
+    expensive ops — never fails the bench."""
     import tempfile
 
     try:
@@ -293,8 +292,8 @@ def main() -> None:
     # 2^22-record LOGICAL microbatches (the r05 throughput point) run
     # as 4×2^20 chained sub-batch programs: ingest amortization stays
     # at 2^22 while fired rows become host-visible at sub-batch
-    # cadence — the p99 decoupling ISSUE 6 ships (r05 paid p99 ≈ 406ms
-    # for the same median; PROFILE.md §8.5/§8.6 have the curves).
+    # cadence — the p99 decoupling ISSUE 6 ships (latency on the
+    # current chip: not measured).
     batch = HEADLINE_BATCH
     sub = HEADLINE_SUB_BATCHES
     # warmup: same operator configs → shared compiled kernels (covers
@@ -321,7 +320,7 @@ def main() -> None:
         "value": eps,
         "unit": "events/sec/chip",
         # vs an ASSUMED single-node CPU-Flink baseline (no network in
-        # this environment to measure the real one; see BASELINE.md)
+        # this environment to measure the real one)
         "vs_baseline": round(eps / ASSUMED_FLINK_EVENTS_PER_SEC, 3),
         "baseline_assumed": True,
         "batch": batch,
@@ -331,21 +330,20 @@ def main() -> None:
         "spread_pct": round((rates[-1] - rates[0]) / eps * 100, 1),
         "trials": trials,
         # fire-dispatch → sink-delivery latency of fired windows (the
-        # latency-marker analogue; BASELINE.md's p99 column), from the
+        # latency-marker analogue), from the
         # median-throughput trial. Samples are stamped per fire cohort
         # at actual host-visibility (drain fetch), not at queue-item
         # delivery — see driver._note_ring_latency.
         "p99_latency_ms": med["p99_latency_ms"],
         "p50_latency_ms": med["p50_latency_ms"],
         # control-plane config + the median trial's per-phase wall
-        # attribution (throttle/drain/advance vs dispatch/fire) — the
-        # §12 acceptance bar reads throttle_share_pct off this field
+        # attribution (throttle/drain/advance vs dispatch/fire)
         "fire_gate": med["fire_gate"],
         "readiness": med["readiness"],
         "phase_breakdown": med["phase_breakdown"],
-        # per-op device-time summary from one short profiled run: the
-        # §8.5 anomaly hunt ships IN the artifact (jax.profiler.trace
-        # via pipeline.profile-dir; obs/profiling.py)
+        # per-op device-time summary from one short profiled run
+        # (jax.profiler.trace via pipeline.profile-dir;
+        # obs/profiling.py)
         "profile_top_ops": _profile_top_ops(batch, sub),
     }))
 
@@ -480,9 +478,9 @@ def run_wordcount(batch_size: int, n_batches: int) -> float:
 
 def run_wordcount_log_fed(batch_size: int, n_batches: int) -> float:
     """Log-fed WordCount — the host→device INGEST/TRANSPORT plane's
-    number (VERDICT r05: the ingest plane lost its measured line). A
-    producer pass commits the word stream into an embedded durable-log
-    topic (flink_tpu/log/, sealed columnar segments + commit markers);
+    number. A producer pass commits the word stream into an embedded
+    durable-log topic (flink_tpu/log/, sealed columnar segments +
+    commit markers);
     the MEASURED pass replays the topic's committed offsets through
     LogSource, so every record pays deserialization + host keying +
     h2d + dispatch — the path a job chained behind another job's
@@ -686,7 +684,7 @@ def run_sessions(batch_size: int, n_batches: int,
     aggregation with event time + allowed lateness (the Criteo-style
     workload: many users, bursty activity separated by gaps). Returns
     events/sec. ``host_parallelism`` pins host.parallelism for the
-    §9.4 thread-count sweep; None = the declared default."""
+    thread-count sweep; None = the declared default."""
     from flink_tpu.api.environment import StreamExecutionEnvironment
     from flink_tpu.api.sources import GeneratorSource
     from flink_tpu.api.windowing import EventTimeSessionWindows
@@ -731,9 +729,9 @@ def suite() -> None:
     """Full bench suite (`python bench.py --suite`): every implemented
     BASELINE.json config — one JSON line per config (the driver's
     graded metric remains the default Q5 single line)."""
-    # per-config batch sizes: each workload's sweet spot on this
-    # transport (PROFILE.md §8.2 — bigger batches amortize per-step
-    # relay overheads until a config-specific ceiling)
+    # per-config batch sizes, hand-picked per workload (bigger batches
+    # amortize per-step overheads until a config-specific ceiling;
+    # where that ceiling is on the current chip: not measured)
     run_wordcount(1 << 20, 4)  # warmup
     eps0 = run_wordcount(1 << 20, 24)
     print(json.dumps({"metric": "wordcount_tumbling_1s_events_per_sec",
@@ -751,9 +749,8 @@ def suite() -> None:
     print(json.dumps({"metric": "session_clickstream_events_per_sec",
                       "value": round(eps4), "unit": "events/sec/chip"}))
     # log-fed WordCount: the job-chaining ingest plane (durable-log
-    # replay → host keying → h2d → dispatch). Restores the measured
-    # host→device number VERDICT r05 flagged as missing; a regression
-    # in columnar deserialization, LogSource replay, or the h2d path
+    # replay → host keying → h2d → dispatch): a regression in
+    # columnar deserialization, LogSource replay, or the h2d path
     # lands here every round.
     run_wordcount_log_fed(1 << 18, 4)  # warmup
     epsl = run_wordcount_log_fed(1 << 18, 24)
@@ -766,9 +763,9 @@ def suite() -> None:
     run_q5_backfill(1 << 18, n_hist=8, n_live=4)
     # host-fed Q5 (device_source=False): the INGEST plane's number.
     # The headline's device-chained generator moves ~zero record bytes
-    # over the link (VERDICT r05 missing #2 / weak #2); this permanent
-    # companion line materializes every record on the host and pays
-    # the full keying + h2d + dispatch path, so ingest regressions are
+    # over the link; this permanent companion line materializes every
+    # record on the host and pays the full keying + h2d + dispatch
+    # path, so ingest regressions are
     # measured every round instead of hiding behind the devgen number.
     run_q5(1 << 20, 4, shards=128, slots=256, device_source=False)
     t0 = time.perf_counter()
@@ -780,9 +777,8 @@ def suite() -> None:
         "metric": "nexmark_q5_hot_items_host_fed_events_per_sec",
         "value": round((1 << 20) * 24 / el5h),
         "unit": "events/sec/chip",
-        # the §8.3 attribution on the HOST-FED plane: the throttle-wait
-        # share of batch wall is the number the §12 acceptance bar
-        # compares (≥2× reduction vs the separate-probe control plane)
+        # the phase attribution on the HOST-FED plane (throttle-wait
+        # share of batch wall included)
         "phase_breakdown": _phase_summary(m5h, el5h)}))
     main()  # Q5 headline last (its line is the one the driver records)
 
@@ -833,13 +829,13 @@ def concurrent_jobs_bench(k: int, batch_size: int = 1 << 18,
     real admission/deploy plane.
 
     CORE-COUNT GUARD (the ``--host-parallelism`` pattern): the ≥1.5×
-    aggregate target exists because the CHIP sits ~50% idle under one
-    job (PROFILE.md §8.3) — K co-resident jobs overlap into the idle
-    half. On a CPU host with fewer than 2K cores the K jobs are
-    compute-bound on the SAME cores, so the ratio measures scheduler
-    contention, not the subsystem; such hosts get an explicit SKIPPED
-    line for the target while the measured numbers still print (the
-    measurement path itself runs everywhere)."""
+    aggregate target presumes the CHIP sits partly idle under one job
+    (idle share on the current chip: not measured) — K co-resident jobs
+    overlap into the idle part. On a CPU host with fewer than 2K cores
+    the K jobs are compute-bound on the SAME cores, so the ratio
+    measures scheduler contention, not the subsystem; such hosts get
+    an explicit SKIPPED line for the target while the measured numbers
+    still print (the measurement path itself runs everywhere)."""
     from flink_tpu.config import Configuration
     from flink_tpu.runtime.session import LocalSessionCluster
 
@@ -906,9 +902,9 @@ def concurrent_jobs_bench(k: int, batch_size: int = 1 << 18,
             "skipped": "insufficient-cores",
             "cores": cores,
             "required_cores": required,
-            "detail": "the >=1.5x aggregate target exists because the "
-                      "chip is ~50% idle under one job (PROFILE.md "
-                      f"§8.3); on a {cores}-core CPU host {k} "
+            "detail": "the >=1.5x aggregate target presumes the chip "
+                      "sits partly idle under one job; on a "
+                      f"{cores}-core CPU host {k} "
                       "concurrent CPU-bound jobs share the same cores, "
                       "so the ratio measures contention, not the "
                       "subsystem — re-run on the chip host"}))
@@ -919,13 +915,13 @@ def concurrent_jobs_bench(k: int, batch_size: int = 1 << 18,
 
 
 def host_parallelism_sweep(spec: str) -> None:
-    """`python bench.py --host-parallelism 1,2,4,8`: the §9.4
+    """`python bench.py --host-parallelism 1,2,4,8`: the
     thread-count sweep on the sessions config (#4) — one JSON line per
     worker count, same generator/batch shape as the suite's sessions
     line. The PR-notes win claim is the ratio AT THE DECLARED DEFAULT
     (min(4, os.cpu_count())), never the best point of the sweep.
 
-    CORE-COUNT GUARD (ROADMAP carry-over / PROFILE.md §9.4): the
+    CORE-COUNT GUARD (ROADMAP carry-over): the
     ≥1.25× @W=4 target is only MEASURABLE on a host with ≥ 4 physical
     cores — on fewer, W=4 is pure oversubscription and the sweep would
     print a silent parity-or-worse number that reads like a subsystem
@@ -944,7 +940,7 @@ def host_parallelism_sweep(spec: str) -> None:
             "skipped_points": over,
             "cores": cores,
             "required_cores": 4,
-            "detail": "the >=1.25x @W=4 validation (PROFILE.md §9.4) "
+            "detail": "the >=1.25x @W=4 validation "
                       "needs >=4 cores (os.cpu_count; SMT threads "
                       "inflate this — prefer physical-core hosts); "
                       "W>cores would print oversubscription, not the "
@@ -1041,7 +1037,14 @@ def rescale_bench(at_batch: int, to_procs: int, *,
     2N+1 cores the post-cut processes contend for the same cores and
     the ratio measures the scheduler, so such hosts get an explicit
     SKIPPED line for the ratio while time-to-rescale (a control-plane
-    number, not compute-bound) still prints everywhere."""
+    number, not compute-bound) still prints everywhere.
+
+    ONE PROCESS PER CHIP: a chip belongs to one process, so N runner
+    processes on one chip host cannot each have it. The runners here
+    are forced onto the CPU by design (``JAX_PLATFORMS=cpu``) and the
+    line says so (``runner_platform``): its rates are CPU-runner
+    rates, never events/sec/chip. This parent never initializes a JAX
+    backend."""
     import shutil
     import subprocess
     import sys as _sys
@@ -1154,6 +1157,8 @@ def rescale_bench(at_batch: int, to_procs: int, *,
         line = {
             "metric": "q5_live_process_rescale",
             "unit": "ms",
+            # the runners are CPU processes by design (see docstring)
+            "runner_platform": "cpu",
             "rescale_at_batch": at_batch,
             "rescale_to_processes": to_procs,
             "batch": batch_size,
@@ -1377,8 +1382,8 @@ if __name__ == "__main__":
     # control-plane A/B axes for the Q5 runs (run_q5 merges
     # CONTROL_OVERRIDES): the default headline, `--sub-batches` sweeps,
     # and `--suite`'s Q5 lines honor them — e.g. `--sub-batches 1,2,4
-    # --fire-gate off` measures the ungated sweep for PROFILE.md §12's
-    # before/after table. Modes whose confs never pass through run_q5
+    # --fire-gate off` measures the ungated sweep (the before/after
+    # axis). Modes whose confs never pass through run_q5
     # REJECT the flags loudly rather than silently ignoring them.
     if "--fire-gate" in sys.argv or "--readiness" in sys.argv:
         for mode in ("--backfill", "--host-parallelism",

@@ -2,6 +2,12 @@
 # Local cluster bootstrap (ref: flink-dist bin/start-cluster.sh):
 # one coordinator + one runner per host entry, HA-ready when
 # FLINK_TPU_HA_DIR points at shared storage.
+#
+# One runner per chip host, by design: a chip belongs to one process at
+# a time, so a second runner on the same chip host cannot get it (it
+# fails or hangs at backend start-up). One runner drives every chip of
+# its host (cluster.mesh-devices). The coordinator never initializes a
+# JAX backend and may share the host.
 set -euo pipefail
 
 REPO="$(cd "$(dirname "$0")/.." && pwd)"

@@ -32,7 +32,7 @@ validation, §3.6 — generalized into a rule engine):
   module-qualified calls, lock/lease binding types) and the protocol
   rules walk its edges: tracer leaks in jit kernels (host conversions /
   Python branches on traced values, followed through the helpers the
-  traced arguments flow into — the failure class PROFILE §8.1's design
+  traced arguments flow into — the failure class the kernels' design
   rules exist to prevent), fault-point drift in BOTH directions
   (unknown ``faults.fire`` literals and registered points nothing
   fires), config/metric name drift, unlocked shared-state writes in

@@ -740,10 +740,10 @@ def readiness_invalid(plan, config) -> Iterable[Finding]:
              fix="leave pipeline.fire-gate true (the default) under "
                  "sub-batching")
 def fire_gate_invalid(plan, config) -> Iterable[Finding]:
-    """Fire-gating forced OFF under a config that needs it (PROFILE.md
-    §12): pipeline.sub-batches > 1 pays the fire/top-n select sort on
+    """Fire-gating forced OFF under a config that needs it:
+    pipeline.sub-batches > 1 pays the fire/top-n select sort on
     EVERY sub-batch dispatch whether or not any window fires — exactly
-    the §8.6 throughput-vs-K tax the gate removes. Warn, not error:
+    the throughput-vs-K tax the gate removes. Warn, not error:
     gate-off is the legitimate A/B measurement axis."""
     from flink_tpu.config import PipelineOptions
 
@@ -758,8 +758,8 @@ def fire_gate_invalid(plan, config) -> Iterable[Finding]:
             "every sub-batch dispatch pays the full fire/top-n select "
             "subgraph (one dominant sort) whether or not any window "
             "can fire — K dispatches per logical batch pay it K times, "
-            "the measured §8.6 throughput tax that made sub-batching "
-            "trade throughput for p99",
+            "the throughput tax that made sub-batching trade throughput "
+            "for p99",
             fix="leave pipeline.fire-gate true (committed output is "
                 "byte-identical; false exists as the A/B measurement "
                 "axis), or run sub-batches=1 if the gate must stay off")

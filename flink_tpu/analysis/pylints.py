@@ -1,7 +1,7 @@
 """Repo AST lints — pure-stdlib ``ast`` pass over the codebase itself.
 
 The runtime's correctness leans on conventions no unit test can see
-whole: jit kernels must stay trace-pure (PROFILE §8.1's design rules
+whole: jit kernels must stay trace-pure (the kernels' design rules
 exist because host round-trips inside kernels silently retrace or
 pin stale values), ``faults.fire`` literals must match the registry in
 ``faults.py`` (a drifted literal = a chaos plan that injects nothing),

@@ -263,7 +263,7 @@ class PipelineOptions:
     SUB_BATCHES = ConfigOption(
         "pipeline.sub-batches", 1,
         "Chained sub-batch device programs per LOGICAL microbatch (the "
-        "fire/emit decoupling knob, PROFILE.md §8.6): K > 1 splits each "
+        "fire/emit decoupling knob): K > 1 splits each "
         "logical batch into K equal sub-batch steps with watermark "
         "advances, fire dispatches, and drain deliveries interleaved at "
         "sub-batch boundaries — a fired window's rows become "
@@ -279,19 +279,19 @@ class PipelineOptions:
         "device folds K partial batches instead of one.")
     FIRE_GATE = ConfigOption(
         "pipeline.fire-gate", True,
-        "Fire-gated dispatch (PROFILE.md §12): the fused/devgen step "
+        "Fire-gated dispatch: the fused/devgen step "
         "programs run the fire/top-n/ring-append subgraph — and the "
         "pane purge — under a device-side conditional keyed on the "
         "dispatch header's window-end list, so a sub-batch in which no "
         "window can fire skips the dominant select sort instead of "
-        "paying it every dispatch (the §8.6 sub-batch throughput tax). "
+        "paying it every dispatch (the sub-batch throughput tax). "
         "Committed output is byte-identical either way (the ungated "
         "subgraph is a provable no-op on a fireless step); false "
         "restores the unconditional pre-gate programs (the A/B axis).")
     READINESS = ConfigOption(
         "pipeline.readiness", "piggyback",
         "How ingest backpressure learns that an in-flight device step "
-        "completed (PROFILE.md §8.3 lever a / §12). 'piggyback' "
+        "completed. 'piggyback' "
         "(default): every fused/devgen dispatch announces a tiny "
         "per-step output (the devgen stats vector / the fused kernel's "
         "emit-ring head row) with copy_to_host_async at dispatch, and "
@@ -310,8 +310,8 @@ class PipelineOptions:
         "summary to <dir>/profile_summary.json (flink_tpu/obs/"
         "profiling.py; the summary also rides JobResult.metrics under "
         "'profile.trace_summary'). The first-class seam for naming "
-        "per-op device costs that black-box bisection cannot (PROFILE."
-        "md §8.5). Empty = off (zero overhead).")
+        "per-op device costs that black-box bisection cannot. Empty = "
+        "off (zero overhead).")
     PROFILE_STEPS = ConfigOption(
         "pipeline.profile-steps", 8,
         "Logical batches captured inside the jax.profiler.trace window "
@@ -405,7 +405,7 @@ class LogOptions:
         "until a batch holds at least this many rows before entering "
         "the pipeline — small sealed blocks otherwise starve the "
         "device path with tiny dispatches (the backfill bench's "
-        "dominant cost on this container, PROFILE.md §11). Replay "
+        "dominant cost on a CPU container). Replay "
         "positions advance at merged-batch boundaries and stay "
         "checkpoint-exact. 0 = per-block reads (the legacy "
         "granularity).")
@@ -797,8 +797,8 @@ class HostOptions:
         "Worker threads of the driver's shared host pool "
         "(flink_tpu/parallel/hostpool.py) running the host-resident "
         "operator paths: the key-sharded session span registry, the "
-        "pane-partitioned spill store, and the chunked windowAll fold "
-        "(PROFILE.md §9). 1 = the exact serial path (no pool threads; "
+        "pane-partitioned spill store, and the chunked windowAll fold. "
+        "1 = the exact serial path (no pool threads; "
         "keeps single-core benchmark numbers reproducible). Default "
         "min(4, os.cpu_count()); the plan analyzer warns on values < 1 "
         "or beyond os.cpu_count() (HOST_PARALLELISM_INVALID).")
@@ -806,8 +806,8 @@ class HostOptions:
         "host.fold-chunk-records", 1 << 18,
         "Batch-size floor (and chunk size) of the host spill store's "
         "tree-reduction fold: batches below it absorb in one pass "
-        "(pool dispatch overhead would exceed the fold, PROFILE.md "
-        "§9.2); at or above it the batch splits into chunks of this "
+        "(pool dispatch overhead would exceed the fold); at or above "
+        "it the batch splits into chunks of this "
         "many records whose pane partials combine in chunk order. The "
         "chunk size is independent of host.parallelism, so the "
         "reduction tree — and the output bytes — do not change with "

@@ -313,7 +313,7 @@ class DcnExchange:
         for j in sorted(self._out):
             faults.fire("dcn.send", exc=ConnectionError, peer=j)
             # encode IN the worker, not here: the per-array CRC pass is
-            # the dominant per-byte cost (PROFILE.md §10) and runs
+            # the dominant per-byte cost and runs
             # GIL-free — on the caller it would serialize all N-1
             # outbound checksums on one thread, exactly what the
             # worker fan-out exists to overlap. An encode failure

@@ -7,8 +7,8 @@ never a per-record self-describing document. The v0 exchange here
 shipped each step as a checkpoint-blobformat payload: one json.dumps +
 json.loads per frame per peer per step, a bytearray rebuild of the
 whole payload on encode, and base64 for anything non-array. Fine for
-correctness, ~133 MB/s loopback (VERDICT row 53) — an order of
-magnitude under what the socket can move.
+correctness, slow on the wire (~133 MB/s loopback on a CPU
+container) — an order of magnitude under what the socket can move.
 
 v1 is a fixed header + raw CRC'd array sections, built for the
 exchange's actual payload shape (framework-built numeric arrays plus a
@@ -62,7 +62,7 @@ from flink_tpu import faults
 # GIL-free CRC-32 (bit-identical to zlib.crc32, codec.cc slice-by-8):
 # per-peer I/O threads checksum frames CONCURRENTLY — zlib's GIL-held
 # pass would serialize every checksum in the process and cost more
-# than the whole legacy wire at 1MB payloads (measured; PROFILE.md §10)
+# than the whole legacy wire at 1MB payloads (CPU container)
 from flink_tpu.native_codec import crc32 as _crc32
 
 MAGIC = b"DCNB"

@@ -424,8 +424,8 @@ class LogSource(Source):
         # log.* option defaults — direct construction and from_config
         # agree): zero-copy mmap decode, read batches COALESCED to
         # batch_records rows (small on-disk blocks otherwise starve
-        # the device pipeline with tiny dispatches — the measured 2.6x
-        # on the backfill bench, PROFILE.md §11), one merged batch of
+        # the device pipeline with tiny dispatches — 2.6x on the
+        # backfill bench, CPU container), one merged batch of
         # readahead decoded while the pipeline consumes the previous
         if batch_records < 0:
             raise LogError(

@@ -13,6 +13,8 @@ must route to the same key shard.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 from typing import List, Optional, Tuple
@@ -21,53 +23,106 @@ import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_REPO, "native", "codec.cc")
-_SO = os.path.join(_REPO, "native", "libflinktpucodec.so")
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+# why the library is not loaded (build stderr / OSError / missing
+# symbol); None while untried or once loaded — see unavailable_reason()
+_reason: Optional[str] = None
 
 
-def build(force: bool = False) -> bool:
-    """Compile the codec .so (g++ -O3). Returns success. A .so older
-    than the source is rebuilt."""
-    if os.path.exists(_SO) and not force:
-        if not os.path.exists(_SRC):
-            return True  # prebuilt-only deployment: nothing to compare
-        if os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-            return True
+class CodecBuildError(RuntimeError):
+    """The codec library could not be built or loaded; the message is
+    the compiler's stderr, the loader's OSError or the missing symbol."""
+
+
+def library_path(src: str = _SRC) -> str:
+    """Where the library built from ``src`` lives. The name carries a
+    hash of the source's CONTENT, so a library is only ever loaded for
+    the exact ``codec.cc`` beside it — a ``.so`` carried over from
+    another tree or machine (whatever its mtime) has another name and
+    is never picked up."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(os.path.dirname(src),
+                        f"libflinktpucodec-{digest}.so")
+
+
+def build_library(src: str = _SRC, cxx: str = "g++") -> str:
+    """Path of the shared library built from ``src``, compiling it
+    (``cxx -O3``) unless the library named for this source content is
+    already there. Raises :class:`CodecBuildError` with the reason."""
+    try:
+        so = library_path(src)
+    except OSError as e:
+        raise CodecBuildError(f"cannot read codec source: {e}") from e
+    if os.path.exists(so):
+        return so
+    # compile beside the target and rename into place: a concurrent
+    # process never dlopens a half-written file
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO],
+            [cxx, "-O3", "-shared", "-fPIC", "-std=c++17", src, "-o", tmp],
             check=True, capture_output=True)
-        return True
-    except (subprocess.CalledProcessError, FileNotFoundError):
-        return False
+        os.replace(tmp, so)
+    except FileNotFoundError as e:
+        raise CodecBuildError(f"compiler not found: {e}") from e
+    except subprocess.CalledProcessError as e:
+        raise CodecBuildError(
+            f"{cxx} exited {e.returncode} on {src}:\n"
+            + e.stderr.decode("utf-8", "replace")) from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    # libraries of earlier source contents are dead weight now
+    for old in glob.glob(os.path.join(os.path.dirname(so),
+                                      "libflinktpucodec*.so")):
+        if old != so:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return so
 
 
-def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
-    if _lib is not None or _tried:
-        return _lib
-    _tried = True
-    # build() is a fast no-op when the .so is fresh; calling it
-    # unconditionally also rebuilds a STALE .so (older than codec.cc) —
-    # loading one would fail symbol binding below
-    if not build():
-        return None
+def load_library(so: str) -> ctypes.CDLL:
+    """dlopen ``so`` and declare every entry point's signature. Raises
+    :class:`CodecBuildError` naming the loader error or the symbol the
+    library lacks."""
     try:
-        lib = ctypes.CDLL(_SO)
-    except OSError:
-        return None
+        lib = ctypes.CDLL(so)
+    except OSError as e:
+        raise CodecBuildError(f"cannot load {so}: {e}") from e
     i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
     f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
     try:
         _bind(lib, i64p, f32p)
-    except AttributeError:
-        # stale prebuilt .so missing newer symbols and no compiler to
-        # rebuild: fall back to numpy rather than crash callers
-        return None
-    _lib = lib
+    except AttributeError as e:
+        raise CodecBuildError(f"{so} lacks a symbol: {e}") from e
+    return lib
+
+
+def _load() -> Optional[ctypes.CDLL]:
+    global _lib, _tried, _reason
+    if _lib is not None or _tried:
+        return _lib
+    _tried = True
+    try:
+        _lib = load_library(build_library(_SRC))
+    except CodecBuildError as e:
+        # the numpy fallbacks keep an unbuilt package working; the
+        # reason stays retrievable (unavailable_reason)
+        _reason = str(e)
     return _lib
+
+
+def unavailable_reason() -> Optional[str]:
+    """Why ``native_available()`` is False — the build's stderr, the
+    loader's error or the missing symbol; None when the library is
+    loaded."""
+    _load()
+    return _reason
 
 
 def _bind(lib, i64p, f32p) -> None:

@@ -5,9 +5,8 @@ TaskManager (rest/profiler endpoints) — here the accelerator analogue:
 wrap N WARM driver steps in ``jax.profiler.trace`` and reduce the
 emitted Chrome-trace events to a per-op device-time summary, so a
 "which op costs what" question is answered by measurement instead of
-black-box bisection (the PROFILE.md §8.5 mandate: the ~40ms fused-step
-composition anomaly did not yield to A/B splitting — only a real
-per-op trace can name it).
+black-box bisection (a composition cost inside one fused program does
+not yield to A/B splitting — only a per-op trace can name it).
 
 Two artifacts per profiled run, both under the configured directory:
 

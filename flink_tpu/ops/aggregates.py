@@ -51,8 +51,7 @@ class LaneAggregate:
     finalize: Callable[[jax.Array, jax.Array, jax.Array, jax.Array], Arrays]
     name: str = "agg"
     # record fields ``lift`` reads. The operator uploads ONLY these to
-    # the device — on a remote-attached chip the host→device link is the
-    # throughput ceiling, so unused lanes must never ride it (count()
+    # the device — unused lanes never ride the host→device link (count()
     # uploads nothing but the packed slot ids). None = unknown: keep all.
     fields: Optional[Tuple[str, ...]] = None
     # When every sum lane is the IDENTITY lift of one record field

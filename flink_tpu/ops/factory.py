@@ -49,7 +49,7 @@ class OperatorBuildContext:
     # None = the declared config default
     fold_chunk_records: Optional[int] = None
     # pipeline.fire-gate: device-side conditional around the fire/top-n/
-    # ring-append subgraph of the fused step programs (PROFILE.md §12)
+    # ring-append subgraph of the fused step programs
     fire_gate: bool = True
     # pipeline.readiness: 'piggyback' (throttle consumes an announced
     # per-step token) or 'probe' (legacy is_ready spin)

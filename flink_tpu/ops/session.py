@@ -21,7 +21,7 @@ static pane layout, so the decomposition is:
   Python list of dataclasses per key and died at exactly that scale).
 - fired sessions stay in the registry until allowed lateness expires so
   late records re-open/merge and re-fire (late firing semantics).
-- the registry is **key-sharded onto the host pool** (PROFILE.md §9.1):
+- the registry is **key-sharded onto the host pool**:
   under ``host.parallelism = W > 1`` it splits into W independent span
   stores (``key % W`` — the key-group discipline), and the per-shard
   merge/fire/expiry passes run as pool tasks. Sessions never merge
@@ -151,7 +151,7 @@ class SessionOperator:
         self.watermark = LONG_MIN
         self.late_records = 0
         self.state_version = 0
-        # key-sharded registry (PROFILE §9.1): W independent stores at
+        # key-sharded registry: W independent stores at
         # host.parallelism = W; exactly one (the serial path) at W = 1
         self._pool = (host_pool if host_pool is not None
                       and host_pool.parallelism > 1 else None)
@@ -307,8 +307,9 @@ class SessionOperator:
         would cost a round trip per batch)."""
         import jax
 
-        cpu = jax.local_devices(backend="cpu")[0]
-        with jax.default_device(cpu):
+        from flink_tpu.hostsync import host_cpu_device
+
+        with jax.default_device(host_cpu_device()):
             import jax.numpy as jnp
 
             s, mx, mn = self.agg.lift_masked(
@@ -504,9 +505,10 @@ class SessionOperator:
     def _emit(self, cols: Tuple[np.ndarray, ...]) -> Dict[str, np.ndarray]:
         import jax
 
+        from flink_tpu.hostsync import host_cpu_device
+
         key, start, last, sums, maxs, mins, count = cols[:7]
-        cpu = jax.local_devices(backend="cpu")[0]
-        with jax.default_device(cpu):
+        with jax.default_device(host_cpu_device()):
             import jax.numpy as jnp
 
             res = self.agg.finalize(

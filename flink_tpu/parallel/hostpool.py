@@ -1,18 +1,18 @@
 """Shared host worker-pool plane — the multi-core host operator runtime.
 
 The three host-resident operator paths (session span registry, windowAll
-pane fold, host spill store) all serialized on one core (PROFILE.md §9,
-VERDICT r05 weak #7 / missing #8). This module is the shared plane they
+pane fold, host spill store) all serialized on one core. This module
+is the shared plane they
 scale on: ONE ``HostPool`` per driver, sized by ``host.parallelism``,
 handed to every operator that owns host-parallel work. The heavy passes
 are numpy-dominated and release the GIL inside C loops, so a thread
 pool (no pickling, shared address space) is the right executor shape.
 
-Determinism contract (the §9.4 measurement/correctness gate):
+Determinism contract (the measurement/correctness gate):
 
 - ``host.parallelism = 1`` is the EXACT serial path: no executor is
   created, tasks run inline on the caller thread in submission order —
-  the single-core numbers in PROFILE.md stay reproducible.
+  single-core numbers stay reproducible.
 - At any parallelism, ``run_tasks`` returns results in SUBMISSION
   order, so callers combine partials in a schedule-independent order.
   Every client combine is associative and exact on its lane monoids
@@ -180,5 +180,5 @@ class HostPool:
 
 
 def default_parallelism() -> int:
-    """The declared default: ``min(4, os.cpu_count())`` (PROFILE §9.4)."""
+    """The declared default: ``min(4, os.cpu_count())``."""
     return min(4, os.cpu_count() or 1)

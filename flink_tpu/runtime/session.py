@@ -7,8 +7,9 @@ onto shared workers (slot sharing + quotas, §3.4), and an active
 resource manager grows/shrinks the fleet with demand. The per-job
 submit path (``python -m flink_tpu run``) spins a private runtime per
 job; this module is the shared-service alternative the ROADMAP's
-"millions of users" north star needs — many jobs per chip, because the
-measured headline path leaves the chip ~50% idle (PROFILE.md §8.3).
+"millions of users" north star needs — many jobs per chip, on the
+premise that one job leaves the chip idle part of the time (idle share
+on the current chip: not measured).
 
 Pieces:
 
@@ -632,7 +633,9 @@ class LocalSessionCluster:
     """Dispatcher + RPC server + N in-process runners, one object —
     the MiniCluster analogue for session mode. Everything rides the
     real RPC plane (runner registration, heartbeats, deploy pushes),
-    only the processes are threads."""
+    only the processes are threads — which is also why N local runners
+    can share one chip: they are ONE process, and a chip belongs to one
+    process at a time."""
 
     def __init__(self, config: Optional[Configuration] = None,
                  runners: int = 1, runner_prefix: str = "local",

@@ -1,33 +1,16 @@
-"""jax API compatibility — one import site for symbols that moved
-between jax releases.
+"""The jax mesh symbols the package uses, in one import site.
 
-``shard_map`` was promoted from ``jax.experimental.shard_map`` to
-``jax.shard_map``; containers pin either side of the move. Every
-shard_map consumer (ops/window.py, exchange parity tests, bench_micro)
-imports it from here, and the tier-1 capability probe in
-tests/conftest.py keys on :data:`HAS_SHARD_MAP` — mesh tests skip
-instead of erroring when NEITHER spelling exists.
+Written for the one installation there is (jax 0.9.0): ``jax.shard_map``
+and ``jax.experimental.mesh_utils.create_hybrid_device_mesh`` both exist;
+every shard_map consumer (ops/window.py, exchange parity tests,
+bench_micro) imports it from here.
 """
 from __future__ import annotations
 
 import jax
+from jax.experimental.mesh_utils import create_hybrid_device_mesh
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # pragma: no cover - no shard_map at all
-        shard_map = None
-
-HAS_SHARD_MAP = shard_map is not None
-
-try:
-    from jax.experimental.mesh_utils import create_hybrid_device_mesh
-except ImportError:  # pragma: no cover - older mesh_utils layout
-    create_hybrid_device_mesh = None
-
-HAS_HYBRID_MESH = create_hybrid_device_mesh is not None
+shard_map = jax.shard_map
 
 
 def hybrid_device_mesh(mesh_shape, dcn_mesh_shape, devices):
@@ -39,16 +22,14 @@ def hybrid_device_mesh(mesh_shape, dcn_mesh_shape, devices):
 
     The jax helper groups devices by process granule; on a
     single-granule fleet (one process's local devices, or the virtual
-    CPU mesh) it rejects multi-slice shapes, so any single-granule —
-    or shim-less — call falls back to a plain C-order reshape, which
-    is exactly the hybrid layout when the device list is already
-    slice-major."""
+    CPU mesh) it rejects multi-slice shapes, so a single-granule call
+    falls back to a plain C-order reshape, which is exactly the hybrid
+    layout when the device list is already slice-major."""
     import numpy as np
 
     devices = list(devices)
     shape = tuple(d * i for d, i in zip(dcn_mesh_shape, mesh_shape))
-    if create_hybrid_device_mesh is not None and any(
-            d > 1 for d in dcn_mesh_shape):
+    if any(d > 1 for d in dcn_mesh_shape):
         granules = {getattr(d, "process_index", 0) for d in devices}
         if len(granules) > 1:
             return create_hybrid_device_mesh(

@@ -531,8 +531,8 @@ int64_t ingest_combine(
 // addressing table above) + event-time pane + late/refire accounting +
 // (slot, ring-column) histogram in ONE scan over (keys, ts) — the
 // separate ht_lookup pass wrote and re-read an 8 MB slots array per
-// 2^20 batch on the single-core bench host (~12ms); folding the probe
-// into the scan removes that traffic entirely (PROFILE.md §7.4 lever a).
+// 2^20 batch; folding the probe into the scan removes that traffic
+// entirely.
 //
 // Records whose key is NOT in the table are skipped and their indices
 // written to out_miss (caller registers the new keys, then re-invokes
@@ -691,8 +691,8 @@ static uint32_t crc32_slice8(const uint8_t* p, int64_t len, uint32_t init) {
 // domain; a load-time SELF-CHECK against the table path (below)
 // guards the constants — a mismatch disables this path entirely, so
 // a wrong constant can only ever cost speed, never correctness.
-// Measured here: slice-by-8 ~1.8 GB/s, PCLMUL ~10+ GB/s — the log
-// tier's decode bandwidth is CRC-bound without it (PROFILE.md §11).
+// Measured on a CPU container: slice-by-8 ~1.8 GB/s, PCLMUL ~10+ GB/s —
+// the log tier's decode bandwidth is CRC-bound without it.
 #include <immintrin.h>
 
 __attribute__((target("pclmul,sse4.1")))

@@ -233,7 +233,7 @@ def _subbatch_indivisible(tmp_path):
 def _fire_gate_off_under_subbatching(tmp_path):
     # gating forced off under the config that needs it: K sub-batch
     # dispatches per logical batch each pay the full fire/top-n select
-    # sort (the §8.6 tax)
+    # sort (the sub-batching tax)
     return analyze_config(Configuration({
         "pipeline.fire-gate": False,
         "pipeline.sub-batches": 4}))

@@ -1,5 +1,5 @@
 """Micro-benchmark suite smoke: every metric runs at toy size and
-emits a parseable line (tier-7 analogue, SURVEY §5; BASELINE.md list)."""
+emits a parseable line (tier-7 analogue, SURVEY §5)."""
 import json
 
 import pytest

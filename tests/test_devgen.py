@@ -100,6 +100,54 @@ class TestGoldenEquality:
         assert got_dev == got_host and len(got_dev) > 0
 
 
+class TestPlaneCounters:
+    """JobResult.metrics says which plane a device-generator source's
+    batches took — no fallback to the host hides the device."""
+
+    def test_chained_job_counts_every_batch_through_the_chain(self):
+        cfg = _cfg()
+        _, res = _run_q5(bid_stream_device, cfg)
+        assert res.metrics["device_chain_attached"] == 1
+        assert res.metrics["device_chain_batches"] == cfg.n_batches
+        assert res.metrics["device_chain_fallback_batches"] == 0
+        _, host = _run_q5(bid_stream, cfg)
+        assert host.metrics["device_chain_attached"] == 0
+        assert host.metrics["device_chain_batches"] == 0
+
+    def test_gate_closed_batches_are_counted_as_fallbacks(self, monkeypatch):
+        from flink_tpu.ops.window import WindowOperator
+
+        cfg = _cfg()
+        golden, _ = _run_q5(bid_stream, cfg)
+        real = WindowOperator.process_batch_device
+
+        def every_other(self, batch_index):
+            # a devgen gate that closes on odd batches
+            return batch_index % 2 == 0 and real(self, batch_index)
+
+        monkeypatch.setattr(WindowOperator, "process_batch_device",
+                            every_other)
+        got, res = _run_q5(bid_stream_device, cfg)
+        assert got == golden
+        assert res.metrics["device_chain_attached"] == 1
+        assert res.metrics["device_chain_batches"] == cfg.n_batches // 2
+        assert (res.metrics["device_chain_fallback_batches"]
+                == cfg.n_batches // 2)
+        assert res.metrics["batches"] >= cfg.n_batches
+
+    def test_refused_attach_is_visible(self, monkeypatch):
+        from flink_tpu.ops.window import WindowOperator
+
+        monkeypatch.setattr(WindowOperator, "attach_device_source",
+                            lambda self, spec: False)
+        cfg = _cfg(n_batches=2)
+        got, res = _run_q5(bid_stream_device, cfg)
+        assert got == _run_q5(bid_stream, cfg)[0]
+        assert res.metrics["device_chain_attached"] == 0
+        assert res.metrics["device_chain_batches"] == 0
+        assert res.metrics["device_chain_fallback_batches"] == 0
+
+
 class TestAttachGate:
     def test_domain_larger_than_registered_prefix_refused(self):
         # a restored directory holding only an identity PREFIX of the

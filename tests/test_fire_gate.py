@@ -1,5 +1,4 @@
-"""Fire-gated dispatch + piggybacked completion (ISSUE 15, PROFILE.md
-§12).
+"""Fire-gated dispatch + piggybacked completion (ISSUE 15).
 
 The contract under test, exactly as shipped:
 

@@ -16,7 +16,7 @@ from flink_tpu.api.windowing import SlidingEventTimeWindows, TumblingEventTimeWi
 from flink_tpu.config import Configuration
 from flink_tpu.time.watermarks import WatermarkStrategy
 
-pytestmark = pytest.mark.shard_map  # device-mesh suite: skipped when shard_map is unavailable
+pytestmark = pytest.mark.shard_map  # device-mesh suite
 
 
 def make_env(mesh=None, extra=None):

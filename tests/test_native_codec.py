@@ -10,8 +10,7 @@ from flink_tpu.records import hash_string_key
 
 class TestNativeCodec:
     def test_builds(self):
-        assert nc.build(), "g++ build failed"
-        assert nc.native_available()
+        assert nc.native_available(), nc.unavailable_reason()
 
     def test_tokenize_hash_matches_python(self):
         lines = ["to be or not to be", "  leading  and   double spaces ",
@@ -153,3 +152,83 @@ class TestNativeHashTable:
             ks = rng.integers(0, 1_000, 5_000)
             assert np.array_equal(d1.assign(ks), d2.assign(ks))
         assert d1.num_keys() == d2.num_keys()
+
+
+class TestContentKeyedBuild:
+    """The library is always the one built from the source beside it:
+    the rebuild decision is keyed on the source's CONTENT (a hash in the
+    .so name), never on mtimes, and a failed build keeps its reason."""
+
+    TINY = 'extern "C" int answer() { return 42; }\n'
+
+    def _src(self, tmp_path, text=None):
+        src = tmp_path / "codec.cc"
+        src.write_text(self.TINY if text is None else text)
+        return str(src)
+
+    def test_touching_mtimes_changes_nothing(self, tmp_path):
+        import os
+
+        src = self._src(tmp_path)
+        so = nc.build_library(src)
+        before = os.stat(so)
+        # the staleness an mtime rule would act on, both ways round
+        os.utime(src, (before.st_mtime + 3600, before.st_mtime + 3600))
+        assert nc.build_library(src) == so
+        os.utime(so, (1, 1))
+        assert nc.build_library(src) == so
+        after = os.stat(so)
+        assert after.st_ino == before.st_ino  # never recompiled
+
+    def test_changing_the_source_rebuilds_under_a_new_name(self, tmp_path):
+        import ctypes
+        import os
+
+        src = self._src(tmp_path)
+        old = nc.build_library(src)
+        with open(src, "a") as f:
+            f.write('extern "C" int more() { return 7; }\n')
+        new = nc.build_library(src)
+        assert new != old and os.path.exists(new)
+        assert not os.path.exists(old)  # superseded library swept
+        assert ctypes.CDLL(new).more() == 7
+
+    def test_foreign_library_is_not_picked_up(self, tmp_path):
+        import os
+        import shutil
+
+        src = self._src(tmp_path)
+        real = nc.build_library(src)
+        # a .so copied in from another tree: right directory, wrong name
+        foreign = str(tmp_path / "libflinktpucodec.so")
+        shutil.copy(real, foreign)
+        os.unlink(real)
+        assert nc.build_library(src) == real != foreign
+        assert os.path.exists(real)
+
+    def test_failing_compiler_leaves_a_retrievable_reason(self, tmp_path):
+        src = self._src(tmp_path, "this is not C++\n")
+        with pytest.raises(nc.CodecBuildError, match="error"):
+            nc.build_library(src)
+        with pytest.raises(nc.CodecBuildError, match="compiler not found"):
+            nc.build_library(self._src(tmp_path), cxx="no-such-compiler")
+        with pytest.raises(nc.CodecBuildError, match="cannot read"):
+            nc.build_library(str(tmp_path / "absent.cc"))
+
+    def test_library_lacking_a_symbol_is_refused_with_its_name(self, tmp_path):
+        so = nc.build_library(self._src(tmp_path))
+        with pytest.raises(nc.CodecBuildError, match="tokenize_hash"):
+            nc.load_library(so)
+
+    def test_package_keeps_the_reason_and_falls_back(self, tmp_path,
+                                                     monkeypatch):
+        # an unbuildable source: the numpy fallbacks serve, and the
+        # compiler's words are kept instead of a silent None
+        monkeypatch.setattr(nc, "_SRC", self._src(tmp_path, "int x = ;\n"))
+        monkeypatch.setattr(nc, "_lib", None)
+        monkeypatch.setattr(nc, "_tried", False)
+        monkeypatch.setattr(nc, "_reason", None)
+        assert not nc.native_available()
+        assert "error" in nc.unavailable_reason()
+        assert nc.hash_keys_native(np.arange(4)) is None
+        assert nc.hash_strings(["a"]).tolist() == [hash_string_key("a")]

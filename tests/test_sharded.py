@@ -16,7 +16,7 @@ from flink_tpu.parallel.mesh import AXIS, make_mesh_plan
 from flink_tpu.utils.jaxcompat import shard_map
 
 
-pytestmark = pytest.mark.shard_map  # device-mesh suite: skipped when shard_map is unavailable
+pytestmark = pytest.mark.shard_map  # device-mesh suite
 
 
 @pytest.fixture(scope="module")

@@ -325,3 +325,29 @@ class TestSpillStoreUnit:
         st.purge_below(2)
         assert st.fire([2], 2, 1000, 0, 2000) is None
         assert st.records_spilled == 4
+
+
+class TestHostPinnedLaneMath:
+    """Spilled keys (and session segments) do their lane math on JAX's
+    CPU backend; when the platform list hides it (JAX_PLATFORMS=tpu)
+    both sites fail the same loud way instead of one raising and the
+    other silently moving the work to the accelerator."""
+
+    def test_hidden_cpu_backend_raises_naming_the_setting(self, monkeypatch):
+        import jax
+
+        from flink_tpu.ops.session import SessionOperator
+
+        def no_cpu(backend=None, **_kw):
+            raise RuntimeError(f"Unknown backend {backend}")
+
+        monkeypatch.setattr(jax, "local_devices", no_cpu)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        store = HostSpillStore(aggregates.sum_of("v"))
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS='tpu'"):
+            store.absorb(np.arange(4), np.zeros(4, np.int64),
+                         {"v": np.ones(4, np.float32)})
+        sess = SessionOperator(1000, aggregates.sum_of("v"))
+        with pytest.raises(RuntimeError, match="tpu,cpu"):
+            sess.process_batch(np.arange(4), np.arange(4, dtype=np.int64),
+                               {"v": np.ones(4, np.float32)})
