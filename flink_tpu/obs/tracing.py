@@ -1,4 +1,5 @@
-"""Tracing: spans for checkpoint/recovery + on-demand thread sampling.
+"""Tracing: spans for checkpoint/recovery, the data path's phase clock,
+and on-demand thread sampling.
 
 ref: SURVEY §6.1 — flink-core ``traces/`` Span/TraceReporter (emitted
 for checkpointing and job recovery from CheckpointStatsTracker), and
@@ -13,6 +14,17 @@ driver loop and checkpoint threads at human frequencies, never per
 record. Reporters get each completed span synchronously (the
 TraceReporter seam); the REST server exposes the ring at /traces and
 aggregated thread stacks at /flamegraph.
+
+The data path has a ``PhaseClock`` per run (the driver's; a bare
+operator has its own): per thread at most ONE phase is open, so a
+thread's time is a flat partition into named leaves — seconds, count
+and longest single interval each. Tracer spans and phases alike hold a
+``jax.profiler.TraceAnnotation`` open for their interval: whenever a
+profiler session runs (``pipeline.profile-dir``, ``jax.profiler
+.start_trace``) they are host events of ITS trace, on the device
+trace's clock. There is no switch: with no session a span is one
+object and two calls. Phases are per batch or per fire, never per
+record.
 """
 from __future__ import annotations
 
@@ -23,19 +35,147 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
-__all__ = ["Span", "Tracer", "tracer", "sample_threads"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["PhaseClock", "Span", "Tracer", "tracer", "sample_threads"]
+
+
+def _annotation(name: str, attributes: Dict[str, Any]):
+    """An ENTERED ``jax.profiler.TraceAnnotation``: recorded by the
+    profiler itself when a session is running, inert otherwise."""
+    ann = TraceAnnotation(name, **attributes)
+    ann.__enter__()
+    return ann
+
+
+class _ThreadPhases:
+    """One thread's side of a PhaseClock: the open phase and this
+    thread's totals (single writer; ``PhaseClock.snapshot`` merges)."""
+
+    __slots__ = ("name", "t0", "ann", "stats")
+
+    def __init__(self) -> None:
+        self.name: Optional[str] = None
+        self.t0 = 0.0
+        self.ann = None
+        # name -> [seconds, count, longest_s, longest_began]
+        self.stats: Dict[str, List[float]] = {}
+
+
+class _PhaseSpan:
+    """``with clock.span(name) as sp:`` — ``name`` for the block, then
+    the phase that was open before it again (none, off the loop thread).
+    ``sp.t0`` / ``sp.t1`` are the clock readings at its ends."""
+
+    __slots__ = ("_clock", "_name", "_attrs", "_prev", "t0", "t1")
+
+    def __init__(self, clock: "PhaseClock", name: str, attrs) -> None:
+        self._clock, self._name, self._attrs = clock, name, attrs
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "_PhaseSpan":
+        self._prev, _, self.t0 = self._clock._switch(self._name, self._attrs)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1 = self._clock._switch(self._prev, {})[2]
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
+class PhaseClock:
+    """Where each thread of one run spends its wall time.
+
+    ``phase(name)`` closes the phase open on the CALLING thread and
+    opens ``name`` (and does nothing when ``name`` is the open one);
+    ``stop()`` closes it and opens none; ``span(name)``
+    is ``name`` for a ``with`` block and the previous phase after it. No
+    phase encloses another, so a thread's phases sum to its wall time
+    between its first ``phase()`` and its ``stop()``."""
+
+    def __init__(self) -> None:
+        self.t_start = time.perf_counter()
+        self._local = threading.local()
+        self._threads: List[_ThreadPhases] = []
+        self._lock = threading.Lock()
+
+    def _mine(self) -> _ThreadPhases:
+        try:
+            return self._local.phases
+        except AttributeError:
+            tp = self._local.phases = _ThreadPhases()
+            with self._lock:
+                self._threads.append(tp)
+            return tp
+
+    def _switch(self, name: Optional[str], attrs: Dict[str, Any]):
+        """-> (the phase that was open, when it opened, now)."""
+        tp = self._mine()
+        now = time.perf_counter()
+        prev, t_open = tp.name, tp.t0
+        if prev == name and not attrs:
+            return prev, t_open, now    # already open: one interval
+        if prev is not None:
+            tp.ann.__exit__(None, None, None)
+            dt = now - t_open
+            st = tp.stats.get(prev)
+            if st is None:
+                tp.stats[prev] = [dt, 1, dt, t_open]
+            else:
+                st[0] += dt
+                st[1] += 1
+                if dt > st[2]:
+                    st[2], st[3] = dt, t_open
+        tp.name, tp.t0 = name, now
+        tp.ann = None if name is None else _annotation(name, attrs)
+        return prev, t_open, now
+
+    def phase(self, name: str, **attributes: Any) -> float:
+        """Switch the calling thread to ``name``; returns the clock
+        reading (``time.perf_counter()``) of the switch."""
+        return self._switch(name, attributes)[2]
+
+    def stop(self) -> float:
+        """Close the calling thread's open phase, if any; returns the
+        clock reading, as ``phase`` does."""
+        return self._switch(None, {})[2]
+
+    def span(self, name: str, **attributes: Any) -> _PhaseSpan:
+        return _PhaseSpan(self, name, attributes)
+
+    def open_phase(self) -> Optional[str]:
+        """The phase open on the calling thread."""
+        return self._mine().name
+
+    def snapshot(self) -> Dict[str, Dict[str, float]]:
+        """name -> ``seconds``, ``count``, ``longest_ms`` and
+        ``longest_at_s`` (when that interval began, seconds after the
+        clock was made) over all threads' CLOSED intervals."""
+        with self._lock:
+            threads = list(self._threads)
+        out: Dict[str, Dict[str, float]] = {}
+        for tp in threads:
+            for name, (secs, n, longest, began) in list(tp.stats.items()):
+                o = out.setdefault(name, {"seconds": 0.0, "count": 0,
+                                          "longest_ms": 0.0,
+                                          "longest_at_s": 0.0})
+                o["seconds"] += secs
+                o["count"] += n
+                if longest * 1e3 > o["longest_ms"]:
+                    o["longest_ms"] = longest * 1e3
+                    o["longest_at_s"] = began - self.t_start
+        return out
 
 
 @dataclasses.dataclass
 class Span:
     name: str
-    start: float
+    start: float                  # wall clock, what /traces shows
     end: Optional[float] = None
     attributes: Dict[str, Any] = dataclasses.field(default_factory=dict)
-
-    @property
-    def duration_ms(self) -> Optional[float]:
-        return None if self.end is None else (self.end - self.start) * 1e3
+    duration_ms: Optional[float] = None   # on the monotonic clock
 
     def to_dict(self) -> Dict[str, Any]:
         return {"name": self.name, "start": self.start,
@@ -50,6 +190,8 @@ class _SpanHandle:
     def __init__(self, trc: "Tracer", span: Span) -> None:
         self._trc = trc
         self.span = span
+        self._ann = _annotation(span.name, span.attributes)
+        self._t0 = time.perf_counter()
 
     def set(self, key: str, value: Any) -> "_SpanHandle":
         self.span.attributes[key] = value
@@ -61,6 +203,8 @@ class _SpanHandle:
     def __exit__(self, exc_type, exc, tb) -> None:
         if exc is not None:
             self.span.attributes["error"] = f"{type(exc).__name__}: {exc}"
+        self.span.duration_ms = (time.perf_counter() - self._t0) * 1e3
+        self._ann.__exit__(None, None, None)
         self._trc._finish(self.span)
 
 

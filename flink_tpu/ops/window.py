@@ -46,6 +46,7 @@ from jax.sharding import PartitionSpec as P
 
 from flink_tpu.api.windowing import WindowAssigner
 from flink_tpu.hostsync import ready_wait
+from flink_tpu.obs.tracing import PhaseClock
 from flink_tpu.utils.jaxcompat import shard_map
 from flink_tpu.ops.aggregates import LaneAggregate
 from flink_tpu.parallel.mesh import AXIS, MeshPlan
@@ -1213,14 +1214,17 @@ class WindowOperator:
         # never read a version older than the rows it must deliver.
         self._ring_versions: collections.deque = collections.deque(maxlen=4)
         self._ring_version_no = 0
-        # fire-cohort latency bookkeeping (driver emit_latency_ms): a
-        # (ring_version, dispatch_stamp) entry per row-carrying fire,
-        # popped to _delivered_stamps by the drain_ring call whose
+        # fire-cohort bookkeeping (the driver's "trace.fires" records
+        # and emit_latency_ms): a (ring_version, cohort) entry per
+        # row-carrying fire — the cohort (_fire_cohort) holds its window
+        # ends and its dispatch stamp — popped to _delivered_stamps,
+        # with the fetch's own stamps, by the drain_ring call whose
         # fetched version first makes those rows HOST-VISIBLE. The
-        # driver records one histogram sample per delivered cohort —
-        # without this, a drain poll that coalesces several sub-batch
-        # fires would attribute every row to the OLDEST marker's stamp
-        # and overstate p99 (under-reporting the sub-batch cadence win).
+        # driver stamps the sink and records one histogram sample per
+        # delivered cohort — without this, a drain poll that coalesces
+        # several sub-batch fires would attribute every row to the
+        # OLDEST marker's stamp and overstate p99 (under-reporting the
+        # sub-batch cadence win).
         # Both deques are bounded: in modes where nothing pops them
         # (the synchronous spill+top-n drain), old entries fall off —
         # lost samples, never lost rows.
@@ -1330,9 +1334,13 @@ class WindowOperator:
         # records dropped because the key directory shard was FULL —
         # always accounted, surfaced in metrics/JobResult (never silent)
         self.records_dropped_full: int = 0
-        # per-phase wall-time accumulators (seconds) — the profile the
-        # perf work is steered by; a few perf_counter calls per batch,
-        # so always on
+        # where the time goes: the phase clock (the driver hands over
+        # its run's; a bare operator keeps this one) names every stretch
+        # of process_batch / advance_watermark / the ring fetch, always
+        # on; prof holds COUNTERS (ring fetches and skips, batches that
+        # took a pre-aggregated upload) and the per-operator drain_fetch
+        # seconds (profile.opN.*), fed by the drain.fetch phase
+        self.phases = PhaseClock()
         self.prof: Dict[str, float] = collections.defaultdict(float)
 
         if mesh_plan is None:
@@ -1662,13 +1670,19 @@ class WindowOperator:
         # masking, drop accounting, min/max, refire candidates, and the
         # pre-agg histogram (the numpy path below makes ~6 full-array
         # passes — real milliseconds on the single-core bench host)
-        if (self.mesh_plan is None
-                and self._spill is None and self._preagg_lanes == ()
-                and (valid is None or bool(np.all(valid)))
-                and self._process_batch_fused(keys, ts)):
-            return
+        with self.phases.span("window.key_scan"):
+            if (self.mesh_plan is None
+                    and self._spill is None and self._preagg_lanes == ()
+                    and (valid is None or bool(np.all(valid)))
+                    and self._process_batch_fused(keys, ts)):
+                return
+            self._process_batch_general(keys, ts, data, valid)
+
+    def _process_batch_general(self, keys, ts, data, valid) -> None:
+        """The numpy lane of ``process_batch``: any aggregate, validity
+        mask, spill store or mesh."""
+        ph = self.phases.phase
         self._flush_stash()
-        t0 = time.perf_counter()
         self.state_version += 1
         keys = np.asarray(keys, dtype=np.int64)
         ts = np.asarray(ts, dtype=np.int64)
@@ -1717,10 +1731,7 @@ class WindowOperator:
                 self._refire.update(self.plan.late_refire_ends(
                     panes[late_ok], self._fired_below_end, self.watermark))
 
-        t1 = time.perf_counter()
-        self.prof["pb_host_pre"] += t1 - t0
         slots = self.directory.assign(keys)
-        self.prof["pb_assign"] += time.perf_counter() - t1
         bad = valid & (slots < 0)
         if bad.any():
             full = bad & (slots == KeyDirectory.FULL)
@@ -1741,14 +1752,11 @@ class WindowOperator:
             if bad.any():
                 account_full_drop(self, int(bad.sum()))
             valid = valid & ~bad & ~full
-        t2 = time.perf_counter()
         if self.mesh_plan is None and self._preagg_dispatch(
                 slots, panes, valid, data):
-            self.prof["pb_preagg"] += time.perf_counter() - t2
-            self._note_dispatch(self.state.counts[0, 0])
-            if not self.external_throttle:
-                self.throttle()
+            self._throttle_unless_external()
             return
+        ph("window.pack")
         from flink_tpu.records import device_cast
         # upload ONLY the lanes the aggregate reads: e.g. Q5's count()
         # needs no record fields at all
@@ -1768,18 +1776,16 @@ class WindowOperator:
             n_blocks = self.mesh_plan.n_devices if self.mesh_plan else 1
             dt = np.int32 if (n_blocks * self.layout.rows + 1) * ring < 2**31 else np.int64
             packed = packed.astype(dt, copy=False)
-        t3 = time.perf_counter()
-        self.prof["pb_pack"] += t3 - t2
         if self.mesh_plan is None:
             if local_split:
-                sc = split_encode(slots, (panes % ring).astype(np.uint8), valid)
-                self.state = self._apply_split(
-                    self.state, jnp.asarray(sc),
-                    {k: jnp.asarray(v) for k, v in data.items()})
-            else:
-                self.state = self._apply(
-                    self.state, jnp.asarray(packed),
-                    {k: jnp.asarray(v) for k, v in data.items()})
+                packed = split_encode(
+                    slots, (panes % ring).astype(np.uint8), valid)
+            ph("window.h2d")
+            dpacked = jnp.asarray(packed)
+            ddata = {k: jnp.asarray(v) for k, v in data.items()}
+            ph("window.step_dispatch")
+            self.state = (self._apply_split if local_split
+                          else self._apply)(self.state, dpacked, ddata)
         else:
             n_dev = self.mesh_plan.n_devices
             ov_total = None
@@ -1800,16 +1806,16 @@ class WindowOperator:
                         for k, v in dt_chunk.items()}
                 if self._split_upload:
                     pv = pk >= 0
-                    sc = split_encode(
+                    pk = split_encode(
                         np.where(pv, pk // ring, 0),
                         np.where(pv, pk % ring, 0).astype(np.uint8), pv)
-                    self.state, overflow = self._apply_sharded_split(
-                        self.state, jnp.asarray(sc),
-                        {k: jnp.asarray(v) for k, v in dt_chunk.items()})
-                else:
-                    self.state, overflow = self._apply_sharded(
-                        self.state, jnp.asarray(pk),
-                        {k: jnp.asarray(v) for k, v in dt_chunk.items()})
+                ph("window.h2d")
+                dpk = jnp.asarray(pk)
+                ddata = {k: jnp.asarray(v) for k, v in dt_chunk.items()}
+                ph("window.step_dispatch")
+                self.state, overflow = (
+                    self._apply_sharded_split if self._split_upload
+                    else self._apply_sharded)(self.state, dpk, ddata)
                 # LAZY overflow accounting: int(overflow) would block the
                 # pipeline on every step. One device-side sum per PUSH
                 # (not per chunk) so the marker deque stays 1:1 with
@@ -1817,15 +1823,19 @@ class WindowOperator:
                 # chunk's scalar. The host-side split makes overflow
                 # structurally impossible — the counter is the backstop.
                 ov_total = overflow if ov_total is None else ov_total + overflow
+                ph("window.pack")   # the next chunk's padding
             if ov_total is not None:
                 self._overflow_markers.append(ov_total)
-        t4 = time.perf_counter()
-        self.prof["pb_dispatch"] += t4 - t3
+        ph("window.step_dispatch")
         # inflight marker: a tiny scalar DERIVED from the new state — the
         # state buffers themselves are donated to the next step, so
         # holding them would read deleted buffers
         self._note_dispatch(self.state.counts[0, 0])
+        self._throttle_unless_external()
+
+    def _throttle_unless_external(self) -> None:
         if not self.external_throttle:
+            self.phases.phase("ingest.throttle")
             self.throttle()
 
     def _process_batch_fused(self, keys: np.ndarray, ts: np.ndarray) -> bool:
@@ -1864,7 +1874,7 @@ class WindowOperator:
                 return False  # degenerate lateness span: general path
             bits = int(span)
         prev_min, prev_max = self._min_pane_seen, self._max_pane_seen
-        t_scan = time.perf_counter()
+        ph = self.phases.phase
         for _attempt in (0, 1):
             domain = self.layout.slots * self.plan.ring
             if (self._preagg_ws is None or self._preagg_ws.domain != domain
@@ -1881,9 +1891,7 @@ class WindowOperator:
                 # new keys this batch: allocate + insert (no second
                 # lookup — the probe already proved absence), then
                 # continue the SAME scan over just the missed records
-                t1 = time.perf_counter()
                 self.directory.register_misses(keys[miss_ix])
-                self.prof["pb_assign"] += time.perf_counter() - t1
                 scan = ingest_fused_scan_native(
                     keys[miss_ix], ts[miss_ix], self.directory._table,
                     self.plan.pane_ms, self.plan.offset_ms,
@@ -1914,7 +1922,6 @@ class WindowOperator:
                 continue
             break
         self.state_version += 1
-        self.prof["preagg_combine"] += time.perf_counter() - t_scan
         self.late_records += n_late
         if n_bad:
             account_full_drop(self, n_bad)
@@ -1925,7 +1932,8 @@ class WindowOperator:
                 late_panes, self._fired_below_end, self.watermark))
         if n_valid == 0:
             return True
-        tc = time.perf_counter()
+        ph("window.pack")
+        self.prof["preagg_batches"] += 1
         domain = self.layout.slots * self.plan.ring
         cap = _next_pow2(max(res.npairs, 256))
         if cmax < 0xFFF and domain <= (1 << 20):
@@ -1936,24 +1944,30 @@ class WindowOperator:
                 res, self._preagg_ws, FUSED_HDR, cap)
             if self._fused_step is not None and self._stash_u32 is None:
                 self._stash_u32 = buf
-                self.prof["pb_preagg"] += time.perf_counter() - tc
                 return True
-            self.state = self._preagg_u32(
-                self.state, jnp.asarray(buf[FUSED_HDR:]))
+            buf, step = buf[FUSED_HDR:], self._preagg_u32
         else:
             pairs, cnts = ingest_fused_finalize_pairs_native(
                 res, self._preagg_ws)
             if cmax <= 0xFFFF:
-                buf = preagg_encode_u16(pairs, cnts, cap)
-                self.state = self._preagg_u16(self.state, jnp.asarray(buf))
+                buf, step = preagg_encode_u16(pairs, cnts, cap), \
+                    self._preagg_u16
             else:
-                buf = preagg_encode_i32(pairs, cnts, [], cap)
-                self.state = self._preagg_i32(self.state, jnp.asarray(buf))
-        self.prof["pb_preagg"] += time.perf_counter() - tc
-        self._note_dispatch(self.state.counts[0, 0])
-        if not self.external_throttle:
-            self.throttle()
+                buf, step = preagg_encode_i32(pairs, cnts, [], cap), \
+                    self._preagg_i32
+        self._upload_and_step(step, buf)
+        self._throttle_unless_external()
         return True
+
+    def _upload_and_step(self, step, buf: np.ndarray) -> None:
+        """One pre-aggregated pair buffer to the device and through its
+        apply program, as the phases window.h2d and
+        window.step_dispatch."""
+        self.phases.phase("window.h2d")
+        dbuf = jnp.asarray(buf)
+        self.phases.phase("window.step_dispatch")
+        self.state = step(self.state, dbuf)
+        self._note_dispatch(self.state.counts[0, 0])
 
     def _flush_stash(self) -> None:
         """Dispatch a pending fused-lane pair buffer as a plain apply —
@@ -1964,9 +1978,8 @@ class WindowOperator:
         if buf is None:
             return
         self._stash_u32 = None
-        self.state = self._preagg_u32(
-            self.state, jnp.asarray(buf[FUSED_HDR:]))
-        self._note_dispatch(self.state.counts[0, 0])
+        with self.phases.span("window.h2d"):   # then the caller's again
+            self._upload_and_step(self._preagg_u32, buf[FUSED_HDR:])
 
     def _preagg_dispatch(
         self,
@@ -1999,7 +2012,6 @@ class WindowOperator:
         # cardinality batches keep the per-record path
         if bpp * cap > 2 * len(panes):
             return False
-        tc = time.perf_counter()
         domain = self.layout.slots * ring
         native = None
         if cap <= (1 << 21):
@@ -2018,8 +2030,8 @@ class WindowOperator:
             pairs, cnts, lanes = preagg_combine(
                 slots, panes % ring, valid, data, lanes_f,
                 ring=ring, domain=domain)
-        te = time.perf_counter()
-        self.prof["preagg_combine"] += te - tc
+        self.phases.phase("window.pack")
+        self.prof["preagg_batches"] += 1
         cap = _next_pow2(max(len(pairs), 256))
         cmax = 0 if len(cnts) == 0 else int(cnts.max())
         if not lanes and cmax < 0xFFF and domain <= (1 << 20):
@@ -2028,26 +2040,13 @@ class WindowOperator:
             buf[:len(pairs)] = (pairs.astype(np.int64) << 12
                                 | cnts.astype(np.int64)).astype(np.uint32
                                                                 ).view(np.int32)
-            th = time.perf_counter()
-            dbuf = jnp.asarray(buf)
-            td = time.perf_counter()
-            self.state = self._preagg_u32(self.state, dbuf)
+            step = self._preagg_u32
         elif not lanes and cmax <= 0xFFFF:
-            buf = preagg_encode_u16(pairs, cnts, cap)
-            th = time.perf_counter()
-            dbuf = jnp.asarray(buf)
-            td = time.perf_counter()
-            self.state = self._preagg_u16(self.state, dbuf)
+            buf, step = preagg_encode_u16(pairs, cnts, cap), self._preagg_u16
         else:
-            buf = preagg_encode_i32(pairs, cnts, lanes, cap)
-            th = time.perf_counter()
-            dbuf = jnp.asarray(buf)
-            td = time.perf_counter()
-            self.state = self._preagg_i32(self.state, dbuf)
-        tz = time.perf_counter()
-        self.prof["preagg_encode"] += th - te
-        self.prof["preagg_h2d"] += td - th
-        self.prof["preagg_disp"] += tz - td
+            buf, step = preagg_encode_i32(pairs, cnts, lanes, cap), \
+                self._preagg_i32
+        self._upload_and_step(step, buf)
         return True
 
     def hbm_bytes(self) -> int:
@@ -2140,14 +2139,12 @@ class WindowOperator:
         push lock — the block is where transfer-bound pipelines spend
         most of their time, and holding the lock through it would stall
         the drain thread's deliveries behind it (emit latency)."""
-        t0 = time.perf_counter()
         while len(self._inflight) > self.max_inflight_steps:
             self._retire_step()
         # overflow markers older than the steps just retired are ready
         # (int() is a cheap host read); draining to the same bound keeps
         # the deque finite in jobs that never checkpoint
         self._resolve_overflow(bound=self.max_inflight_steps)
-        self.prof["pb_throttle_wait"] += time.perf_counter() - t0
 
     def quiesce(self) -> None:
         """Block until every dispatched step has completed. The driver
@@ -2282,7 +2279,10 @@ class WindowOperator:
         single device→host transfer happens on first access."""
         if wm < self.watermark or (wm == self.watermark and not self._refire):
             return self._empty()
-        taw = time.perf_counter()
+        with self.phases.span("window.fire_dispatch"):
+            return self._advance_watermark(wm)
+
+    def _advance_watermark(self, wm: int) -> "FiredWindows":
         # device-generated steps whose stats have landed: fold them in
         # (late accounting, refire scheduling, miss repair) BEFORE this
         # advance enumerates its fire list; never park behind in-flight
@@ -2312,7 +2312,6 @@ class WindowOperator:
                 self._flush_stash()  # miss repair stashed host pairs
             out = self._advance_fused_devgen(wm, ends)
             if out is not None:
-                self.prof["aw_dispatch"] += time.perf_counter() - taw
                 return out
             self._flush_devgen()  # fire list overflowed: chunked path
         # fused path: the pending ingest stash + these fires + the purge
@@ -2321,7 +2320,6 @@ class WindowOperator:
                 and self._spill is None and self.mesh_plan is None):
             out = self._advance_fused(wm, ends)
             if out is not None:
-                self.prof["aw_dispatch"] += time.perf_counter() - taw
                 return out
         self._flush_stash()
         # host-store keys fire on the SAME ends list (incl. refires) —
@@ -2346,7 +2344,8 @@ class WindowOperator:
                 if extra is not None:
                     self._pending_ring_extras.append(extra)
             if out._ring or extra is not None:
-                out = FiredWindows(data=self.drain_ring())
+                out = FiredWindows(data=self.drain_ring(),
+                                   cohort=out.cohort)
         else:
             out = self._fire_ends(ends)
             if extra is not None:
@@ -2375,7 +2374,6 @@ class WindowOperator:
             self._cleared_below = new_dead
             if self._spill is not None:
                 self._spill.purge_below(new_dead)
-        self.prof["aw_dispatch"] += time.perf_counter() - taw
         return out
 
     def _fused_fill_header(self, wm: int, ends: List[int],
@@ -2439,8 +2437,11 @@ class WindowOperator:
         ends_f, cleared_after = hdr
         self._stash_u32 = None
         used = self._used_mask_device()
+        self.phases.phase("window.h2d")
+        dbuf = jnp.asarray(buf)
+        self.phases.phase("window.fire_dispatch")
         self.state, self._emit_ring, token = self._fused_step(
-            self.state, self._ensure_ring(), jnp.asarray(buf), used,
+            self.state, self._ensure_ring(), dbuf, used,
             sel_cap=self._topn_cap(MIN_FIRE_PAD),
             fire_pad=self._fire_pad_bucket(len(ends_f)))
         # the NON-donated emit-ring output doubles as the completion
@@ -2454,7 +2455,7 @@ class WindowOperator:
         else:
             self._note_dispatch(self._emit_ring)
         self._cleared_below = cleared_after
-        return self._ring_after_fire(len(ends_f), covered=True)
+        return self._ring_after_fire(ends_f, covered=True)
 
     # -- device-chained generator ingest (see devgen_step_kernel) --------
 
@@ -2501,6 +2502,10 @@ class WindowOperator:
         stash the index for the next advance's single dispatch.
         Returns False when a gate closed; the caller falls back to host
         materialization for this batch."""
+        with self.phases.span("window.step_dispatch"):
+            return self._process_batch_device(batch_index)
+
+    def _process_batch_device(self, batch_index: int) -> bool:
         spec = self._devgen_spec
         if spec is None or self.plan.ring > 64:
             return False
@@ -2541,8 +2546,7 @@ class WindowOperator:
                       or pmin < dead or pmin < refire_below)
         self._stash_devgen = (int(batch_index), int(dead),
                               int(refire_below), bool(need_stats))
-        if not self.external_throttle:
-            self.throttle()
+        self._throttle_unless_external()
         return True
 
     def _dispatch_devgen(self, buf: np.ndarray, batch_index: int,
@@ -2595,7 +2599,7 @@ class WindowOperator:
         self._dispatch_devgen(buf, batch_index, dead, need_stats,
                               fire_pad=self._fire_pad_bucket(len(ends_f)))
         self._cleared_below = cleared_after
-        return self._ring_after_fire(len(ends_f), covered=True)
+        return self._ring_after_fire(ends_f, covered=True)
 
     def _flush_devgen(self) -> None:
         """Dispatch a pending device-generated batch as a fire-less
@@ -2673,7 +2677,17 @@ class WindowOperator:
                 if redo.any():
                     self.process_batch(keys[out][redo], ts[out][redo], {})
 
-    def _ring_after_fire(self, n_ends: int,
+    def _fire_cohort(self, end_panes: List[int]) -> Dict[str, Any]:
+        """The record of one fire dispatch: its window ends (ms) and
+        ``t_fire``, on ``time.perf_counter()``. The fetch that makes its
+        rows host-visible adds ``t_fetch0`` / ``t_fetch1``; the driver
+        adds ``op``, ``t_input`` and ``t_sink`` (see
+        ``Driver.fire_records``)."""
+        return {"window_ends": [e * self.plan.pane_ms + self.plan.offset_ms
+                                for e in end_panes],
+                "t_fire": time.perf_counter()}
+
+    def _ring_after_fire(self, ends: List[int],
                          covered: bool = False) -> "FiredWindows":
         """Post-fire ring bookkeeping shared by the fused and chunked
         top-n paths: version bump + cadenced announce (see
@@ -2682,13 +2696,15 @@ class WindowOperator:
         devgen paths) — that token (or any later one) re-validates the
         piggybacked head; a chunked fire has no token of its own, so
         only a FUTURE dispatch's token can."""
+        n_ends = len(ends)
+        cohort = None
         with self._ring_lock:
             self._ring_version_no += 1
             if n_ends > 0:
                 # row-carrying fire: stamp the cohort for host-visibility
                 # latency attribution (see _fire_stamps above)
-                self._fire_stamps.append(
-                    (self._ring_version_no, time.time()))
+                cohort = self._fire_cohort(ends)
+                self._fire_stamps.append((self._ring_version_no, cohort))
                 # rows may have been appended: the piggybacked ring head
                 # goes stale until a token at/after this fire lands
                 self._ring_head_known = False
@@ -2706,7 +2722,8 @@ class WindowOperator:
                 self._last_announce = now
                 self._rows_bound_since_announce = 0
             return FiredWindows(op=self, ring=True,
-                                ring_no=self._ring_version_no)
+                                ring_no=self._ring_version_no,
+                                cohort=cohort)
 
     def _fire_ends(self, ends: List[int]) -> "FiredWindows":
         if not ends or self._max_pane_seen is None:
@@ -2750,8 +2767,9 @@ class WindowOperator:
                 buf.copy_to_host_async()
                 packs.append((lo, buf))
         if self._topn is not None:
-            return self._ring_after_fire(len(ends))
-        return FiredWindows(op=self, packs=packs)
+            return self._ring_after_fire(ends)
+        return FiredWindows(op=self, packs=packs,
+                            cohort=self._fire_cohort(ends))
 
     def _fire_packed2(self) -> bool:
         """Static gate of the 2-column packed fire layout (local
@@ -2860,6 +2878,42 @@ class WindowOperator:
                 self._emit_ring = jnp.zeros(shape, jnp.int32)
         return self._emit_ring
 
+    def _fetch_ring_version(self, need: int, opportunistic: bool):
+        """Under the ring lock: ``(ring array, its version)`` of the
+        newest ANNOUNCED version >= ``need`` whose async copy already
+        landed — never park behind the in-flight compute of a
+        just-dispatched fire (a barrier's rows must be present, hence
+        ``need``) — or ``(None, None)`` when an opportunistic poll finds
+        nothing announced."""
+        acceptable = [(no, arr_) for no, arr_ in
+                      self._ring_versions if no >= need]
+        target = None
+        no_read = None
+        for no, cand in reversed(acceptable):
+            if cand.is_ready():
+                target, no_read = cand, no
+                break
+        else:
+            if acceptable:
+                # oldest OK = soonest
+                no_read, target = acceptable[0]
+        if target is None:
+            if opportunistic:
+                # nothing announced yet (or announce cadence not due):
+                # fetch nothing; the next poll gets it
+                return None, None
+            # barrier needs a version newer than any announced copy:
+            # announce the live ring now so the fetch is a landed-copy
+            # read, not an unannounced round trip
+            target = self._emit_ring
+            no_read = self._ring_version_no
+            target.copy_to_host_async()
+            self._ring_versions.append((self._ring_version_no, target))
+            self._last_announce = time.perf_counter()
+            self._rows_bound_since_announce = 0
+        ready_wait(target)
+        return np.asarray(target), no_read         # ONE round trip
+
     def drain_ring(self, min_no: Optional[int] = None) -> Dict[str, np.ndarray]:
         """Fetch the emit ring ONCE and decode every row appended since
         the previous drain (the host-side poll of the device emit
@@ -2894,60 +2948,21 @@ class WindowOperator:
                 # the head): their rows are already host-visible, so
                 # deliver the stamps NOW — a zero-row fire cohort's
                 # latency sample must not age across skipped polls.
-                while self._fire_stamps:
-                    self._delivered_stamps.append(
-                        self._fire_stamps.popleft())
+                now = time.perf_counter()
+                self._deliver_stamps(self._ring_version_no, now, now)
                 self.prof["drain_skips"] += 1
                 arr = None
             else:
-                tdr = time.perf_counter()
-                # fetch the newest ANNOUNCED version whose async copy
-                # already landed — never park behind the in-flight
-                # compute of a just-dispatched fire — among versions
-                # >= min_no (a barrier's rows must be present).
-                need = (self._ring_version_no if min_no is None
-                        else min_no)
-                acceptable = [(no, arr_) for no, arr_ in
-                              self._ring_versions if no >= need]
-                target = None
-                no_read = None
-                for no, cand in reversed(acceptable):
-                    if cand.is_ready():
-                        target, no_read = cand, no
-                        break
-                else:
-                    if acceptable:
-                        # oldest OK = soonest
-                        no_read, target = acceptable[0]
-                if target is None:
-                    if min_no == 0:
-                        # opportunistic poll with nothing announced yet
-                        # (or announce cadence not due): fetch nothing;
-                        # the next poll gets it
-                        arr = None
-                    else:
-                        # barrier needs a version newer than any
-                        # announced copy: announce the live ring now so
-                        # the fetch is a landed-copy read, not an
-                        # unannounced round trip
-                        target = self._emit_ring
-                        no_read = self._ring_version_no
-                        target.copy_to_host_async()
-                        self._ring_versions.append(
-                            (self._ring_version_no, target))
-                        self._last_announce = time.perf_counter()
-                        self._rows_bound_since_announce = 0
-                if target is not None:
-                    ready_wait(target)
-                    arr = np.asarray(target)         # ONE round trip
+                need = self._ring_version_no if min_no is None else min_no
+                with self.phases.span("drain.fetch", ring=need) as fetch:
+                    arr, no_read = self._fetch_ring_version(
+                        need, opportunistic=(min_no == 0))
+                if no_read is not None:
                     # every fire cohort at or below the fetched version
-                    # just became host-visible — hand its dispatch
-                    # stamp to the latency accounting
-                    while (self._fire_stamps
-                           and self._fire_stamps[0][0] <= no_read):
-                        self._delivered_stamps.append(
-                            self._fire_stamps.popleft())
-                self.prof["drain_fetch"] += time.perf_counter() - tdr
+                    # just became host-visible — hand it, with this
+                    # fetch's stamps, to the latency accounting
+                    self._deliver_stamps(no_read, fetch.t0, fetch.t1)
+                self.prof["drain_fetch"] += fetch.seconds
                 self.prof["drain_fetches"] += 1
         if arr is None:
             out = dict(self._empty())
@@ -3003,14 +3018,24 @@ class WindowOperator:
             out = _drain_merge_extras(out, extras, self._topn)
         return out
 
-    def take_delivered_fire_stamps(self):
-        """Pop the dispatch stamps of fire cohorts whose rows became
+    def _deliver_stamps(self, no_read: int, t_fetch0: float,
+                        t_fetch1: float) -> None:
+        """Under the ring lock: every fire cohort at or below ring
+        version ``no_read`` is host-visible as of the fetch that ran
+        from ``t_fetch0`` to ``t_fetch1``."""
+        while self._fire_stamps and self._fire_stamps[0][0] <= no_read:
+            cohort = self._fire_stamps.popleft()[1]
+            cohort["t_fetch0"], cohort["t_fetch1"] = t_fetch0, t_fetch1
+            self._delivered_stamps.append(cohort)
+
+    def take_delivered_fires(self) -> List[Dict[str, Any]]:
+        """Pop the fire cohorts (``_fire_cohort``) whose rows became
         host-visible since the last call (see ``_fire_stamps``). The
-        driver records one emit-latency sample per cohort at delivery
-        time — host-visibility-accurate even when one drain poll
-        coalesces many sub-batch fires."""
+        driver stamps ``t_sink`` on each and records one emit-latency
+        sample per cohort at delivery time — host-visibility-accurate
+        even when one drain poll coalesces many sub-batch fires."""
         with self._ring_lock:
-            out = [stamp for _, stamp in self._delivered_stamps]
+            out = list(self._delivered_stamps)
             self._delivered_stamps.clear()
             return out
 
@@ -3256,7 +3281,10 @@ class FiredWindows(Mapping):
 
     def __init__(self, data: Optional[Dict[str, np.ndarray]] = None,
                  fetch=None, op=None, packs=None, ring: bool = False,
-                 ring_no: int = 0):
+                 ring_no: int = 0, cohort: Optional[Dict] = None):
+        # the fire's record (WindowOperator._fire_cohort), None for a
+        # batch that fired no window end
+        self.cohort = cohort
         self._data = data
         self._fetch = fetch
         self._op = op
@@ -3277,14 +3305,23 @@ class FiredWindows(Mapping):
                 self._data = self._op.drain_ring()
                 self._op = None
             else:
-                bufs = jax.device_get([b for _, b in self._packs])
-                self._data = self._op._decode_packs(self._packs, bufs)
-                self._packs = self._op = None
+                self._fetch_packs(lambda packs: jax.device_get(
+                    [b for _, b in packs]))
         if self._extra is not None:
             self._data = _merge_spill_rows(
                 self._data, self._extra, self._topn_spec)
             self._extra = None
         return self._data
+
+    def _fetch_packs(self, get) -> None:
+        """Fetch (``drain.fetch``) and decode this fire's pack buffers."""
+        with self._op.phases.span("drain.fetch") as fetch:
+            bufs = get(self._packs)
+        if self.cohort is not None:
+            self.cohort["t_fetch0"] = fetch.t0
+            self.cohort["t_fetch1"] = fetch.t1
+        self._data = self._op._decode_packs(self._packs, bufs)
+        self._packs = self._op = None
 
     @staticmethod
     def materialize_many(fireds: List["FiredWindows"],
@@ -3324,9 +3361,8 @@ class FiredWindows(Mapping):
                 f._op = None
         for f in fireds:
             if f._data is None and f._packs is not None:
-                bufs = [np.asarray(ready_wait(b)) for _, b in f._packs]
-                f._data = f._op._decode_packs(f._packs, bufs)
-                f._packs = f._op = None
+                f._fetch_packs(lambda packs: [
+                    np.asarray(ready_wait(b)) for _, b in packs])
 
     def __getitem__(self, key: str) -> np.ndarray:
         return self.materialize()[key]

@@ -34,7 +34,25 @@ from flink_tpu.graph.compiler import (
     ExecNode,
     ExecutionPlan,
 )
+from flink_tpu.obs.tracing import PhaseClock
 from flink_tpu.time.watermarks import LONG_MIN, WatermarkTracker, make_generator
+
+# fire cohorts kept for JobResult "trace.fires" (and its records: the
+# newest this many)
+FIRE_RECORDS = 4096
+
+# the six phases of phase_breakdown(), each the sum of these leaves of
+# the run's PhaseClock. "ingest.bookkeeping" and "drain.deliver" are in
+# none: the six never covered them.
+PHASE_LEAVES = {
+    "source": ("ingest.source_wait",),
+    "dispatch": ("ingest.link_wait", "ingest.route", "window.key_scan",
+                 "window.pack", "window.h2d", "window.step_dispatch"),
+    "throttle": ("ingest.throttle",),
+    "drain": ("drain.fetch",),
+    "advance": ("wm.advance",),
+    "fire": ("window.fire_dispatch",),
+}
 
 Batch = Tuple[Dict[str, np.ndarray], np.ndarray, np.ndarray]  # data, ts, valid
 
@@ -140,10 +158,20 @@ class Driver:
         self._sub_factor: Dict[int, int] = {}
         g.gauge("debloat_chunk",
                 lambda: float(self._debloat_chunk or 0))
-        # per-phase wall-time accumulators (seconds) for the ingest loop
-        # and drain thread — merged into JobResult as profile.* so perf
-        # work is steered by measurement, not vibes
-        self.prof: Dict[str, float] = collections.defaultdict(float)
+        # where the ingest loop and the drain thread spend their time
+        # (obs/tracing.py PhaseClock; a fresh one per run, shared with
+        # the operators) — JobResult's profile.phase.*, and host spans
+        # of any profiler trace
+        self.phases = PhaseClock()
+        # per-window fire records (JobResult "trace.fires"): one cohort
+        # per fire dispatch, stamped on time.perf_counter() as it moves
+        # input -> fire -> fetch -> sink. _t_input: when the source
+        # handed over its latest batch (or said it had no more)
+        self._fires: collections.deque = collections.deque(
+            maxlen=FIRE_RECORDS)
+        self._t_input = 0.0
+        self._t_loop = 0.0       # when the ingest loop began, and how
+        self._loop_wall_s = 0.0  # long it ran: its leaves sum to this
         self._emit_q = None
         self._profiler = None  # armed per run (pipeline.profile-dir)
         self._drain_error: Optional[BaseException] = None
@@ -881,6 +909,7 @@ class Driver:
         # fleet double-checkpoints back-to-back every interval
         stale_sp = False        # same staleness for the savepoint flag:
         # the in-flight step's meta predates the savepoint just served
+        ph = self.phases.phase
         while True:
             if self._cancel is not None and self._cancel.is_set():
                 # stop-with-savepoint (rescale) sets cancel from the
@@ -892,7 +921,9 @@ class Driver:
             batch_ix = None
             while order:
                 ix = order[0]
+                ph("ingest.source_wait")
                 nxt = next(d[ix], None)
+                self._t_input = ph("ingest.bookkeeping")
                 if nxt is None:
                     order.pop(0)
                     continue
@@ -908,6 +939,7 @@ class Driver:
                     mx = int(ts.max())
                     self._max_ts[sid] = max(self._max_ts[sid], mx)
                     self._wm_gens[sid][batch_ix].on_batch(mx)
+                ph("ingest.route")
                 keys = np.asarray(data[key_field], np.int64)
                 # process destination from the ONE routing truth the
                 # hybrid mesh plan also uses (exchange/partitioners.py):
@@ -923,6 +955,7 @@ class Driver:
                             "data": {k: np.asarray(v)[m]
                                      for k, v in data.items()},
                             "ts": ts[m]}
+            ph("ingest.bookkeeping")
             local_wm = (min(self._wm_gens[sid][i].current() for i in order)
                         if order else _FINAL)
             want_ckpt = (pid == 0 and self._coordinator is not None
@@ -944,7 +977,9 @@ class Driver:
                     # the reference's all-acks-then-notifyComplete rule,
                     # 4.C, carried on the rendezvous instead of RPC)
                     "persisted": int(st.persisted_id)}
+            ph("ingest.exchange")
             h = ex.exchange_async(shares, meta)
+            ph("ingest.bookkeeping")
             if overlap and pending_x is None:
                 # prime the double buffer: nothing to consume yet
                 pending_x = h
@@ -981,12 +1016,14 @@ class Driver:
                 # step's records are NOT covered (the analyzer-warned
                 # at-most-once trade).
                 if self._coordinator is not None and st.pending is None:
+                    ph("ingest.checkpoint")
                     st.pending = self._begin_checkpoint()
                     self._ckpt_pending = st.pending
                     st.pending.future.result()  # durable before acking
                     st.pending_id = st.pending.checkpoint_id
                     st.persisted_id = st.pending_id
                 st.last_chk = time.time()
+                ph("ingest.bookkeeping")
                 # without the drain, the in-flight step still carries
                 # its pre-snapshot ckpt flag — consume it ABSORBED
                 stale_ckpt = pending_x is not None
@@ -1028,7 +1065,10 @@ class Driver:
             from flink_tpu import faults
 
             faults.fire("dcn.overlap.consume", exc=ConnectionError)
+        ph = self.phases.phase
+        ph("ingest.exchange")   # the rendezvous: peers' shares and metas
         payloads, metas = handle.result()
+        ph("ingest.route")
         parts = [p for p in payloads if p is not None
                  and len(p["ts"])]
         if parts:
@@ -1036,18 +1076,19 @@ class Driver:
                   for k in parts[0]["data"]}
             mts = np.concatenate([p["ts"] for p in parts])
             self._push_dcn_merged(sid, md, mts)
-            for op in self._ops.values():
-                if hasattr(op, "throttle"):
-                    op.throttle()
+            self._throttle_ops()
             self._eps_meter.mark(len(mts))
+        ph("ingest.bookkeeping")
         # identical global watermark on every process: min of the
         # piggybacked locals (exhausted processes report _FINAL so
         # they stop pinning the clock)
         gwm = min(int(m["wm"]) for m in metas)
         if gwm != _FINAL and gwm > self._out_wm[sid]:
             self._out_wm[sid] = gwm
+        ph("wm.advance")
         with self._push_lock:
             self._propagate_watermarks()
+        ph("ingest.bookkeeping")
         self._check_drain_error()
         # commit the PREVIOUS checkpoint once every process acked
         # durability (phase 2): only then may 2PC sinks publish
@@ -1090,10 +1131,12 @@ class Driver:
         sub = -(-nrec // k)  # ceil: ragged tails allowed cross-host
         for lo in range(0, nrec, sub):
             hi = min(lo + sub, nrec)
+            self.phases.phase("ingest.route")
             with self._push_lock:
                 self._push_downstream(
                     sid, ({kk: v[lo:hi] for kk, v in md.items()},
                           mts[lo:hi], valid[lo:hi]))
+                self.phases.phase("wm.advance")
                 self._propagate_watermarks()
             self._check_drain_error()
 
@@ -1247,7 +1290,8 @@ class Driver:
         req.clear()
         if self._coordinator is None:
             return  # unreachable via the runner path (validated there)
-        h = self.checkpoint_now(savepoint=True)
+        with self.phases.span("ingest.checkpoint"):
+            h = self.checkpoint_now(savepoint=True)
         self.last_savepoint = h.path
         cb = getattr(req, "on_complete", None)
         if cb is not None:
@@ -1452,6 +1496,10 @@ class Driver:
             MetricsServer(self.registry, port, bind) if port else None)
         self._emit_q = queue.Queue()
         self._drain_discard = [False]  # fresh cell per run (see __init__)
+        self.phases = PhaseClock()
+        for op in self._ops.values():
+            op.phases = self.phases
+        self._fires.clear()
         # per-op device profiling window (pipeline.profile-dir): wraps
         # N warm driver steps in jax.profiler.trace and reduces the
         # trace to a per-op summary (obs/profiling.py)
@@ -1492,6 +1540,7 @@ class Driver:
         try:
             return self._run_loop(job_name, drain, interval_ms, restore)
         except BaseException:
+            self.phases.stop()  # a failed run leaves no phase open
             # Failed attempt: an in-flight background checkpoint must
             # NOT commit its 2PC epoch (its snapshot may cover state the
             # failure invalidated); abandon it uncommitted — the
@@ -1716,11 +1765,12 @@ class Driver:
                 d[i] = (_Prefetcher(it, depth=prefetch)
                         if prefetch > 0 else it)
 
+        ph = self.phases.phase
+        self._t_loop = ph("ingest.bookkeeping")
         if self.plan.runtime_mode == "batch":
             return self._run_batch(job_name, srcs, drain)
 
         last_chk = time.time()
-        prof = self.prof
         if self._dcn is not None:
             try:
                 self._ingest_loop_dcn(srcs, interval_ms, job_name)
@@ -1738,20 +1788,19 @@ class Driver:
                     if self._cancel is not None and self._cancel.is_set():
                         raise JobCancelledError(job_name)
                     it = srcs[sid][split_ix]
-                    t0 = time.perf_counter()
+                    ph("ingest.source_wait")
                     nxt = next(it, None)
-                    t1 = time.perf_counter()
-                    prof["source_next"] += t1 - t0
+                    self._t_input = ph("ingest.bookkeeping")
                     if nxt is None:
                         splits_alive.remove(split_ix)
                         continue
                     already_sub = False
                     if isinstance(nxt, _DevBatch):
                         op = self._ops[self._dev_chains[sid]]
+                        ph("ingest.link_wait")
                         with self._link_lock:
                             pass
-                        t2 = time.perf_counter()
-                        prof["link_lock_wait"] += t2 - t1
+                        ph("ingest.route")
                         with self._push_lock:
                             ok = op.process_batch_device(nxt.index)
                             if ok:
@@ -1775,10 +1824,8 @@ class Driver:
                             f = self._sub_factor.get(sid, 1)
                             if (f == 1 or self._readiness == "piggyback"
                                     or (nxt.index + 1) % f == 0):
-                                for op2 in self._ops.values():
-                                    if hasattr(op2, "throttle"):
-                                        op2.throttle()
-                            prof["push"] += time.perf_counter() - t2
+                                self._throttle_ops()
+                            ph("ingest.bookkeeping")
                             self._positions[sid][split_ix] += 1
                             self._eps_meter.mark(nxt.n)
                             mx = nxt.ts_max
@@ -1794,6 +1841,7 @@ class Driver:
                         # bit-exact sub-batch slice — already at
                         # sub-batch size, so the host path must not
                         # slice it K ways again)
+                        ph("ingest.bookkeeping")
                         self.metrics["device_chain_fallback_batches"] += 1
                         already_sub = self._sub_factor.get(sid, 1) > 1
                         nxt = self._dev_subdivided.get(
@@ -1808,12 +1856,11 @@ class Driver:
                         # the drain at sub-batch cadence. Position /
                         # eps / max-ts accounting stays below, at
                         # logical-batch granularity.
-                        t1 = self._ingest_host_subbatched(
-                            sid, split_ix, splits_alive, data, ts, t1)
+                        self._ingest_host_subbatched(
+                            sid, split_ix, splits_alive, data, ts)
                     else:
                         for data_c, ts_c in self._debloat_split(data, ts):
-                            t1 = self._push_source_chunk(
-                                sid, data_c, ts_c, t1)
+                            self._push_source_chunk(sid, data_c, ts_c)
                     self._advance_position(sid, split_ix, data, ts)
                     self._eps_meter.mark(len(ts))
                     if len(ts):
@@ -1824,10 +1871,10 @@ class Driver:
                 # exhausted splits stop holding the watermark back
                 # (ref: idle-channel handling in the valve)
                 self._recombine_source_wm(sid, splits_alive)
-                t3 = time.perf_counter()
+                ph("wm.advance")
                 with self._push_lock:
                     self._propagate_watermarks()
-                prof["advance_wm"] += time.perf_counter() - t3
+                ph("ingest.bookkeeping")
                 self._check_drain_error()
             if self._profiler is not None:
                 self._profiler.step()
@@ -1842,20 +1889,26 @@ class Driver:
             if (self._coordinator is not None and interval_ms > 0
                     and self._ckpt_pending is None
                     and (time.time() - last_chk) * 1000 >= interval_ms):
+                ph("ingest.checkpoint")
                 self._ckpt_pending = self._begin_checkpoint()
                 last_chk = time.time()
+                ph("ingest.bookkeeping")
 
         # end of input: final watermark per stateful op flushes everything.
         # Quiesce the device pipeline first (outside the push lock — the
         # drain keeps delivering) so the flush fires don't queue behind
         # in-flight ingest steps and their latency stays steady-state.
+        ph("ingest.throttle")
         for op in self._ops.values():
             if hasattr(op, "quiesce"):
                 op.quiesce()
         for sid in self.plan.sources:
             self._out_wm[sid] = _FINAL
+        self._t_input = ph("wm.advance")
         with self._push_lock:
             self._propagate_watermarks(final=True)
+        # the loop is over: what follows waits for the drain and commits
+        self._loop_wall_s = self.phases.stop() - self._t_loop
         self._flush_emits()
         # a savepoint requested after the last batch boundary must still
         # land (bounded inputs can finish before the next loop pass)
@@ -1937,14 +1990,26 @@ class Driver:
                         self.metrics.get(counter, 0) + getattr(op, counter))
         final = dict(self.metrics)
         final.update(self.registry.snapshot())
-        for k, v in self.prof.items():
-            final[f"profile.driver.{k}"] = v
         # the per-phase breakdown (dispatch/throttle/drain/advance/fire)
         # under the ONE shared accounting (phase_breakdown) — bench
         # artifacts embed these next to profile_top_ops so control-
         # plane wins are attributed, not asserted
         for k, v in self.phase_breakdown().items():
             final[f"profile.phase.{k}"] = round(v, 6)
+        # the leaves themselves: seconds, count and longest interval
+        # each, and the run's longest — every value a number
+        # (bench.py's _phase_summary calls float() on each)
+        final["profile.phase.loop_wall_s"] = round(self._loop_wall_s, 6)
+        leaves = self.phases.snapshot()
+        for leaf, st in leaves.items():
+            final[f"profile.phase.{leaf}"] = round(st["seconds"], 6)
+            final[f"profile.phase.{leaf}.n"] = st["count"]
+            final[f"profile.phase.longest_ms.{leaf}"] = st["longest_ms"]
+        worst = max(leaves.values(), key=lambda st: st["longest_ms"],
+                    default={"longest_ms": 0.0, "longest_at_s": 0.0})
+        final["profile.phase.longest_ms"] = worst["longest_ms"]
+        final["profile.phase.longest_at_s"] = worst["longest_at_s"]
+        final["trace.fires"] = self.fire_records()
         if self._profiler is not None:
             summary = self._profiler.close()
             if summary is not None:
@@ -2014,6 +2079,7 @@ class Driver:
         finally:
             self._batch_capture = {}
             shuffle.close()
+        self._loop_wall_s = self.phases.stop() - self._t_loop
         self._commit_final_epoch()
         self.metrics["batch_wall_s"] = round(time.perf_counter() - t0, 3)
         return self._finish_run(job_name, drain)
@@ -2047,29 +2113,26 @@ class Driver:
         batch through its stage's pipelined (stateless) chain — and
         into blocking-edge spools at the stage boundary. No watermark
         propagation per batch: time only moves at the wave finalize."""
-        prof = self.prof
+        ph = self.phases.phase
         for split_ix in sorted(d):
             it = d[split_ix]
             while True:
                 if self._cancel is not None and self._cancel.is_set():
                     raise JobCancelledError(job_name)
-                t0 = time.perf_counter()
+                ph("ingest.source_wait")
                 nxt = next(it, None)
-                prof["source_next"] += time.perf_counter() - t0
+                self._t_input = ph("ingest.route")
                 if nxt is None:
                     break
                 data, ts = nxt
                 ts = np.asarray(ts, np.int64)
-                t1 = time.perf_counter()
                 with self._push_lock:
                     self.metrics["records_in"] += len(ts)
                     self.metrics["batches"] += 1
                     self._push_downstream(
                         sid, (dict(data), ts, np.ones(len(ts), bool)))
-                for op in self._ops.values():
-                    if hasattr(op, "throttle"):
-                        op.throttle()
-                prof["push"] += time.perf_counter() - t1
+                self._throttle_ops()
+                ph("ingest.bookkeeping")
                 self._advance_position(sid, split_ix, data, ts)
                 self._eps_meter.mark(len(ts))
                 if len(ts):
@@ -2098,7 +2161,7 @@ class Driver:
             for data, ts in shuffle.edge(u, v).read():
                 if self._cancel is not None and self._cancel.is_set():
                     raise JobCancelledError(job_name)
-                t1 = time.perf_counter()
+                self._t_input = self.phases.phase("ingest.route")
                 with self._push_lock:
                     self.metrics["shuffle_records_replayed"] = (
                         self.metrics.get("shuffle_records_replayed", 0)
@@ -2110,10 +2173,8 @@ class Driver:
                         # nothing else polls between wave finalizes
                         for b in op.poll():
                             self._push_downstream(v, b)
-                for o in self._ops.values():
-                    if hasattr(o, "throttle"):
-                        o.throttle()
-                self.prof["push"] += time.perf_counter() - t1
+                self._throttle_ops()
+                self.phases.phase("ingest.bookkeeping")
                 self._check_drain_error()
 
     def _batch_finalize_wave(self, stage) -> None:
@@ -2124,39 +2185,44 @@ class Driver:
         (and captured into downstream blocking edges) before the wave
         is declared finished."""
         only = set(stage.nodes)
+        self.phases.phase("ingest.throttle")
         for nid in only:
             op = self._ops.get(nid)
             if op is not None and hasattr(op, "quiesce"):
                 op.quiesce()
+        self._t_input = self.phases.phase("wm.advance")
         with self._push_lock:
             self._propagate_watermarks(final=True, only=only)
+        self.phases.phase("ingest.drain_wait")
         self._flush_emits()
+        self.phases.phase("ingest.bookkeeping")
 
-    def _push_source_chunk(self, sid: int, data_c, ts_c,
-                           t1: float) -> float:
+    def _push_source_chunk(self, sid: int, data_c, ts_c) -> None:
         """Push ONE ingest chunk downstream (the hot-loop body shared
         by the plain and sub-batched paths): link-quiet handshake,
-        locked push + metrics, backpressure wait OUTSIDE the lock.
-        Returns the next chunk's profiling anchor."""
-        prof = self.prof
-        valid = np.ones(len(ts_c), bool)
+        locked push + metrics, backpressure wait OUTSIDE the lock."""
+        ph = self.phases.phase
         # yield the transport to a drain fetch in progress (see
         # _link_lock): blocks only while one is active
+        ph("ingest.link_wait")
         with self._link_lock:
             pass
-        t2 = time.perf_counter()
-        prof["link_lock_wait"] += t2 - t1
+        ph("ingest.route")   # the operator below opens its window.* phases
+        valid = np.ones(len(ts_c), bool)
         with self._push_lock:
             self.metrics["records_in"] += len(ts_c)
             self.metrics["batches"] += 1
             self._push_downstream(sid, (dict(data_c), ts_c, valid))
         # backpressure wait OUTSIDE the lock: the drain thread must be
         # able to deliver while ingest blocks on the device pipeline
+        self._throttle_ops()
+        ph("ingest.bookkeeping")
+
+    def _throttle_ops(self) -> None:
+        self.phases.phase("ingest.throttle")
         for op in self._ops.values():
             if hasattr(op, "throttle"):
                 op.throttle()
-        prof["push"] += time.perf_counter() - t2
-        return time.perf_counter()
 
     def _recombine_source_wm(self, sid: int, splits_alive) -> None:
         """Source watermark = min over ALIVE split generators (a
@@ -2173,8 +2239,7 @@ class Driver:
                 self._wm_gens[sid][i].current() for i in owned)
 
     def _ingest_host_subbatched(self, sid: int, split_ix: int,
-                                splits_alive, data, ts,
-                                t1: float) -> float:
+                                splits_alive, data, ts) -> None:
         """Host-plane sub-batching (pipeline.sub-batches = K > 1): the
         logical batch is pushed as K equal slices, and after EACH slice
         the watermark clock advances and fires dispatch — a fired
@@ -2184,7 +2249,7 @@ class Driver:
         committed rows match the K=1 run; only fire GROUPING is finer.
         Position advance and throughput accounting stay with the
         caller, at logical-batch granularity."""
-        prof = self.prof
+        ph = self.phases.phase
         n = len(ts)
         sub = max(1, -(-n // self._sub_batches))  # ceil: ragged tails
         gens = self._wm_gens[sid]
@@ -2193,16 +2258,15 @@ class Driver:
             data_s = {k: v[lo:hi] for k, v in data.items()}
             ts_s = ts[lo:hi]
             for data_c, ts_c in self._debloat_split(data_s, ts_s):
-                t1 = self._push_source_chunk(sid, data_c, ts_c, t1)
+                self._push_source_chunk(sid, data_c, ts_c)
             if len(ts_s):
                 gens[split_ix].on_batch(int(ts_s.max()))
             self._recombine_source_wm(sid, splits_alive)
-            t3 = time.perf_counter()
+            ph("wm.advance")
             with self._push_lock:
                 self._propagate_watermarks()
-            prof["advance_wm"] += time.perf_counter() - t3
+            ph("ingest.bookkeeping")
             self._check_drain_error()
-        return t1
 
     def _advance_position(self, sid: int, split_ix: int, data, ts) -> None:
         """One consumed source batch: the SOURCE defines what the next
@@ -2218,45 +2282,20 @@ class Driver:
         """Cumulative per-phase wall seconds of this run — ONE
         accounting shared by the bench artifacts (per-trial
         ``phase_breakdown``), the JobResult (``profile.phase.*``), and
-        the web-UI backpressure gauge, so the cost attribution
-        (throttle / drain / advance / fire) is measured the same way
-        everywhere instead of each consumer summing its own subset.
-
-        Phases (best-effort attribution from the always-on prof
-        accumulators, clamped non-negative):
-          source   — source iterator next() (decode/generate)
-          dispatch — ingest push + device-step dispatch, MINUS the
-                     throttle share accrued inside it (push timing
-                     wraps the throttle loop)
-          throttle — backpressure waits (pb_throttle_wait)
-          drain    — emit-ring/pack fetch time: the drain thread's
-                     link-held window plus ring fetches made outside
-                     it (the sync spill drain runs on the loop thread)
-          advance  — watermark-advance bookkeeping minus the fire
-                     dispatch it wraps
-          fire     — fire-path dispatch inside advance_watermark
-                     (aw_dispatch)"""
-        def opsum(key: str) -> float:
-            return sum(getattr(op, "prof", {}).get(key, 0.0)
-                       for op in self._ops.values())
-
-        prof = self.prof
-        throttle = opsum("pb_throttle_wait")
-        fire = opsum("aw_dispatch")
-        drain_thread = prof.get("drain_link_held", 0.0)
-        # drain_fetch accrues inside the drain thread's link window on
-        # the async path; count only the excess (sync drains on the
-        # loop thread) so the two never double-count
-        drain = drain_thread + max(0.0, opsum("drain_fetch") - drain_thread)
-        return {
-            "source": prof.get("source_next", 0.0),
-            "dispatch": max(0.0, prof.get("push", 0.0)
-                            + prof.get("link_lock_wait", 0.0) - throttle),
-            "throttle": throttle,
-            "drain": drain,
-            "advance": max(0.0, prof.get("advance_wm", 0.0) - fire),
-            "fire": fire,
-        }
+        the web-UI backpressure gauge. Each phase is the sum of the
+        leaves of the run's phase clock that ``PHASE_LEAVES`` gives it:
+          source   — waiting for the source iterator's next()
+          dispatch — link-quiet wait, routing down to the operator, host
+                     keying, packing, upload and the step's launch
+          throttle — backpressure waits
+          drain    — emit-ring / pack fetches, whichever thread makes them
+          advance  — watermark propagation outside the fire
+          fire     — the operator's advance_watermark: fire-list header
+                     and the fire / fused-step launch"""
+        snap = self.phases.snapshot()
+        return {k: sum(snap[leaf]["seconds"] for leaf in leaves
+                       if leaf in snap)
+                for k, leaves in PHASE_LEAVES.items()}
 
     def live_metrics(self) -> Dict[str, Any]:
         """Racy-read live counters for the heartbeat-carried job
@@ -2423,6 +2462,7 @@ class Driver:
                         fired = op.advance_watermark(op.final_watermark())
                     else:
                         fired = op.advance_processing_time()
+                    self.phases.phase("wm.advance")
                     self._emit_fired(nid, fired)
                     self._out_wm[nid] = in_wm
                     continue
@@ -2431,6 +2471,8 @@ class Driver:
                     wm = op.final_watermark()
                 if wm > op.watermark or final:
                     fired = op.advance_watermark(wm)
+                    # the operator opened window.* phases for its fire
+                    self.phases.phase("wm.advance")
                     self._emit_fired(nid, fired)
                 # processing-time TIMERS (KeyedProcessFunction) fire on
                 # the clock alongside the event-time advance
@@ -2461,49 +2503,71 @@ class Driver:
         mailbox thread (ref: PipelinedSubpartition.notifyDataAvailable).
         Stateful downstream (a second window stage) keeps the in-line
         path so operator state is touched by one thread only."""
+        cohort = getattr(fired, "cohort", None)
+        if cohort is not None:
+            # the operator stamped t_fire at its dispatch; the batch that
+            # carried the watermark past these ends is the latest handed
+            # over (with several splits: an upper bound)
+            cohort["op"] = nid
+            cohort["t_input"] = self._t_input
+            self._fires.append(cohort)
         if self._emit_q is not None and self._stateless_downstream(nid):
-            self._emit_q.put((nid, fired, time.time()))
+            self._emit_q.put((nid, fired, time.perf_counter()))
             return
-        self._emit_fired_sync(nid, fired, time.time())
+        self._emit_fired_sync(nid, fired, time.perf_counter())
 
     def _emit_fired_sync(self, nid: int, fired, stamp: float) -> None:
         ring_origin = getattr(fired, "_ring", False)
-        out = dict(fired)  # materializes lazy FiredWindows
-        if ring_origin:
-            # emit-ring fires: one latency sample PER FIRE COHORT whose
-            # rows this drain made host-visible, stamped NOW (delivery)
-            # against each cohort's own dispatch time. The per-batch
-            # sample below would attribute every coalesced sub-batch
-            # fire to the OLDEST queue item's stamp — overstating p99
-            # exactly when sub-batching improves it.
-            self._note_ring_latency(nid)
-        if "__ts__" in out:
-            # process-function emissions: explicit per-row timestamps
-            ts = np.asarray(out.pop("__ts__"), np.int64)
-            nrec = len(ts)
-        else:
-            nrec = len(out.get("window_end", ()))  # windowed schemas
-            # (keyed rows also carry "key"; windowAll rows don't)
-            ts = (np.asarray(out["window_end"], np.int64) - 1
-                  if nrec else np.zeros(0, np.int64))
-        if nrec == 0:
-            return
-        self.metrics["fired_windows"] += nrec
-        valid = np.ones(nrec, bool)
-        self._push_downstream(nid, (out, ts, valid))
-        # latency marker: watermark-advance dispatch → delivered at sink
-        # (ref: streaming/runtime/streamrecord/LatencyMarker.java)
-        if not ring_origin:
-            self._lat_hist.update((time.time() - stamp) * 1000.0)
+        attrs = {"ring": fired._ring_no} if ring_origin else {}
+        with self.phases.span("drain.deliver", **attrs):
+            out = dict(fired)  # materializes lazy FiredWindows
+            # the fire cohorts whose rows this delivery makes visible at
+            # the sink. Emit-ring fires: every cohort the drain's fetch
+            # made host-visible (one poll coalesces several sub-batch
+            # fires; each keeps its OWN dispatch stamp); a pack fire: its
+            # own; other operators' emissions have none
+            if ring_origin:
+                cohorts = self._ops[nid].take_delivered_fires()
+            else:
+                cohorts = [c for c in (getattr(fired, "cohort", None),)
+                           if c is not None]
+            if "__ts__" in out:
+                # process-function emissions: explicit per-row timestamps
+                ts = np.asarray(out.pop("__ts__"), np.int64)
+                nrec = len(ts)
+            else:
+                nrec = len(out.get("window_end", ()))  # windowed schemas
+                # (keyed rows also carry "key"; windowAll rows don't)
+                ts = (np.asarray(out["window_end"], np.int64) - 1
+                      if nrec else np.zeros(0, np.int64))
+            if nrec:
+                self.metrics["fired_windows"] += nrec
+                valid = np.ones(nrec, bool)
+                self._push_downstream(nid, (out, ts, valid))
+            # latency marker: fire dispatch → delivered at sink (ref:
+            # streaming/runtime/streamrecord/LatencyMarker.java), read
+            # off the fire records; an emission without one is stamped
+            # where it was handed to the drain
+            now = time.perf_counter()
+            for c in cohorts:
+                c["t_sink"] = now
+                self._lat_hist.update((now - c["t_fire"]) * 1000.0)
+            if nrec and not cohorts and not ring_origin:
+                self._lat_hist.update((now - stamp) * 1000.0)
 
-    def _note_ring_latency(self, nid: int) -> None:
-        op = self._ops.get(nid)
-        take = getattr(op, "take_delivered_fire_stamps", None)
-        if take is None:
-            return
-        now = time.time()
-        for fire_stamp in take():
-            self._lat_hist.update((now - fire_stamp) * 1000.0)
+    def fire_records(self) -> List[Dict[str, Any]]:
+        """One record per window end of each fire cohort (the newest
+        ``FIRE_RECORDS``): ``op``, ``window_end`` and, on
+        ``time.perf_counter()``, ``t_input`` (the source handed over the
+        batch that carried the watermark past the end), ``t_fire`` (fire
+        dispatched), ``t_fetch0`` / ``t_fetch1`` (the fetch of its rows
+        began / ended), ``t_sink`` (``sink.write`` returned). A stamp
+        the cohort never reached is ``None``."""
+        stamps = ("t_input", "t_fire", "t_fetch0", "t_fetch1", "t_sink")
+        out = [{"op": c.get("op"), "window_end": int(we),
+                **{k: c.get(k) for k in stamps}}
+               for c in list(self._fires) for we in c["window_ends"]]
+        return out[-FIRE_RECORDS:]
 
     def _stateless_downstream(self, nid: int) -> bool:
         """True iff nothing stateful (window/session/join) is reachable
@@ -2563,7 +2627,8 @@ class Driver:
             # of a blocking device round trip.
             # A pending barrier (_flush_req) cancels the wait instantly.
             if self._emit_defer_s > 0 and items[0] is not None:
-                wait = self._emit_defer_s - (time.time() - items[0][2])
+                wait = self._emit_defer_s - (time.perf_counter()
+                                             - items[0][2])
                 if wait > 0:
                     self._flush_req.wait(wait)
             # opportunistically take the whole backlog: N queued fires
@@ -2585,7 +2650,6 @@ class Driver:
             # the set-after-read race with a second pinned-marker pass.
             barrier = stop or self._flush_req.is_set()
             try:
-                tm0 = time.perf_counter()
                 # fair-drain turn: the device fetch — the part that
                 # holds the shared device→host link — waits its round-
                 # robin turn among co-resident jobs; the host-side
@@ -2595,7 +2659,6 @@ class Driver:
                     with self._link_lock:
                         FiredWindows.materialize_many(
                             [f for _, f, _ in batch], barrier=barrier)
-                self.prof["drain_link_held"] += time.perf_counter() - tm0
                 with self._push_lock:
                     # re-check under the push lock: the run may have
                     # aborted (and aborted the sinks) while this batch
@@ -2648,7 +2711,7 @@ class Driver:
                     if no and getattr(op, "_emit_ring", None) is not None:
                         self._emit_q.put(
                             (nid, FiredWindows(op=op, ring=True, ring_no=no),
-                             time.time()))
+                             time.perf_counter()))
                         extra = True
                 if extra:
                     self._emit_q.join()

@@ -583,7 +583,7 @@ class TestHostPreaggregation:
         wms[-1] = t + 20_000
         op, ours, golden = run_pair(
             assigner, agg, events, wms, golden_agg=golden_agg)
-        took_preagg = op.prof.get("pb_preagg", 0) > 0
+        took_preagg = op.prof.get("preagg_batches", 0) > 0
         assert took_preagg == expect_preagg
         # f32 lane accumulation order differs between the paths; compare
         # with an f32-level tolerance, not digit-exact
