@@ -533,14 +533,21 @@ def ingest_fused_scan_native(
     Returns (result, miss_indices) or None (unavailable / cap
     overflow — the workspace was re-zeroed; caller falls back). Pass
     ``cont`` to continue a previous scan's pair list and stats (the
-    miss-registration second pass)."""
+    miss-registration second pass).
+
+    ``result.stats`` is ``[n_valid, n_late, n_bad, pane_min, pane_max,
+    n_refire, n_miss, cmax, pane_moves]``, summed (min / max taken)
+    over a scan and its ``cont`` calls. The ninth, ``pane_moves``, is
+    how many records had their pane worked out by division because
+    they did not lie in the pane of the record before: 1-2 a batch on
+    an in-order stream, ~n where panes alternate record by record."""
     lib = _load()
     if lib is None:
         return None
     n = len(ts)
     if cont is None:
         out_pairs = np.empty(cap, np.int32)
-        stats = np.zeros(8, np.int64)
+        stats = np.zeros(9, np.int64)
         stats[3] = np.iinfo(np.int64).max   # pmin seed
         stats[4] = np.iinfo(np.int64).min   # pmax seed
         bitmap = np.zeros(max((bitmap_bits + 7) // 8, 1), np.uint8)

@@ -1904,7 +1904,7 @@ class WindowOperator:
                     self._preagg_ws.rezero()
                     return False
             (n_valid, n_late, n_bad, pmin, pmax, n_refire, _nmiss,
-             cmax) = (int(x) for x in res.stats)
+             cmax, pane_moves) = (int(x) for x in res.stats)
             if n_valid == 0:
                 break
             if self._min_pane_seen is None or pmin < self._min_pane_seen:
@@ -1923,6 +1923,9 @@ class WindowOperator:
             break
         self.state_version += 1
         self.late_records += n_late
+        # how often the scan had to divide for a pane: 1-2 a batch on an
+        # in-order stream, ~n when panes alternate (codec.cc PaneCursor)
+        self.prof["scan_pane_moves"] += pane_moves
         if n_bad:
             account_full_drop(self, n_bad)
         if n_refire:

@@ -2018,6 +2018,13 @@ class Driver:
             for k, v in getattr(op, "prof", {}).items():
                 final[f"profile.op{nid}.{k}"] = final.get(
                     f"profile.op{nid}.{k}", 0.0) + v
+                if k == "scan_pane_moves":
+                    # once more beside the leaf it explains: whatever
+                    # reads profile.phase.* (bench artifacts, the
+                    # benchmark's detail line) then shows whether
+                    # window.key_scan ran on the pane cursor's cheap path
+                    final["profile.phase.scan_pane_moves"] = final.get(
+                        "profile.phase.scan_pane_moves", 0.0) + v
         return JobResult(job_name, final)
 
     # -- bounded execution (execution.runtime-mode=batch) ----------------

@@ -470,12 +470,102 @@ int64_t preagg_combine(int64_t n, const int64_t* slots, const int64_t* panes,
   return np_;
 }
 
-// Fused ingest pass for the window operator's count-only fast lane:
-// ONE scan over (ts, slots) computes event-time panes, the
-// late-beyond-lateness drop mask, bad-slot accounting, pane min/max,
-// late-refire candidates, AND the (slot, ring-column) histogram that
-// the pre-agg upload ships — replacing four or five full-array numpy
-// passes (each ~5-10ms per 2^20 on the single-core host) with one.
+// The pane of event time that the record before fell in, kept between
+// records. A record costs two 64-bit divisions when its pane is worked
+// out from its timestamp (t / pane_ms floored, pane % ring), and an
+// in-order stream changes pane once in ~10^6 records (PERF.md section 5:
+// a 2^20 batch spans 23 ms against 2 s panes). So a record whose
+// t = ts - offset_ms lies in [lo, lo + pane_ms) takes pane, ring column,
+// dead and refire from here for one compare; any other moves the cursor
+// with the divisions above. Nothing is assumed of the stream's order: a
+// stream whose panes alternate record by record moves every time and
+// pays the divisions plus that compare.
+//
+// pmin / pmax and the refire bitmap change only with the pane, so the
+// first VALID record of each cursor position writes them (`noted`); a
+// move on a late or bad-slot record writes nothing, as a scan record by
+// record would not.
+struct PaneCursor {
+  int64_t pane_ms, ring, dead_below, refire_below, bitmap_base, bitmap_bits;
+  int64_t lo;      // first t of the cached pane
+  uint64_t width;  // pane_ms, or 0 while nothing is cached: no t matches
+  int64_t pane, col;
+  int64_t pmin, pmax;
+  int64_t moves;   // times the pane was worked out by division
+  bool dead;       // pane < dead_below: its records are late
+  bool refire;     // a late-refire candidate that has a bit in the bitmap
+  bool noted;      // pmin / pmax / bitmap already hold this pane
+};
+
+static inline void pane_cursor_init(
+    PaneCursor* c, int64_t pane_ms, int64_t ring, int64_t dead_below,
+    int64_t refire_below, int64_t bitmap_base, int64_t bitmap_len,
+    int64_t pmin, int64_t pmax) {
+  c->pane_ms = pane_ms; c->ring = ring; c->dead_below = dead_below;
+  c->refire_below = refire_below; c->bitmap_base = bitmap_base;
+  c->bitmap_bits = bitmap_len * 8;
+  c->lo = 0; c->width = 0; c->pane = 0; c->col = 0;
+  c->pmin = pmin; c->pmax = pmax; c->moves = 0;
+  c->dead = c->refire = c->noted = false;
+}
+
+// Point the cursor at t's pane (t = ts - offset_ms; floored division,
+// so negative event times land in the pane below zero). True when it
+// had to move.
+static inline bool pane_cursor_seek(PaneCursor* c, int64_t t) {
+  if ((uint64_t)t - (uint64_t)c->lo < c->width) return false;
+  int64_t rem = t % c->pane_ms;
+  int64_t pane = t / c->pane_ms - (rem < 0 ? 1 : 0);
+  if (rem < 0) rem += c->pane_ms;
+  c->lo = t - rem;
+  c->width = (uint64_t)c->pane_ms;
+  c->pane = pane;
+  c->col = pane % c->ring;
+  if (c->col < 0) c->col += c->ring;
+  c->dead = pane < c->dead_below;
+  c->refire = false;
+  if (pane < c->refire_below) {
+    int64_t off = pane - c->bitmap_base;
+    c->refire = off >= 0 && off < c->bitmap_bits;
+  }
+  c->noted = false;
+  ++c->moves;
+  return true;
+}
+
+// A valid record landed in the cursor's pane: fold the pane into
+// pmin / pmax and the refire bitmap, once per cursor position.
+static inline void pane_cursor_note(PaneCursor* c, uint8_t* refire_bitmap) {
+  if (c->noted) return;
+  c->noted = true;
+  if (c->pane < c->pmin) c->pmin = c->pane;
+  if (c->pane > c->pmax) c->pmax = c->pane;
+  if (c->refire) {
+    int64_t off = c->pane - c->bitmap_base;
+    refire_bitmap[off >> 3] |= (uint8_t)(1u << (off & 7));
+  }
+}
+
+// Count one record into pair p = slot * ring + column; a pair's first
+// touch appends it to out_pairs. False when that would pass ``cap``.
+static inline bool pair_count(int32_t* hist, int64_t p, int32_t* out_pairs,
+                              int64_t* np_, int64_t cap) {
+  if (hist[p] == 0) {
+    if (*np_ >= cap) return false;
+    out_pairs[(*np_)++] = (int32_t)p;
+  }
+  ++hist[p];
+  return true;
+}
+
+// Fused ingest pass over (ts, slots) for a caller that already holds
+// the slots: per record the event-time pane (through the cursor above),
+// the late-beyond-lateness drop, bad-slot accounting, pane min / max,
+// late-refire candidates, and the (slot, ring-column) histogram that the
+// pre-agg upload ships, in one scan instead of four or five full-array
+// numpy passes. ingest_fused_scan below is the same semantics behind a
+// key probe, and the one the window operator calls; the two share the
+// cursor so that they stay one algorithm.
 // ``hist`` must be zero on entry; touched entries are reset (see
 // preagg_combine). Returns distinct-pair count, or -1 on cap overflow
 // (workspaces left dirty — caller re-zeros).
@@ -488,30 +578,18 @@ int64_t ingest_combine(
     int64_t* out_stats, uint8_t* refire_bitmap, int64_t bitmap_base,
     int64_t bitmap_len) {
   int64_t np_ = 0, n_valid = 0, n_late = 0, n_bad = 0, n_refire = 0;
-  int64_t pmin = INT64_MAX, pmax = INT64_MIN;
+  PaneCursor cur;
+  pane_cursor_init(&cur, pane_ms, ring, dead_below, refire_below,
+                   bitmap_base, bitmap_len, INT64_MAX, INT64_MIN);
   for (int64_t i = 0; i < n; ++i) {
-    int64_t t = ts[i] - offset_ms;
-    int64_t pane = t / pane_ms - ((t % pane_ms) < 0 ? 1 : 0);  // floored
-    if (pane < dead_below) { ++n_late; continue; }
+    pane_cursor_seek(&cur, ts[i] - offset_ms);
+    if (cur.dead) { ++n_late; continue; }
     if (slots[i] < 0) { ++n_bad; continue; }
     ++n_valid;
-    if (pane < pmin) pmin = pane;
-    if (pane > pmax) pmax = pane;
-    if (pane < refire_below) {
-      int64_t off = pane - bitmap_base;
-      if (off >= 0 && off < bitmap_len * 8) {
-        refire_bitmap[off >> 3] |= (uint8_t)(1u << (off & 7));
-        ++n_refire;
-      }
-    }
-    int64_t col = pane % ring;
-    if (col < 0) col += ring;
-    int64_t p = slots[i] * ring + col;
-    if (hist[p] == 0) {
-      if (np_ >= cap) return -1;
-      out_pairs[np_++] = (int32_t)p;
-    }
-    hist[p] += 1;
+    pane_cursor_note(&cur, refire_bitmap);
+    n_refire += cur.refire;
+    if (!pair_count(hist, slots[i] * ring + cur.col, out_pairs, &np_, cap))
+      return -1;
   }
   for (int64_t j = 0; j < np_; ++j) {
     int64_t p = out_pairs[j];
@@ -521,31 +599,56 @@ int64_t ingest_combine(
   out_stats[0] = n_valid;
   out_stats[1] = n_late;
   out_stats[2] = n_bad;
-  out_stats[3] = pmin;
-  out_stats[4] = pmax;
+  out_stats[3] = cur.pmin;
+  out_stats[4] = cur.pmax;
   out_stats[5] = n_refire;
   return np_;
 }
 
 // Fully-fused count-only ingest: key->slot directory probe (the open-
 // addressing table above) + event-time pane + late/refire accounting +
-// (slot, ring-column) histogram in ONE scan over (keys, ts) — the
-// separate ht_lookup pass wrote and re-read an 8 MB slots array per
-// 2^20 batch; folding the probe into the scan removes that traffic
-// entirely.
+// (slot, ring-column) histogram in ONE scan over (keys, ts), so no
+// slots array is written and read back. This call is the span
+// window.key_scan, most of the host's time a batch (PERF.md section 5),
+// and its loop does per record only what differs per record:
+//
+//   - the batch is walked in blocks of SCAN_BLOCK records. Pass A
+//     resolves each key of the block to its slot; its iterations are
+//     independent, so the core overlaps the table's cache misses. Pass
+//     B, in record order, does everything that depends on order: the
+//     miss list, late / bad counts, first-touch pair order, the
+//     histogram, and the -1 / -2 returns at the record that overflows;
+//   - the pane comes from the cursor above (no division while the pane
+//     holds), and with it pmin / pmax / the refire bit. Pass B takes a
+//     record "the long way" (everything the semantics ask, in their
+//     order) until one finds its pane cached, live and noted; from there
+//     it RUNS: while the next record has a known slot >= 0 and lies in
+//     the same pane there is nothing to decide but the pair, and
+//     n_valid / n_refire grow by the run's length. Any other record
+//     ends the run and goes the long way;
+//   - cmax, the largest count of any pair, is one pass over the pairs
+//     at the end: counts only grow, so the largest final count IS the
+//     running maximum, also across a ``cont`` call, which walks the
+//     workspace's pairs of both calls.
 //
 // Records whose key is NOT in the table are skipped and their indices
-// written to out_miss (caller registers the new keys, then re-invokes
-// over the miss subset with np_in continuing — at steady state with a
-// bounded key domain the miss list is empty). Keys mapped to a
-// NEGATIVE slot (directory FULL sentinel) count into n_bad exactly as
-// the unfused path did.
+// written to out_miss, ascending (caller registers the new keys, then
+// re-invokes over the miss subset with np_in continuing — at steady
+// state with a bounded key domain the miss list is empty). The probe
+// comes before the pane: an unknown key must reach the miss list even
+// when its record is late (registration is not drop-sensitive). Keys
+// mapped to a NEGATIVE slot (directory FULL sentinel) count into n_bad
+// exactly as the unfused path did.
 //
 // stats accumulate ACROSS calls: [n_valid, n_late, n_bad, pmin, pmax,
-// n_refire, n_miss, cmax]; the caller seeds pmin=INT64_MAX,
-// pmax=INT64_MIN, rest 0. Returns the running distinct-pair count, or
-// -1 on pair-cap overflow / -2 on miss-cap overflow (workspace left
-// dirty; caller re-zeros and falls back).
+// n_refire, n_miss, cmax, pane_moves]; the caller seeds pmin=INT64_MAX,
+// pmax=INT64_MIN, rest 0. pane_moves counts the records whose pane was
+// worked out by division: 1-2 a batch on an in-order stream, ~n where
+// panes alternate. Returns the running distinct-pair count, or
+// -1 on pair-cap overflow / -2 on miss-cap overflow (stats untouched,
+// workspace left dirty; caller re-zeros and falls back).
+static const int64_t SCAN_BLOCK = 512;
+
 int64_t ingest_fused_scan(
     int64_t n, const int64_t* keys, const int64_t* ts, void* ht,
     int64_t pane_ms, int64_t offset_ms, int64_t ring,
@@ -553,56 +656,75 @@ int64_t ingest_fused_scan(
     int32_t* hist, int32_t* out_pairs, int64_t np_in, int64_t cap,
     int64_t* stats, uint8_t* refire_bitmap, int64_t bitmap_base,
     int64_t bitmap_len, int64_t* out_miss, int64_t miss_cap) {
-  FtHashTable* t = (FtHashTable*)ht;
+  const FtHashTable* t = (const FtHashTable*)ht;
   int64_t np_ = np_in, n_valid = 0, n_late = 0, n_bad = 0;
   int64_t n_refire = 0, n_miss = stats[6];
-  int64_t pmin = stats[3], pmax = stats[4], cmax = stats[7];
-  for (int64_t i = 0; i < n; ++i) {
-    // probe first: an unknown key must reach the miss list even when
-    // its record is late (registration is not drop-sensitive)
-    uint64_t ix = ht_mix((uint64_t)keys[i]) & t->mask;
-    int64_t slot;
-    for (;;) {
-      if (!t->used[ix]) { slot = INT64_MIN; break; }  // miss
-      if (t->keys[ix] == keys[i]) { slot = t->vals[ix]; break; }
-      ix = (ix + 1) & t->mask;
-    }
-    if (slot == INT64_MIN) {
-      if (n_miss >= miss_cap) return -2;
-      out_miss[n_miss++] = i;
-      continue;
-    }
-    int64_t tt = ts[i] - offset_ms;
-    int64_t pane = tt / pane_ms - ((tt % pane_ms) < 0 ? 1 : 0);
-    if (pane < dead_below) { ++n_late; continue; }
-    if (slot < 0) { ++n_bad; continue; }
-    ++n_valid;
-    if (pane < pmin) pmin = pane;
-    if (pane > pmax) pmax = pane;
-    if (pane < refire_below) {
-      int64_t off = pane - bitmap_base;
-      if (off >= 0 && off < bitmap_len * 8) {
-        refire_bitmap[off >> 3] |= (uint8_t)(1u << (off & 7));
-        ++n_refire;
+  PaneCursor cur;
+  pane_cursor_init(&cur, pane_ms, ring, dead_below, refire_below,
+                   bitmap_base, bitmap_len, stats[3], stats[4]);
+  int64_t slot_of[SCAN_BLOCK];  // INT64_MIN = key not in the table
+  // the last record taken the long way found its pane cached: the
+  // stream runs along one pane, it does not hop between panes
+  bool steady = false;
+  for (int64_t b0 = 0; b0 < n; b0 += SCAN_BLOCK) {
+    const int64_t bn = n - b0 < SCAN_BLOCK ? n - b0 : SCAN_BLOCK;
+    const int64_t* bk = keys + b0;
+    const int64_t* bts = ts + b0;
+    for (int64_t j = 0; j < bn; ++j) {
+      uint64_t ix = ht_mix((uint64_t)bk[j]) & t->mask;
+      int64_t slot;
+      for (;;) {
+        if (!t->used[ix]) { slot = INT64_MIN; break; }
+        if (t->keys[ix] == bk[j]) { slot = t->vals[ix]; break; }
+        ix = (ix + 1) & t->mask;
       }
+      slot_of[j] = slot;
     }
-    int64_t col = pane % ring;
-    if (col < 0) col += ring;
-    int64_t p = slot * ring + col;
-    if (hist[p] == 0) {
-      if (np_ >= cap) return -1;
-      out_pairs[np_++] = (int32_t)p;
+    for (int64_t j = 0; j < bn;) {
+      if (steady && cur.noted && !cur.dead) {
+        // the run: nothing to decide for a record but its pair
+        const uint64_t lo_ts = (uint64_t)cur.lo + (uint64_t)offset_ms;
+        const uint64_t width = cur.width;
+        const int64_t col = cur.col, j0 = j;
+        for (; j < bn; ++j) {
+          const int64_t slot = slot_of[j];
+          if (slot < 0 || (uint64_t)bts[j] - lo_ts >= width) break;
+          if (!pair_count(hist, slot * ring + col, out_pairs, &np_, cap))
+            return -1;
+        }
+        n_valid += j - j0;
+        if (cur.refire) n_refire += j - j0;
+        if (j == bn) break;
+      }
+      // one record the long way: anything may happen to it
+      const int64_t slot = slot_of[j];
+      if (slot == INT64_MIN) {
+        if (n_miss >= miss_cap) return -2;
+        out_miss[n_miss++] = b0 + j++;
+        continue;
+      }
+      steady = !pane_cursor_seek(&cur, bts[j++] - offset_ms);
+      if (cur.dead) { ++n_late; continue; }
+      if (slot < 0) { ++n_bad; continue; }
+      ++n_valid;
+      pane_cursor_note(&cur, refire_bitmap);
+      n_refire += cur.refire;
+      if (!pair_count(hist, slot * ring + cur.col, out_pairs, &np_, cap))
+        return -1;
     }
-    if (++hist[p] > cmax) cmax = hist[p];
   }
+  int64_t cmax = stats[7];
+  for (int64_t j = 0; j < np_; ++j)
+    if (hist[out_pairs[j]] > cmax) cmax = hist[out_pairs[j]];
   stats[0] += n_valid;
   stats[1] += n_late;
   stats[2] += n_bad;
-  stats[3] = pmin;
-  stats[4] = pmax;
+  stats[3] = cur.pmin;
+  stats[4] = cur.pmax;
   stats[5] += n_refire;
   stats[6] = n_miss;
   stats[7] = cmax;
+  stats[8] += cur.moves;
   return np_;
 }
 

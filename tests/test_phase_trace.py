@@ -121,12 +121,27 @@ class TestJobPhases:
         op_keys = {k.split(".", 2)[2] for k in res.metrics
                    if k.startswith("profile.op")}
         assert op_keys <= {"drain_fetch", "drain_fetches", "drain_skips",
-                           "preagg_batches"}
+                           "preagg_batches", "scan_pane_moves"}
         fetch = sum(v for k, v in res.metrics.items()
                     if k.startswith("profile.op")
                     and k.endswith(".drain_fetch"))
         assert fetch == pytest.approx(
             res.metrics["profile.phase.drain.fetch"], abs=1e-5)
+
+    def test_scan_pane_moves_beside_the_key_scan(self, job):
+        """The scan's own counter: an in-order stream costs a division or
+        two a batch (one more in a call over newly registered keys), not
+        one a record; and it rides along under ``profile.phase.``."""
+        m = job[0].metrics
+        per_op = {k: v for k, v in m.items()
+                  if k.startswith("profile.op")
+                  and k.endswith(".scan_pane_moves")}
+        assert len(per_op) == 1
+        (key, moves), = per_op.items()
+        batches = m[key.replace("scan_pane_moves", "preagg_batches")]
+        assert batches > 0
+        assert batches <= moves <= 4 * batches < BATCH
+        assert m["profile.phase.scan_pane_moves"] == moves
 
 
 class TestFireRecords:

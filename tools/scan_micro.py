@@ -1,0 +1,149 @@
+"""Micro-check of the native key scan: ns a record, on the host it runs on.
+
+Times ``ingest_fused_scan`` (``native/codec.cc``; the span
+``window.key_scan`` of the count-only lane) alone, one call a batch as
+the operator makes it, on the benchmark configuration's own keys
+(``benchmark/configs/nexmark_q5.py`` ``make_pool``) and the replay
+mix's timestamps, two ways:
+
+- ``in_order``: the stream as the cells offer it, so a batch has one
+  pane or two and the scan's pane cursor hardly moves;
+- ``shuffled``: the same keys with timestamps drawn record by record
+  from 5 panes, so the cursor moves on about four records in five and
+  every move pays the floored division.
+
+Host only: no device program runs and nothing here is a benchmark
+metric. One JSON line: ns a record (best and median of ``--reps``
+calls), cursor moves a batch (``null`` from a library that has no
+such counter), and what the machine gives the process — CPU model,
+``os.cpu_count()``, the affinity mask's size, the cgroup's CPU quota.
+
+    python tools/scan_micro.py [--n 1048576] [--reps 30] [--seed 1]
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.configs import nexmark_q5  # noqa: E402
+from flink_tpu import native_codec  # noqa: E402
+from flink_tpu.api.windowing import SlidingEventTimeWindows  # noqa: E402
+from flink_tpu.config import Configuration  # noqa: E402
+from flink_tpu.ops.window import WindowPlan  # noqa: E402
+from flink_tpu.state.keyed import KeyDirectory  # noqa: E402
+
+PANES_SHUFFLED = 5
+
+
+def machine() -> dict:
+    model = None
+    try:
+        with open("/proc/cpuinfo") as f:
+            model = next((ln.split(":", 1)[1].strip() for ln in f
+                          if ln.startswith("model name")), None)
+    except OSError:
+        pass
+    quota = None
+    for path in ("/sys/fs/cgroup/cpu.max",
+                 "/sys/fs/cgroup/cpu/cpu.cfs_quota_us"):
+        try:
+            with open(path) as f:
+                quota = f.read().strip()
+            break
+        except OSError:
+            continue
+    return {"cpu_model": model, "cpu_count": os.cpu_count(),
+            "sched_affinity": len(os.sched_getaffinity(0)),
+            "cgroup_cpu_quota": quota}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--n", type=int, default=None,
+                    help="records a batch (default: the job conf's)")
+    ap.add_argument("--reps", type=int, default=30)
+    ap.add_argument("--seed", type=int, default=1)
+    args = ap.parse_args()
+    if not native_codec.native_available():
+        print(json.dumps({"error": native_codec.unavailable_reason()}))
+        return 1
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "nexmark_q5.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(ROOT, "benchmark", "traffic", "replay.json")) as f:
+        rate = int(json.load(f)["events_per_ms"])
+    p = cfg["params"]
+    conf = Configuration.from_file(os.path.join(ROOT, "confs", cfg["conf"]))
+    n = args.n or int(conf.get_raw("pipeline.microbatch-size"))
+    pool = nexmark_q5.make_pool(args.seed, n, p)
+
+    # the job's own pane plan: q5.sql's HOP under the suite's watermark delay
+    plan = WindowPlan.plan(
+        SlidingEventTimeWindows.of(int(p["window_ms"]), int(p["slide_ms"])),
+        max_out_of_orderness_ms=int(p["out_of_orderness_ms"]))
+    pane_ms, ring = plan.pane_ms, plan.ring
+    directory = KeyDirectory(int(conf.get_raw("state.num-key-shards")),
+                             int(conf.get_raw("state.slots-per-shard")))
+    ws = native_codec.PreaggWorkspace(directory.local_slots * ring, 0)
+    cap = 1 << 19
+    rng = np.random.default_rng(args.seed)
+    lo = np.iinfo(np.int64).min
+
+    def scan(keys, ts):
+        t0 = time.perf_counter()
+        out = native_codec.ingest_fused_scan_native(
+            keys, ts, directory._table, pane_ms, plan.offset_ms, ring, ws,
+            cap, lo, lo, 0, miss_cap=len(ts))
+        dt = time.perf_counter() - t0
+        res, miss = out
+        if len(miss):   # the operator's own second pass, untimed here
+            directory.register_misses(keys[miss])
+            res, _ = native_codec.ingest_fused_scan_native(
+                keys[miss], ts[miss], directory._table, pane_ms,
+                plan.offset_ms, ring, ws, cap, lo, lo, 0, cont=res,
+                miss_cap=1)
+        moves = int(res.stats[8]) if len(res.stats) > 8 else None
+        native_codec.ingest_fused_finalize_pairs_native(res, ws)
+        return dt, moves
+
+    def ts_in_order(i):
+        return (i * n + np.arange(n, dtype=np.int64)) // rate
+
+    def ts_shuffled(i):
+        return (i * PANES_SHUFFLED * pane_ms
+                + rng.integers(0, PANES_SHUFFLED * pane_ms, n))
+
+    out = {"n": n, "reps": args.reps, "seed": args.seed, "ring": ring,
+           "library": os.path.basename(native_codec.build_library())}
+    for name, make_ts in (("in_order", ts_in_order),
+                          ("shuffled", ts_shuffled)):
+        for i in range(len(pool)):      # registers the keys, warms caches
+            scan(pool[i]["auction"], make_ts(i))
+        times, moves = [], []
+        for i in range(args.reps):
+            dt, mv = scan(pool[i % len(pool)]["auction"], make_ts(i))
+            times.append(dt)
+            moves.append(mv)
+        out[name] = {
+            "ns_per_record_best": 1e9 * min(times) / n,
+            "ns_per_record_median": 1e9 * statistics.median(times) / n,
+            "pane_moves_per_batch": None if moves[0] is None
+            else statistics.mean(moves)}
+    out["keys"] = directory.num_keys()
+    out["machine"] = machine()
+    print(json.dumps(out))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
