@@ -412,8 +412,8 @@ def traced_run(trace_dir, overrides, nexmark, n_batches,
 def mesh_inspector(name, n_chips, devices):
     """The mesh plane's own checks, as a q5_plane ``inspect`` hook: pane
     state key-sharded over ``n_chips`` distinct devices, a 1/n share of
-    the rows on each, memory in use grown on every one."""
-    import numpy as np
+    the rows on each, memory in use grown on every one, records received
+    by every one."""
     from jax.sharding import NamedSharding
 
     def in_use():
@@ -446,13 +446,16 @@ def mesh_inspector(name, n_chips, devices):
             raise SmokeFailure(
                 f"{name}: bytes_in_use did not grow on every device "
                 f"(before {before}, growth {grew})")
-        # records per device: the reference's per-auction totals through
-        # the operator's own key -> shard -> device map
-        dev_of = (op.directory.shard_of(
-            np.arange(pane_counts.shape[1], dtype=np.int64))
-            // op.mesh_plan.shards_per_device)
-        per_dev = np.bincount(dev_of, weights=pane_counts.sum(axis=0),
-                              minlength=n_chips)
+        # records per device: the program's own count (what each device
+        # received from the keyed exchange), which has to add up to the
+        # reference's bids; every device has to have received some
+        per_dev = op.exchange_stats()["records"]
+        if (len(per_dev) != n_chips or int(per_dev.min()) <= 0
+                or int(per_dev.sum()) != int(pane_counts.sum())):
+            raise SmokeFailure(
+                f"{name}: the exchange's records per device "
+                f"{per_dev.tolist()} do not add up to the reference's "
+                f"{int(pane_counts.sum())} bids on {n_chips} devices")
         return {"state_sharding": str(counts.sharding.spec),
                 "state_devices": [str(d) for d in devs],
                 "state_rows_per_device": rows_per,

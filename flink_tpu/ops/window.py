@@ -36,7 +36,7 @@ import functools
 import threading
 import time
 from collections.abc import Mapping
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1108,6 +1108,204 @@ class WindowPlan:
 # exchange/ reuses the same kernels inside shard_map).
 # ---------------------------------------------------------------------------
 
+class _ShardedKernels(NamedTuple):
+    """The jitted ``shard_map`` programs of one mesh configuration."""
+
+    init: Any
+    apply: Any
+    apply_split: Any
+    fire_pack: Any
+    ring_topn: Any
+    clear: Any
+
+
+@functools.lru_cache(maxsize=32)
+def _sharded_kernels(mp: MeshPlan, agg, layout: PaneStateLayout,
+                     panes_per_window: int,
+                     exchange_capacity: Optional[int], exchange_impl: str,
+                     topn_spec) -> _ShardedKernels:
+    """The mesh path's programs, built once per configuration and shared
+    by every operator of that configuration in the process (the local
+    path's module-level jits do the same through their static
+    arguments): an operator that built its own closures made every job
+    trace and compile them again — inside the job's first batches.
+    The fire and top-n programs take their compaction capacity as a
+    static argument, so each pow2 bucket is one entry of the jit's own
+    cache. Like any module-level jit, an entry lives as long as the
+    process; what it keeps alive is its mesh and programs, not an
+    operator.
+
+    ``layout`` is one device's block."""
+    from flink_tpu.exchange.spi import get_shuffle
+
+    keyby_exchange = get_shuffle(exchange_impl)
+    mesh = mp.mesh
+    n_dev = mp.n_devices
+    spd = mp.slots_per_device
+    ring_len = layout.ring
+    rows_local = layout.rows
+    total_rows = n_dev * rows_local
+
+    @functools.partial(jax.jit, out_shardings=mp.row_sharding())
+    def init():
+        def lane(width, fill):
+            if width == 0:
+                return None
+            return jnp.full((total_rows, ring_len, width),
+                            fill, jnp.float32)
+
+        return PaneState(
+            sums=lane(layout.sum_width, 0.0),
+            maxs=lane(layout.max_width, -jnp.inf),
+            mins=lane(layout.min_width, jnp.inf),
+            counts=jnp.zeros((total_rows, ring_len), jnp.int32),
+        )
+
+    def apply_shard(state, packed, data):
+        # packed = global_slot * ring + ring_ix (see apply_kernel);
+        # route by owner device, then rebase to the local slot block
+        cap = exchange_capacity or packed.shape[0]
+        valid = packed >= 0
+        p = jnp.where(valid, packed, 0)
+        slot = p // ring_len
+        dest = jnp.where(valid, slot // spd, 0).astype(jnp.int32)
+        payload = {"__sp__": packed, **data}
+        recv, rvalid, overflow = keyby_exchange(
+            dest, valid, payload, n_devices=n_dev, capacity=cap)
+        my = lax.axis_index(AXIS)
+        rp = recv["__sp__"]
+        rvalid = rvalid & (rp >= 0)
+        rq = jnp.where(rvalid, rp, 0)
+        local_packed = jnp.where(
+            rvalid,
+            (rq // ring_len - my * spd) * ring_len + rq % ring_len,
+            -1)
+        new_state = apply_kernel(
+            state, local_packed,
+            {k: v for k, v in recv.items() if not k.startswith("__")},
+            agg=agg, ring=ring_len, dump_row=layout.slots)
+        # what the exchange did, for the host to read when the step has
+        # retired (see _resolve_overflow): word 0 the records the
+        # all_to_all dropped over all devices, word 1 + d the records
+        # device d received and scattered — one psum, as before
+        report = (jnp.zeros(1 + n_dev, jnp.int32)
+                  .at[0].set(jnp.sum(overflow).astype(jnp.int32))
+                  .at[1 + my].set(jnp.sum(rvalid).astype(jnp.int32)))
+        return new_state, lax.psum(report, AXIS)
+
+    def lane_spec(width):
+        return None if width == 0 else P(AXIS)
+
+    state_spec = PaneState(
+        sums=lane_spec(layout.sum_width), maxs=lane_spec(layout.max_width),
+        mins=lane_spec(layout.min_width), counts=P(AXIS))
+    batch_spec = P(AXIS)
+    rep = P()
+
+    apply = jax.jit(
+        shard_map(
+            apply_shard, mesh=mesh,
+            in_specs=(state_spec, batch_spec, batch_spec),
+            out_specs=(state_spec, rep),
+        ),
+        donate_argnums=(0,),
+    )
+
+    def apply_shard_split(state, sc, data):
+        # 3-byte upload (see apply_kernel_split): decode + recombine
+        # to the packed form on device — the host link gets the byte
+        # savings; the ICI exchange keeps its existing layout
+        slot, col = split_decode(sc)
+        packed = jnp.where(
+            slot == INVALID_SLOT_U16,
+            jnp.int32(-1),
+            slot.astype(jnp.int32) * ring_len + col.astype(jnp.int32))
+        return apply_shard(state, packed, data)
+
+    apply_split = jax.jit(
+        shard_map(
+            apply_shard_split, mesh=mesh,
+            in_specs=(state_spec, batch_spec, batch_spec),
+            out_specs=(state_spec, rep),
+        ),
+        donate_argnums=(0,),
+    )
+
+    # compaction capacity is a static shape → one compiled program per
+    # pow2 bucket (the bucket grows with registered keys), kept by the
+    # jit's own cache as the local path's static arguments are
+    @functools.partial(jax.jit, static_argnames=("out_cap",))
+    def fire_shard(state, params, used_mask, out_cap: int):
+        def body(state, params, used_mask):
+            packed = fire_pack_kernel(
+                state, params, used_mask,
+                agg=agg, panes_per_window=panes_per_window,
+                ring=ring_len, out_cap=out_cap)
+            # globalize row ids (each device block carries its own
+            # rows); column 0 of body rows is the slot row, head
+            # row 0 holds n
+            my = lax.axis_index(AXIS).astype(jnp.int32)
+            offset = jnp.zeros_like(packed[:, 0]).at[1:].set(
+                my * rows_local)
+            return packed.at[:, 0].add(offset)
+
+        return shard_map(
+            body, mesh=mesh, in_specs=(state_spec, rep, P(AXIS)),
+            out_specs=P(AXIS))(state, params, used_mask)
+
+    topn_shard = None
+    if topn_spec is not None:
+        by, topn = topn_spec
+
+        @functools.partial(jax.jit, static_argnames=("sel_cap",))
+        def topn_shard(state, emit_ring, params, used_mask, sel_cap: int):
+            def body(state, emit_ring, params, used_mask):
+                lo, hi, anchor, end_panes, w_valid = (
+                    _unpack_fire_params(params))
+                # Global per-window threshold: each device ranks its
+                # local rows, the top-k candidates ride one tiny
+                # all_gather over ICI, every device selects its local
+                # rows against the GLOBAL n-th value (distributed
+                # RANK() <= n), and appends winners to ITS OWN block
+                # of the emit ring.
+                sums, maxs, mins, counts = fire_kernel(
+                    state, end_panes, w_valid, lo, hi,
+                    panes_per_window=panes_per_window, ring=ring_len)
+                rows = counts.shape[0]
+                nz = ((counts > 0) & used_mask[:, None]
+                      & w_valid[None, :])
+                res = agg.finalize(sums, maxs, mins, counts)
+                v = jnp.where(nz, res[by].astype(jnp.float32), -jnp.inf)
+                k = min(topn, rows)
+                local_top = lax.top_k(v.T, k)[0]               # (W, k)
+                all_top = lax.all_gather(
+                    local_top, AXIS, axis=1, tiled=True)       # (W, n_dev*k)
+                # -inf thresh (< n global candidates) selects all real
+                # rows — nz masks out non-candidates
+                thresh = lax.top_k(all_top, k)[0][:, k - 1]
+                my = lax.axis_index(AXIS).astype(jnp.int32)
+                return _topn_select_append(
+                    emit_ring, sums, maxs, mins, counts, nz, v,
+                    thresh, end_panes, anchor, agg=agg,
+                    sel_cap=sel_cap, row_offset=my * rows_local)
+
+            return shard_map(
+                body, mesh=mesh,
+                in_specs=(state_spec, P(AXIS), rep, P(AXIS)),
+                out_specs=P(AXIS))(state, emit_ring, params, used_mask)
+
+    clear = jax.jit(
+        shard_map(
+            clear_kernel, mesh=mesh,
+            in_specs=(state_spec, rep),
+            out_specs=state_spec,
+        ),
+        donate_argnums=(0,),
+    )
+    return _ShardedKernels(init, apply, apply_split, fire_shard,
+                           topn_shard, clear)
+
+
 class WindowOperator:
     """Drives the kernels for one keyed window aggregation.
 
@@ -1328,6 +1526,15 @@ class WindowOperator:
         self._max_pane_seen: Optional[int] = None
         self.late_records: int = 0
         self.exchange_overflow: int = 0
+        # what the keyed exchange of a mesh did over the job: steps
+        # dispatched (a batch is one chunk unless a capacity splits it),
+        # bytes handed to the upload, and the records each mesh device
+        # received — counted on the device that scattered them and read
+        # with the overflow word (see _resolve_overflow)
+        self.exchange_chunks: int = 0
+        self.exchange_upload_bytes: int = 0
+        self.exchange_records = np.zeros(
+            mesh_plan.n_devices if mesh_plan is not None else 0, np.int64)
         # bumped on every mutation; checkpointing reuses the previous
         # blob when unchanged (incremental, RocksDB shared-SST analogue)
         self.state_version: int = 0
@@ -1458,197 +1665,33 @@ class WindowOperator:
         return _next_pow2(min(nk, per_block) * w)
 
     def _init_sharded_state(self) -> PaneState:
-        mp = self.mesh_plan
-        total_rows = mp.n_devices * self.layout.rows
-        sharding = mp.row_sharding()
+        return self._sharded_kernels().init()
 
-        @functools.partial(jax.jit, out_shardings=sharding)
-        def init():
-            def lane(width, fill):
-                if width == 0:
-                    return None
-                return jnp.full((total_rows, self.layout.ring, width),
-                                fill, jnp.float32)
-
-            return PaneState(
-                sums=lane(self.layout.sum_width, 0.0),
-                maxs=lane(self.layout.max_width, -jnp.inf),
-                mins=lane(self.layout.min_width, jnp.inf),
-                counts=jnp.zeros((total_rows, self.layout.ring), jnp.int32),
-            )
-
-        return init()
+    def _sharded_kernels(self) -> "_ShardedKernels":
+        return _sharded_kernels(
+            self.mesh_plan, self.agg, self.layout,
+            self.plan.panes_per_window, self.exchange_capacity,
+            self.exchange_impl, self._topn)
 
     def _build_sharded_kernels(self) -> None:
         """The full distributed hot path: per-device bucket-by-owner →
         all_to_all over the mesh (keyBy repartition on ICI) → local pane
         scatter. Fire/clear are embarrassingly parallel over row blocks.
+        The programs are shared process-wide by configuration (see
+        ``_sharded_kernels``), as the local path's module-level jits
+        are: a second job of the same shape traces and compiles nothing.
         """
-        from flink_tpu.exchange.spi import get_shuffle
-
-        keyby_exchange = get_shuffle(self.exchange_impl)
-        mp = self.mesh_plan
-        agg = self.agg
-        plan = self.plan
-        layout = self.layout
-        spd = mp.slots_per_device
-        n_dev = mp.n_devices
-
-        ring_len = plan.ring
-
-        def apply_shard(state, packed, data):
-            # packed = global_slot * ring + ring_ix (see apply_kernel);
-            # route by owner device, then rebase to the local slot block
-            cap = self.exchange_capacity or packed.shape[0]
-            valid = packed >= 0
-            p = jnp.where(valid, packed, 0)
-            slot = p // ring_len
-            dest = jnp.where(valid, slot // spd, 0).astype(jnp.int32)
-            payload = {"__sp__": packed, **data}
-            recv, rvalid, overflow = keyby_exchange(
-                dest, valid, payload, n_devices=n_dev, capacity=cap)
-            my = lax.axis_index(AXIS)
-            rp = recv["__sp__"]
-            rvalid = rvalid & (rp >= 0)
-            rq = jnp.where(rvalid, rp, 0)
-            local_packed = jnp.where(
-                rvalid,
-                (rq // ring_len - my * spd) * ring_len + rq % ring_len,
-                -1)
-            new_state = apply_kernel(
-                state, local_packed,
-                {k: v for k, v in recv.items() if not k.startswith("__")},
-                agg=agg, ring=ring_len, dump_row=layout.slots)
-            return new_state, lax.psum(jnp.sum(overflow), AXIS)
-
-        rows_local = layout.rows
-
-        state_spec = jax.tree_util.tree_map(lambda _: P(AXIS), self.state)
-        batch_spec = P(AXIS)
-        rep = P()
-
-        self._apply_sharded = jax.jit(
-            shard_map(
-                apply_shard, mesh=mp.mesh,
-                in_specs=(state_spec, batch_spec, batch_spec),
-                out_specs=(state_spec, rep),
-            ),
-            donate_argnums=(0,),
-        )
-
-        def apply_shard_split(state, sc, data):
-            # 3-byte upload (see apply_kernel_split): decode + recombine
-            # to the packed form on device — the host link gets the byte
-            # savings; the ICI exchange keeps its existing layout
-            slot, col = split_decode(sc)
-            packed = jnp.where(
-                slot == INVALID_SLOT_U16,
-                jnp.int32(-1),
-                slot.astype(jnp.int32) * ring_len + col.astype(jnp.int32))
-            return apply_shard(state, packed, data)
-
-        self._apply_sharded_split = jax.jit(
-            shard_map(
-                apply_shard_split, mesh=mp.mesh,
-                in_specs=(state_spec, batch_spec, batch_spec),
-                out_specs=(state_spec, rep),
-            ),
-            donate_argnums=(0,),
-        )
+        k = self._sharded_kernels()
+        self._apply_sharded = k.apply
+        self._apply_sharded_split = k.apply_split
         # global slot ids must fit uint16 with 0xFFFF reserved
-        self._split_upload = n_dev * spd < INVALID_SLOT_U16 and ring_len <= 256
-
-        # compaction capacity is a static shape → one compiled shard_map
-        # per pow2 bucket (cached; bucket grows with registered keys)
-        fire_cache: Dict[int, Any] = {}
-
-        def fire_pack_sharded(state, params, used_mask, out_cap: int):
-            fn = fire_cache.get(out_cap)
-            if fn is None:
-                def fire_shard(state, params, used_mask):
-                    packed = fire_pack_kernel(
-                        state, params, used_mask,
-                        agg=agg, panes_per_window=plan.panes_per_window,
-                        ring=plan.ring, out_cap=out_cap)
-                    # globalize row ids (each device block carries its own
-                    # rows); column 0 of body rows is the slot row, head
-                    # row 0 holds n
-                    my = lax.axis_index(AXIS).astype(jnp.int32)
-                    offset = jnp.zeros_like(packed[:, 0]).at[1:].set(
-                        my * rows_local)
-                    return packed.at[:, 0].add(offset)
-
-                fn = jax.jit(
-                    shard_map(
-                        fire_shard, mesh=mp.mesh,
-                        in_specs=(state_spec, rep, P(AXIS)),
-                        out_specs=P(AXIS),
-                    )
-                )
-                fire_cache[out_cap] = fn
-            return fn(state, params, used_mask)
-
-        self._fire_pack = fire_pack_sharded
-
+        self._split_upload = (
+            self.mesh_plan.n_devices * self.mesh_plan.slots_per_device
+            < INVALID_SLOT_U16 and self.plan.ring <= 256)
+        self._fire_pack = k.fire_pack
         if self._topn is not None:
-            by, topn = self._topn
-            topn_cache: Dict[int, Any] = {}
-
-            def ring_topn_sharded(state, emit_ring, params, used_mask,
-                                  sel_cap: int):
-                fn = topn_cache.get(sel_cap)
-                if fn is None:
-                    def topn_shard(state, emit_ring, params, used_mask):
-                        lo, hi, anchor, end_panes, w_valid = (
-                            _unpack_fire_params(params))
-                        # Global per-window threshold: each device ranks
-                        # its local rows, the top-k candidates ride one
-                        # tiny all_gather over ICI, every device selects
-                        # its local rows against the GLOBAL n-th value
-                        # (distributed RANK() <= n), and appends winners
-                        # to ITS OWN block of the emit ring.
-                        sums, maxs, mins, counts = fire_kernel(
-                            state, end_panes, w_valid, lo, hi,
-                            panes_per_window=plan.panes_per_window,
-                            ring=plan.ring)
-                        rows = counts.shape[0]
-                        nz = ((counts > 0) & used_mask[:, None]
-                              & w_valid[None, :])
-                        res = agg.finalize(sums, maxs, mins, counts)
-                        v = jnp.where(nz, res[by].astype(jnp.float32),
-                                      -jnp.inf)
-                        k = min(topn, rows)
-                        local_top = lax.top_k(v.T, k)[0]           # (W, k)
-                        all_top = lax.all_gather(
-                            local_top, AXIS, axis=1, tiled=True)   # (W, n_dev*k)
-                        # -inf thresh (< n global candidates) selects all
-                        # real rows — nz masks out non-candidates
-                        thresh = lax.top_k(all_top, k)[0][:, k - 1]
-                        my = lax.axis_index(AXIS).astype(jnp.int32)
-                        return _topn_select_append(
-                            emit_ring, sums, maxs, mins, counts, nz, v,
-                            thresh, end_panes, anchor, agg=agg,
-                            sel_cap=sel_cap, row_offset=my * rows_local)
-
-                    fn = jax.jit(
-                        shard_map(
-                            topn_shard, mesh=mp.mesh,
-                            in_specs=(state_spec, P(AXIS), rep, P(AXIS)),
-                            out_specs=P(AXIS),
-                        )
-                    )
-                    topn_cache[sel_cap] = fn
-                return fn(state, emit_ring, params, used_mask)
-
-            self._ring_topn = ring_topn_sharded
-        self._clear = jax.jit(
-            shard_map(
-                clear_kernel, mesh=mp.mesh,
-                in_specs=(state_spec, rep),
-                out_specs=state_spec,
-            ),
-            donate_argnums=(0,),
-        )
+            self._ring_topn = k.ring_topn
+        self._clear = k.clear
 
     # -- data path -------------------------------------------------------
     def process_batch(
@@ -1789,6 +1832,7 @@ class WindowOperator:
         else:
             n_dev = self.mesh_plan.n_devices
             ov_total = None
+            ph("window.exchange_split")
             for pk, dt_chunk, target in self._split_for_exchange(
                     packed, data, n_dev):
                 # the chunk length was pow2-bucketed + device-aligned by
@@ -1804,6 +1848,7 @@ class WindowOperator:
                         k: np.concatenate(
                             [v, np.zeros((pad,) + v.shape[1:], v.dtype)])
                         for k, v in dt_chunk.items()}
+                ph("window.pack")
                 if self._split_upload:
                     pv = pk >= 0
                     pk = split_encode(
@@ -1812,18 +1857,23 @@ class WindowOperator:
                 ph("window.h2d")
                 dpk = jnp.asarray(pk)
                 ddata = {k: jnp.asarray(v) for k, v in dt_chunk.items()}
+                self.exchange_chunks += 1
+                self.exchange_upload_bytes += pk.nbytes + sum(
+                    v.nbytes for v in dt_chunk.values())
                 ph("window.step_dispatch")
-                self.state, overflow = (
+                self.state, report = (
                     self._apply_sharded_split if self._split_upload
                     else self._apply_sharded)(self.state, dpk, ddata)
-                # LAZY overflow accounting: int(overflow) would block the
-                # pipeline on every step. One device-side sum per PUSH
-                # (not per chunk) so the marker deque stays 1:1 with
-                # _inflight and throttle() never touches an in-flight
-                # chunk's scalar. The host-side split makes overflow
-                # structurally impossible — the counter is the backstop.
-                ov_total = overflow if ov_total is None else ov_total + overflow
-                ph("window.pack")   # the next chunk's padding
+                # LAZY exchange accounting: reading the step's report
+                # (records dropped, records each device received) would
+                # block the pipeline on every step. One device-side sum
+                # per PUSH (not per chunk) so the marker deque stays 1:1
+                # with _inflight and throttle() never touches an
+                # in-flight chunk's report. The host-side split makes
+                # overflow structurally impossible — the counter is the
+                # backstop.
+                ov_total = report if ov_total is None else ov_total + report
+                ph("window.exchange_split")   # the next chunk's padding
             if ov_total is not None:
                 self._overflow_markers.append(ov_total)
         ph("window.step_dispatch")
@@ -2065,6 +2115,25 @@ class WindowOperator:
             ring = (self.EMIT_RING_ROWS + 2) * cols * 4
         return state + ring
 
+    def exchange_stats(self) -> Optional[Dict[str, Any]]:
+        """What the mesh's keyed exchange did so far, ``None`` without a
+        mesh: ``chunks`` (sharded steps dispatched), ``upload_bytes``,
+        ``records`` (per mesh device, the records it received from the
+        all_to_all and scattered; steps still in flight are waited for)
+        and ``state_rows`` (per mesh device, the pane-state rows that
+        lie on it)."""
+        mp = self.mesh_plan
+        if mp is None:
+            return None
+        self._resolve_overflow()
+        rows = {sh.device: int(sh.data.shape[0])
+                for sh in self.state.counts.addressable_shards}
+        return {"chunks": self.exchange_chunks,
+                "upload_bytes": self.exchange_upload_bytes,
+                "records": self.exchange_records.copy(),
+                "state_rows": np.asarray(
+                    [rows.get(d, 0) for d in mp.mesh.devices.flat], np.int64)}
+
     def _note_dispatch(self, marker, token=None, head=None) -> None:
         """Record one dispatched device step on the in-flight credit
         deque. ``marker``: a non-donated output of the step (the legacy
@@ -2168,7 +2237,9 @@ class WindowOperator:
         ``bound``) into the counter. With the host-side batch split, any
         non-zero value is a routing bug — fail loudly, not under-count."""
         while len(self._overflow_markers) > bound:
-            self.exchange_overflow += int(self._overflow_markers.popleft())
+            report = np.asarray(self._overflow_markers.popleft())
+            self.exchange_overflow += int(report[0])
+            self.exchange_records += report[1:]
         if self.exchange_overflow:
             raise RuntimeError(
                 f"exchange overflow: {self.exchange_overflow} records "

@@ -47,7 +47,8 @@ FIRE_RECORDS = 4096
 PHASE_LEAVES = {
     "source": ("ingest.source_wait",),
     "dispatch": ("ingest.link_wait", "ingest.route", "window.key_scan",
-                 "window.pack", "window.h2d", "window.step_dispatch"),
+                 "window.exchange_split", "window.pack", "window.h2d",
+                 "window.step_dispatch"),
     "throttle": ("ingest.throttle",),
     "drain": ("drain.fetch",),
     "advance": ("wm.advance",),
@@ -1988,6 +1989,7 @@ class Driver:
                 if hasattr(op, counter):
                     self.metrics[counter] = (
                         self.metrics.get(counter, 0) + getattr(op, counter))
+        self.metrics.update(self._exchange_metrics())
         final = dict(self.metrics)
         final.update(self.registry.snapshot())
         # the per-phase breakdown (dispatch/throttle/drain/advance/fire)
@@ -2026,6 +2028,38 @@ class Driver:
                     final["profile.phase.scan_pane_moves"] = final.get(
                         "profile.phase.scan_pane_moves", 0.0) + v
         return JobResult(job_name, final)
+
+    def _exchange_metrics(self) -> Dict[str, float]:
+        """What the mesh's keyed exchange did over the job, summed over
+        the operators that ran on the mesh (``exchange_stats``); empty
+        when none did. ``exchange_chunks`` (sharded steps dispatched),
+        ``exchange_upload_bytes``, ``exchange_records.<d>`` (records mesh
+        device ``d`` received) with ``exchange_records_max`` / ``_mean``
+        and their ratio ``exchange_shard_skew``, and the gauge
+        ``exchange_devices_idle``: mesh devices that held no pane-state
+        rows or received no record, 0 on a sound run."""
+        meshed = [st for st in (
+            op.exchange_stats() for op in self._ops.values()
+            if hasattr(op, "exchange_stats")) if st is not None]
+        if not meshed:
+            return {}
+        records = sum(st["records"] for st in meshed)
+        out: Dict[str, float] = {
+            "exchange_devices_idle": sum(
+                int(np.count_nonzero((st["records"] == 0)
+                                     | (st["state_rows"] == 0)))
+                for st in meshed),
+            "exchange_chunks": sum(st["chunks"] for st in meshed),
+            "exchange_upload_bytes": sum(
+                st["upload_bytes"] for st in meshed),
+            "exchange_records_max": int(records.max()),
+            "exchange_records_mean": float(records.mean())}
+        for d, n in enumerate(records):
+            out[f"exchange_records.{d}"] = int(n)
+        if records.sum() > 0:
+            out["exchange_shard_skew"] = float(
+                records.max() / records.mean())
+        return out
 
     # -- bounded execution (execution.runtime-mode=batch) ----------------
     def _run_batch(self, job_name: str, srcs, drain) -> "JobResult":
