@@ -1114,6 +1114,7 @@ class _ShardedKernels(NamedTuple):
     init: Any
     apply: Any
     apply_split: Any
+    apply_pairs: Any
     fire_pack: Any
     ring_topn: Any
     clear: Any
@@ -1161,6 +1162,17 @@ def _sharded_kernels(mp: MeshPlan, agg, layout: PaneStateLayout,
             counts=jnp.zeros((total_rows, ring_len), jnp.int32),
         )
 
+    def exchange_report(overflow, records):
+        # what the exchange did, for the host to read when the step has
+        # retired (see _resolve_overflow): word 0 the entries the
+        # all_to_all dropped over all devices, word 1 + d the RECORDS
+        # device d received and scattered — one psum
+        report = (jnp.zeros(1 + n_dev, jnp.int32)
+                  .at[0].set(jnp.sum(overflow).astype(jnp.int32))
+                  .at[1 + lax.axis_index(AXIS)].set(
+                      jnp.sum(records).astype(jnp.int32)))
+        return lax.psum(report, AXIS)
+
     def apply_shard(state, packed, data):
         # packed = global_slot * ring + ring_ix (see apply_kernel);
         # route by owner device, then rebase to the local slot block
@@ -1184,14 +1196,7 @@ def _sharded_kernels(mp: MeshPlan, agg, layout: PaneStateLayout,
             state, local_packed,
             {k: v for k, v in recv.items() if not k.startswith("__")},
             agg=agg, ring=ring_len, dump_row=layout.slots)
-        # what the exchange did, for the host to read when the step has
-        # retired (see _resolve_overflow): word 0 the records the
-        # all_to_all dropped over all devices, word 1 + d the records
-        # device d received and scattered — one psum, as before
-        report = (jnp.zeros(1 + n_dev, jnp.int32)
-                  .at[0].set(jnp.sum(overflow).astype(jnp.int32))
-                  .at[1 + my].set(jnp.sum(rvalid).astype(jnp.int32)))
-        return new_state, lax.psum(report, AXIS)
+        return new_state, exchange_report(overflow, rvalid)
 
     def lane_spec(width):
         return None if width == 0 else P(AXIS)
@@ -1226,6 +1231,41 @@ def _sharded_kernels(mp: MeshPlan, agg, layout: PaneStateLayout,
         shard_map(
             apply_shard_split, mesh=mesh,
             in_specs=(state_spec, batch_spec, batch_spec),
+            out_specs=(state_spec, rep),
+        ),
+        donate_argnums=(0,),
+    )
+
+    def apply_pairs_shard(state, buf):
+        # local-global aggregation: the host pre-aggregated the batch
+        # (see _preagg_dispatch), so an entry of the exchange is one
+        # (global slot, ring column) pair with its count and pre-added
+        # sum lanes — preagg_encode_i32's buffer, pair < 0 = padding —
+        # and not a record. Same routing as apply_shard: by the owner
+        # of the pair's slot, then rebased to the local slot block
+        cap = exchange_capacity or buf.shape[0]
+        pair = buf[:, 0]
+        valid = pair >= 0
+        dest = jnp.where(valid, pair // ring_len // spd, 0).astype(jnp.int32)
+        cols = {f"c{i}": buf[:, i] for i in range(buf.shape[1])}
+        recv, rvalid, overflow = keyby_exchange(
+            dest, valid, cols, n_devices=n_dev, capacity=cap)
+        my = lax.axis_index(AXIS)
+        rvalid = rvalid & (recv["c0"] >= 0)
+        cnt = jnp.where(rvalid, recv["c1"], 0)
+        local = jnp.stack(
+            [jnp.where(rvalid, recv["c0"] - my * (spd * ring_len), -1), cnt]
+            + [recv[f"c{i}"] for i in range(2, buf.shape[1])], axis=1)
+        new_state = apply_preagg_i32_kernel(
+            state, local, sum_width=layout.sum_width, ring=ring_len,
+            dump_row=layout.slots)
+        # in RECORDS: the sum of the counts of the pairs received
+        return new_state, exchange_report(overflow, cnt)
+
+    apply_pairs = jax.jit(
+        shard_map(
+            apply_pairs_shard, mesh=mesh,
+            in_specs=(state_spec, batch_spec),
             out_specs=(state_spec, rep),
         ),
         donate_argnums=(0,),
@@ -1302,8 +1342,8 @@ def _sharded_kernels(mp: MeshPlan, agg, layout: PaneStateLayout,
         ),
         donate_argnums=(0,),
     )
-    return _ShardedKernels(init, apply, apply_split, fire_shard,
-                           topn_shard, clear)
+    return _ShardedKernels(init, apply, apply_split, apply_pairs,
+                           fire_shard, topn_shard, clear)
 
 
 class WindowOperator:
@@ -1533,6 +1573,10 @@ class WindowOperator:
         # with the overflow word (see _resolve_overflow)
         self.exchange_chunks: int = 0
         self.exchange_upload_bytes: int = 0
+        # valid entries handed to the all_to_all, padding not counted: a
+        # record on the per-record lane, a pre-aggregated pair on the
+        # pair lane (see _exchange_pairs)
+        self.exchange_entries: int = 0
         self.exchange_records = np.zeros(
             mesh_plan.n_devices if mesh_plan is not None else 0, np.int64)
         # bumped on every mutation; checkpointing reuses the previous
@@ -1575,18 +1619,7 @@ class WindowOperator:
             self.layout.rows <= INVALID_SLOT_U16 and self.plan.ring <= 256)
         self._apply_split = functools.partial(
             _JIT_APPLY_SPLIT, agg=self.agg, dump_row=self.layout.slots)
-        # host pre-aggregation path: eligible when every accumulator
-        # lane is a host-combinable sum (LaneAggregate.sum_fields) and
-        # the (slot, ring column) pair domain keeps the host bincount
-        # cheap. The per-batch choice (pairs vs records bytes) is
-        # dynamic — see _preagg_dispatch.
-        self._preagg_lanes = None
-        self._preagg_ws = None  # lazy; domain changes on ring growth
-        if (self.agg.max_width == 0 and self.agg.min_width == 0
-                and self.agg.sum_fields is not None
-                and len(self.agg.sum_fields) == self.agg.sum_width
-                and self.layout.slots * self.plan.ring <= (1 << 23)):
-            self._preagg_lanes = self.agg.sum_fields
+        self._plan_preagg()
         self._preagg_u16 = functools.partial(
             _JIT_PREAGG_U16, ring=self.plan.ring, dump_row=self.layout.slots)
         self._preagg_u32 = functools.partial(
@@ -1627,6 +1660,24 @@ class WindowOperator:
         else:
             self._fused_step = None
         self._clear = _JIT_CLEAR
+
+    def _plan_preagg(self) -> None:
+        """Host pre-aggregation path: eligible when every accumulator
+        lane is a host-combinable sum (LaneAggregate.sum_fields) and
+        the (slot, ring column) pair domain keeps the host bincount
+        cheap. The domain spans the directory's slot space
+        (``directory.local_slots``): one block locally, every device's
+        block under a mesh, where slots are GLOBAL (apply_shard routes
+        by slot // spd). The per-batch choice (pairs vs records bytes)
+        is dynamic — see _preagg_dispatch."""
+        self._preagg_lanes = None
+        self._preagg_ws = None  # lazy; domain changes on ring growth
+        if (self.agg.max_width == 0 and self.agg.min_width == 0
+                and self.agg.sum_fields is not None
+                and len(self.agg.sum_fields) == self.agg.sum_width
+                and self.directory.local_slots * self.plan.ring
+                <= (1 << 23)):
+            self._preagg_lanes = self.agg.sum_fields
 
     def _fire_pad_bucket(self, n_ends: int) -> int:
         """Static width of a fused dispatch's fire subgraph: the pow2
@@ -1684,6 +1735,12 @@ class WindowOperator:
         k = self._sharded_kernels()
         self._apply_sharded = k.apply
         self._apply_sharded_split = k.apply_split
+        # local-global aggregation: pre-aggregated pairs cross the
+        # exchange where the batch allows it (see _exchange_pairs); the
+        # fused apply+fire+clear step stays one-chip only
+        self._apply_sharded_pairs = k.apply_pairs
+        self._plan_preagg()
+        self._fused_step = None
         # global slot ids must fit uint16 with 0xFFFF reserved
         self._split_upload = (
             self.mesh_plan.n_devices * self.mesh_plan.slots_per_device
@@ -1714,8 +1771,7 @@ class WindowOperator:
         # pre-agg histogram (the numpy path below makes ~6 full-array
         # passes — real milliseconds on the single-core bench host)
         with self.phases.span("window.key_scan"):
-            if (self.mesh_plan is None
-                    and self._spill is None and self._preagg_lanes == ()
+            if (self._spill is None and self._preagg_lanes == ()
                     and (valid is None or bool(np.all(valid)))
                     and self._process_batch_fused(keys, ts)):
                 return
@@ -1723,7 +1779,8 @@ class WindowOperator:
 
     def _process_batch_general(self, keys, ts, data, valid) -> None:
         """The numpy lane of ``process_batch``: any aggregate, validity
-        mask, spill store or mesh."""
+        mask or spill store, and every batch whose pairs the fused scan
+        did not make."""
         ph = self.phases.phase
         self._flush_stash()
         self.state_version += 1
@@ -1795,8 +1852,7 @@ class WindowOperator:
             if bad.any():
                 account_full_drop(self, int(bad.sum()))
             valid = valid & ~bad & ~full
-        if self.mesh_plan is None and self._preagg_dispatch(
-                slots, panes, valid, data):
+        if self._preagg_dispatch(slots, panes, valid, data):
             self._throttle_unless_external()
             return
         ph("window.pack")
@@ -1832,6 +1888,7 @@ class WindowOperator:
         else:
             n_dev = self.mesh_plan.n_devices
             ov_total = None
+            self.exchange_entries += int(np.count_nonzero(valid))
             ph("window.exchange_split")
             for pk, dt_chunk, target in self._split_for_exchange(
                     packed, data, n_dev):
@@ -1926,7 +1983,7 @@ class WindowOperator:
         prev_min, prev_max = self._min_pane_seen, self._max_pane_seen
         ph = self.phases.phase
         for _attempt in (0, 1):
-            domain = self.layout.slots * self.plan.ring
+            domain = self.directory.local_slots * self.plan.ring
             if (self._preagg_ws is None or self._preagg_ws.domain != domain
                     or self._preagg_ws.nlanes != 0):
                 self._preagg_ws = PreaggWorkspace(domain, 0)
@@ -1987,6 +2044,11 @@ class WindowOperator:
             return True
         ph("window.pack")
         self.prof["preagg_batches"] += 1
+        if self.mesh_plan is not None:
+            self._exchange_pairs(*ingest_fused_finalize_pairs_native(
+                res, self._preagg_ws), [])
+            self._throttle_unless_external()
+            return True
         domain = self.layout.slots * self.plan.ring
         cap = _next_pow2(max(res.npairs, 256))
         if cmax < 0xFFF and domain <= (1 << 20):
@@ -2065,7 +2127,7 @@ class WindowOperator:
         # cardinality batches keep the per-record path
         if bpp * cap > 2 * len(panes):
             return False
-        domain = self.layout.slots * ring
+        domain = self.directory.local_slots * ring
         native = None
         if cap <= (1 << 21):
             from flink_tpu.native_codec import (
@@ -2085,6 +2147,9 @@ class WindowOperator:
                 ring=ring, domain=domain)
         self.phases.phase("window.pack")
         self.prof["preagg_batches"] += 1
+        if self.mesh_plan is not None:
+            self._exchange_pairs(pairs, cnts, lanes)
+            return True
         cap = _next_pow2(max(len(pairs), 256))
         cmax = 0 if len(cnts) == 0 else int(cnts.max())
         if not lanes and cmax < 0xFFF and domain <= (1 << 20):
@@ -2102,6 +2167,44 @@ class WindowOperator:
         self._upload_and_step(step, buf)
         return True
 
+    def _exchange_pairs(self, pairs: np.ndarray, cnts: np.ndarray,
+                        lanes: List[np.ndarray]) -> None:
+        """The mesh's pair lane (local-global aggregation, ref: the
+        mini-batch two-phase aggregate of table/runtime): one batch's
+        pre-aggregated (global slot, ring column) pairs, their counts
+        and pre-added sum lanes cross the keyed exchange, not its
+        records. The buffer is preagg_encode_i32's (32-bit counts: a hot
+        key puts half a batch on one pair), padded and cut into arrival
+        blocks exactly as a record chunk is, so the capacity check of
+        ``_split_for_exchange`` holds for pairs as it does for records.
+        Dispatched at once: nothing is stashed under a mesh."""
+        ph = self.phases.phase
+        ph("window.exchange_split")
+        names = [f"lane{i}" for i in range(len(lanes))]
+        cols = {"cnt": cnts, **dict(zip(names, lanes))}
+        report = None
+        for pk, dt, target in self._split_for_exchange(
+                pairs, cols, self.mesh_plan.n_devices, floor=256):
+            ph("window.pack")
+            buf = preagg_encode_i32(
+                pk, dt["cnt"], [dt[k] for k in names], target)
+            ph("window.h2d")
+            dbuf = jnp.asarray(buf)
+            self.exchange_chunks += 1
+            self.exchange_upload_bytes += buf.nbytes
+            ph("window.step_dispatch")
+            self.state, chunk_report = self._apply_sharded_pairs(
+                self.state, dbuf)
+            report = (chunk_report if report is None
+                      else report + chunk_report)
+            ph("window.exchange_split")   # the next chunk's padding
+        self.exchange_entries += len(pairs)
+        ph("window.step_dispatch")
+        # one report a push (see the per-record lane), which is also the
+        # in-flight marker: an output of the step that is not donated
+        self._overflow_markers.append(report)
+        self._note_dispatch(report)
+
     def hbm_bytes(self) -> int:
         """Static device-state footprint PER DEVICE: pane tensors +
         emit ring. HBM is a per-chip resource — state shards one layout
@@ -2118,6 +2221,8 @@ class WindowOperator:
     def exchange_stats(self) -> Optional[Dict[str, Any]]:
         """What the mesh's keyed exchange did so far, ``None`` without a
         mesh: ``chunks`` (sharded steps dispatched), ``upload_bytes``,
+        ``entries`` (valid entries handed to the all_to_all: records, or
+        pairs on the pair lane),
         ``records`` (per mesh device, the records it received from the
         all_to_all and scattered; steps still in flight are waited for)
         and ``state_rows`` (per mesh device, the pane-state rows that
@@ -2130,6 +2235,7 @@ class WindowOperator:
                 for sh in self.state.counts.addressable_shards}
         return {"chunks": self.exchange_chunks,
                 "upload_bytes": self.exchange_upload_bytes,
+                "entries": self.exchange_entries,
                 "records": self.exchange_records.copy(),
                 "state_rows": np.asarray(
                     [rows.get(d, 0) for d in mp.mesh.devices.flat], np.int64)}
@@ -2255,7 +2361,7 @@ class WindowOperator:
 
     def _split_for_exchange(
             self, packed: np.ndarray, data: Dict[str, np.ndarray],
-            n_dev: int) -> List[Tuple[np.ndarray, Dict, int]]:
+            n_dev: int, floor: int = 1) -> List[Tuple[np.ndarray, Dict, int]]:
         """Split a batch so no (source-block, destination) bucket of the
         all_to_all exchange exceeds ``exchange_capacity`` — data loss
         becomes structurally impossible instead of counted (the
@@ -2269,10 +2375,14 @@ class WindowOperator:
         overflow after padding. Capacity None = block-sized buckets,
         which can never overflow — one chunk, no check. ``b == 1`` is
         the termination backstop: a single record occupies one bucket,
-        safe for any capacity ≥ 1 (enforced at config load)."""
+        safe for any capacity ≥ 1 (enforced at config load). ``packed``
+        holds records or, on the pair lane, pre-aggregated pairs (the
+        same slot * ring + column ids); ``floor`` is the least dispatch
+        length, which keeps small pair counts off a program each."""
         cap = self.exchange_capacity
         if cap is None:
-            return [(packed, data, self._pow2_target(len(packed), n_dev))]
+            return [(packed, data,
+                     self._pow2_target(max(len(packed), floor), n_dev))]
         ring = self.plan.ring
         spd = self.mesh_plan.slots_per_device
         out: List[Tuple[np.ndarray, Dict, int]] = []
@@ -2282,7 +2392,7 @@ class WindowOperator:
             b = len(pk)
             if not b:
                 continue
-            target = self._pow2_target(b, n_dev)
+            target = self._pow2_target(max(b, floor), n_dev)
             L = target // n_dev  # arrival-split block length AT DISPATCH
             valid = pk >= 0
             dest = np.where(valid, (pk // ring) // spd, 0)

@@ -2033,7 +2033,10 @@ class Driver:
         """What the mesh's keyed exchange did over the job, summed over
         the operators that ran on the mesh (``exchange_stats``); empty
         when none did. ``exchange_chunks`` (sharded steps dispatched),
-        ``exchange_upload_bytes``, ``exchange_records.<d>`` (records mesh
+        ``exchange_upload_bytes``, ``exchange_entries`` (valid entries
+        handed to the all_to_all: a record each, or a pre-aggregated
+        pair where the batch took the pair lane),
+        ``exchange_records.<d>`` (records mesh
         device ``d`` received) with ``exchange_records_max`` / ``_mean``
         and their ratio ``exchange_shard_skew``, and the gauge
         ``exchange_devices_idle``: mesh devices that held no pane-state
@@ -2052,6 +2055,7 @@ class Driver:
             "exchange_chunks": sum(st["chunks"] for st in meshed),
             "exchange_upload_bytes": sum(
                 st["upload_bytes"] for st in meshed),
+            "exchange_entries": sum(st["entries"] for st in meshed),
             "exchange_records_max": int(records.max()),
             "exchange_records_mean": float(records.mean())}
         for d, n in enumerate(records):

@@ -244,6 +244,37 @@ def test_cont_call_after_register_misses(c, finalize):
     _check(res, miss, st, [], ws, finalize)
 
 
+def test_pairs_over_the_global_domain_of_a_mesh_equal_preagg_combine():
+    """Under a mesh the directory's slots are GLOBAL (four device blocks
+    of ``SLOTS`` here), so the workspace spans ``4 * SLOTS * RING`` and
+    a pair id passes one block's domain. The scan's pairs and counts
+    are those of the numpy lane's ``preagg_combine`` over the same
+    slots, which is what crossed the exchange per record before."""
+    from flink_tpu.ops.window import preagg_combine
+
+    devices = 4
+    rng = np.random.default_rng(11)
+    known = {1000 + 3 * j: int(s) for j, s in enumerate(
+        rng.permutation(devices * SLOTS)[:150])}
+    assert max(known.values()) >= 3 * SLOTS      # the last block is used
+    c = make(n=5 * BLOCK + 3, table=known, ts=_spread(3),
+             keys=lambda rng, n: 1000 + 3 * rng.integers(0, 150, n))
+    ws = nc.PreaggWorkspace(devices * SLOTS * RING, 0)
+    res, miss = _run_native(c, _table(known), ws)
+    assert len(miss) == 0
+    pairs, counts = nc.ingest_fused_finalize_pairs_native(res, ws)
+    slots = np.array([known[k] for k in c["keys"].tolist()], np.int64)
+    panes = c["ts"] // PANE_MS
+    want_pairs, want_counts, _ = preagg_combine(
+        slots, panes % RING, np.ones(len(slots), bool), {}, (),
+        ring=RING, domain=ws.domain)
+    order = np.argsort(pairs)
+    assert pairs[order].tolist() == want_pairs.tolist()
+    assert counts[order].tolist() == want_counts.tolist()
+    assert pairs.max() >= SLOTS * RING > 0       # past one block's domain
+    assert not ws.hist.any()
+
+
 def test_pane_moves_count_the_mechanism():
     """In order the cursor moves once a pane; alternating panes move it on
     every record (and cost what the division always did)."""
