@@ -35,12 +35,6 @@ SLIDE_MS = 1_000
 # these pipelines/configs at zero findings).
 BENCH_CONF = {"analysis.fail-on": "off"}
 
-# CLI A/B axes (--fire-gate on|off, --readiness piggyback|probe):
-# merged into every run's conf AFTER the per-config builders, so the
-# COMMITTED confs (job_confs/--dump-confs, exercised with no overrides)
-# stay byte-stable while a measurement run can flip the control-plane
-# knobs without editing code (the before/after axis).
-CONTROL_OVERRIDES: dict = {}
 
 def _phase_summary(metrics: dict, wall_s: float) -> dict:
     """Per-trial phase breakdown, derived from the JobResult's
@@ -140,9 +134,7 @@ def job_confs() -> dict:
     """Every benched config's job conf at its committed suite/headline
     parameters, keyed by the confs/ file stem."""
     return {
-        "bench_q5_headline": _q5_conf(HEADLINE_BATCH, 128, 256,
-                                      HEADLINE_SUB_BATCHES),
-        "bench_q5_host_fed": _q5_conf(1 << 20, 128, 256, 1),
+        "bench_q5_host_fed": _q5_conf(Q5_BATCH, 128, 256, 1),
         "bench_q7": _q7_conf(1 << 18),
         "bench_q8": _q8_conf(1 << 18),
         "bench_wordcount": _wordcount_conf(1 << 20),
@@ -190,12 +182,10 @@ def _counting_sink():
 
 
 def run_q5(batch_size: int, n_batches: int, *, shards: int, slots: int,
-           device_source: bool = True, sub_batches: int = 1,
-           profile_dir: str = "") -> dict:
+           sub_batches: int = 1, profile_dir: str = "") -> dict:
     from flink_tpu.api.environment import StreamExecutionEnvironment
     from flink_tpu.config import Configuration
-    from flink_tpu.nexmark.generator import (
-        NexmarkConfig, bid_stream, bid_stream_device)
+    from flink_tpu.nexmark.generator import NexmarkConfig, bid_stream
     from flink_tpu.nexmark.queries import q5_hot_items
 
     # events_per_ms=100 → one 131k batch spans ~1.3s of event time, so
@@ -207,19 +197,14 @@ def run_q5(batch_size: int, n_batches: int, *, shards: int, slots: int,
     # sub-batch fire/emit decoupling: fires reach
     # the host at ~batch_wall/K cadence instead of riding the drain
     # behind one full logical-batch device step
-    conf = {**_q5_conf(batch_size, shards, slots, sub_batches),
-            **CONTROL_OVERRIDES}
+    conf = _q5_conf(batch_size, shards, slots, sub_batches)
     if profile_dir:
         # per-op device trace of N warm steps (obs/profiling.py); the
         # summary rides JobResult.metrics["profile.trace_summary"]
         conf["pipeline.profile-dir"] = profile_dir
     env = StreamExecutionEnvironment(Configuration(conf))
     emitted, sink = _counting_sink()
-    # device_source: the generator is synthesized inside the window
-    # operator's step program (DeviceGeneratorSource — zero record
-    # bytes on the link); False measures the host-materialized path
-    src = bid_stream_device(cfg) if device_source else bid_stream(cfg)
-    q5_hot_items(env, src, sink,
+    q5_hot_items(env, bid_stream(cfg), sink,
                  window_ms=WINDOW_MS, slide_ms=SLIDE_MS,
                  out_of_orderness_ms=1_000)
     res = env.execute("nexmark-q5")
@@ -227,12 +212,9 @@ def run_q5(batch_size: int, n_batches: int, *, shards: int, slots: int,
     return res.metrics
 
 
-# THE default sub-batch config of the headline (and of the acceptance
-# bar): 2^22-record logical batches executed as 4 chained 2^20
-# sub-batch device programs — logical-batch ingest amortization with
-# fire visibility at sub-batch cadence.
-HEADLINE_BATCH = 1 << 22
-HEADLINE_SUB_BATCHES = 4
+# the Q5 job's batch (confs/bench_q5_host_fed.conf): 2^20 records, one
+# device step per batch
+Q5_BATCH = 1 << 20
 
 
 def _q5_trial(batch, n_meas, sub_batches, profile_dir=""):
@@ -246,10 +228,6 @@ def _q5_trial(batch, n_meas, sub_batches, profile_dir=""):
         "events_per_sec": round(batch * n_meas / elapsed),
         "batch": batch,
         "sub_batches": sub_batches,
-        "fire_gate": bool(CONTROL_OVERRIDES.get(
-            "pipeline.fire-gate", True)),
-        "readiness": str(CONTROL_OVERRIDES.get(
-            "pipeline.readiness", "piggyback")),
         "p50_latency_ms": round(metrics.get("driver.emit_latency_ms.p50", 0.0), 1),
         "p90_latency_ms": round(metrics.get("driver.emit_latency_ms.p90", 0.0), 1),
         "p99_latency_ms": round(metrics.get("driver.emit_latency_ms.p99", 0.0), 1),
@@ -289,16 +267,13 @@ def _profile_top_ops(batch, sub_batches, n_batches=16):
 
 
 def main() -> None:
-    # 2^22-record LOGICAL microbatches (the r05 throughput point) run
-    # as 4×2^20 chained sub-batch programs: ingest amortization stays
-    # at 2^22 while fired rows become host-visible at sub-batch
-    # cadence — the p99 decoupling ISSUE 6 ships (latency on the
-    # current chip: not measured).
-    batch = HEADLINE_BATCH
-    sub = HEADLINE_SUB_BATCHES
+    # the host-fed Q5 job: every record is materialized on the host and
+    # pays keying + h2d + dispatch
+    batch = Q5_BATCH
+    sub = 1
     # warmup: same operator configs → shared compiled kernels (covers
     # apply, steady fires, ring growth + remap, catch-up fires, clear,
-    # emit-ring drain; the subdivided devgen spec is part of the key)
+    # emit-ring drain)
     run_q5(batch, 12, shards=128, slots=256, sub_batches=sub)
 
     # long enough that the fixed end-of-input flush is amortized — the
@@ -336,48 +311,14 @@ def main() -> None:
         # delivery — see driver._note_ring_latency.
         "p99_latency_ms": med["p99_latency_ms"],
         "p50_latency_ms": med["p50_latency_ms"],
-        # control-plane config + the median trial's per-phase wall
-        # attribution (throttle/drain/advance vs dispatch/fire)
-        "fire_gate": med["fire_gate"],
-        "readiness": med["readiness"],
+        # the median trial's per-phase wall attribution
+        # (throttle/drain/advance vs dispatch/fire)
         "phase_breakdown": med["phase_breakdown"],
         # per-op device-time summary from one short profiled run
         # (jax.profiler.trace via pipeline.profile-dir;
         # obs/profiling.py)
         "profile_top_ops": _profile_top_ops(batch, sub),
     }))
-
-
-def sub_batch_sweep(spec: str) -> None:
-    """``python bench.py --sub-batches 1,2,4,8``: the fire-cadence
-    sweep on the headline Q5 config — one JSON line per K with
-    throughput AND the latency histogram, so the throughput/p99
-    trade-off of the sub-batch knob is measured, not asserted. The
-    headline claim remains the DEFAULT config's line (bench main), not
-    the sweep's best point."""
-    ks = [int(x) for x in spec.split(",") if x.strip()]
-    if not ks:
-        raise SystemExit("--sub-batches needs a list, e.g. 1,2,4")
-    for k in ks:
-        if k < 1 or HEADLINE_BATCH % k:
-            raise SystemExit(
-                f"--sub-batches values must divide {HEADLINE_BATCH}, "
-                f"got {k}")
-    for k in ks:
-        # per-K warmup: the sub-batch count is a STATIC of the devgen
-        # step kernel (batch shape + generator spec), so every K
-        # compiles its own program — warm each before its clock
-        run_q5(HEADLINE_BATCH, 8, shards=128, slots=256, sub_batches=k)
-        trial, _ = _q5_trial(HEADLINE_BATCH, 24, k)
-        print(json.dumps({
-            "metric": "nexmark_q5_hot_items_end_to_end_events_per_sec",
-            "unit": "events/sec/chip",
-            "value": trial["events_per_sec"],
-            **{f: trial[f] for f in (
-                "batch", "sub_batches", "fire_gate", "readiness",
-                "p50_latency_ms", "p90_latency_ms", "p99_latency_ms",
-                "max_latency_ms", "phase_breakdown")},
-        }))
 
 
 def run_q7(batch_size: int, n_batches: int) -> float:
@@ -761,26 +702,7 @@ def suite() -> None:
     # over to the live tail, with the never-compacted reference match
     # verified inside the artifact (ISSUE 9 / ROADMAP item 4)
     run_q5_backfill(1 << 18, n_hist=8, n_live=4)
-    # host-fed Q5 (device_source=False): the INGEST plane's number.
-    # The headline's device-chained generator moves ~zero record bytes
-    # over the link; this permanent companion line materializes every
-    # record on the host and pays the full keying + h2d + dispatch
-    # path, so ingest regressions are
-    # measured every round instead of hiding behind the devgen number.
-    run_q5(1 << 20, 4, shards=128, slots=256, device_source=False)
-    t0 = time.perf_counter()
-    m5h = run_q5(1 << 20, 24, shards=128, slots=256, device_source=False)
-    el5h = time.perf_counter() - t0
-    assert m5h["emitted"] > 0, "host-fed q5 emitted nothing"
-    assert m5h.get("records_dropped_full", 0) == 0, "host-fed q5 dropped"
-    print(json.dumps({
-        "metric": "nexmark_q5_hot_items_host_fed_events_per_sec",
-        "value": round((1 << 20) * 24 / el5h),
-        "unit": "events/sec/chip",
-        # the phase attribution on the HOST-FED plane (throttle-wait
-        # share of batch wall included)
-        "phase_breakdown": _phase_summary(m5h, el5h)}))
-    main()  # Q5 headline last (its line is the one the driver records)
+    main()  # host-fed Q5 last
 
 
 def session_bench_build(env) -> None:
@@ -1379,36 +1301,6 @@ def state_backend_bench(backend: str, key_domain: int,
 if __name__ == "__main__":
     import sys
 
-    # control-plane A/B axes for the Q5 runs (run_q5 merges
-    # CONTROL_OVERRIDES): the default headline, `--sub-batches` sweeps,
-    # and `--suite`'s Q5 lines honor them — e.g. `--sub-batches 1,2,4
-    # --fire-gate off` measures the ungated sweep (the before/after
-    # axis). Modes whose confs never pass through run_q5
-    # REJECT the flags loudly rather than silently ignoring them.
-    if "--fire-gate" in sys.argv or "--readiness" in sys.argv:
-        for mode in ("--backfill", "--host-parallelism",
-                     "--concurrent-jobs", "--dump-confs",
-                     "--rescale-at-batch", "--state-backend"):
-            if mode in sys.argv:
-                raise SystemExit(
-                    f"--fire-gate/--readiness only apply to the Q5 "
-                    f"paths (headline, --sub-batches, --suite); {mode} "
-                    "would silently ignore them — set pipeline.fire-"
-                    "gate / pipeline.readiness in the job conf instead")
-    if "--fire-gate" in sys.argv:
-        ix = sys.argv.index("--fire-gate")
-        val = sys.argv[ix + 1] if ix + 1 < len(sys.argv) else ""
-        if val not in ("on", "off"):
-            raise SystemExit("--fire-gate needs on|off")
-        CONTROL_OVERRIDES["pipeline.fire-gate"] = val == "on"
-        del sys.argv[ix:ix + 2]
-    if "--readiness" in sys.argv:
-        ix = sys.argv.index("--readiness")
-        val = sys.argv[ix + 1] if ix + 1 < len(sys.argv) else ""
-        if val not in ("piggyback", "probe"):
-            raise SystemExit("--readiness needs piggyback|probe")
-        CONTROL_OVERRIDES["pipeline.readiness"] = val
-        del sys.argv[ix:ix + 2]
     if "--dump-confs" in sys.argv:
         ix = sys.argv.index("--dump-confs")
         if ix + 1 >= len(sys.argv):
@@ -1453,11 +1345,6 @@ if __name__ == "__main__":
         state_backend_bench(sys.argv[ix + 1], kd)
     elif "--backfill" in sys.argv:
         run_q5_backfill(artifact="BENCH_BACKFILL.json")
-    elif "--sub-batches" in sys.argv:
-        ix = sys.argv.index("--sub-batches")
-        if ix + 1 >= len(sys.argv):
-            raise SystemExit("--sub-batches needs a list, e.g. 1,2,4")
-        sub_batch_sweep(sys.argv[ix + 1])
     elif "--suite" in sys.argv:
         suite()
     else:

@@ -270,100 +270,6 @@ def bench_fire_flush(iters: int = 10) -> None:
           p99=round(1e3 * float(np.quantile(lat, 0.99)), 3))
 
 
-def bench_control(iters: int = 150,
-                  artifact: str | None = None) -> list:
-    """#6: control-plane readiness probe — per-wait cost of retiring
-    one in-flight device step under the two ``pipeline.readiness``
-    mechanisms:
-
-    - ``probe``: ``hostsync.ready_wait`` — an ``is_ready()`` spin with
-      a 2ms sleep quantum (the older throttle). On a CPU backend each
-      probe is a local flag read, so the measured overhead is the
-      poll-quantum overshoot; the constraint line names the backend
-      this artifact measured.
-    - ``piggyback``: consume a tiny ``copy_to_host_async``-announced
-      output of the same dispatch (``np.asarray`` blocks on the
-      in-flight transfer only — no poll loop, no extra round trip).
-
-    Reported as per-wait MICROSECONDS OVER the pure compute wall
-    (block_until_ready baseline), plus the ratio."""
-    import os
-
-    import jax
-    import jax.numpy as jnp
-
-    from flink_tpu.hostsync import ready_wait
-
-    rows: list = []
-
-    @jax.jit
-    def step(x):
-        # enough work that the dispatch is genuinely in flight when the
-        # wait starts (a few hundred µs on one CPU core)
-        for _ in range(4):
-            x = jnp.tanh(x @ x)
-        return x, x[0, :8]
-
-    x = jnp.asarray(np.random.default_rng(7).random((384, 384),
-                                                    np.float32))
-    out, tok = step(x)  # compile
-    jax.block_until_ready(out)
-
-    def run(mode: str) -> float:
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out, tok = step(x)
-            if mode == "block":
-                jax.block_until_ready(out)
-            elif mode == "probe":
-                ready_wait(out)
-            else:  # piggyback
-                tok.copy_to_host_async()
-                np.asarray(tok)
-        return (time.perf_counter() - t0) / iters
-
-    base = run("block")
-    run("probe")  # warm both wait paths before the measured passes
-    probe = run("probe")
-    piggy = run("piggyback")
-    cores = len(os.sched_getaffinity(0))
-    constraint = (
-        f"{jax.default_backend()} backend, {cores} schedulable core(s): "
-        "where is_ready is a local flag read, probe overhead is the "
-        "2ms poll-quantum overshoot only")
-    over_probe = max(0.0, probe - base) * 1e6
-    over_piggy = max(0.0, piggy - base) * 1e6
-    _emit(rows, "control_wait_us_probe", over_probe, "us/wait",
-          mode="is_ready spin (pipeline.readiness=probe)",
-          wall_us=round(probe * 1e6, 1), constraint=constraint)
-    _emit(rows, "control_wait_us_piggyback", over_piggy, "us/wait",
-          mode="announced-transfer consume (pipeline.readiness="
-               "piggyback)", wall_us=round(piggy * 1e6, 1),
-          constraint=constraint)
-    # the robust headline is the absolute per-wait saving — the ratio's
-    # denominator can measure below timer noise (piggyback overhead ~0),
-    # so it is floored and flagged rather than reported as a silly
-    # divide-by-epsilon number
-    _emit(rows, "control_wait_saved_us", over_probe - over_piggy,
-          "us/wait",
-          note="per-wait overhead removed by piggybacked readiness "
-               "(probe minus piggyback, each over the "
-               "block_until_ready baseline)", constraint=constraint)
-    floor_us = 5.0
-    _emit(rows, "control_readiness_speedup",
-          over_probe / max(over_piggy, floor_us), "x",
-          note="per-wait overhead ratio; >1 = piggybacked readiness "
-               "retires a step cheaper than the is_ready spin",
-          denominator_floored=over_piggy < floor_us,
-          floor_us=floor_us, constraint=constraint,
-          host_cores=cores)
-    if artifact:
-        _write_artifact(artifact, "control_plane", rows,
-                        backend=jax.default_backend(), host_cores=cores,
-                        iters=iters)
-    return rows
-
-
 def bench_checkpoint(tmp: str | None = None) -> None:
     """#5: snapshot bytes/sec (HBM→host→store) and resume time."""
     import shutil
@@ -650,7 +556,6 @@ def main() -> None:
     bench_codec()
     bench_columnar(artifact="BENCH_COLUMNAR.json")
     bench_fire_flush()
-    bench_control()
     bench_checkpoint()
     bench_dcn(artifact="BENCH_DCN.json")
     bench_dcn_q5(artifact="BENCH_DCN_Q5.json")

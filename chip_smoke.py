@@ -7,12 +7,9 @@ rows collected by a sink — on one TPU chip in ONE process, at the sizes
 the repo commits, and checks every committed row against a plain numpy
 reference kept in this file:
 
-- ``device_chained``: ``confs/bench_q5_headline.conf`` (2^22-record logical
-  batches as 4 x 2^20), ``bid_stream_device`` — the generator runs inside
-  the window operator's device step;
 - ``host_fed``: ``confs/bench_q5_host_fed.conf`` (2^20, K=1),
-  ``bid_stream`` — records cross the host-device link, the plane every
-  deployment uses;
+  ``bid_stream`` — records cross the host-device link, as in every
+  deployment;
 - ``sum_lane``: count + ``sum_of("price")`` on the same bids under the
   host-fed conf — the only job here whose fire carries a float SUM lane,
   checked against an f64 sum within ``SUM_RTOL``.
@@ -24,14 +21,14 @@ measured run's; ``compiled_in_run`` should be zero programs).
 
     python chip_smoke.py                   # one chip; what the driver runs
     python chip_smoke.py --chips 4         # host-fed Q5 over a 4-chip mesh
-    python chip_smoke.py --trace-dir DIR   # + one traced device-chained run
+    python chip_smoke.py --trace-dir DIR   # + one traced host-fed run
     python chip_smoke.py --cpu-rehearsal   # same code, tiny sizes, CPU
 
 It exits non-zero, with the reason on stderr and no result line, when JAX
 finds no TPU (it never selects a platform itself outside the rehearsal),
-when the native codec cannot be built here, when a chained batch fell back
-to host materialization, when a record was dropped, or when any row
-differs from the reference. On success it prints two JSON lines: the
+when the native codec cannot be built here, when a record was dropped, or
+when any row differs from the reference. On success it prints two JSON
+lines: the
 report (per-plane events, rows, compile_s / run_s, counters, versions,
 compile cache; ``"rehearsal": true`` in the rehearsal — elapsed seconds
 and counts only, this is not a benchmark), and, as the last stdout line,
@@ -166,16 +163,16 @@ def columns(batches, fields):
             if batches else np.zeros(0, np.int64) for f in fields]
 
 
-def run_q5(conf, cfg, device_source: bool):
+def run_q5(conf, cfg):
     """One Q5 job through env.execute(); (JobResult, sorted rows, env)."""
     from flink_tpu.api.environment import StreamExecutionEnvironment
-    from flink_tpu.nexmark.generator import bid_stream, bid_stream_device
+    from flink_tpu.nexmark.generator import bid_stream
     from flink_tpu.nexmark.queries import q5_hot_items
 
     env = StreamExecutionEnvironment(conf)
     batches, sink = collecting_sink()
-    src = bid_stream_device(cfg) if device_source else bid_stream(cfg)
-    q5_hot_items(env, src, sink, window_ms=WINDOW_MS, slide_ms=SLIDE_MS,
+    q5_hot_items(env, bid_stream(cfg), sink, window_ms=WINDOW_MS,
+                 slide_ms=SLIDE_MS,
                  out_of_orderness_ms=OUT_OF_ORDERNESS_MS)
     res = env.execute("chip-smoke-q5")
     we, au, ct = columns(batches, ("window_end", "auction", "bid_count"))
@@ -204,26 +201,13 @@ def run_count_sum(conf, cfg):
     return res, columns(batches, ("window_end", "key", "count", "sum_price"))
 
 
-def check_counters(name: str, metrics: dict, *, chained_batches: int) -> dict:
-    """The plane a job's batches took, from the driver's counters; a
-    fallback to the host on the chained plane, or a dropped record
-    anywhere, fails the smoke."""
+def check_counters(name: str, metrics: dict) -> dict:
+    """The driver's loss counters; a dropped record fails the smoke."""
     got = {k: int(metrics.get(k, 0)) for k in (
-        "device_chain_attached", "device_chain_batches",
-        "device_chain_fallback_batches", "records_dropped_full",
-        "late_records")}
+        "records_dropped_full", "late_records")}
     if got["records_dropped_full"]:
         raise SmokeFailure(f"{name}: records_dropped_full = "
                            f"{got['records_dropped_full']}")
-    if got["device_chain_fallback_batches"]:
-        raise SmokeFailure(
-            f"{name}: {got['device_chain_fallback_batches']} chained "
-            "batches fell back to host materialization")
-    if got["device_chain_batches"] != chained_batches or (
-            bool(chained_batches) != bool(got["device_chain_attached"])):
-        raise SmokeFailure(
-            f"{name}: expected {chained_batches} batches through the "
-            f"device chain, counters say {got}")
     return got
 
 
@@ -297,17 +281,15 @@ def job_setup(conf_file, overrides, nexmark, n_batches):
 
 
 def q5_plane(name, conf_file, overrides, nexmark, n_batches,
-             device_source, watch, inspect=None) -> dict:
+             watch, inspect=None) -> dict:
     """One Q5 plane: warm-up, measured run, counters, rows against the
     reference. ``inspect(env, pane_counts)`` adds a plane's own checks
     on the finished job (the mesh's state placement)."""
     conf, cfg, k = job_setup(conf_file, overrides, nexmark, n_batches)
 
     def measured():
-        res, rows, env = run_q5(conf, cfg, device_source)
-        counters = check_counters(
-            name, res.metrics,
-            chained_batches=n_batches * k if device_source else 0)
+        res, rows, env = run_q5(conf, cfg)
+        counters = check_counters(name, res.metrics)
         pane_counts = reference_panes(cfg, False)[0]
         expect = reference_hot_items(sliding(pane_counts))
         if rows != expect:
@@ -322,8 +304,7 @@ def q5_plane(name, conf_file, overrides, nexmark, n_batches,
                 **(inspect(env, pane_counts) if inspect else {}),
                 "rows_sorted": rows}
 
-    return timed_plane(
-        name, watch, lambda: run_q5(conf, cfg, device_source), measured)
+    return timed_plane(name, watch, lambda: run_q5(conf, cfg), measured)
 
 
 def sum_lane_plane(overrides, nexmark, n_batches, watch) -> dict:
@@ -336,7 +317,7 @@ def sum_lane_plane(overrides, nexmark, n_batches, watch) -> dict:
 
     def measured():
         res, (we, key, cnt, sm) = run_count_sum(conf, cfg)
-        counters = check_counters(name, res.metrics, chained_batches=0)
+        counters = check_counters(name, res.metrics)
         ref_c, ref_s = map(sliding, reference_panes(cfg, True))
         e = we // SLIDE_MS
         if len(np.unique(e * n_auctions + key)) != len(e):
@@ -376,33 +357,39 @@ def sum_lane_plane(overrides, nexmark, n_batches, watch) -> dict:
         name, watch, lambda: run_count_sum(conf, cfg), measured)
 
 
+# the spans under which a host-fed batch's device step goes out: the
+# ingest loop's phase clock names them, and they are host events of any
+# trace the job is run under
+STEP_SPANS = ("window.step_dispatch", "window.fire_dispatch")
+
+
 def traced_run(trace_dir, overrides, nexmark, n_batches,
                want_device_plane: bool) -> dict:
-    """One device-chained Q5 under the existing pipeline.profile-dir seam
-    (obs/profiling.py): the summary must show the chained step, and on a
-    chip a device plane with its ops."""
+    """One host-fed Q5 under the existing pipeline.profile-dir seam
+    (obs/profiling.py): the summary must show the job's step dispatch,
+    and on a chip a device plane with its ops."""
     conf, cfg, _ = job_setup(
-        "bench_q5_headline.conf",
+        "bench_q5_host_fed.conf",
         {**overrides, "pipeline.profile-dir": trace_dir}, nexmark, n_batches)
-    res, _, _ = run_q5(conf, cfg, True)
+    res, _, _ = run_q5(conf, cfg)
     summary = res.metrics.get("profile.trace_summary")
     if not summary or summary.get("error"):
         raise SmokeFailure(f"traced run left no summary: {summary}")
     planes = summary["planes"]
     step = [{"plane": p["plane"], **op} for p in planes for op in p["ops"]
-            if "devgen_step_kernel" in op["op"]]
+            if op["op"] in STEP_SPANS]
     device = [p for p in planes if p["device"]]
     if not step or (want_device_plane and not device):
         raise SmokeFailure(
-            "the trace summary lacks the chained step or a device plane: "
+            "the trace summary lacks the step dispatch or a device plane: "
             f"planes {[(p['plane'], p['device']) for p in planes]}, "
-            f"devgen_step_kernel events {step}")
+            f"{STEP_SPANS} events {step}")
     return {"trace_file": summary["trace_file"],
             "steps": summary.get("steps"),
             "window_wall_s": summary.get("window_wall_s"),
             "planes": [{"plane": p["plane"], "device": p["device"],
                         "total_ms": p["total_ms"]} for p in planes],
-            "devgen_step_kernel": step,
+            "step_dispatch": step,
             "device_top_ops": [{"plane": p["plane"], "ops": p["ops"][:12]}
                                for p in device[:2]]}
 
@@ -482,7 +469,7 @@ def main(argv=None) -> int:
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="same code at a tiny size on the CPU")
     ap.add_argument("--trace-dir", default="",
-                    help="also run one traced device-chained job "
+                    help="also run one traced host-fed job "
                          "(pipeline.profile-dir) and report its planes")
     args = ap.parse_args(argv)
 
@@ -552,22 +539,21 @@ def main(argv=None) -> int:
                               else traceback.format_exc())
             log(f"{name}: FAILED — {failures[name]}")
 
-    def q5(name, conf_file, device_source, extra=None, inspect=None):
+    def q5(name, conf_file, extra=None, inspect=None):
         phase(name, lambda: q5_plane(
             name, conf_file, {**overrides, **(extra or {})}, nexmark,
-            n_batches, device_source, watch, inspect))
+            n_batches, watch, inspect))
 
     if args.chips == 1:
-        q5("device_chained", "bench_q5_headline.conf", True)
-        q5("host_fed", "bench_q5_host_fed.conf", False)
+        q5("host_fed", "bench_q5_host_fed.conf")
         phase("sum_lane", lambda: sum_lane_plane(
             overrides, nexmark, n_sum, watch))
     else:
         # the same host-fed job on one chip, then over the mesh: one
         # process driving every chip, the two row sets identical
         mesh = f"host_fed_mesh{args.chips}"
-        q5("host_fed", "bench_q5_host_fed.conf", False)
-        q5(mesh, "bench_q5_host_fed.conf", False,
+        q5("host_fed", "bench_q5_host_fed.conf")
+        q5(mesh, "bench_q5_host_fed.conf",
            extra={"cluster.mesh-devices": args.chips},
            inspect=mesh_inspector(mesh, args.chips, devices))
         if mesh in planes and "host_fed" in planes:

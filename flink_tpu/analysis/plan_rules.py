@@ -717,54 +717,6 @@ def subbatch_invalid(plan, config) -> Iterable[Finding]:
                     "wall time")
 
 
-@config_rule("READINESS_INVALID", "error",
-             fix="pipeline.readiness is 'piggyback' or 'probe'")
-def readiness_invalid(plan, config) -> Iterable[Finding]:
-    """An unknown pipeline.readiness value: the driver rejects the job
-    at build (inside Driver._build_ops), so the default
-    analysis.fail-on=error gate must block it at SUBMIT — the
-    SUBBATCH_INVALID discipline for build-rejected config."""
-    from flink_tpu.config import PipelineOptions
-
-    readiness = str(config.get(PipelineOptions.READINESS)).strip().lower()
-    if readiness not in ("piggyback", "probe"):
-        yield _f(
-            f"pipeline.readiness={readiness!r} is not a known mode: "
-            "the driver rejects the job at build",
-            fix="use 'piggyback' (throttle consumes an announced "
-                "per-step token — no is_ready round trips) or 'probe' "
-                "(legacy is_ready spin, zero per-step d2h traffic)")
-
-
-@config_rule("FIRE_GATE_INVALID", "warn",
-             fix="leave pipeline.fire-gate true (the default) under "
-                 "sub-batching")
-def fire_gate_invalid(plan, config) -> Iterable[Finding]:
-    """Fire-gating forced OFF under a config that needs it:
-    pipeline.sub-batches > 1 pays the fire/top-n select sort on
-    EVERY sub-batch dispatch whether or not any window fires — exactly
-    the throughput-vs-K tax the gate removes. Warn, not error:
-    gate-off is the legitimate A/B measurement axis."""
-    from flink_tpu.config import PipelineOptions
-
-    try:
-        gate = bool(config.get(PipelineOptions.FIRE_GATE))
-        k = int(config.get(PipelineOptions.SUB_BATCHES))
-    except (TypeError, ValueError):
-        return  # SUBBATCH_INVALID owns the parse failure
-    if not gate and k > 1:
-        yield _f(
-            f"pipeline.fire-gate=false with pipeline.sub-batches={k}: "
-            "every sub-batch dispatch pays the full fire/top-n select "
-            "subgraph (one dominant sort) whether or not any window "
-            "can fire — K dispatches per logical batch pay it K times, "
-            "the throughput tax that made sub-batching trade throughput "
-            "for p99",
-            fix="leave pipeline.fire-gate true (committed output is "
-                "byte-identical; false exists as the A/B measurement "
-                "axis), or run sub-batches=1 if the gate must stay off")
-
-
 @config_rule("CHECKPOINT_IN_BATCH", "error",
              fix="drop checkpointing config or run in streaming mode")
 def checkpoint_in_batch(plan, config) -> Iterable[Finding]:

@@ -90,97 +90,6 @@ class CollectionSource(Source):
 
 
 @dataclasses.dataclass
-class DeviceGeneratorSource(Source):
-    """Generator source whose batches can be synthesized ON the
-    accelerator, chained directly into the consuming window operator's
-    step program (the operator-chaining principle — ref: chained
-    operators elide serialization, StreamingJobGraphGenerator chaining;
-    flink-connector-datagen as the embedded-source role — taken to its
-    TPU conclusion: the 'exchange' between source and operator is
-    device registers, not even host memory).
-
-    Contract: ``device_keys_ts(batch_index)`` (jax-traceable, i64
-    scalar → (keys, ts) device arrays) and ``keys_ts_host(i)`` (numpy)
-    must be BIT-EXACT for the same index — the host copy repairs
-    device-side key-table misses and replays after restore.
-    ``gen(split, i)`` materializes the full field set for consumers the
-    chain can't host (non-count aggregates, multi-op fan-out, DCN).
-    ``ts_bounds(i)`` returns the batch's exact (min_ts, max_ts) so the
-    driver can run the watermark clock without touching the device."""
-
-    gen: Callable[[str, int], Optional[Batch]]
-    device_keys_ts: Callable = None
-    keys_ts_host: Callable = None
-    ts_bounds: Callable = None
-    key_field: str = "key"
-    batch_size: int = 8192
-    n_batches: int = 0
-    is_bounded: bool = True
-    # bounded key domain [0, key_domain): REQUIRED for device chaining —
-    # on device, key→slot must be a pure function (dense identity; see
-    # KeyDirectory.register_dense), because a table probe is a large
-    # gather there. Records outside the domain are repaired
-    # host-side. Dictionary-encoded keys (this framework's string
-    # convention) fit naturally; None disables the device chain.
-    key_domain: Optional[int] = None
-    # PROVEN bound: the generator guarantees every key lies in
-    # [0, key_domain) by construction (e.g. a multiply-shift range
-    # reduction). Lets the operator skip the per-step stats round trip
-    # when the batch's pane bounds also rule out late/refire work —
-    # one fewer device→host transfer per microbatch.
-    keys_bounded: bool = False
-    # sub-batch re-slicing (pipeline.sub-batches, the fire/emit
-    # decoupling knob): a callable ``k -> DeviceGeneratorSource`` whose
-    # result produces the IDENTICAL record stream at batch_size/k
-    # granularity — sub-batch j of logical batch i must be batch
-    # i*k + j of the returned source, bit-exact slice [j*b', (j+1)*b')
-    # of the logical batch. None = the source cannot subdivide; the
-    # driver then keeps its device chain at logical granularity.
-    subdivide: Optional[Callable[[int], "DeviceGeneratorSource"]] = None
-    # declared record schema (field → numpy dtype name) of ``gen``'s
-    # batches; seeds the analyzer's schema lattice (declared_schema)
-    schema: Optional[Dict[str, str]] = None
-
-    def declared_schema(self) -> Optional[Dict[str, str]]:
-        return dict(self.schema) if self.schema is not None else None
-
-    def subdivided(self, k: int) -> "DeviceGeneratorSource":
-        """The equivalent source at batch_size/k granularity (see
-        ``subdivide``). Raises when the source declares no subdivision
-        or the batch size does not split evenly — callers decide
-        whether that is a config error or a fallback."""
-        if k < 1:
-            raise ValueError(f"sub-batch count must be >= 1, got {k}")
-        if k == 1:
-            return self
-        if self.subdivide is None:
-            raise ValueError(
-                "this DeviceGeneratorSource declares no subdivide "
-                "callable — it cannot re-slice its stream")
-        if self.batch_size % k:
-            raise ValueError(
-                f"pipeline.sub-batches={k} does not divide the device "
-                f"source's batch_size={self.batch_size}")
-        return self.subdivide(k)
-
-    def splits(self) -> List[str]:
-        return ["0"]  # device chaining is single-split by construction
-
-    def open_split(self, split: str, start_pos: int = 0) -> Iterator[Batch]:
-        i = start_pos
-        while True:
-            b = self.gen(split, i)
-            if b is None:
-                return
-            yield b
-            i += 1
-
-    @property
-    def bounded(self) -> bool:
-        return self.is_bounded
-
-
-@dataclasses.dataclass
 class GeneratorSource(Source):
     """Rate-unbounded generator source (ref: flink-connector-datagen
     DataGeneratorSource). ``gen(split, batch_index)`` returns a batch or

@@ -268,40 +268,15 @@ class PipelineOptions:
         "advances, fire dispatches, and drain deliveries interleaved at "
         "sub-batch boundaries — a fired window's rows become "
         "host-visible at sub-batch cadence (~batch_wall/K) instead of "
-        "full-batch cadence, while source positions, throttle probes, "
-        "and checkpoint checks stay amortized at the logical-batch "
-        "granularity. Must divide pipeline.microbatch-size (the plan "
-        "analyzer rejects misconfigurations at submit, SUBBATCH_"
-        "INVALID). 1 = the exact pre-split path. Committed output is "
+        "full-batch cadence, while source positions and checkpoint "
+        "checks stay at the logical-batch granularity. Must divide "
+        "pipeline.microbatch-size (the plan analyzer rejects "
+        "misconfigurations at submit, SUBBATCH_INVALID). 1 = one "
+        "step per logical batch. Committed output is "
         "byte-identical across K for exact lane monoids (counts, "
         "min/max, integer sums — the same contract as host.parallelism"
         "); float sums may differ in last-bit rounding because the "
         "device folds K partial batches instead of one.")
-    FIRE_GATE = ConfigOption(
-        "pipeline.fire-gate", True,
-        "Fire-gated dispatch: the fused/devgen step "
-        "programs run the fire/top-n/ring-append subgraph — and the "
-        "pane purge — under a device-side conditional keyed on the "
-        "dispatch header's window-end list, so a sub-batch in which no "
-        "window can fire skips the dominant select sort instead of "
-        "paying it every dispatch (the sub-batch throughput tax). "
-        "Committed output is byte-identical either way (the ungated "
-        "subgraph is a provable no-op on a fireless step); false "
-        "restores the unconditional pre-gate programs (the A/B axis).")
-    READINESS = ConfigOption(
-        "pipeline.readiness", "piggyback",
-        "How ingest backpressure learns that an in-flight device step "
-        "completed. 'piggyback' "
-        "(default): every fused/devgen dispatch announces a tiny "
-        "per-step output (the devgen stats vector / the fused kernel's "
-        "emit-ring head row) with copy_to_host_async at dispatch, and "
-        "the throttle retires the step by CONSUMING that in-flight "
-        "transfer — no separate is_ready control round trips, and the "
-        "token's ring-head words stand in for a ring-header poll "
-        "(opportunistic drains skip provably-empty fetches). 'probe': "
-        "the legacy is_ready spin on the step's in-flight marker "
-        "(zero per-step d2h traffic — the trade on transports where "
-        "any per-step transfer costs in-situ service time).")
     PROFILE_DIR = ConfigOption(
         "pipeline.profile-dir", "",
         "When set, the driver wraps pipeline.profile-steps WARM logical "

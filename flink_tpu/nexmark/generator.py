@@ -100,153 +100,6 @@ def bid_stream(cfg: NexmarkConfig) -> GeneratorSource:
                            schema=BID_SCHEMA)
 
 
-@dataclasses.dataclass(frozen=True)
-class _NexmarkDeviceBidGen:
-    """jnp-traceable bid generator, bit-identical to codec.cc smx().
-    A frozen dataclass (hash/eq by parameters) so it is a STABLE jit
-    static argument: two sources with the same shape share the compiled
-    devgen step across jobs — the warmup-shares-compilation contract.
-
-    ``sub_batches`` > 1 re-slices the stream (pipeline.sub-batches):
-    ``batch_size`` is then the SUB-batch size, index ``s`` yields the
-    bit-exact slice [off, off + batch_size) of LOGICAL batch s //
-    sub_batches (off = (s % sub_batches) * batch_size) — the splitmix
-    counter is seeded from the logical index and advanced by the
-    within-logical-batch record offset, so the record stream is
-    IDENTICAL at every sub-batch count."""
-
-    batch_size: int
-    events_per_ms: int
-    hot_ratio: int
-    n_hot: int
-    n_auctions: int
-    sub_batches: int = 1
-
-    def __call__(self, batch_index):
-        import jax.numpy as jnp
-
-        b = self.batch_size
-        k = self.sub_batches
-        logical = batch_index // k if k > 1 else batch_index
-        # within-logical-batch record offset of this sub-batch
-        off = (batch_index % k) * b if k > 1 else batch_index * 0
-        # counter-based splitmix64, bit-identical to codec.cc smx()
-        # (single split: the C seed for LOGICAL batch i is just i)
-        G = jnp.uint64(0x9E3779B97F4A7C15)
-        base = (logical.astype(jnp.uint64)
-                * jnp.uint64(0xD1342543DE82EF95) + jnp.uint64(1))
-        idx = off.astype(jnp.uint64) + jnp.arange(b, dtype=jnp.uint64)
-        z = base + idx * G + G  # smx advances the counter BEFORE mixing
-        z = (z ^ (z >> jnp.uint64(30))) * jnp.uint64(0xBF58476D1CE4E5B9)
-        z = (z ^ (z >> jnp.uint64(27))) * jnp.uint64(0x94D049BB133111EB)
-        r1 = z ^ (z >> jnp.uint64(31))
-        hot = ((r1 & jnp.uint64(0xFF))
-               % jnp.uint64(self.hot_ratio)) == 0
-        a32 = (r1 >> jnp.uint64(8)) & jnp.uint64(0xFFFFFFFF)
-        auction = jnp.where(
-            hot, (a32 * jnp.uint64(self.n_hot)) >> jnp.uint64(32),
-            (a32 * jnp.uint64(self.n_auctions))
-            >> jnp.uint64(32)).astype(jnp.int64)
-        ids = (logical * (b * k) + off
-               + jnp.arange(b, dtype=jnp.int64))
-        ts = ids // self.events_per_ms
-        return auction, ts
-
-
-def bid_stream_device(cfg: NexmarkConfig,
-                      sub_batches: int = 1) -> "DeviceGeneratorSource":
-    """Device-resident bid generator (Q5/Q7 input): the same
-    counter-based splitmix64 stream as ``native/codec.cc nexmark_bids``,
-    expressed in jnp so the consuming operator's step program can
-    synthesize the batch ON the accelerator (see
-    ops/window.py devgen_step_kernel). ``device_keys_ts`` is BIT-EXACT
-    with the C generator's auction lane — verified by
-    tests/test_devgen.py — so the host can repair key-table misses and
-    replay after restore from the identical stream.
-
-    ``sub_batches`` > 1 presents the IDENTICAL record stream at
-    ``cfg.batch_size / sub_batches`` granularity (the driver calls this
-    through ``DeviceGeneratorSource.subdivided`` when
-    ``pipeline.sub-batches`` is set): sub-batch index s covers the
-    bit-exact slice of logical batch s // sub_batches, so committed
-    output is byte-identical at every sub-batch count."""
-    from flink_tpu.api.sources import DeviceGeneratorSource
-
-    if cfg.n_splits != 1:
-        # the device formula and ts_bounds assume the single-split id
-        # base i*batch_size; _event_ids interleaves splits — mixing the
-        # two would break the bit-exact miss-repair contract
-        raise ValueError("bid_stream_device requires n_splits == 1")
-    k = int(sub_batches)
-    if k < 1 or cfg.batch_size % k:
-        raise ValueError(
-            f"sub_batches={k} must be >= 1 and divide "
-            f"batch_size={cfg.batch_size}")
-    host = bid_stream(cfg)
-    B = cfg.batch_size          # LOGICAL batch size (the seed unit)
-    b = B // k                  # produced (sub-)batch size
-    n_hot = max(1, cfg.num_active_auctions // HOT_AUCTION_RATIO)
-    device_keys_ts = _NexmarkDeviceBidGen(
-        batch_size=b, events_per_ms=cfg.events_per_ms,
-        hot_ratio=cfg.hot_ratio, n_hot=n_hot,
-        n_auctions=cfg.num_active_auctions, sub_batches=k)
-
-    # one-entry memo: a logical batch's K sub-repairs (or its K gen
-    # fallbacks below) synthesize the C batch once, not K times
-    _host_memo: list = [(-1, None)]
-
-    def _host_logical(logical: int):
-        from flink_tpu.native_codec import nexmark_bids_native
-
-        if _host_memo[0][0] != logical:
-            _host_memo[0] = (logical, nexmark_bids_native(
-                logical, B, cfg.hot_ratio, n_hot,
-                cfg.num_active_auctions, cfg.num_active_people))
-        return _host_memo[0][1]
-
-    def keys_ts_host(s: int):
-        logical, off = s // k, (s % k) * b
-        native = _host_logical(logical)
-        ids = logical * B + off + np.arange(b, dtype=np.int64)
-        return native[0][off:off + b], ids // cfg.events_per_ms
-
-    def ts_bounds(s: int):
-        base = (s // k) * B + (s % k) * b
-        return base // cfg.events_per_ms, (base + b - 1) // cfg.events_per_ms
-
-    _gen_memo: list = [(None, None)]
-
-    def gen(split: str, s: int):
-        # host-materialization fallback (a devgen gate closed): the C
-        # generator's seed unit is the LOGICAL batch — synthesize it
-        # once per logical index (memo) and slice this sub-batch out
-        if k == 1:
-            return host.gen(split, s)
-        key = (split, s // k)
-        if _gen_memo[0][0] != key:
-            _gen_memo[0] = (key, host.gen(split, s // k))
-        full = _gen_memo[0][1]
-        if full is None:
-            return None
-        data, ts = full
-        off = (s % k) * b
-        return ({kk: v[off:off + b] for kk, v in data.items()},
-                ts[off:off + b])
-
-    return DeviceGeneratorSource(
-        gen=gen, device_keys_ts=device_keys_ts,
-        keys_ts_host=keys_ts_host, ts_bounds=ts_bounds,
-        key_field="auction", batch_size=b, n_batches=cfg.n_batches * k,
-        # multiply-shift range reduction: auction < n_auctions ALWAYS
-        key_domain=cfg.num_active_auctions, keys_bounded=True,
-        schema=BID_SCHEMA,
-        # further subdivision re-derives from the config so the logical
-        # seed unit stays cfg.batch_size (only the K=1 source carries
-        # it; the driver subdivides exactly once)
-        subdivide=(lambda kk: bid_stream_device(cfg, sub_batches=kk))
-        if k == 1 else None)
-
-
 def person_stream(cfg: NexmarkConfig) -> GeneratorSource:
     """New-person events (Q8 left input): fields person, state_id."""
 

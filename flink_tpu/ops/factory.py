@@ -48,12 +48,6 @@ class OperatorBuildContext:
     # host.fold-chunk-records, the spill store's tree-fold batch floor;
     # None = the declared config default
     fold_chunk_records: Optional[int] = None
-    # pipeline.fire-gate: device-side conditional around the fire/top-n/
-    # ring-append subgraph of the fused step programs
-    fire_gate: bool = True
-    # pipeline.readiness: 'piggyback' (throttle consumes an announced
-    # per-step token) or 'probe' (legacy is_ready spin)
-    readiness: str = "piggyback"
     # state.backend='lsm' (disk spill tier, state/lsm.py): memtable
     # budget, run-file root, and the compaction trigger
     memory_budget_bytes: int = 64 * 1024 * 1024
@@ -120,8 +114,6 @@ def _window_factory(node, ctx: OperatorBuildContext):
         exchange_impl=ctx.exchange_impl,
         host_pool=ctx.host_pool,
         fold_chunk_records=ctx.fold_chunk_records,
-        fire_gate=ctx.fire_gate,
-        readiness=ctx.readiness,
     )
     op.max_inflight_steps = ctx.max_inflight_steps
     # backpressure blocks happen OUTSIDE the push lock (the ingest loop

@@ -574,9 +574,8 @@ def _ring_append_topn_core(
 # fused-step header layout, in i32 words:
 # [0:2]=pane_lo i64, [2:4]=pane_hi i64, [4:6]=anchor i64,
 # [6]=unused, [7]=clear-mask bits (ring<=32), [8:8+MIN_FIRE_PAD]=window-
-# end deltas vs pane_lo (sentinel INT32_MIN = padding), then at
-# DEVGEN_HDR_OFF the device-generator params (batch index, dead_below,
-# refire_below as i64), zero pad to FUSED_HDR = 128 words = 512 bytes
+# end deltas vs pane_lo (sentinel INT32_MIN = padding), zero pad to
+# FUSED_HDR = 128 words = 512 bytes
 # (sized so the header was never a "tiny" upload; whether tiny uploads
 # cost extra on the current chip: not measured)
 FUSED_HDR = 128
@@ -586,6 +585,7 @@ _DELTA_SENTINEL = -(2**30)
 # clear_kernel); the KERNEL reads only its static fire_pad
 # prefix of them (pow2-bucketed to the real end count, _fire_pad_bucket)
 MIN_FIRE_PAD = 64
+assert 8 + MIN_FIRE_PAD <= FUSED_HDR   # the deltas stay inside the header
 
 
 def fused_step_kernel(
@@ -601,7 +601,6 @@ def fused_step_kernel(
     by: str,
     topn: int,
     dump_row: int,
-    fire_gate: bool = False,
     fire_pad: int = MIN_FIRE_PAD,
 ) -> Tuple[PaneState, jax.Array, jax.Array]:
     """ONE device dispatch per microbatch: pre-aggregated apply +
@@ -613,9 +612,9 @@ def fused_step_kernel(
     measured. ref: 4.B/4.D hot paths, dispatched as one program.
 
     Third output: the emit ring's HEAD ROW after this step's fire —
-    the piggybacked readiness/ring-header token (announced at dispatch;
-    the throttle consumes it instead of is_ready-probing, and its
-    [total, truncated] words stand in for a ring-header poll)."""
+    the step's in-flight token (announced at dispatch; the throttle
+    consumes it, and its [total, truncated] words stand in for a
+    ring-header poll)."""
     hdr = buf[:FUSED_HDR]
     pairs = buf[FUSED_HDR:]
     state = _apply_preagg_u32_core(
@@ -623,7 +622,7 @@ def fused_step_kernel(
     state, emit_ring = _fused_fire_clear(
         state, emit_ring, hdr, used_mask, agg=agg,
         panes_per_window=panes_per_window, ring=ring, sel_cap=sel_cap,
-        by=by, topn=topn, fire_gate=fire_gate, fire_pad=fire_pad)
+        by=by, topn=topn, fire_pad=fire_pad)
     return state, emit_ring, emit_ring[0]
 
 
@@ -632,30 +631,28 @@ def _hdr_i64(hdr: jax.Array, i: int) -> jax.Array:
     shifts. NOT ``lax.bitcast_convert_type(i32[1,2] -> i64)``: an i64
     made that way and then captured by the fire gate's ``lax.cond``
     aborts XLA:TPU's compiler (libtpu 0.0.34, HloReplicationAnalysis:
-    "Invalid index {0} for shape u32[1,2]") — seen on a v5e for both
-    fused step programs; the shift form compiles and is bit-equal."""
+    "Invalid index {0} for shape u32[1,2]") — seen on a v5e; the shift
+    form compiles and is bit-equal."""
     lo = hdr[i].astype(jnp.int64) & jnp.int64(0xFFFFFFFF)
     return (hdr[i + 1].astype(jnp.int64) << 32) | lo
 
 
 def _fused_fire_clear(state, emit_ring, hdr, used_mask, *, agg,
                       panes_per_window, ring, sel_cap, by, topn,
-                      fire_gate=False, fire_pad=MIN_FIRE_PAD):
-    """Shared fire + clear tail of the one-dispatch step kernels: the
-    fire parameters and the purge mask ride the FUSED_HDR header.
+                      fire_pad=MIN_FIRE_PAD):
+    """Fire + clear tail of the one-dispatch step kernel: the fire
+    parameters and the purge mask ride the FUSED_HDR header.
 
-    ``fire_gate`` (pipeline.fire-gate): the fire/top-n/ring-append
-    subgraph — whose stable argsort + top_k would otherwise run on
-    every dispatch whether or not any window fires — runs under a
-    ``lax.cond`` keyed on the header's
-    window-end list, and the pane purge under a second cond keyed on
-    the clear words. The host fills both header fields before dispatch
+    The fire/top-n/ring-append subgraph — whose stable argsort + top_k
+    would otherwise run on every dispatch whether or not any window
+    fires — runs under a ``lax.cond`` keyed on the header's window-end
+    list, and the pane purge under a second cond keyed on the clear
+    words. The host fills both header fields before dispatch
     (``_fused_fill_header``), so a non-firing sub-batch skips the sort
     entirely. Byte-identical by construction: with no valid ends the
-    ungated core selects zero rows and leaves ring bytes and head
-    counters unchanged, and a zero clear mask is the identity — the
-    cond only skips provably-no-op work. fire_gate=False is the exact
-    pre-gate graph.
+    fire core selects zero rows and leaves ring bytes and head counters
+    unchanged, and a zero clear mask is the identity — the cond only
+    skips provably-no-op work.
 
     ``fire_pad``: how many of the header's MIN_FIRE_PAD window-end
     slots this program READS — the static width of the whole fire
@@ -683,14 +680,11 @@ def _fused_fire_clear(state, emit_ring, hdr, used_mask, *, agg,
             used_mask, agg=agg, panes_per_window=panes_per_window,
             ring=ring, sel_cap=sel_cap, by=by, topn=topn)
 
-    if fire_gate:
-        emit_ring = lax.cond(jnp.any(w_valid), _fire,
-                             lambda ring_in: ring_in, emit_ring)
-    else:
-        emit_ring = _fire(emit_ring)
+    emit_ring = lax.cond(jnp.any(w_valid), _fire,
+                         lambda ring_in: ring_in, emit_ring)
     # 64-bit clear mask split over header words [7] (columns 0-31)
     # and [6] (columns 32-63) — rings up to 64 stay on the one-dispatch
-    # fused paths (a 2^22-record batch's event span outgrows 32)
+    # fused path (a 2^22-record batch's event span outgrows 32)
     cm = (lax.shift_right_logical(
         clear_lo, jnp.arange(min(ring, 32), dtype=jnp.int32))
         & jnp.int32(1)) != 0
@@ -701,132 +695,17 @@ def _fused_fire_clear(state, emit_ring, hdr, used_mask, *, agg,
         cm = jnp.concatenate([cm, cm_hi])
     if ring > 64:
         cm = jnp.concatenate([cm, jnp.zeros(ring - 64, bool)])
-    if fire_gate:
-        state = lax.cond(
-            (clear_lo != 0) | (clear_hi != 0),
-            lambda s: clear_kernel(s, cm.astype(jnp.int32)),
-            lambda s: s, state)
-    else:
-        state = clear_kernel(state, cm.astype(jnp.int32))
+    state = lax.cond(
+        (clear_lo != 0) | (clear_hi != 0),
+        lambda s: clear_kernel(s, cm.astype(jnp.int32)),
+        lambda s: s, state)
     return state, emit_ring
-
-
-# refire-candidate bitmap span of the device-generator step (panes
-# above dead_below); configs whose lateness span exceeds this fall back
-# to the host ingest path
-DEVGEN_REFIRE_BITS = 2048
-
-
-def devgen_step_kernel(
-    state: PaneState,
-    emit_ring: jax.Array,
-    buf: jax.Array,        # (FUSED_HDR,) int32 header ONLY — no pairs
-    used_mask: jax.Array,
-    *,
-    gen,                   # traceable (batch_index i64) -> (keys, ts)
-    key_domain: int,       # keys [0, key_domain) map to slot == key
-    agg: LaneAggregate,
-    panes_per_window: int,
-    ring: int,
-    sel_cap: int,
-    by: str,
-    topn: int,
-    dump_row: int,
-    pane_ms: int,
-    offset_ms: int,
-    fire_gate: bool = False,
-    fire_pad: int = MIN_FIRE_PAD,
-) -> Tuple[PaneState, jax.Array, jax.Array]:
-    """Device-chained generator ingest: ONE dispatch synthesizes the
-    microbatch ON DEVICE, maps keys to slots, segment-sums the panes,
-    fires and clears — zero per-record host work and zero record bytes
-    on the link. This is the chained-source pattern taken to its TPU
-    conclusion (ref: operator chaining elides serialization between
-    chained operators — SURVEY §3.2; flink-connector-datagen as the
-    embedded source): the source lives INSIDE the window operator's
-    step program.
-
-    Key→slot is the DENSE IDENTITY map over the source's declared
-    bounded key domain (KeyDirectory.register_dense): slot must be a
-    pure function of key on device: a table probe needs a large gather
-    and a per-record update a large scatter, both slow ops on TPU,
-    while sort/cumsum/segment primitives are cheap (none of these costs
-    measured on the current chip). Records
-    outside the domain are EXCLUDED from the apply and counted in the
-    stats output; the host re-synthesizes the batch bit-exactly (the
-    generator contract), registers the new keys, and applies just those
-    records through the pair path. The third output is an int32 stats
-    vector: [n_valid, n_late, n_miss, ring_total, n_refire,
-    ring_truncated, 0, 0] ++ refire-candidate bitmap over panes
-    [dead_below, dead_below + DEVGEN_REFIRE_BITS) — words 3/5 carry
-    the emit ring's POST-FIRE head counters, so the announced stats
-    copy doubles as the piggybacked readiness token AND a ring-header
-    poll (no separate fetch)."""
-    hdr = buf[:FUSED_HDR]
-    batch_index = _hdr_i64(hdr, DEVGEN_HDR_OFF)
-    dead_below = _hdr_i64(hdr, DEVGEN_HDR_OFF + 2)
-    refire_below = _hdr_i64(hdr, DEVGEN_HDR_OFF + 4)
-    keys, ts = gen(batch_index)
-    hit = (keys >= 0) & (keys < key_domain)
-    slot = jnp.where(hit, keys, jnp.int64(0))
-    pane = (ts - offset_ms) // pane_ms           # floor div
-    late = hit & (pane < dead_below)
-    miss = ~hit
-    valid = hit & ~late
-    col = pane % ring                            # sign of divisor: >= 0
-    # flat segment-sum, NOT a 2D scatter: XLA lowers a large
-    # scatter-add serially on TPU, while segment_sum over the flat pane
-    # domain is a cheap primitive (cost not measured on the current
-    # chip)
-    n_rows = state.counts.shape[0]               # layout slots + dump
-    flat = jnp.where(valid, slot * ring + col,
-                     jnp.int64(dump_row * ring)).astype(jnp.int32)
-    inc = jax.ops.segment_sum(
-        jnp.ones(flat.shape[0], state.counts.dtype), flat,
-        num_segments=n_rows * ring)
-    state = PaneState(sums=state.sums, maxs=state.maxs, mins=state.mins,
-                      counts=state.counts + inc.reshape(n_rows, ring))
-    refire = valid & (pane < refire_below)
-    roff = jnp.where(refire, pane - dead_below,
-                     DEVGEN_REFIRE_BITS).astype(jnp.int32)
-    rbm = jax.ops.segment_sum(
-        jnp.ones_like(roff), roff,
-        num_segments=DEVGEN_REFIRE_BITS + 1)[:DEVGEN_REFIRE_BITS]
-    # materialize the ingest before the fire reads it: without the
-    # barrier XLA fuses the segment_sum into the fire path's many
-    # reads of counts and re-evaluates it per read
-    state = PaneState(
-        sums=state.sums, maxs=state.maxs, mins=state.mins,
-        counts=lax.optimization_barrier(state.counts))
-    state, emit_ring = _fused_fire_clear(
-        state, emit_ring, hdr, used_mask, agg=agg,
-        panes_per_window=panes_per_window, ring=ring, sel_cap=sel_cap,
-        by=by, topn=topn, fire_gate=fire_gate, fire_pad=fire_pad)
-    # stats words 3/5 = the POST-FIRE ring head [total, truncated]:
-    # the one announced copy carries ingest accounting, step readiness,
-    # AND the ring header in a single transfer
-    stats = jnp.concatenate([
-        jnp.stack([valid.sum().astype(jnp.int32),
-                   late.sum().astype(jnp.int32),
-                   miss.sum().astype(jnp.int32),
-                   emit_ring[0, 0],
-                   refire.sum().astype(jnp.int32),
-                   emit_ring[0, 1],
-                   jnp.int32(0), jnp.int32(0)]).astype(jnp.int32),
-        (rbm > 0).astype(jnp.int32)])
-    return state, emit_ring, stats
 
 
 _JIT_FUSED_STEP = jax.jit(
     fused_step_kernel,
     static_argnames=("agg", "panes_per_window", "ring", "sel_cap", "by",
-                     "topn", "dump_row", "fire_gate", "fire_pad"),
-    donate_argnums=(0,))
-_JIT_DEVGEN_STEP = jax.jit(
-    devgen_step_kernel,
-    static_argnames=("gen", "key_domain", "agg", "panes_per_window",
-                     "ring", "sel_cap", "by", "topn", "dump_row",
-                     "pane_ms", "offset_ms", "fire_gate", "fire_pad"),
+                     "topn", "dump_row", "fire_pad"),
     donate_argnums=(0,))
 
 
@@ -928,10 +807,6 @@ MAX_FIRE_CHUNK = 4
 # the ring/top-n path appends in HBM (no per-fire fetch buffer), so it
 # takes a steady advance's whole window list in ONE dispatch
 MAX_FIRE_CHUNK_RING = 16
-# devgen header params (batch_index, dead_below, refire_below as i64)
-# start right after the fire-delta region; must stay inside FUSED_HDR
-DEVGEN_HDR_OFF = 8 + MIN_FIRE_PAD
-assert DEVGEN_HDR_OFF + 6 <= FUSED_HDR
 
 
 def _next_pow2(n: int) -> int:
@@ -1373,31 +1248,11 @@ class WindowOperator:
         exchange_impl: str = "all-to-all",
         host_pool: Optional[Any] = None,
         fold_chunk_records: Optional[int] = None,
-        fire_gate: bool = True,
-        readiness: str = "piggyback",
     ) -> None:
         self.assigner = assigner
         self.agg = agg
         self.mesh_plan = mesh_plan
         self.exchange_impl = exchange_impl
-        # fire-gated dispatch (pipeline.fire-gate): the
-        # fused/devgen step programs run the fire/top-n/ring-append
-        # subgraph (and the pane purge) under lax.cond, so a dispatch
-        # whose header carries no fireable window end skips the
-        # sort instead of paying it every sub-batch.
-        # False = the exact pre-gate graphs (the A/B axis).
-        self.fire_gate = bool(fire_gate)
-        # step-readiness plumbing (pipeline.readiness): 'piggyback'
-        # derives throttle readiness from a tiny ANNOUNCED per-step
-        # output (the devgen stats vector / the fused kernel's ring-head
-        # row) — the wait is a consume of an in-flight transfer, never a
-        # separate is_ready poll of the backend; 'probe' is the legacy
-        # is_ready spin on the in-flight marker.
-        if readiness not in ("piggyback", "probe"):
-            raise ValueError(
-                f"pipeline.readiness must be 'piggyback' or 'probe', "
-                f"got {readiness!r}")
-        self.readiness = readiness
         # piggybacked ring-header knowledge (coalesced readback): tokens
         # carry the emit ring's [total, truncated] head words; once a
         # token AT OR AFTER the last row-carrying fire has landed, an
@@ -1518,12 +1373,6 @@ class WindowOperator:
         # by the next advance's single fused dispatch (see
         # fused_step_kernel) or flushed by _flush_stash
         self._stash_u32: Optional[np.ndarray] = None
-        # device-chained generator source (see devgen_step_kernel):
-        # spec, the pending batch index, and in-flight per-step stats
-        # awaiting reconciliation
-        self._devgen_spec = None
-        self._stash_devgen: Optional[Tuple[int, int, int, bool]] = None
-        self._devstats_pending: collections.deque = collections.deque()
         # RLock: the spill+top-n sync path holds it across
         # _fire_ends → drain_ring, and _fire_ends' announce block
         # takes it again (ingest vs drain-thread deque race)
@@ -1655,7 +1504,6 @@ class WindowOperator:
                 by=by,
                 topn=n,
                 dump_row=self.layout.slots,
-                fire_gate=self.fire_gate,
             ) if self.plan.ring <= 64 else None)
         else:
             self._fused_step = None
@@ -1689,11 +1537,7 @@ class WindowOperator:
         scales with the bucket, so K sub-batch dispatches of ~W/K real
         ends each cost ≈ one W-wide fire instead of K full-pad fires —
         the other half of the sub-batching tax next to the zero-end
-        cond skip.
-        Gating off keeps the full MIN_FIRE_PAD width (the exact
-        pre-gate program, the A/B axis)."""
-        if not self.fire_gate:
-            return MIN_FIRE_PAD
+        cond skip."""
         return min(MIN_FIRE_PAD, _next_pow2(max(n_ends, 1)))
 
     def _topn_cap(self, w: int) -> int:
@@ -1934,7 +1778,7 @@ class WindowOperator:
             if ov_total is not None:
                 self._overflow_markers.append(ov_total)
         ph("window.step_dispatch")
-        # inflight marker: a tiny scalar DERIVED from the new state — the
+        # inflight token: a tiny scalar DERIVED from the new state — the
         # state buffers themselves are donated to the next step, so
         # holding them would read deleted buffers
         self._note_dispatch(self.state.counts[0, 0])
@@ -2201,7 +2045,7 @@ class WindowOperator:
         self.exchange_entries += len(pairs)
         ph("window.step_dispatch")
         # one report a push (see the per-record lane), which is also the
-        # in-flight marker: an output of the step that is not donated
+        # in-flight token: an output of the step that is not donated
         self._overflow_markers.append(report)
         self._note_dispatch(report)
 
@@ -2240,54 +2084,39 @@ class WindowOperator:
                 "state_rows": np.asarray(
                     [rows.get(d, 0) for d in mp.mesh.devices.flat], np.int64)}
 
-    def _note_dispatch(self, marker, token=None, head=None) -> None:
+    def _note_dispatch(self, token, head=None) -> None:
         """Record one dispatched device step on the in-flight credit
-        deque. ``marker``: a non-donated output of the step (the legacy
-        is_ready probe target). ``token``: a tiny ANNOUNCED
-        (copy_to_host_async) output of the same step — piggybacked
-        readiness retires the step by CONSUMING its in-flight copy
-        instead of probing; ``head=(i_total, i_trunc)`` names the emit-
-        ring header words the token carries (coalesced readback).
+        deque. ``token``: a tiny non-donated output of the step, which
+        is ANNOUNCED (copy_to_host_async) here — the throttle retires
+        the step by CONSUMING that in-flight copy, a wait on a transfer
+        and never a separate is_ready poll of the backend. The ingest
+        dispatches pass a derived scalar (``state.counts[0, 0]``, the
+        sharded step's report); the fused step passes the emit ring's
+        head row, and ``head=(i_total, i_trunc)`` names the ring header
+        words such a token carries (coalesced readback).
 
-        THE announce happens here, once, for every token (re-announcing
-        an already-announced array is a no-op, so callers whose token
-        was announced for other reasons — the devgen stats copy under
-        need_stats — never double-pay): a token that skipped its
-        announce would silently turn the throttle's consume into the
-        unannounced blocking round trip piggyback exists to remove.
-        Token-less callers (the preagg/apply/stash ingest dispatches)
-        under piggyback readiness announce their MARKER instead — it is
-        already a tiny derived scalar (``state.counts[0, 0]``), so the
-        throttle's wait stays a transfer consume on EVERY dispatch
-        plane, not just the fused/devgen advances. Probe mode announces
-        nothing (zero per-step d2h, the documented trade)."""
-        seq = 0
-        if token is None and self.readiness == "piggyback":
-            token = marker  # consume-only: carries no ring-head words
-        if token is not None:
-            if hasattr(token, "copy_to_host_async"):
-                token.copy_to_host_async()
-            self._token_seq += 1
-            seq = self._token_seq
-        self._inflight.append((marker, token, head, seq))
+        THE announce happens here, once, for every step (re-announcing
+        an already-announced array is a no-op): a token that skipped its
+        announce would silently turn the throttle's consume into an
+        unannounced blocking round trip."""
+        if hasattr(token, "copy_to_host_async"):
+            token.copy_to_host_async()
+        self._token_seq += 1
+        self._inflight.append((token, head, self._token_seq))
 
     def _retire_step(self) -> None:
         """Retire the oldest in-flight step: consume its announced
-        readiness token when it has one (a wait on an in-flight
-        transfer, not an extra control round trip), else fall back to
-        the is_ready spin on the marker."""
-        marker, token, head, seq = self._inflight.popleft()
-        if token is not None:
-            arr = np.asarray(token)  # blocks on the announced copy only
-            if head is not None:
-                self._note_ring_head(arr, head, seq)
-        else:
-            ready_wait(marker)
+        token (a wait on an in-flight transfer, not an extra control
+        round trip)."""
+        token, head, seq = self._inflight.popleft()
+        arr = np.asarray(token)  # blocks on the announced copy only
+        if head is not None:
+            self._note_ring_head(arr, head, seq)
 
     @staticmethod
     def _raise_truncation(truncated: int) -> None:
         """The ONE top-n winner-buffer overflow error — raised from the
-        ring fetch (drain_ring) and from a landed readiness token's
+        ring fetch (drain_ring) and from a landed step token's
         head words, which detect it without a fetch."""
         raise RuntimeError(
             f"top-n winner-buffer truncation: {truncated} selected "
@@ -2329,9 +2158,6 @@ class WindowOperator:
         calls this before the FINAL watermark advance so the flush fires
         dispatch onto an idle device — their emit latency then measures
         fire+fetch, not the whole tail of the ingest pipeline."""
-        self._flush_devgen()
-        if self._devstats_pending:
-            self._reconcile_devstats()
         self._flush_stash()
         while self._inflight:
             self._retire_step()
@@ -2422,7 +2248,6 @@ class WindowOperator:
         whatever live pane aliases those old ring columns into the new
         columns, duplicating data into phantom windows."""
         self._flush_stash()  # stashed pairs are encoded in OLD ring columns
-        self._flush_devgen()  # pending device batch: same ring contract
         old_ring = self.plan.ring
         new_ring = _next_pow2(need + 4)
         lo = self._cleared_below
@@ -2467,12 +2292,6 @@ class WindowOperator:
             return self._advance_watermark(wm)
 
     def _advance_watermark(self, wm: int) -> "FiredWindows":
-        # device-generated steps whose stats have landed: fold them in
-        # (late accounting, refire scheduling, miss repair) BEFORE this
-        # advance enumerates its fire list; never park behind in-flight
-        # compute unless the backlog exceeds the repair deadline
-        if self._devstats_pending:
-            self._reconcile_devstats(force=False)
         self.state_version += 1
         prev = self.watermark
         self.watermark = wm
@@ -2489,15 +2308,6 @@ class WindowOperator:
         if self._fired_below_end is None or frontier > self._fired_below_end:
             self._fired_below_end = frontier
         self._refire.clear()
-        # device-generated path: the pending batch index + these fires
-        # + the purge ride ONE dispatch whose only upload is the header
-        if self._stash_devgen is not None:
-            if self._stash_u32 is not None:
-                self._flush_stash()  # miss repair stashed host pairs
-            out = self._advance_fused_devgen(wm, ends)
-            if out is not None:
-                return out
-            self._flush_devgen()  # fire list overflowed: chunked path
         # fused path: the pending ingest stash + these fires + the purge
         # ride ONE device dispatch with ONE upload
         if (self._stash_u32 is not None and self._fused_step is not None
@@ -2628,238 +2438,13 @@ class WindowOperator:
             self.state, self._ensure_ring(), dbuf, used,
             sel_cap=self._topn_cap(MIN_FIRE_PAD),
             fire_pad=self._fire_pad_bucket(len(ends_f)))
-        # the NON-donated emit-ring output doubles as the completion
-        # marker — no extra gather launch, and it survives the next
-        # step's donation of the state buffers. Piggyback readiness
-        # additionally registers the kernel's ring-head token
-        # (_note_dispatch announces it) so the throttle's wait is a
-        # consume of that in-flight copy.
-        if self.readiness == "piggyback":
-            self._note_dispatch(self._emit_ring, token=token, head=(0, 1))
-        else:
-            self._note_dispatch(self._emit_ring)
+        # the kernel's ring-head row is the step's token
+        # (_note_dispatch announces it): the throttle's wait is a
+        # consume of that in-flight copy, and its head words stand in
+        # for a ring-header poll
+        self._note_dispatch(token, head=(0, 1))
         self._cleared_below = cleared_after
         return self._ring_after_fire(ends_f, covered=True)
-
-    # -- device-chained generator ingest (see devgen_step_kernel) --------
-
-    def attach_device_source(self, spec) -> bool:
-        """Chain a DeviceGeneratorSource into this operator's step
-        program: batches are synthesized on device and never cross the
-        link. Requires the source to declare a bounded key domain — the
-        directory pre-registers it densely so slot == key is a pure
-        function on device (see devgen_step_kernel). Returns False when
-        this operator configuration can't host it — the driver then
-        materializes batches normally."""
-        from flink_tpu.native_codec import NativeHashTable
-
-        if (self._fused_step is None or self._topn is None
-                or self._preagg_lanes != () or self._spill is not None
-                or self.mesh_plan is not None
-                or self.uses_processing_time):
-            return False
-        if not isinstance(self.directory._table, NativeHashTable):
-            return False  # the miss-repair path needs the C probe
-        d = getattr(spec, "key_domain", None)
-        if d is None or d <= 0 or d > self.layout.slots:
-            return False
-        if self.directory.num_keys() == 0:
-            self.directory.register_dense(d)
-        else:
-            # restored/pre-populated directory: the dense identity must
-            # already hold for the WHOLE domain — a strict prefix would
-            # leave slots [num_keys, d) writable by the device kernel
-            # yet unregistered and unclaimed by the allocator
-            if self.directory.num_keys() < d:
-                return False
-            probe = np.arange(d, dtype=np.int64)
-            vals, found = self.directory._table.lookup_keys(probe)
-            if not (found.all() and (vals == probe).all()):
-                return False
-        self._devgen_spec = spec
-        return True
-
-    def process_batch_device(self, batch_index: int) -> bool:
-        """Accept one device-generated batch: validate the gates,
-        pre-grow the ring from the HOST-KNOWN ts bounds (exact — the
-        generator contract is deterministic in the batch index), and
-        stash the index for the next advance's single dispatch.
-        Returns False when a gate closed; the caller falls back to host
-        materialization for this batch."""
-        with self.phases.span("window.step_dispatch"):
-            return self._process_batch_device(batch_index)
-
-    def _process_batch_device(self, batch_index: int) -> bool:
-        spec = self._devgen_spec
-        if spec is None or self.plan.ring > 64:
-            return False
-        dead = self._cleared_below
-        refire_below = (self._fired_below_end
-                        if self._fired_below_end is not None
-                        else np.iinfo(np.int64).min)
-        if (refire_below > dead
-                and refire_below - dead > DEVGEN_REFIRE_BITS):
-            return False
-        ts_min, ts_max = spec.ts_bounds(batch_index)
-        pane_ms, off = self.plan.pane_ms, self.plan.offset_ms
-        pmin = (int(ts_min) - off) // pane_ms
-        pmax = (int(ts_max) - off) // pane_ms
-        if pmax < dead:
-            return False  # whole batch past lateness: host path accounts
-        # a pending batch must dispatch against the CURRENT ring layout
-        # before any growth remap below
-        self._flush_devgen()
-        eff_min = max(pmin, dead)
-        prev_min, prev_max = self._min_pane_seen, self._max_pane_seen
-        new_min = eff_min if prev_min is None else min(prev_min, eff_min)
-        new_max = pmax if prev_max is None else max(prev_max, pmax)
-        if new_max - max(dead, new_min) >= self.plan.ring:
-            self._grow_ring(new_max - max(dead, new_min) + 1,
-                            prev_min, prev_max)
-            if self.plan.ring > 64:
-                return False  # outgrew the clear words: host path
-        self.state_version += 1
-        self._min_pane_seen = new_min
-        self._max_pane_seen = new_max
-        # stats are needed only when something in them could be
-        # nonzero: an unproven key bound (misses), panes below the
-        # dead frontier (late accounting), or panes below the fired
-        # frontier (refire candidates) — at steady state a monotone
-        # source clears all three and the round trip is skipped
-        need_stats = (not getattr(spec, "keys_bounded", False)
-                      or pmin < dead or pmin < refire_below)
-        self._stash_devgen = (int(batch_index), int(dead),
-                              int(refire_below), bool(need_stats))
-        self._throttle_unless_external()
-        return True
-
-    def _dispatch_devgen(self, buf: np.ndarray, batch_index: int,
-                         dead: int, need_stats: bool = True,
-                         fire_pad: int = MIN_FIRE_PAD) -> None:
-        by, n = self._topn
-        step = functools.partial(
-            _JIT_DEVGEN_STEP, gen=self._devgen_spec.device_keys_ts,
-            key_domain=int(self._devgen_spec.key_domain),
-            agg=self.agg, panes_per_window=self.plan.panes_per_window,
-            ring=self.plan.ring, by=by, topn=n,
-            dump_row=self.layout.slots, pane_ms=self.plan.pane_ms,
-            offset_ms=self.plan.offset_ms, fire_gate=self.fire_gate)
-        used = self._used_mask_device()
-        self.state, self._emit_ring, stats = step(
-            self.state, self._ensure_ring(), jnp.asarray(buf), used,
-            sel_cap=self._topn_cap(MIN_FIRE_PAD), fire_pad=fire_pad)
-        # the stats lane rides home asynchronously and reconciles at a
-        # later advance; under probe readiness, when the spec PROVES
-        # the key bound and the batch's pane range rules out
-        # late/refire work, the whole transfer is skipped.
-        # Piggyback readiness registers it as the step's token instead
-        # (_note_dispatch announces it): the landed copy carries the
-        # post-fire ring head in words 3/5 — one transfer serves
-        # accounting, the throttle, and the ring-header poll.
-        if need_stats:
-            if self.readiness != "piggyback" \
-                    and hasattr(stats, "copy_to_host_async"):
-                stats.copy_to_host_async()
-            self._devstats_pending.append((batch_index, dead, stats))
-        if self.readiness == "piggyback":
-            self._note_dispatch(self._emit_ring, token=stats, head=(3, 5))
-        else:
-            self._note_dispatch(self._emit_ring)
-
-    def _advance_fused_devgen(self, wm: int,
-                              ends: List[int]) -> Optional["FiredWindows"]:
-        """One-dispatch advance over a device-generated batch:
-        generate + probe + apply + fire + purge in a single program
-        whose only upload is the 512-byte header."""
-        buf = np.zeros(FUSED_HDR, np.int32)
-        hdr = self._fused_fill_header(wm, ends, buf)
-        if hdr is None:
-            return None
-        ends_f, cleared_after = hdr
-        batch_index, dead, refire_below, need_stats = self._stash_devgen
-        self._stash_devgen = None
-        buf[DEVGEN_HDR_OFF:DEVGEN_HDR_OFF + 6] = np.array(
-            [batch_index, dead, refire_below], np.int64).view(np.int32)
-        self._dispatch_devgen(buf, batch_index, dead, need_stats,
-                              fire_pad=self._fire_pad_bucket(len(ends_f)))
-        self._cleared_below = cleared_after
-        return self._ring_after_fire(ends_f, covered=True)
-
-    def _flush_devgen(self) -> None:
-        """Dispatch a pending device-generated batch as a fire-less
-        step — every consumer of up-to-date state calls this (snapshots,
-        quiesce, ring growth, the chunked advance path)."""
-        if self._stash_devgen is None:
-            return
-        batch_index, dead, refire_below, need_stats = self._stash_devgen
-        self._stash_devgen = None
-        lo = (self._cleared_below if self._min_pane_seen is None
-              else max(self._cleared_below, self._min_pane_seen))
-        if self._ring_anchor is None:
-            self._ring_anchor = lo
-        hi_v = (self._max_pane_seen if self._max_pane_seen is not None
-                else lo - 1)
-        buf = np.zeros(FUSED_HDR, np.int32)
-        buf[:6] = np.array([lo, hi_v, self._ring_anchor],
-                           np.int64).view(np.int32)
-        buf[8:8 + MIN_FIRE_PAD] = np.full(MIN_FIRE_PAD, _DELTA_SENTINEL,
-                                          np.int64).astype(np.int32)
-        buf[DEVGEN_HDR_OFF:DEVGEN_HDR_OFF + 6] = np.array(
-            [batch_index, dead, refire_below], np.int64).view(np.int32)
-        self._dispatch_devgen(buf, batch_index, dead, need_stats,
-                              fire_pad=self._fire_pad_bucket(0))
-
-    # how many un-reconciled device steps may accumulate before an
-    # advance force-blocks on the oldest one's stats: at steady state
-    # the copies land while later batches dispatch, so reconciliation
-    # is a local read; the bound keeps miss repair well inside the
-    # pane ring's lifetime
-    DEVSTATS_MAX_LAG = 2
-
-    def _reconcile_devstats(self, force: bool = True) -> None:
-        """Fold landed device-step stats into host accounting: late
-        drops, directory-FULL drops, refire scheduling — and repair
-        MISSES by re-synthesizing the batch bit-exactly on the host,
-        registering the new keys, and applying just the missed records
-        through the normal ingest path (their windows, if already
-        fired, re-fire with corrected contents — the panes are still
-        alive because reconciliation is bounded to DEVSTATS_MAX_LAG
-        advances after the dispatch, well inside the ring's lifetime).
-
-        ``force=False`` consumes only entries whose announced copy has
-        LANDED (never parks behind in-flight compute — the same rule as
-        the emit-ring drain), except that entries older than
-        DEVSTATS_MAX_LAG block regardless."""
-        while self._devstats_pending:
-            if (not force
-                    and len(self._devstats_pending) <= self.DEVSTATS_MAX_LAG
-                    and not self._devstats_pending[0][2].is_ready()):
-                return
-            batch_index, dead, stats = self._devstats_pending.popleft()
-            arr = np.asarray(stats)
-            n_valid, n_late, n_miss, _unused, n_refire = (
-                int(x) for x in arr[:5])
-            self.late_records += n_late
-            if n_refire:
-                rbm = arr[8:8 + DEVGEN_REFIRE_BITS]
-                late_panes = np.flatnonzero(rbm) + dead
-                self._refire.update(self.plan.late_refire_ends(
-                    late_panes, self._fired_below_end, self.watermark))
-            if n_miss:
-                keys, ts = self._devgen_spec.keys_ts_host(batch_index)
-                out = (keys < 0) | (keys >= self._devgen_spec.key_domain)
-                vals, found = self.directory._table.lookup_keys(
-                    np.ascontiguousarray(keys[out], np.int64))
-                # out-of-domain keys the directory already rejected as
-                # FULL stay dropped — account them loudly (the
-                # default-safe policy); the rest re-apply normally and
-                # register through the ordinary allocation path
-                n_full = int((found & (vals < 0)).sum())
-                if n_full:
-                    account_full_drop(self, n_full)
-                redo = ~(found & (vals < 0))
-                if redo.any():
-                    self.process_batch(keys[out][redo], ts[out][redo], {})
 
     def _fire_cohort(self, end_panes: List[int]) -> Dict[str, Any]:
         """The record of one fire dispatch: its window ends (ms) and
@@ -2876,8 +2461,8 @@ class WindowOperator:
         """Post-fire ring bookkeeping shared by the fused and chunked
         top-n paths: version bump + cadenced announce (see
         _ring_versions). ``covered``: this fire rode a dispatch whose
-        readiness token carries the POST-fire ring head (the fused/
-        devgen paths) — that token (or any later one) re-validates the
+        token carries the POST-fire ring head (the fused step) — that
+        token (or any later one) re-validates the
         piggybacked head; a chunked fire has no token of its own, so
         only a FUTURE dispatch's token can."""
         n_ends = len(ends)
@@ -3285,12 +2870,7 @@ class WindowOperator:
         return self._spill.records_spilled if self._spill is not None else 0
 
     def snapshot_state(self) -> Dict[str, Any]:
-        # the snapshot must include stashed records AND every pending
-        # device-step's reconciliation (miss repair may stash pairs,
-        # hence the order: flush devgen → reconcile → flush pairs)
-        self._flush_devgen()
-        if self._devstats_pending:
-            self._reconcile_devstats()
+        # the snapshot must include stashed records
         self._flush_stash()
         self._resolve_overflow()  # a checkpoint must not hide pending loss
         spill_snap = (self._spill.snapshot()
@@ -3364,10 +2944,8 @@ class WindowOperator:
         self.late_records = snap["late_records"]
         self.records_dropped_full = snap.get("records_dropped_full", 0)
         # pre-restore device steps are from a dead timeline (their
-        # in-flight markers/tokens included — a stale token's ring head
-        # must never be folded into the restored timeline's facts)
-        self._stash_devgen = None
-        self._devstats_pending.clear()
+        # in-flight tokens included — a stale token's ring head must
+        # never be folded into the restored timeline's facts)
         self._inflight.clear()
         snap_spill = snap.get("spill")
         if self._spill is not None and snap_spill is not None:

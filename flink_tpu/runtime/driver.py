@@ -89,12 +89,6 @@ class Driver:
         self._max_ts: Dict[int, int] = {}
         self.metrics: Dict[str, int] = {
             "records_in": 0, "records_out": 0, "batches": 0, "fired_windows": 0,
-            # which plane a device-generator source's batches took:
-            # sources chained into a window operator's step program,
-            # (sub-)batches dispatched through that chain, and chained
-            # (sub-)batches a gate sent back to host materialization
-            "device_chain_attached": 0, "device_chain_batches": 0,
-            "device_chain_fallback_batches": 0,
         }
         from flink_tpu.obs.metrics import MetricRegistry
 
@@ -136,9 +130,9 @@ class Driver:
         # each logical batch as K chained sub-batch device steps with
         # watermark advances + fire dispatches interleaved at sub-batch
         # boundaries, so fired rows become host-visible at ~batch_wall/K
-        # cadence. Source positions / throttle probes / checkpoint
-        # checks stay at logical-batch granularity. K=1 IS the exact
-        # pre-split path (every new branch is guarded on K > 1).
+        # cadence. Source positions / checkpoint checks stay at
+        # logical-batch granularity. K=1 takes none of it (every
+        # sub-batch branch is guarded on K > 1).
         self._sub_batches = int(config.get(_PO.SUB_BATCHES))
         if self._sub_batches < 1:
             raise ValueError(
@@ -151,12 +145,6 @@ class Driver:
                 f"divide pipeline.microbatch-size ({mb}) — sub-batches "
                 "are equal slices of the logical batch (the plan "
                 "analyzer flags this at submit: SUBBATCH_INVALID)")
-        # per-source sub-batch factor actually in effect this run:
-        # sub_batches for device-chained sources iterating a subdivided
-        # stream (positions then count SUB-batches), 1 otherwise (host
-        # path slices inside one position). Snapshots record it so a
-        # restore under a different factor can re-base positions.
-        self._sub_factor: Dict[int, int] = {}
         g.gauge("debloat_chunk",
                 lambda: float(self._debloat_chunk or 0))
         # where the ingest loop and the drain thread spend their time
@@ -258,7 +246,7 @@ class Driver:
     def _build_ops(self) -> None:
         num_shards = self.config.get(StateOptions.NUM_KEY_SHARDS)
         slots = self.config.get(StateOptions.SLOTS_PER_SHARD)
-        self._base_inflight = int(
+        base_inflight = int(
             self.config.get(PipelineOptions.MAX_INFLIGHT_STEPS))
         # session resource shares (runtime/session.py): the dispatcher
         # stamps session.concurrent-jobs = K (the STATIC slot-
@@ -274,29 +262,15 @@ class Driver:
         self._session_share = max(
             1, int(self.config.get(SessionOptions.CONCURRENT_JOBS)))
         if self._session_share > 1:
-            self._base_inflight = max(
-                1, self._base_inflight // self._session_share)
+            base_inflight = max(
+                1, base_inflight // self._session_share)
         # sub-batching dispatches K steps per logical batch, each 1/K
         # the records: scale the in-flight credit so pipeline depth
         # measured in LOGICAL batches (and therefore in bytes queued on
         # the transport) is unchanged — emit polls read only landed
         # ring copies, so the deeper sub-step queue never parks a drain
-        # behind in-flight compute. A device chain whose source cannot
-        # subdivide still steps at LOGICAL granularity; its operator is
-        # reset to the base credit at chain attach (the scaled credit
-        # there would queue K× the bytes, not the same bytes).
-        inflight = self._base_inflight * self._sub_batches
-        # control-plane knobs: fire-gated dispatch and
-        # the readiness mechanism the throttle uses. Validated here so a
-        # typo fails at build, not deep inside the first throttle.
-        self._fire_gate = bool(self.config.get(PipelineOptions.FIRE_GATE))
-        self._readiness = str(
-            self.config.get(PipelineOptions.READINESS)).strip().lower()
-        if self._readiness not in ("piggyback", "probe"):
-            raise ValueError(
-                f"pipeline.readiness must be 'piggyback' or 'probe', "
-                f"got {self._readiness!r} (the plan analyzer flags this "
-                "at submit: READINESS_INVALID)")
+        # behind in-flight compute.
+        inflight = base_inflight * self._sub_batches
         xcap = self.config.get(PipelineOptions.EXCHANGE_CAPACITY)
         if xcap < 0:
             raise ValueError(
@@ -362,8 +336,6 @@ class Driver:
             shard_range=shard_range,
             host_pool=self.host_pool,
             fold_chunk_records=fold_chunk,
-            fire_gate=self._fire_gate,
-            readiness=self._readiness,
             memory_budget_bytes=int(
                 self.config.get(StateOptions.MEMORY_BUDGET_BYTES)),
             lsm_dir=str(self.config.get(StateOptions.LSM_DIR)),
@@ -533,10 +505,6 @@ class Driver:
             for nid, op in self._ops.items()}
         return {
             "sources": {sid: dict(pos) for sid, pos in self._positions.items()},
-            # the sub-batch factor positions were counted under (device
-            # chains iterate a subdivided stream): restore re-bases
-            # positions when the factor differs — see _run_loop
-            "sub_factors": dict(self._sub_factor),
             "wm_gens": {sid: [g.snapshot() for g in gens]
                         for sid, gens in self._wm_gens.items()},
             "max_ts": dict(self._max_ts),
@@ -574,9 +542,21 @@ class Driver:
     def _restore(self, payload: Dict[str, Any]) -> None:
         self._positions = {sid: dict(pos)
                            for sid, pos in payload["sources"].items()}
-        self._restored_sub_factors = {
-            int(k): int(v)
-            for k, v in payload.get("sub_factors", {}).items()}
+        # source positions count LOGICAL batches. A checkpoint that
+        # records a sub-batch factor other than 1 was written by a
+        # device-chained source (removed), whose positions counted
+        # SUB-batches: reading them as logical positions would skip
+        # records silently
+        chained = {k: int(v)
+                   for k, v in payload.get("sub_factors", {}).items()
+                   if int(v) != 1}
+        if chained:
+            raise ValueError(
+                f"checkpoint field 'sub_factors' records a sub-batch "
+                f"factor other than 1 for source(s) {chained}: its "
+                "source positions count sub-batches of a device-chained "
+                "generator source, which this version no longer has — "
+                "it cannot be restored as logical batch positions")
         # time-state keys may be absent: a state-processor savepoint
         # with reset_watermarks() restarts event time from scratch
         for sid, states in payload.get("wm_gens", {}).items():
@@ -1141,54 +1121,6 @@ class Driver:
                 self._propagate_watermarks()
             self._check_drain_error()
 
-    def _maybe_chain_device_source(self, sid: int, n) -> None:
-        """Chain a DeviceGeneratorSource into its consuming window
-        operator when the topology allows it: single downstream window
-        node keyed on the source's key field, single process, and an
-        operator config the devgen kernel can host (the operator's own
-        gate). Any miss falls back to normal host materialization.
-
-        ``pipeline.sub-batches`` > 1: the source is SUBDIVIDED before
-        attach — the operator's step program runs at sub-batch
-        granularity (bit-exact slices of the logical stream), so fires
-        ride each sub-step's dispatch and positions count sub-batches
-        (``self._sub_factor[sid]``). A source that declares no
-        subdivision chains at logical granularity — sub-batch fire
-        cadence then applies only to host-fed sources."""
-        from flink_tpu.api.sources import DeviceGeneratorSource
-
-        src = n.source
-        if (not isinstance(src, DeviceGeneratorSource)
-                or src.device_keys_ts is None or self._dcn is not None
-                or len(n.downstream) != 1):
-            return
-        wid = n.downstream[0]
-        wn = self.plan.node(wid)
-        if (wn.kind != "window"
-                or getattr(wn, "key_field", None) != src.key_field):
-            return
-        factor = 1
-        if self._sub_batches > 1 and src.subdivide is not None:
-            # a declared-but-failing subdivision is a config error (the
-            # source's batch size does not split into K) — loud, not a
-            # silent fall back to full-batch fire cadence
-            src = src.subdivided(self._sub_batches)
-            factor = self._sub_batches
-        op = self._ops.get(wid)
-        if op is not None and hasattr(op, "attach_device_source") \
-                and op.attach_device_source(src):
-            self._dev_chains[sid] = wid
-            self.metrics["device_chain_attached"] += 1
-            if factor > 1:
-                self._sub_factor[sid] = factor
-                self._dev_subdivided[sid] = src
-            elif self._sub_batches > 1:
-                # the chain stays at LOGICAL granularity (no subdivide
-                # callable): the ×K in-flight credit from _build_ops
-                # would let K× the bytes queue before throttle engages
-                # — restore the base depth for this operator
-                op.max_inflight_steps = self._base_inflight
-
     def _enumerate_owned(self, sid: int, n_splits: int) -> List[int]:
         """Which split indices THIS runner reads (ref: FLIP-27
         SplitEnumerator on the JM assigning splits to readers — SURVEY
@@ -1662,10 +1594,6 @@ class Driver:
                     "transfer plane (out of scope, see COMPONENTS #57)")
             self._dcn = self._dcn_connect()
 
-        # per-source sub-batch factor the restored checkpoint's positions
-        # were written under (see _snapshot "sub_factors"); {} = fresh
-        # run or pre-sub-batch checkpoint (factor 1 everywhere)
-        self._restored_sub_factors: Dict[int, int] = {}
         if restore:
             if restore == "latest":
                 payload = (self._dcn_negotiated_restore()
@@ -1708,34 +1636,9 @@ class Driver:
         # state stay globally indexed (checkpoints are runner-agnostic).
         srcs = self._srcs = {}
         self._owned_splits: Dict[int, List[int]] = {}
-        # device-chained generator sources: source synthesized inside
-        # the window operator's step program (see DeviceGeneratorSource
-        # + ops/window.py devgen_step_kernel); maps sid -> window nid
-        self._dev_chains: Dict[int, int] = {}
-        # sid -> the SUBDIVIDED source actually iterated this run
-        # (pipeline.sub-batches > 1 on a device chain); marker
-        # iteration, gen fallback, and positions all use it
-        self._dev_subdivided: Dict[int, Any] = {}
         prefetch = self.config.get(PipelineOptions.SOURCE_PREFETCH)
         for sid in self.plan.sources:
             n = self.plan.node(sid)
-            if self.plan.runtime_mode != "batch":
-                # batch mode keeps the host materialization path: the
-                # devgen chain fuses per-step fire logic into the step
-                # program, which final-only firing deliberately skips
-                self._maybe_chain_device_source(sid, n)
-            # restored positions were written in the restoring run's
-            # sub-batch units — re-base them to THIS run's factor. Only
-            # positions landing on a common sub-batch boundary convert
-            # (a checkpoint cut mid-logical-batch at K=4 cannot resume
-            # at K=3); misaligned factors fail loudly here rather than
-            # silently replaying a partial logical batch.
-            old_f = int(self._restored_sub_factors.get(sid, 1))
-            new_f = int(self._sub_factor.get(sid, 1))
-            if old_f != new_f:
-                for i, p in list(self._positions[sid].items()):
-                    self._positions[sid][i] = _rebase_position(
-                        int(p), old_f, new_f, sid=sid, split_ix=i)
             splits = n.source.splits()
             owned = self._enumerate_owned(sid, len(splits))
             self._owned_splits[sid] = owned
@@ -1753,14 +1656,6 @@ class Driver:
                 self._out_wm[sid] = _FINAL
             d = srcs[sid] = {}
             for i in owned:
-                if sid in self._dev_chains:
-                    # no materialization, no feeder thread: the
-                    # iterator yields per-batch metadata markers only
-                    # (sub-batch markers when the chain subdivided)
-                    d[i] = _dev_batch_markers(
-                        self._dev_subdivided.get(sid, n.source),
-                        self._positions[sid].get(i, 0))
-                    continue
                 it = n.source.open_split(splits[i],
                                          self._positions[sid].get(i, 0))
                 d[i] = (_Prefetcher(it, depth=prefetch)
@@ -1795,63 +1690,10 @@ class Driver:
                     if nxt is None:
                         splits_alive.remove(split_ix)
                         continue
-                    already_sub = False
-                    if isinstance(nxt, _DevBatch):
-                        op = self._ops[self._dev_chains[sid]]
-                        ph("ingest.link_wait")
-                        with self._link_lock:
-                            pass
-                        ph("ingest.route")
-                        with self._push_lock:
-                            ok = op.process_batch_device(nxt.index)
-                            if ok:
-                                self.metrics["records_in"] += nxt.n
-                                self.metrics["batches"] += 1
-                                self.metrics["device_chain_batches"] += 1
-                        if ok:
-                            # probe readiness: each throttle wait is
-                            # a separate poll of the backend, so they
-                            # amortize at LOGICAL-batch granularity —
-                            # only the last sub-batch of a logical
-                            # group rate-matches (the in-flight credit was
-                            # scaled by the same factor in _build_ops,
-                            # so depth in bytes is unchanged).
-                            # Piggybacked readiness makes each wait a
-                            # consume of an already-announced transfer
-                            # (no extra round trip), so the throttle
-                            # rate-matches at EVERY sub-batch — the
-                            # credit accounting scales with the finer
-                            # cadence instead of batching it.
-                            f = self._sub_factor.get(sid, 1)
-                            if (f == 1 or self._readiness == "piggyback"
-                                    or (nxt.index + 1) % f == 0):
-                                self._throttle_ops()
-                            ph("ingest.bookkeeping")
-                            self._positions[sid][split_ix] += 1
-                            self._eps_meter.mark(nxt.n)
-                            mx = nxt.ts_max
-                            self._max_ts[sid] = max(self._max_ts[sid], mx)
-                            self._wm_gens[sid][split_ix].on_batch(mx)
-                            self._wm_lag.set(mx - self._out_wm[sid])
-                            self._check_drain_error()
-                            continue
-                        # a devgen gate closed for this batch (ring
-                        # outgrew the header, oversized lateness span):
-                        # materialize it on the host and push normally
-                        # (the subdivided stream's gen yields the same
-                        # bit-exact sub-batch slice — already at
-                        # sub-batch size, so the host path must not
-                        # slice it K ways again)
-                        ph("ingest.bookkeeping")
-                        self.metrics["device_chain_fallback_batches"] += 1
-                        already_sub = self._sub_factor.get(sid, 1) > 1
-                        nxt = self._dev_subdivided.get(
-                            sid, self.plan.node(sid).source).gen(
-                            "0", nxt.index)
                     data, ts = nxt
                     ts = np.asarray(ts, np.int64)
-                    if self._sub_batches > 1 and not already_sub:
-                        # sub-batch fire/emit decoupling, host plane:
+                    if self._sub_batches > 1:
+                        # sub-batch fire/emit decoupling:
                         # K equal slices, each followed by a watermark
                         # advance + fire dispatch, so fired rows reach
                         # the drain at sub-batch cadence. Position /
@@ -2285,7 +2127,7 @@ class Driver:
 
     def _ingest_host_subbatched(self, sid: int, split_ix: int,
                                 splits_alive, data, ts) -> None:
-        """Host-plane sub-batching (pipeline.sub-batches = K > 1): the
+        """Sub-batching (pipeline.sub-batches = K > 1): the
         logical batch is pushed as K equal slices, and after EACH slice
         the watermark clock advances and fires dispatch — a fired
         window's rows become host-visible at sub-batch cadence instead
@@ -2777,27 +2619,6 @@ class _DcnStepState:
     persisted_id: int = -1  # newest id THIS process holds durably
 
 
-class _DevBatch:
-    """Per-batch metadata marker of a device-chained generator source:
-    the batch itself is synthesized on the accelerator; the host loop
-    only needs its index, record count, and exact ts bounds (for the
-    watermark clock and metrics)."""
-
-    __slots__ = ("index", "ts_min", "ts_max", "n")
-
-    def __init__(self, index: int, ts_min: int, ts_max: int, n: int):
-        self.index = index
-        self.ts_min = ts_min
-        self.ts_max = ts_max
-        self.n = n
-
-
-def _dev_batch_markers(src, start: int):
-    for i in range(start, src.n_batches):
-        tmin, tmax = src.ts_bounds(i)
-        yield _DevBatch(i, tmin, tmax, src.batch_size)
-
-
 class _Prefetcher:
     """Pulls source batches ahead on a feeder thread so record
     generation/decode overlaps the main loop's keying + h2d + dispatch
@@ -2871,25 +2692,6 @@ class _Prefetcher:
             self._done = True
             raise item
         return item
-
-
-def _rebase_position(pos: int, old_f: int, new_f: int, *,
-                     sid: int = 0, split_ix: int = 0) -> int:
-    """Convert a source replay position between sub-batch factors: a
-    position counted in old_f sub-batches per logical batch becomes the
-    equivalent count in new_f units. Only positions on a common
-    sub-batch boundary convert (a checkpoint cut mid-logical-batch at
-    K=4 cannot resume at K=3) — misalignment fails loudly rather than
-    silently replaying a partial logical batch."""
-    scaled = pos * new_f
-    if scaled % old_f:
-        raise ValueError(
-            f"checkpoint position {pos} of source {sid} split "
-            f"{split_ix} was taken at sub-batch factor {old_f} and "
-            f"does not align to factor {new_f} — restore with the "
-            "original pipeline.sub-batches, or from a logical-batch-"
-            "aligned checkpoint")
-    return scaled // old_f
 
 
 _FINAL = np.iinfo(np.int64).max  # end-of-input marker watermark

@@ -244,34 +244,6 @@ class KeyDirectory:
             slots[miss_ix] = alloc[inv]
         return slots
 
-    def register_dense(self, n: int) -> None:
-        """Pre-register keys [0, n) with slot == key — the device-
-        chained generator contract (ops/window.py devgen_step_kernel):
-        on device, slot must be a PURE FUNCTION of key, because probing
-        a table there needs a large gather (a slow op on TPU; cost not
-        measured on the current chip) while identity is free. A legal
-        allocation order — all
-        mappings downstream go through the table and rev arrays — but
-        it bypasses hash sharding, so it requires an EMPTY directory
-        that owns every shard. Later out-of-domain keys still allocate
-        normally from each shard's remaining slots."""
-        if self.num_keys():
-            raise ValueError("register_dense requires an empty directory")
-        if (self.shard_lo, self.shard_hi) != (0, self.num_shards):
-            raise ValueError("register_dense requires the full shard range")
-        if n > self.local_slots:
-            raise ValueError(
-                f"dense key domain {n} exceeds capacity {self.local_slots}")
-        keys = np.arange(n, dtype=np.int64)
-        self._table.insert_batch(keys, hash_keys_numpy(keys), keys)
-        self._rev_keys[:n] = keys
-        self._rev_used[:n] = True
-        # claim the dense region from each shard's free pointer so the
-        # ordinary allocator never hands one of these slots out again
-        self._next_free[:] = np.clip(
-            n - np.arange(self.num_shards) * self.slots_per_shard,
-            0, self.slots_per_shard)
-
     def register_misses(self, miss_keys: np.ndarray) -> None:
         """Register keys KNOWN to be absent (the fused C scan already
         probed them — codec.cc ingest_fused_scan): allocate + insert

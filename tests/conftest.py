@@ -67,8 +67,8 @@ def pytest_configure(config):
         "session -U/+U refires, RetractSink exactly-once under chaos, "
         "and the lifted SQL shapes (agg-over-join, HAVING) (tier-1)")
     config.addinivalue_line(
-        "markers", "firegate: fire-gated dispatch + piggybacked "
-        "readiness (pipeline.fire-gate / pipeline.readiness) — "
-        "gate-on/off byte-identity at K∈{1,2,4}, the host-fed "
-        "late-refire gate predicate, readiness-mode parity, and the "
-        "FIRE_GATE_INVALID / READINESS_INVALID analyzer rules (tier-1)")
+        "markers", "firegate: the fused step's fire gate and the "
+        "announced step tokens — the host-fed late-refire gate "
+        "predicate against a numpy golden, the coalesced ring "
+        "readback, and the unknown-key treatment of the two removed "
+        "options pipeline.fire-gate / pipeline.readiness (tier-1)")

@@ -86,11 +86,16 @@ class TestOperatorDirect:
             return dict(data)
 
         op = AsyncIOOperator(gate, capacity=2, ordered=True, workers=4)
-        t0 = time.time()
+        t0 = time.monotonic()
         for i in range(3):
             op.submit(({"i": np.array([i])}, np.array([i]),
                        np.ones(1, bool)), i)
-        assert time.time() - t0 < 0.2  # submits are non-blocking
+        # submits are non-blocking: all three returned over capacity
+        # while the gate was still shut (one that waited for a free
+        # slot would sit out the gate's 10 s); no deadline a loaded
+        # host can miss
+        assert not release.is_set()
+        assert time.monotonic() - t0 < 5.0
 
         def delayed_release():
             time.sleep(0.25)
@@ -98,7 +103,8 @@ class TestOperatorDirect:
 
         threading.Thread(target=delayed_release, daemon=True).start()
         op.throttle()  # 3 running > capacity 2: blocks until release
-        assert time.time() - t0 >= 0.2
+        assert release.is_set()
+        assert time.monotonic() - t0 >= 0.25
         op.poll(drain=True)
         op.close()
 

@@ -70,25 +70,6 @@ def test_bench_columnar_axis_and_artifact(tmp_path):
     assert persisted["lines"] == rows
 
 
-def test_bench_control_probe_vs_piggyback_and_artifact(tmp_path):
-    """The control-plane probe (ISSUE 15 satellite): per-wait cost of
-    the is_ready spin vs the piggybacked announced-transfer consume,
-    with the honest backend/core constraint recorded on every line and
-    the speedup as a machine-readable artifact number."""
-    import json as _json
-
-    art = tmp_path / "control.json"
-    rows = bench_micro.bench_control(iters=10, artifact=str(art))
-    metrics = {r["metric"] for r in rows}
-    assert {"control_wait_us_probe", "control_wait_us_piggyback",
-            "control_readiness_speedup"} <= metrics
-    for r in rows:
-        assert "constraint" in r, r["metric"]
-    persisted = _json.loads(art.read_text())
-    assert persisted["lines"] == rows
-    assert persisted["host_cores"] >= 1
-
-
 @pytest.mark.shard_map
 def test_all_micro_benchmarks_emit(capsys):
     bench_micro.bench_state_update(batch=1 << 12, iters=2)

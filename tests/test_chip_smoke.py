@@ -44,17 +44,16 @@ class TestChipSmoke:
         assert out["rehearsal"] is True and out["device"] == dev
         assert out["native_codec"].startswith("libflinktpucodec-")
         planes = out["planes"]
-        assert set(planes) == {"device_chained", "host_fed", "sum_lane"}
+        assert set(planes) == {"host_fed", "sum_lane"}
         for name, plane in planes.items():
+            # matched: the job's rows equal the in-file numpy
+            # reference's over the same bid_stream records
             assert plane["matched"] is True and plane["rows"] > 0, name
             assert plane["records_dropped_full"] == 0
-            assert plane["device_chain_fallback_batches"] == 0
+            assert plane["late_records"] == 0
             assert plane["compile_s"] > 0 and plane["run_s"] > 0
-        chained = planes["device_chained"]
-        assert chained["device_chain_attached"] == 1
-        assert (chained["device_chain_batches"]
-                == chained["batches"] * chained["sub_batches"])
-        assert planes["host_fed"]["device_chain_batches"] == 0
+        assert planes["host_fed"]["events"] == (
+            planes["host_fed"]["batches"] * (1 << 13))
         assert planes["sum_lane"]["sum_max_rel_err"] <= 1e-5
         # the cache went where the environment said, set from outside
         cc = out["compile_cache"]
@@ -62,6 +61,35 @@ class TestChipSmoke:
         assert cc["entries_after"] > cc["entries_before"] == 0
         assert cc["entries_after"] == sum(
             f.endswith("-cache") for f in os.listdir(cache))
+
+    def test_cpu_rehearsal_traces_the_host_fed_job(self, tmp_path):
+        """``--trace-dir`` runs one more HOST-FED Q5 under
+        pipeline.profile-dir, and its summary names the span under
+        which a host-fed batch's device step goes out."""
+        p = _run(["--cpu-rehearsal", "--trace-dir", str(tmp_path / "tr")],
+                 {"JAX_ENABLE_COMPILATION_CACHE": "false"}, timeout=240)
+        assert p.returncode == 0, p.stderr[-3000:]
+        out = json.loads(p.stdout.strip().splitlines()[-2])
+        trace = out["trace"]
+        by_span = {op["op"]: op["count"] for op in trace["step_dispatch"]}
+        assert by_span.get("window.step_dispatch", 0) >= 1, trace
+        assert os.path.exists(trace["trace_file"])
+        assert set(out["planes"]) == {"host_fed", "sum_lane"}
+
+    def test_cpu_rehearsal_four_chips_equals_one(self):
+        """``--chips 4``: the host-fed job on one device and over a
+        mesh of four (virtual) devices commit the same rows, and every
+        device received records."""
+        p = _run(["--cpu-rehearsal", "--chips", "4"],
+                 {"JAX_ENABLE_COMPILATION_CACHE": "false"}, timeout=240)
+        assert p.returncode == 0, p.stderr[-3000:]
+        out, last = map(json.loads, p.stdout.strip().splitlines()[-2:])
+        assert last["ok"] is True and out["chips_used"] == 4
+        assert set(out["planes"]) == {"host_fed", "host_fed_mesh4"}
+        mesh = out["planes"]["host_fed_mesh4"]
+        assert mesh["matched"] is True and mesh["equals_one_chip"] is True
+        assert len(mesh["records_per_device"]) == 4
+        assert sum(mesh["records_per_device"]) == mesh["events"]
 
     def test_plain_form_refuses_to_run_without_a_tpu(self):
         p = _run([], {"JAX_PLATFORMS": "cpu"})

@@ -367,21 +367,20 @@ class TestGoldenNegatives:
         assert cenv.analyze() == []
 
     def test_bench_headline_conf_and_pipeline_zero_findings(self):
-        """The bench Q5 pipeline under BENCH_CONF with
-        pipeline.sub-batches=4 (the headline config) analyzes clean —
-        device-chained source, declared schema, sub-batch grammar."""
+        """The bench Q5 pipeline under its committed conf
+        (bench_q5_host_fed) analyzes clean — host-fed source, declared
+        schema, sub-batch grammar."""
         import bench
-        from flink_tpu.nexmark.generator import (
-            NexmarkConfig, bid_stream_device)
+        from flink_tpu.nexmark.generator import NexmarkConfig, bid_stream
         from flink_tpu.nexmark.queries import q5_hot_items
         from flink_tpu.api.sinks import FnSink
 
-        conf = bench.job_confs()["bench_q5_headline"]
+        conf = bench.job_confs()["bench_q5_host_fed"]
         env = StreamExecutionEnvironment(Configuration(dict(conf)))
-        cfg = NexmarkConfig(batch_size=1 << 22, n_batches=2,
+        cfg = NexmarkConfig(batch_size=1 << 20, n_batches=2,
                             events_per_ms=100,
                             num_active_auctions=10_000, hot_ratio=4)
-        q5_hot_items(env, bid_stream_device(cfg), FnSink(lambda b: None),
+        q5_hot_items(env, bid_stream(cfg), FnSink(lambda b: None),
                      out_of_orderness_ms=1_000)
         assert env.analyze() == []
 

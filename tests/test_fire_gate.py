@@ -1,27 +1,23 @@
-"""Fire-gated dispatch + piggybacked completion (ISSUE 15).
+"""The fused step's fire gate and the announced step tokens.
 
-The contract under test, exactly as shipped:
+The contract under test:
 
-- ``pipeline.fire-gate`` wraps the fused/devgen step programs' fire/
-  top-n/ring-append subgraph (and the pane purge) in a device-side
-  ``lax.cond`` keyed on the dispatch header's window-end list. The
-  gate only ever skips provably-no-op work, so COMMITTED OUTPUT IS
-  BYTE-IDENTICAL — including row order on the devgen path — with the
-  gate on vs off at every sub-batch count (the tier-1 identity bar).
-- The allowed-lateness REFIRE path must gate correctly: a late-within-
-  lateness record re-fires its already-fired window, and that refire
-  rides the header's end list exactly like a first fire — gating must
-  never suppress it.
-- ``pipeline.readiness`` flips HOW the throttle learns a step is done
-  (piggybacked announced-token consume vs legacy is_ready spin) and
-  nothing else: committed rows are identical across modes.
-- Coalesced readback: a landed token carries the emit ring's head
-  counters, so an opportunistic drain poll that provably has nothing
-  to fetch skips the device round trip (prof["drain_skips"]) — and a
-  later row-carrying fire re-arms the fetch.
-- FIRE_GATE_INVALID (warn) flags gating forced off under sub-batching;
-  READINESS_INVALID (error) flags unknown readiness values, which the
-  driver also rejects at build.
+- The fused step program runs its fire/top-n/ring-append subgraph (and
+  the pane purge) under a device-side ``lax.cond`` keyed on the
+  dispatch header's window-end list. The allowed-lateness REFIRE path
+  must gate correctly: a late-within-lateness record re-fires its
+  already-fired window, and that refire rides the header's end list
+  exactly like a first fire — the gate must never suppress it. The
+  golden is the window semantics in plain numpy.
+- Coalesced readback: every step announces a tiny token; a landed
+  fused-step token carries the emit ring's head counters, so an
+  opportunistic drain poll that provably has nothing to fetch skips
+  the device round trip (prof["drain_skips"]) — and a later
+  row-carrying fire re-arms the fetch.
+- ``pipeline.fire-gate`` and ``pipeline.readiness`` are gone: a conf
+  that still sets them is told so as for any unknown key
+  (CONFIG_KEY_UNKNOWN, warn), by the analyzer, the CLI and the submit
+  gate, and the job runs as it does without them.
 """
 import numpy as np
 import pytest
@@ -31,14 +27,9 @@ from flink_tpu.api.sinks import FnSink
 from flink_tpu.api.sources import GeneratorSource
 from flink_tpu.api.windowing import TumblingEventTimeWindows
 from flink_tpu.config import Configuration
-from flink_tpu.nexmark.generator import NexmarkConfig, bid_stream_device
-from flink_tpu.nexmark.queries import q5_hot_items
 from flink_tpu.time.watermarks import WatermarkStrategy
 
 pytestmark = pytest.mark.firegate
-
-Q5_CFG = dict(batch_size=4096, n_batches=6, events_per_ms=100,
-              num_active_auctions=500, hot_ratio=4)
 
 
 def _capture_sink():
@@ -56,29 +47,16 @@ def _capture_sink():
     return cat, FnSink(cap)
 
 
-def _control_conf(k, fire_gate, readiness, extra=None):
+def _conf(k, extra=None):
     conf = {
         "analysis.fail-on": "off",
-        "pipeline.microbatch-size": Q5_CFG["batch_size"],
+        "pipeline.microbatch-size": 1024,
         "state.num-key-shards": 128,
         "state.slots-per-shard": 64,
         "pipeline.sub-batches": k,
-        "pipeline.fire-gate": fire_gate,
-        "pipeline.readiness": readiness,
     }
     conf.update(extra or {})
     return conf
-
-
-def _run_devgen_q5(k, fire_gate=True, readiness="piggyback"):
-    cat, sink = _capture_sink()
-    env = StreamExecutionEnvironment(Configuration(
-        _control_conf(k, fire_gate, readiness)))
-    q5_hot_items(env, bid_stream_device(NexmarkConfig(**Q5_CFG)), sink,
-                 window_ms=10_000, slide_ms=1_000,
-                 out_of_orderness_ms=1_000)
-    res = env.execute(f"q5-gate-{fire_gate}-{readiness}-k{k}")
-    return cat(), res.metrics
 
 
 def _assert_identical_in_order(golden, got, ctx):
@@ -89,73 +67,36 @@ def _assert_identical_in_order(golden, got, ctx):
             (ctx, f)
 
 
-class TestDevgenGateIdentity:
-    """Devgen Q5 (the headline path): committed rows byte-identical
-    INCLUDING ROW ORDER with fire-gating on vs off at K ∈ {1, 2, 4} —
-    the gate skips work only on steps where the fire subgraph is a
-    provable no-op."""
-
-    def test_gate_on_off_byte_identical_k_1_2_4(self):
-        for k in (1, 2, 4):
-            golden, _ = _run_devgen_q5(k, fire_gate=False,
-                                       readiness="probe")
-            gated, m = _run_devgen_q5(k, fire_gate=True,
-                                      readiness="piggyback")
-            _assert_identical_in_order(golden, gated, f"K={k}")
-
-    def test_gate_alone_identical_same_readiness(self):
-        # isolate the gate axis: same readiness on both sides
-        golden, _ = _run_devgen_q5(4, fire_gate=False,
-                                   readiness="piggyback")
-        gated, _ = _run_devgen_q5(4, fire_gate=True,
-                                  readiness="piggyback")
-        _assert_identical_in_order(golden, gated, "gate-axis")
-
-
-class TestReadinessParity:
-    """pipeline.readiness changes how the throttle waits, nothing
-    else: committed rows identical across modes (gate held constant)."""
-
-    def test_piggyback_vs_probe_identical(self):
-        golden, _ = _run_devgen_q5(4, fire_gate=True, readiness="probe")
-        got, _ = _run_devgen_q5(4, fire_gate=True, readiness="piggyback")
-        _assert_identical_in_order(golden, got, "readiness-axis")
-
-
 class TestHostFedLateRefire:
     """The allowed-lateness refire path on the HOST-FED fused plane: a
     late-within-lateness record re-fires its already-fired window with
     corrected contents, and the gate predicate must include that refire
-    in the header's end list — identical output gated vs ungated."""
+    in the header's end list — the rows are the numpy golden's."""
 
     N_KEYS = 16
+    TOP = 4
+    N = 1024   # a batch the fused scan takes, whole or as two halves
 
     @staticmethod
     def _gen(split, i):
         # batch 0: window [0, 1000); batch 1: ts ~2500 advances the
-        # watermark past the window end (it fires); batch 2: a LATE
-        # record at ts 500 (within lateness) → the fired window must
-        # RE-fire with count corrected
-        if i >= 3:
+        # watermark past the window end (it fires); batch 2: LATE
+        # records at ts 500 (within lateness) → the fired window must
+        # RE-fire with counts corrected; batch 3 moves the watermark on
+        # (no new window end), so the refire rides that advance's step
+        if i >= 4:
             return None
-        n = 256
+        n = TestHostFedLateRefire.N
         rng = np.random.default_rng(42 + i)
         keys = rng.integers(0, TestHostFedLateRefire.N_KEYS, n)
-        if i == 0:
-            ts = rng.integers(0, 1_000, n)
-        elif i == 1:
-            ts = rng.integers(2_400, 2_600, n)
-        else:
-            keys = keys[:8]
-            ts = np.full(8, 500, np.int64)
+        ts = (rng.integers(0, 1_000, n), rng.integers(2_400, 2_600, n),
+              np.full(n, 500), rng.integers(2_600, 2_800, n))[i]
         return {"auction": keys.astype(np.int64),
-                "price": np.ones(len(keys), np.int64)}, ts.astype(np.int64)
+                "price": np.ones(n, np.int64)}, ts.astype(np.int64)
 
-    def _run(self, k, fire_gate, readiness="piggyback"):
+    def _run(self, k, extra=None):
         cat, sink = _capture_sink()
-        env = StreamExecutionEnvironment(Configuration(_control_conf(
-            k, fire_gate, readiness,
-            extra={"pipeline.microbatch-size": 256})))
+        env = StreamExecutionEnvironment(Configuration(_conf(k, extra)))
         stream = env.from_source(
             GeneratorSource(self._gen),
             WatermarkStrategy.for_bounded_out_of_orderness(0))
@@ -163,20 +104,61 @@ class TestHostFedLateRefire:
                .window(TumblingEventTimeWindows.of(1_000))
                .allowed_lateness(10_000)
                .count()
-               .top(4, by="count"))
+               .top(self.TOP, by="count"))
         top.add_sink(sink)
-        env.execute(f"late-refire-{fire_gate}-k{k}")
-        return cat()
+        env.execute(f"late-refire-k{k}")
+        return cat(), env
 
-    def test_refire_survives_gating(self):
-        for k in (1, 2):
-            golden = self._run(k, fire_gate=False, readiness="probe")
-            gated = self._run(k, fire_gate=True)
-            # the late batch must actually have produced a refire (two
-            # emissions of window_end=1000), or this test is vacuous
-            we = np.asarray(golden["window_end"])
-            assert (we == 1_000).sum() >= 2, "no refire in the golden"
-            _assert_identical_in_order(golden, gated, f"refire K={k}")
+    def _golden_emissions(self):
+        """The emissions the window semantics prescribe, in plain
+        numpy, as sorted (window_end, key, count) rows per emission:
+        window 1000 fires once batch 1 has moved the watermark past
+        it, RE-fires with the late batch's records added once batch 3
+        moves the watermark again, and window 3000 fires at the end of
+        input; each emission keeps the keys whose count reaches the
+        4th largest (ties kept)."""
+        batches = [self._gen("0", i) for i in range(4)]
+
+        def top(window_end, upto):
+            cnt = np.zeros(self.N_KEYS, np.int64)
+            for data, ts in batches[:upto]:
+                m = (ts // 1_000 + 1) * 1_000 == window_end
+                cnt += np.bincount(data["auction"][m],
+                                   minlength=self.N_KEYS)
+            thresh = np.sort(cnt)[-self.TOP]
+            return sorted((window_end, int(key), int(cnt[key]))
+                          for key in np.flatnonzero(
+                              (cnt >= thresh) & (cnt > 0)))
+
+        return [top(1_000, 1), top(1_000, 3), top(3_000, 4)]
+
+    @pytest.mark.parametrize("k", [1, 2])
+    def test_refire_survives_gating(self, k, monkeypatch):
+        from flink_tpu.ops.window import WindowOperator
+
+        fused = []   # (window ends handed over, the fused step took them)
+        advance_fused = WindowOperator._advance_fused
+
+        def spy(op, wm, ends):
+            out = advance_fused(op, wm, ends)
+            fused.append((len(ends), out is not None))
+            return out
+
+        monkeypatch.setattr(WindowOperator, "_advance_fused", spy)
+        got, _ = self._run(k)
+        rows = list(zip(got["window_end"].tolist(), got["key"].tolist(),
+                        got["count"].tolist()))
+        first, refire, last = self._golden_emissions()
+        # the late batch must change the window's answer, or the
+        # refire is not observable and this test is vacuous
+        assert first != refire
+        n1, n2 = len(first), len(first) + len(refire)
+        assert sorted(rows[:n1]) == first, k
+        assert sorted(rows[n1:n2]) == refire, k
+        assert sorted(rows[n2:]) == last, k
+        # and both firings of window 1000 rode the gated fused step:
+        # the first beside the empty window 2000, the refire alone
+        assert [n for n, took in fused if took and n] == [2, 1], fused
 
 
 class TestCoalescedReadback:
@@ -192,7 +174,7 @@ class TestCoalescedReadback:
         return WindowOperator(
             SlidingEventTimeWindows.of(10_000, 1_000),
             aggregates.count(), num_shards=16, slots_per_shard=32,
-            top_n=("count", 2), fire_gate=True, readiness="piggyback")
+            top_n=("count", 2))
 
     def test_skip_then_rearm(self):
         op = self._op()
@@ -224,6 +206,30 @@ class TestCoalescedReadback:
         nxt = op.drain_ring(min_no=op._ring_version_no)
         assert len(nxt["window_end"]) > 0
 
+    def test_every_step_announces_a_token_the_throttle_consumes(self):
+        """Every dispatched step — the ingest applies as much as the
+        fused advance — leaves one announced token on the in-flight
+        deque; retiring it is a consume of that copy, and only the
+        fused step's token carries ring-head words."""
+        op = self._op()
+        op.max_inflight_steps = 10**6    # nothing retires by itself
+        rng = np.random.default_rng(7)
+        for i in range(3):
+            op.process_batch(rng.integers(0, 100, 2048),
+                             rng.integers(i * 2_000, i * 2_000 + 2_000,
+                                          2048), {})
+            op.advance_watermark(i * 2_000 + 1_999)
+        steps = list(op._inflight)
+        assert len(steps) == op._token_seq >= 3
+        assert [seq for _, _, seq in steps] == list(
+            range(1, len(steps) + 1))
+        heads = [head for _, head, _ in steps]
+        assert set(heads) <= {None, (0, 1)} and (0, 1) in heads
+        for token, _, _ in steps:
+            assert np.asarray(token).size >= 1   # a landed host copy
+        op.quiesce()
+        assert not op._inflight
+
     def test_barrier_drain_never_skips(self):
         op = self._op()
         rng = np.random.default_rng(6)
@@ -238,50 +244,57 @@ class TestCoalescedReadback:
         assert op.prof.get("drain_skips", 0.0) == skips
 
 
-class TestValidation:
-    def test_driver_rejects_unknown_readiness(self):
-        cat, sink = _capture_sink()
-        env = StreamExecutionEnvironment(Configuration(_control_conf(
-            1, True, "telepathy")))
-        q5_hot_items(env, bid_stream_device(NexmarkConfig(**Q5_CFG)),
-                     sink, window_ms=10_000, slide_ms=1_000)
-        with pytest.raises(ValueError, match="pipeline.readiness"):
-            env.execute("bad-readiness")
+class TestRemovedOptions:
+    """``pipeline.fire-gate`` and ``pipeline.readiness`` no longer
+    exist: a conf that still sets them gets the treatment of any key
+    outside the option grammar."""
 
-    def test_operator_rejects_unknown_readiness(self):
-        from flink_tpu.api.windowing import TumblingEventTimeWindows as T
-        from flink_tpu.ops import aggregates
-        from flink_tpu.ops.window import WindowOperator
+    OLD = {"pipeline.fire-gate": False, "pipeline.readiness": "probe"}
 
-        with pytest.raises(ValueError, match="pipeline.readiness"):
-            WindowOperator(T.of(1_000), aggregates.count(),
-                           readiness="bogus")
-
-    def test_analyzer_unknown_readiness_is_error(self):
+    @pytest.mark.parametrize("key", sorted(OLD))
+    def test_analyzer_reports_an_unknown_key(self, key):
         from flink_tpu.analysis import analyze_config
 
-        fs = analyze_config(Configuration({
-            "pipeline.readiness": "telepathy"}))
-        (f,) = [f for f in fs if f.rule == "READINESS_INVALID"]
-        # build-rejected config blocks at submit under the default gate
-        assert f.severity == "error" and "readiness" in f.message
+        fs = analyze_config(Configuration(
+            {key: self.OLD[key], "pipeline.sub-batches": 4}))
+        (f,) = fs
+        assert (f.rule, f.severity) == ("CONFIG_KEY_UNKNOWN", "warn")
+        assert repr(key) in f.message
 
-    def test_analyzer_gate_off_under_subbatching_arm(self):
-        from flink_tpu.analysis import analyze_config
+    def test_analyze_cli_warns_and_exits_zero(self, tmp_path):
+        import os
+        import subprocess
+        import sys
 
-        fs = analyze_config(Configuration({
-            "pipeline.fire-gate": False,
-            "pipeline.sub-batches": 4}))
-        assert any(f.rule == "FIRE_GATE_INVALID"
-                   and "fire-gate" in f.message for f in fs)
+        conf = tmp_path / "old.conf"
+        conf.write_text("pipeline.fire-gate: false\n"
+                        "pipeline.readiness: probe\n"
+                        "pipeline.sub-batches: 4\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        root = os.path.join(os.path.dirname(__file__), "..")
 
-    def test_analyzer_clean_negatives(self):
-        from flink_tpu.analysis import analyze_config
+        def run(*flags):
+            return subprocess.run(
+                [sys.executable, "-m", "flink_tpu", "analyze", str(conf),
+                 *flags], capture_output=True, text=True, timeout=240,
+                env=env, cwd=root)
 
-        # defaults are clean; gate off at K=1 is a legal A/B axis
-        for conf in ({}, {"pipeline.fire-gate": False},
-                     {"pipeline.readiness": "probe",
-                      "pipeline.sub-batches": 4}):
-            fs = analyze_config(Configuration(conf))
-            assert not [f for f in fs if f.rule == "FIRE_GATE_INVALID"], \
-                conf
+        proc = run()
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        for key in self.OLD:
+            assert f"CONFIG_KEY_UNKNOWN at config: config key '{key}'" \
+                in proc.stdout
+        assert run("--fail-on", "warn").returncode == 1
+
+    def test_driver_runs_the_job_and_records_the_findings(self):
+        # through the submit gate (analysis.fail-on at its default):
+        # the job is admitted, runs as it does without the two keys,
+        # and the findings land on the driver
+        t = TestHostFedLateRefire()
+        golden, _ = t._run(1, {"analysis.fail-on": "error"})
+        got, env = t._run(1, {**self.OLD, "analysis.fail-on": "error"})
+        _assert_identical_in_order(golden, got, "removed options")
+        unknown = [f for f in env._driver.analysis_findings
+                   if f.rule == "CONFIG_KEY_UNKNOWN"]
+        assert len(unknown) == 2 and all(
+            f.severity == "warn" for f in unknown)

@@ -2,12 +2,9 @@
 
 The contract under test, exactly as shipped:
 
-- K = 1 is the pre-change path (every new driver branch guards on
-  K > 1), so the whole existing suite is its regression gate.
-- The headline DEVGEN Q5 pipeline is **byte-identical including row
-  order** at every K: the subdivided device generator re-slices the
-  bit-exact record stream, and emit-ring rows append in fire order.
-- Host-plane pipelines (wordcount, sessions) commit the **identical
+- K = 1 takes none of it (every sub-batch branch of the driver guards
+  on K > 1), so the whole existing suite is its regression gate.
+- Pipelines (wordcount, sessions) commit the **identical
   row set with per-key order preserved**; the global interleave across
   keys follows the fire cadence (a K=1 advance packs many window ends
   into one fire batch; K=4 fires the same ends in ascending groups).
@@ -15,9 +12,11 @@ The contract under test, exactly as shipped:
   corrective late REFIRES earlier than K=1 would — the allowed-
   lateness semantics of a finer watermark cadence, not a defect — so
   the parity goldens here are refire-free by construction.
-- Checkpoints cut at SUB-batch boundaries (positions count sub-batches
-  on subdivided device chains); restore resumes mid-logical-batch, and
-  cross-factor restores re-base positions or fail loudly.
+- Source positions count LOGICAL batches at every K: a host-fed Q5
+  job recovers from a checkpoint exactly once at K = 1 and K = 4, and
+  a checkpoint that records a sub-batch factor other than 1 (written
+  by the removed device-chained source, whose positions counted
+  sub-batches) is refused by name.
 """
 import json
 import os
@@ -36,9 +35,8 @@ from flink_tpu.api.windowing import (
     TumblingEventTimeWindows,
 )
 from flink_tpu.config import Configuration
-from flink_tpu.nexmark.generator import NexmarkConfig, bid_stream_device
+from flink_tpu.nexmark.generator import NexmarkConfig, bid_stream
 from flink_tpu.nexmark.queries import q5_hot_items
-from flink_tpu.runtime.driver import _rebase_position
 from flink_tpu.runtime.supervisor import run_with_recovery
 from flink_tpu.time.watermarks import WatermarkStrategy
 
@@ -75,64 +73,6 @@ def _per_key_seq(rows):
         out.setdefault(k, []).append(
             tuple(rows[f][i].item() for f in fields))
     return out
-
-
-class TestDevgenQ5Parity:
-    """The headline contract: any K produces byte-identical committed
-    output to K=1 — including ROW ORDER (ring rows append in fire
-    order; the subdivided generator is a bit-exact re-slice)."""
-
-    def _run(self, k):
-        cat, sink = _capture_sink()
-        env = StreamExecutionEnvironment(Configuration({
-            "state.num-key-shards": 16, "state.slots-per-shard": 64,
-            "pipeline.microbatch-size": Q5_CFG["batch_size"],
-            "pipeline.sub-batches": k,
-        }))
-        q5_hot_items(env, bid_stream_device(NexmarkConfig(**Q5_CFG)),
-                     sink, window_ms=2000, slide_ms=500,
-                     out_of_orderness_ms=100)
-        metrics = env.execute(f"q5-sub{k}").metrics
-        return cat(), metrics
-
-    def test_k_1_2_4_byte_identical_in_order(self):
-        golden, m1 = self._run(1)
-        assert len(golden["window_end"]) > 0
-        for k in (2, 4):
-            got, mk = self._run(k)
-            assert mk["records_in"] == m1["records_in"]
-            assert set(got) == set(golden)
-            for f in golden:
-                assert np.array_equal(golden[f], got[f]), (k, f)
-
-    def test_subdivided_stream_is_bit_exact(self):
-        import jax.numpy as jnp
-
-        src = bid_stream_device(NexmarkConfig(**Q5_CFG))
-        sub = src.subdivided(4)
-        b = src.batch_size // 4
-        assert sub.batch_size == b
-        assert sub.n_batches == src.n_batches * 4
-        for i in range(2):
-            k1, t1 = (np.asarray(x)
-                      for x in src.device_keys_ts(jnp.int64(i)))
-            for j in range(4):
-                s = 4 * i + j
-                kd, td = (np.asarray(x)
-                          for x in sub.device_keys_ts(jnp.int64(s)))
-                sl = slice(j * b, (j + 1) * b)
-                assert np.array_equal(kd, k1[sl]), s
-                assert np.array_equal(td, t1[sl]), s
-                # host repair copy and ts bounds match the same slice
-                kh, th = sub.keys_ts_host(s)
-                assert np.array_equal(kh, k1[sl]), s
-                lo, hi = sub.ts_bounds(s)
-                assert (lo, hi) == (int(th[0]), int(th[-1]))
-
-    def test_subdivide_rejects_indivisible(self):
-        src = bid_stream_device(NexmarkConfig(**Q5_CFG))
-        with pytest.raises(ValueError, match="does not divide"):
-            src.subdivided(3)
 
 
 class TestHostPlaneParity:
@@ -203,16 +143,18 @@ class TestHostPlaneParity:
             assert _per_key_seq(got) == _per_key_seq(golden), (runner, k)
 
 
-class TestCheckpointAcrossSubBatch:
-    """Positions on a subdivided device chain count SUB-batches: a
-    checkpoint can cut mid-logical-batch, and recovery resumes there —
-    committed output stays byte-identical to the fault-free run (which
-    by the parity gate equals K=1)."""
+class TestHostFedQ5Checkpoint:
+    """Host-fed Q5 (``bid_stream``) under checkpointing: recovery from
+    a checkpoint continues exactly once at K = 1 and K = 4, positions
+    count logical batches, and a checkpoint whose positions counted
+    sub-batches is refused."""
+
+    N_BATCHES = Q5_CFG["n_batches"]
 
     def _build(self, sink):
         def build_env(conf):
             env = StreamExecutionEnvironment(conf)
-            q5_hot_items(env, bid_stream_device(NexmarkConfig(**Q5_CFG)),
+            q5_hot_items(env, bid_stream(NexmarkConfig(**Q5_CFG)),
                          sink, window_ms=2000, slide_ms=500,
                          out_of_orderness_ms=100)
             return env
@@ -222,11 +164,11 @@ class TestCheckpointAcrossSubBatch:
     def _view(sink):
         return [tuple(sorted(r.items())) for r in sink.committed]
 
-    def _conf(self, tmp_path, name, extra=None):
+    def _conf(self, tmp_path, name, k, extra=None):
         c = {
             "state.num-key-shards": 16, "state.slots-per-shard": 64,
             "pipeline.microbatch-size": Q5_CFG["batch_size"],
-            "pipeline.sub-batches": 4,
+            "pipeline.sub-batches": k,
             "execution.checkpointing.dir": str(tmp_path / name),
             "execution.checkpointing.interval": 1,
             "restart-strategy.type": "fixed-delay",
@@ -236,54 +178,95 @@ class TestCheckpointAcrossSubBatch:
         c.update(extra or {})
         return Configuration(c)
 
-    def test_restore_mid_logical_batch_exactly_once(self, tmp_path):
+    @pytest.mark.parametrize("k, factors", [(1, None), (4, 1)],
+                             ids=["k1-empty", "k4-ones"])
+    def test_restore_continues_identically(self, tmp_path, k, factors,
+                                           monkeypatch):
+        """The checkpoints are in the format PR 27's tree wrote on a
+        host-fed job: a ``sub_factors`` field, empty (what it wrote) or
+        holding an explicit 1 per source."""
         from flink_tpu.checkpoint.storage import FsCheckpointStorage
+        from flink_tpu.runtime.driver import Driver
+
+        snapshot = Driver._snapshot
+
+        def snapshot_as_the_parent_wrote_it(driver, *args, **kwargs):
+            payload = snapshot(driver, *args, **kwargs)
+            assert "sub_factors" not in payload
+            payload["sub_factors"] = (
+                {} if factors is None
+                else {sid: factors for sid in payload["sources"]})
+            return payload
+
+        monkeypatch.setattr(Driver, "_snapshot",
+                            snapshot_as_the_parent_wrote_it)
 
         golden_sink = TransactionalCollectSink()
         self._build(golden_sink)(
-            self._conf(tmp_path, "golden-ckpt")).execute("sub-golden")
+            self._conf(tmp_path, "golden-ckpt", k)).execute("q5-golden")
         golden = self._view(golden_sink)
         assert golden
 
+        # the SECOND checkpoint write fails, however many the run gets
+        # round to: a checkpoint begins only once the one before it is
+        # durable and the run ends with one, so there are always two,
+        # and the recovery restores the first
         sink = TransactionalCollectSink()
         plan = (faults.FaultPlan(seed=77)
                 .rule("checkpoint.storage.write", "raise", count=1,
-                      after=2))
+                      after=1))
         with plan.activate(), replayable(plan):
-            run_with_recovery(self._build(sink),
-                              self._conf(tmp_path, "chaos-ckpt"),
-                              job_name="sub-chaos")
+            run_with_recovery(
+                self._build(sink), self._conf(tmp_path, "chaos-ckpt", k),
+                job_name="q5-chaos")
         assert self._view(sink) == golden
+        assert len(plan.log) == 1, "the checkpoint fault never fired"
 
-        # the cut crossed a sub-batch boundary: at least one completed
-        # checkpoint recorded a position mid-logical-batch (not % 4),
-        # stamped with the sub-batch factor restore re-bases against
-        mid = 0
-        for root, job in (("golden-ckpt", "sub-golden"),
-                          ("chaos-ckpt", "sub-chaos")):
+        # positions count LOGICAL batches at every K (never more than
+        # the source has), and a completed checkpoint cut the stream
+        # before its end: the recovery resumed mid-stream
+        seen, mid = 0, 0
+        for root, job in (("golden-ckpt", "q5-golden"),
+                          ("chaos-ckpt", "q5-chaos")):
             storage = FsCheckpointStorage(
                 str(tmp_path / root), job_id=job)
-            seen = 0
             for h in storage.list_complete():
                 seen += 1
                 payload = FsCheckpointStorage.load(h)
-                assert all(int(v) == 4 for v in
-                           payload.get("sub_factors", {}).values())
+                assert len(payload["sub_factors"]) == (
+                    0 if factors is None else len(payload["sources"]))
                 for pos in payload["sources"].values():
-                    mid += sum(1 for p in pos.values() if int(p) % 4)
-            assert seen > 0, f"no completed checkpoints under {root}"
-        assert mid > 0, ("every checkpoint landed on a logical-batch "
-                         "boundary — the mid-batch cut went untested")
+                    assert all(0 <= int(p) <= self.N_BATCHES
+                               for p in pos.values()), (k, pos)
+                    mid += sum(1 for p in pos.values()
+                               if 0 < int(p) < self.N_BATCHES)
+        assert seen > 0, "no completed checkpoints"
+        assert mid > 0, "no checkpoint cut the stream mid-way"
 
-    def test_position_rebase_between_factors(self):
-        assert _rebase_position(6, 4, 2) == 3    # sub 6 of 4 = 1.5 logical
-        assert _rebase_position(8, 4, 1) == 2
-        assert _rebase_position(2, 1, 4) == 8
-        assert _rebase_position(0, 4, 3) == 0
-        with pytest.raises(ValueError, match="does not align"):
-            _rebase_position(5, 4, 2)            # 1.25 logical batches
-        with pytest.raises(ValueError, match="does not align"):
-            _rebase_position(7, 4, 1)
+    def test_restore_refuses_sub_batch_positions(self, tmp_path):
+        """A checkpoint that records a sub-batch factor other than 1
+        for a source holds positions counted in sub-batches: restoring
+        it names the field instead of reading them as logical."""
+        from flink_tpu.checkpoint.storage import FsCheckpointStorage
+
+        conf = self._conf(tmp_path, "ckpt", 4)
+        self._build(TransactionalCollectSink())(conf).execute("q5-old")
+        storage = FsCheckpointStorage(str(tmp_path / "ckpt"),
+                                      job_id="q5-old")
+        latest = storage.latest()
+        payload = FsCheckpointStorage.load(latest)
+        for added_by_load in ("op_file_versions", "op_file_compression",
+                              "op_files", "op_aux_paths"):
+            payload.pop(added_by_load, None)
+        payload["sub_factors"] = {sid: 4 for sid in payload["sources"]}
+        old = storage.save(latest.checkpoint_id + 1, payload,
+                           savepoint=True)
+
+        env = self._build(TransactionalCollectSink())(self._conf(
+            tmp_path, "ckpt2", 4,
+            extra={"execution.checkpointing.restore": old.path}))
+        with pytest.raises(ValueError, match="'sub_factors'"):
+            env.execute("q5-restore-old")
 
 
 class TestSubbatchChaosK4:
