@@ -773,7 +773,12 @@ class HostOptions:
         "(flink_tpu/parallel/hostpool.py) running the host-resident "
         "operator paths: the key-sharded session span registry, the "
         "pane-partitioned spill store, and the chunked windowAll fold. "
-        "1 = the exact serial path (no pool threads; "
+        "The count-only window lane's native key scan "
+        "(ingest_fused_scan) takes its width from the same number: a "
+        "batch of 2 x 65,536 records or more is scanned as up to that "
+        "many contiguous record ranges on native threads of its own "
+        "and merged in range order, byte for byte the serial result. "
+        "1 = the exact serial path (no pool threads, no scan threads; "
         "keeps single-core benchmark numbers reproducible). Default "
         "min(4, os.cpu_count()); the plan analyzer warns on values < 1 "
         "or beyond os.cpu_count() (HOST_PARALLELISM_INVALID).")

@@ -63,7 +63,8 @@ def build_library(src: str = _SRC, cxx: str = "g++") -> str:
     tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            [cxx, "-O3", "-shared", "-fPIC", "-std=c++17", src, "-o", tmp],
+            [cxx, "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread", src,
+             "-o", tmp],
             check=True, capture_output=True)
         os.replace(tmp, so)
     except FileNotFoundError as e:
@@ -193,6 +194,17 @@ def _bind(lib, i64p, f32p) -> None:
         ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
         i32p, i32p, ctypes.c_int64, ctypes.c_int64, i64p, u8p,
         ctypes.c_int64, ctypes.c_int64, i64p, ctypes.c_int64]
+    lib.ingest_fused_scan_split.restype = ctypes.c_int64
+    lib.ingest_fused_scan_split.argtypes = [
+        ctypes.c_int64, i64p, i64p, ctypes.c_void_p, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        i32p, i32p, ctypes.c_int64, i64p, u8p, ctypes.c_int64,
+        ctypes.c_int64, i64p, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_int64, i32p, ctypes.c_int64]
+    lib.scan_workers_new.restype = ctypes.c_void_p
+    lib.scan_workers_new.argtypes = []
+    lib.scan_workers_free.restype = None
+    lib.scan_workers_free.argtypes = [ctypes.c_void_p]
     lib.ingest_fused_finalize_u32.restype = None
     lib.ingest_fused_finalize_u32.argtypes = [
         ctypes.c_int64, i32p, i32p, i32p, ctypes.c_int64, ctypes.c_int64]
@@ -446,14 +458,35 @@ class PreaggWorkspace:
     full-domain clear — the C side resets only touched entries."""
 
     def __init__(self, domain: int, nlanes: int) -> None:
+        self._workers = None
         self.domain = domain
         self.nlanes = nlanes
         self.hist = np.zeros(domain, np.int32)
         self.lane_acc = np.zeros(max(domain * nlanes, 1), np.float64)
+        # what a split scan keeps between batches (see
+        # ingest_fused_scan_native), made when a scan first asks: the
+        # later ranges' private histograms, zero like ``hist``, and
+        # the native threads that run them
+        self.range_hist = np.zeros((0, domain), np.int32)
+
+    def split_state(self, ranges: int) -> Tuple[int, np.ndarray]:
+        """The worker set's handle and ``ranges - 1`` or more zeroed
+        histograms, flat, for ``ingest_fused_scan_split``."""
+        if len(self.range_hist) < ranges - 1:
+            self.range_hist = np.zeros((ranges - 1, self.domain), np.int32)
+        if self._workers is None:
+            self._workers = _load().scan_workers_new()
+        return self._workers, self.range_hist.reshape(-1)
 
     def rezero(self) -> None:
         self.hist[:] = 0
         self.lane_acc[:] = 0.0
+        self.range_hist[:] = 0
+
+    def __del__(self) -> None:
+        if self._workers is not None and _lib is not None:
+            _lib.scan_workers_free(self._workers)   # joins idle threads
+            self._workers = None
 
 
 def preagg_combine_native(
@@ -514,13 +547,23 @@ class IngestFusedResult:
     finalize step deferred so a miss-registration re-scan can continue
     the same workspace."""
 
-    __slots__ = ("npairs", "out_pairs", "stats", "bitmap")
+    __slots__ = ("npairs", "out_pairs", "stats", "bitmap", "ranges")
 
-    def __init__(self, npairs, out_pairs, stats, bitmap):
+    def __init__(self, npairs, out_pairs, stats, bitmap, ranges=1):
         self.npairs = npairs
         self.out_pairs = out_pairs
         self.stats = stats
         self.bitmap = bitmap
+        # record ranges the first pass was scanned in, side by side
+        self.ranges = ranges
+
+
+# Fewest records a range of a split scan is worth a thread for. On the
+# chip's host (PERF.md, PR 30; ms a batch in order, serial / 2 / 4
+# ranges): 2^17 records 0.95 / 0.99 / 0.72, 2^16 records 0.52 / 0.62 /
+# 0.49 — two ranges of 65,536 cost what one of 131,072 does, and under
+# that waking the workers and merging cost more than the ranges save.
+SCAN_RANGE_MIN_RECORDS = 1 << 16
 
 
 def ingest_fused_scan_native(
@@ -528,6 +571,7 @@ def ingest_fused_scan_native(
     pane_ms: int, offset_ms: int, ring: int, ws: "PreaggWorkspace",
     cap: int, dead_below: int, refire_below: int, bitmap_bits: int,
     *, cont: Optional["IngestFusedResult"] = None, miss_cap: int = 0,
+    threads: int = 1,
 ) -> Optional[Tuple["IngestFusedResult", np.ndarray]]:
     """One fused probe+ingest scan (codec.cc ingest_fused_scan).
     Returns (result, miss_indices) or None (unavailable / cap
@@ -535,17 +579,29 @@ def ingest_fused_scan_native(
     ``cont`` to continue a previous scan's pair list and stats (the
     miss-registration second pass).
 
+    ``threads`` > 1 lets a first pass run as that many contiguous
+    record ranges side by side (codec.cc ingest_fused_scan_split: one
+    native thread a range, private workspaces, an ordered merge), as
+    long as every range keeps ``SCAN_RANGE_MIN_RECORDS`` records; a
+    shorter batch takes fewer ranges, down to the serial call.
+    Everything returned but ``pane_moves`` is what the serial call
+    returns, element for element; ``result.ranges`` says how many
+    ranges ran.
+
     ``result.stats`` is ``[n_valid, n_late, n_bad, pane_min, pane_max,
     n_refire, n_miss, cmax, pane_moves]``, summed (min / max taken)
     over a scan and its ``cont`` calls. The ninth, ``pane_moves``, is
     how many records had their pane worked out by division because
     they did not lie in the pane of the record before: 1-2 a batch on
-    an in-order stream, ~n where panes alternate record by record."""
+    an in-order stream, ~n where panes alternate record by record
+    (each range of a split scan seeks its own first pane: in order, up
+    to ``ranges`` + 1 a batch)."""
     lib = _load()
     if lib is None:
         return None
     n = len(ts)
     if cont is None:
+        ranges = max(1, min(threads, n // SCAN_RANGE_MIN_RECORDS))
         out_pairs = np.empty(cap, np.int32)
         stats = np.zeros(9, np.int64)
         stats[3] = np.iinfo(np.int64).max   # pmin seed
@@ -555,19 +611,28 @@ def ingest_fused_scan_native(
     else:
         out_pairs, stats, bitmap = cont.out_pairs, cont.stats, cont.bitmap
         np_in = cont.npairs
+        ranges = cont.ranges    # the first pass's; this one is serial
     miss_cap = max(miss_cap, 1)
     out_miss = np.empty(miss_cap, np.int64)
     stats[6] = 0  # miss list restarts each scan
-    npairs = lib.ingest_fused_scan(
-        n, np.ascontiguousarray(keys, np.int64),
-        np.ascontiguousarray(ts, np.int64), table._h,
-        pane_ms, offset_ms, ring, dead_below, refire_below,
-        ws.hist, out_pairs, np_in, cap, stats, bitmap,
-        dead_below, len(bitmap), out_miss, miss_cap)
+    keys = np.ascontiguousarray(keys, np.int64)
+    ts = np.ascontiguousarray(ts, np.int64)
+    if cont is None and ranges > 1:
+        workers, range_hist = ws.split_state(ranges)
+        npairs = lib.ingest_fused_scan_split(
+            n, keys, ts, table._h, pane_ms, offset_ms, ring, dead_below,
+            refire_below, ws.hist, out_pairs, cap, stats, bitmap,
+            dead_below, len(bitmap), out_miss, miss_cap, workers, ranges,
+            range_hist, ws.domain)
+    else:
+        npairs = lib.ingest_fused_scan(
+            n, keys, ts, table._h, pane_ms, offset_ms, ring, dead_below,
+            refire_below, ws.hist, out_pairs, np_in, cap, stats, bitmap,
+            dead_below, len(bitmap), out_miss, miss_cap)
     if npairs < 0:
         ws.rezero()
         return None
-    res = IngestFusedResult(int(npairs), out_pairs, stats, bitmap)
+    res = IngestFusedResult(int(npairs), out_pairs, stats, bitmap, ranges)
     return res, out_miss[:int(stats[6])]
 
 

@@ -1364,6 +1364,11 @@ class WindowOperator:
                            agg, pool=host_pool,
                            fold_chunk_records=fold_chunk_records)
                        if spill else None)
+        # ranges the count-only lane's native key scan may run side by
+        # side (_process_batch_fused): the shared pool's parallelism,
+        # so host.parallelism = 1 (or no pool) is the serial scan
+        self._scan_threads = (host_pool.parallelism
+                              if host_pool is not None else 1)
         # top-n + spill: host rows can't ride per-fire markers because
         # device rows flow through the SHARED emit ring (a coalesced
         # drain would re-rank against the wrong fires). They queue here
@@ -1834,7 +1839,8 @@ class WindowOperator:
             scan = ingest_fused_scan_native(
                 keys, ts, self.directory._table, self.plan.pane_ms,
                 self.plan.offset_ms, self.plan.ring, self._preagg_ws,
-                cap, dead, refire_below, bits, miss_cap=n)
+                cap, dead, refire_below, bits, miss_cap=n,
+                threads=self._scan_threads)
             if scan is None:
                 return False
             res, miss_ix = scan
@@ -1875,8 +1881,12 @@ class WindowOperator:
         self.state_version += 1
         self.late_records += n_late
         # how often the scan had to divide for a pane: 1-2 a batch on an
-        # in-order stream, ~n when panes alternate (codec.cc PaneCursor)
+        # in-order stream (one more for each further range of a split
+        # scan), ~n when panes alternate (codec.cc PaneCursor); and the
+        # record ranges the first pass ran in side by side: the pool's
+        # parallelism where the batch was long enough, 1 where not
         self.prof["scan_pane_moves"] += pane_moves
+        self.prof["scan_ranges"] += res.ranges
         if n_bad:
             account_full_drop(self, n_bad)
         if n_refire:

@@ -24,6 +24,17 @@ Determinism contract (the measurement/correctness gate):
   (``host.fold-chunk-records``) with a chunk size that does not depend
   on the worker count.
 
+One client takes the pool's SIZE and not its threads: the count-only
+window lane's native key scan (``ops/window.py`` ``_process_batch_fused``
+-> ``native/codec.cc`` ``ingest_fused_scan_split``) runs ``parallelism``
+record ranges at once on native threads of its own inside one ctypes
+call, because tasks here start and end under the GIL: with one Python
+thread busy beside them (a source's feeder, the drain) four ranges
+submitted through ``run_tasks`` took 53-83 ms a 2^20-record batch on
+the chip's host where the one native call took 8.5-9.8 (PERF.md, PR
+30). The contract is the same: 1 is the exact serial call, and any
+width gives the serial call's bytes.
+
 Fault seam: every task submission passes the registered
 ``host.pool.task`` fault point (on the CALLER thread, before dispatch,
 so per-point invocation indices follow deterministic submission order,

@@ -1862,13 +1862,14 @@ class Driver:
             for k, v in getattr(op, "prof", {}).items():
                 final[f"profile.op{nid}.{k}"] = final.get(
                     f"profile.op{nid}.{k}", 0.0) + v
-                if k == "scan_pane_moves":
-                    # once more beside the leaf it explains: whatever
+                if k in ("scan_pane_moves", "scan_ranges"):
+                    # once more beside the leaf they explain: whatever
                     # reads profile.phase.* (bench artifacts, the
                     # benchmark's detail line) then shows whether
-                    # window.key_scan ran on the pane cursor's cheap path
-                    final["profile.phase.scan_pane_moves"] = final.get(
-                        "profile.phase.scan_pane_moves", 0.0) + v
+                    # window.key_scan ran on the pane cursor's cheap
+                    # path, and over how many record ranges at once
+                    final[f"profile.phase.{k}"] = final.get(
+                        f"profile.phase.{k}", 0.0) + v
         return JobResult(job_name, final)
 
     def _exchange_metrics(self) -> Dict[str, float]:

@@ -18,6 +18,13 @@
 #include <cstdlib>
 #include <cstring>
 #include <cmath>
+#include <condition_variable>
+#include <functional>
+#include <memory>
+#include <mutex>
+#include <system_error>
+#include <thread>
+#include <vector>
 
 extern "C" {
 
@@ -649,14 +656,19 @@ int64_t ingest_combine(
 // workspace left dirty; caller re-zeros and falls back).
 static const int64_t SCAN_BLOCK = 512;
 
-int64_t ingest_fused_scan(
-    int64_t n, const int64_t* keys, const int64_t* ts, void* ht,
-    int64_t pane_ms, int64_t offset_ms, int64_t ring,
+// Records [i0, i1) of the batch through the loop described above, into
+// the workspace it is given; miss indices are the records' own (batch)
+// indices. The serial entry runs it over the whole batch into the
+// caller's workspace; the split entry runs it over contiguous ranges
+// side by side, each into a workspace of its own. Writes ``stats`` only
+// when it succeeds, and never stats[7] (cmax: the entry's to compute).
+static int64_t scan_range(
+    int64_t i0, int64_t i1, const int64_t* keys, const int64_t* ts,
+    const FtHashTable* t, int64_t pane_ms, int64_t offset_ms, int64_t ring,
     int64_t dead_below, int64_t refire_below,
     int32_t* hist, int32_t* out_pairs, int64_t np_in, int64_t cap,
     int64_t* stats, uint8_t* refire_bitmap, int64_t bitmap_base,
     int64_t bitmap_len, int64_t* out_miss, int64_t miss_cap) {
-  const FtHashTable* t = (const FtHashTable*)ht;
   int64_t np_ = np_in, n_valid = 0, n_late = 0, n_bad = 0;
   int64_t n_refire = 0, n_miss = stats[6];
   PaneCursor cur;
@@ -666,8 +678,8 @@ int64_t ingest_fused_scan(
   // the last record taken the long way found its pane cached: the
   // stream runs along one pane, it does not hop between panes
   bool steady = false;
-  for (int64_t b0 = 0; b0 < n; b0 += SCAN_BLOCK) {
-    const int64_t bn = n - b0 < SCAN_BLOCK ? n - b0 : SCAN_BLOCK;
+  for (int64_t b0 = i0; b0 < i1; b0 += SCAN_BLOCK) {
+    const int64_t bn = i1 - b0 < SCAN_BLOCK ? i1 - b0 : SCAN_BLOCK;
     const int64_t* bk = keys + b0;
     const int64_t* bts = ts + b0;
     for (int64_t j = 0; j < bn; ++j) {
@@ -713,9 +725,6 @@ int64_t ingest_fused_scan(
         return -1;
     }
   }
-  int64_t cmax = stats[7];
-  for (int64_t j = 0; j < np_; ++j)
-    if (hist[out_pairs[j]] > cmax) cmax = hist[out_pairs[j]];
   stats[0] += n_valid;
   stats[1] += n_late;
   stats[2] += n_bad;
@@ -723,8 +732,244 @@ int64_t ingest_fused_scan(
   stats[4] = cur.pmax;
   stats[5] += n_refire;
   stats[6] = n_miss;
-  stats[7] = cmax;
   stats[8] += cur.moves;
+  return np_;
+}
+
+// The largest count of any recorded pair, no less than ``cmax``.
+static int64_t pairs_cmax(const int32_t* hist, const int32_t* out_pairs,
+                          int64_t np_, int64_t cmax) {
+  for (int64_t j = 0; j < np_; ++j)
+    if (hist[out_pairs[j]] > cmax) cmax = hist[out_pairs[j]];
+  return cmax;
+}
+
+int64_t ingest_fused_scan(
+    int64_t n, const int64_t* keys, const int64_t* ts, void* ht,
+    int64_t pane_ms, int64_t offset_ms, int64_t ring,
+    int64_t dead_below, int64_t refire_below,
+    int32_t* hist, int32_t* out_pairs, int64_t np_in, int64_t cap,
+    int64_t* stats, uint8_t* refire_bitmap, int64_t bitmap_base,
+    int64_t bitmap_len, int64_t* out_miss, int64_t miss_cap) {
+  const int64_t np_ = scan_range(
+      0, n, keys, ts, (const FtHashTable*)ht, pane_ms, offset_ms, ring,
+      dead_below, refire_below, hist, out_pairs, np_in, cap, stats,
+      refire_bitmap, bitmap_base, bitmap_len, out_miss, miss_cap);
+  if (np_ >= 0) stats[7] = pairs_cmax(hist, out_pairs, np_, stats[7]);
+  return np_;
+}
+
+// What one later range of a split scan left behind: its pairs in its
+// own first-touch order with their counts beside them (the range's
+// thread packs them out of its private histogram and zeroes what it
+// touched there, so the merge reads two dense arrays).
+struct RangeOut {
+  int32_t* hist;        // domain entries, zero on entry and after packing
+  int32_t* pairs;
+  int32_t* counts;
+  int64_t np_;          // pair count, or scan_range's -1 / -2
+  int64_t stats[9];
+  uint8_t* bitmap;
+  int64_t* miss;        // ascending batch indices, stats[6] of them
+};
+
+// Fold a later range into the workspace that holds the ranges before
+// it. A pair's first occurrence in record order lies in the first range
+// that holds it, at its place in that range's own first-touch order; so
+// appending each range's NEW pairs in range order, in the range's order,
+// gives the list a serial scan would have written. Counts add, misses
+// are appended (ranges ascend, so the list does). -1 when the merged
+// pairs pass ``cap``, -2 when the merged misses pass ``miss_cap``, else
+// the merged pair count.
+static int64_t merge_range(int32_t* hist, int32_t* out_pairs, int64_t np_,
+                           int64_t cap, int64_t* stats, uint8_t* bitmap,
+                           int64_t bitmap_len, int64_t* out_miss,
+                           int64_t miss_cap, const RangeOut* r) {
+  if (stats[6] + r->stats[6] > miss_cap) return -2;
+  for (int64_t j = 0; j < r->np_; ++j) {
+    const int32_t p = r->pairs[j];
+    if (hist[p] == 0) {
+      if (np_ >= cap) return -1;
+      out_pairs[np_++] = p;
+    }
+    hist[p] += r->counts[j];
+  }
+  stats[0] += r->stats[0];
+  stats[1] += r->stats[1];
+  stats[2] += r->stats[2];
+  if (r->stats[3] < stats[3]) stats[3] = r->stats[3];
+  if (r->stats[4] > stats[4]) stats[4] = r->stats[4];
+  stats[5] += r->stats[5];
+  memcpy(out_miss + stats[6], r->miss, r->stats[6] * sizeof(int64_t));
+  stats[6] += r->stats[6];
+  stats[8] += r->stats[8];
+  for (int64_t b = 0; b < bitmap_len; ++b) bitmap[b] |= r->bitmap[b];
+  return np_;
+}
+
+// A buffer that lives as long as its owner and only grows; what it
+// holds is never read before it is written.
+struct Scratch {
+  std::unique_ptr<char[]> buf;
+  int64_t len = 0;
+  void* get(int64_t bytes) {
+    if (bytes > len) { buf.reset(new char[bytes]); len = bytes; }
+    return buf.get();
+  }
+};
+
+// Threads that wait between batches for the later ranges of a split
+// scan, and those ranges' scratch. Worker w runs range w of a round
+// that has that many; the set grows to the ranges asked of it. One
+// caller owns a set (scan_workers_new / _free) and runs one round at a
+// time. Started per batch instead, three threads held range 0 back by
+// 0.25 ms and eight by 1.1 ms on the chip's host (PERF.md, PR 30).
+struct ScanWorkers {
+  std::mutex mu;
+  std::condition_variable wake, done;
+  std::vector<std::thread> threads;
+  const std::function<void(int64_t)>* job = nullptr;
+  uint64_t round = 0;
+  int64_t ranges = 0;   // this round's: workers 1 .. ranges - 1 run
+  int64_t left = 0;     // of them, still running
+  bool stop = false;
+  Scratch pairs, counts, miss, bitmaps;
+};
+
+static void scan_worker_main(ScanWorkers* w, int64_t me, uint64_t seen) {
+  std::unique_lock<std::mutex> lk(w->mu);
+  for (;;) {
+    w->wake.wait(lk, [&] { return w->stop || w->round != seen; });
+    if (w->stop) return;
+    seen = w->round;
+    if (me >= w->ranges) continue;
+    const std::function<void(int64_t)>* job = w->job;
+    lk.unlock();
+    (*job)(me);
+    lk.lock();
+    if (--w->left == 0) w->done.notify_one();
+  }
+}
+
+void* scan_workers_new() { return new ScanWorkers(); }
+
+void scan_workers_free(void* h) {
+  ScanWorkers* w = (ScanWorkers*)h;
+  {
+    std::lock_guard<std::mutex> lk(w->mu);
+    w->stop = true;
+  }
+  w->wake.notify_all();
+  for (std::thread& th : w->threads) th.join();
+  delete w;
+}
+
+// job(1) .. job(ranges - 1) on the workers, job(0) here; back when all
+// are done. A range whose worker cannot be started runs here too.
+static void scan_workers_run(ScanWorkers* w, int64_t ranges,
+                             const std::function<void(int64_t)>& job) {
+  int64_t have;
+  {
+    std::lock_guard<std::mutex> lk(w->mu);
+    while ((int64_t)w->threads.size() < ranges - 1) {
+      try {
+        w->threads.emplace_back(scan_worker_main, w,
+                                (int64_t)w->threads.size() + 1, w->round);
+      } catch (const std::system_error&) {
+        break;
+      }
+    }
+    have = (int64_t)w->threads.size() + 1;
+    if (have > ranges) have = ranges;
+    w->job = &job;
+    w->ranges = have;
+    w->left = have - 1;
+    ++w->round;
+  }
+  w->wake.notify_all();
+  job(0);
+  for (int64_t j = have; j < ranges; ++j) job(j);
+  std::unique_lock<std::mutex> lk(w->mu);
+  w->done.wait(lk, [&] { return w->left == 0; });
+}
+
+// ingest_fused_scan with its first pass split by record range over
+// ``ranges`` threads: range j is [n*j/ranges, n*(j+1)/ranges). Range 0
+// runs on the calling thread into the caller's workspace (its pairs are
+// the head of the serial list); each later range runs scan_range on a
+// worker into a private histogram (``range_hist``: ranges - 1 rows of
+// ``domain`` entries, zero on entry and on every return but an
+// overflow's), pair list, statistics, bitmap and miss list. While
+// ranges run nothing shared is written: the key table is read-only
+// here. Then merge_range folds them in, in range order, and everything
+// the caller sees — pairs and their order, counts, stats[0..7], bitmap,
+// miss list — is what the serial call over the same batch leaves.
+// stats[8] (pane_moves) is the sum over the ranges: each seeks its
+// first record's pane, so an in-order batch reads up to ranges + 1
+// where the serial call reads 1-2. A first call only (no np_in: the
+// ``cont`` pass over the registered misses stays serial). -1 when any
+// range or the merged list passes ``cap``, -2 when the merged misses
+// pass ``miss_cap``: stats untouched, workspaces dirty.
+int64_t ingest_fused_scan_split(
+    int64_t n, const int64_t* keys, const int64_t* ts, void* ht,
+    int64_t pane_ms, int64_t offset_ms, int64_t ring,
+    int64_t dead_below, int64_t refire_below,
+    int32_t* hist, int32_t* out_pairs, int64_t cap,
+    int64_t* stats, uint8_t* refire_bitmap, int64_t bitmap_base,
+    int64_t bitmap_len, int64_t* out_miss, int64_t miss_cap,
+    void* workers, int64_t ranges, int32_t* range_hist, int64_t domain) {
+  const FtHashTable* t = (const FtHashTable*)ht;
+  ScanWorkers* w = (ScanWorkers*)workers;
+  const int64_t later = ranges - 1;
+  auto lo = [=](int64_t j) { return n * j / ranges; };
+  std::vector<RangeOut> out(later);
+  int32_t* pairs = (int32_t*)w->pairs.get(later * cap * sizeof(int32_t));
+  int32_t* counts = (int32_t*)w->counts.get(later * cap * sizeof(int32_t));
+  uint8_t* bitmaps = (uint8_t*)w->bitmaps.get(later * bitmap_len);
+  memset(bitmaps, 0, later * bitmap_len);
+  // a range has no more misses than records: its slice of one
+  // n-entry buffer, from its own first index on, is room enough
+  int64_t* miss = (int64_t*)w->miss.get(n * sizeof(int64_t));
+  for (int64_t j = 1; j < ranges; ++j) {
+    RangeOut* r = &out[j - 1];
+    r->hist = range_hist + (j - 1) * domain;
+    r->pairs = pairs + (j - 1) * cap;
+    r->counts = counts + (j - 1) * cap;
+    r->bitmap = bitmaps + (j - 1) * bitmap_len;
+    r->miss = miss + lo(j);
+    memcpy(r->stats, stats, sizeof r->stats);
+  }
+  int64_t s[9];
+  memcpy(s, stats, sizeof s);
+  int64_t np_ = 0;
+  const std::function<void(int64_t)> run = [&](int64_t j) {
+    if (j == 0) {
+      np_ = scan_range(
+          0, lo(1), keys, ts, t, pane_ms, offset_ms, ring, dead_below,
+          refire_below, hist, out_pairs, 0, cap, s, refire_bitmap,
+          bitmap_base, bitmap_len, out_miss, miss_cap);
+      return;
+    }
+    RangeOut* r = &out[j - 1];
+    r->np_ = scan_range(
+        lo(j), lo(j + 1), keys, ts, t, pane_ms, offset_ms, ring, dead_below,
+        refire_below, r->hist, r->pairs, 0, cap, r->stats, r->bitmap,
+        bitmap_base, bitmap_len, r->miss, lo(j + 1) - lo(j));
+    for (int64_t k = 0; k < r->np_; ++k) {
+      r->counts[k] = r->hist[r->pairs[k]];
+      r->hist[r->pairs[k]] = 0;
+    }
+  };
+  scan_workers_run(w, ranges, run);
+  for (const RangeOut& r : out)
+    if (np_ >= 0 && r.np_ < 0) np_ = r.np_;
+  for (const RangeOut& r : out)
+    if (np_ >= 0)
+      np_ = merge_range(hist, out_pairs, np_, cap, s, refire_bitmap,
+                        bitmap_len, out_miss, miss_cap, &r);
+  if (np_ < 0) return np_;
+  s[7] = pairs_cmax(hist, out_pairs, np_, s[7]);
+  memcpy(stats, s, sizeof s);
   return np_;
 }
 

@@ -121,7 +121,8 @@ class TestJobPhases:
         op_keys = {k.split(".", 2)[2] for k in res.metrics
                    if k.startswith("profile.op")}
         assert op_keys <= {"drain_fetch", "drain_fetches", "drain_skips",
-                           "preagg_batches", "scan_pane_moves"}
+                           "preagg_batches", "scan_pane_moves",
+                           "scan_ranges"}
         fetch = sum(v for k, v in res.metrics.items()
                     if k.startswith("profile.op")
                     and k.endswith(".drain_fetch"))

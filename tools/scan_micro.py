@@ -12,13 +12,21 @@ mix's timestamps, two ways:
   from 5 panes, so the cursor moves on about four records in five and
   every move pays the floored division.
 
+``--threads`` times each ordering once more per entry of the list, the
+call made as the operator makes it under ``host.parallelism`` = that
+many: the first pass split by record range over native threads and
+merged in range order (``ingest_fused_scan_split``). The floor under
+which the operator stays serial (``SCAN_RANGE_MIN_RECORDS`` a range) is
+lifted here, so that a short ``--n`` shows what the floor is there for.
+
 Host only: no device program runs and nothing here is a benchmark
-metric. One JSON line: ns a record (best and median of ``--reps``
-calls), cursor moves a batch (``null`` from a library that has no
-such counter), and what the machine gives the process — CPU model,
+metric. One JSON line: ns a record and ms a batch (best and median of
+``--reps`` calls), ranges scanned and cursor moves a batch, per entry of
+``--threads``, and what the machine gives the process — CPU model,
 ``os.cpu_count()``, the affinity mask's size, the cgroup's CPU quota.
 
     python tools/scan_micro.py [--n 1048576] [--reps 30] [--seed 1]
+                               [--threads 1,2,4,8]
 """
 from __future__ import annotations
 
@@ -72,7 +80,11 @@ def main() -> int:
                     help="records a batch (default: the job conf's)")
     ap.add_argument("--reps", type=int, default=30)
     ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--threads", default="1",
+                    help="comma list: ranges the first pass is split in")
     args = ap.parse_args()
+    threads = [int(t) for t in args.threads.split(",")]
+    native_codec.SCAN_RANGE_MIN_RECORDS = 1     # the curve, floor lifted
     if not native_codec.native_available():
         print(json.dumps({"error": native_codec.unavailable_reason()}))
         return 1
@@ -99,22 +111,23 @@ def main() -> int:
     rng = np.random.default_rng(args.seed)
     lo = np.iinfo(np.int64).min
 
-    def scan(keys, ts):
+    def scan(keys, ts, t=1):
         t0 = time.perf_counter()
         out = native_codec.ingest_fused_scan_native(
             keys, ts, directory._table, pane_ms, plan.offset_ms, ring, ws,
-            cap, lo, lo, 0, miss_cap=len(ts))
+            cap, lo, lo, 0, miss_cap=len(ts), threads=t)
         dt = time.perf_counter() - t0
         res, miss = out
+        ranges = res.ranges
         if len(miss):   # the operator's own second pass, untimed here
             directory.register_misses(keys[miss])
             res, _ = native_codec.ingest_fused_scan_native(
                 keys[miss], ts[miss], directory._table, pane_ms,
                 plan.offset_ms, ring, ws, cap, lo, lo, 0, cont=res,
                 miss_cap=1)
-        moves = int(res.stats[8]) if len(res.stats) > 8 else None
+        moves = int(res.stats[8])
         native_codec.ingest_fused_finalize_pairs_native(res, ws)
-        return dt, moves
+        return dt, moves, ranges
 
     def ts_in_order(i):
         return (i * n + np.arange(n, dtype=np.int64)) // rate
@@ -129,16 +142,21 @@ def main() -> int:
                           ("shuffled", ts_shuffled)):
         for i in range(len(pool)):      # registers the keys, warms caches
             scan(pool[i]["auction"], make_ts(i))
-        times, moves = [], []
-        for i in range(args.reps):
-            dt, mv = scan(pool[i % len(pool)]["auction"], make_ts(i))
-            times.append(dt)
-            moves.append(mv)
-        out[name] = {
-            "ns_per_record_best": 1e9 * min(times) / n,
-            "ns_per_record_median": 1e9 * statistics.median(times) / n,
-            "pane_moves_per_batch": None if moves[0] is None
-            else statistics.mean(moves)}
+        out[name] = {}
+        for t in threads:
+            scan(pool[0]["auction"], make_ts(0), t)   # its workspaces
+            times, moves = [], []
+            for i in range(args.reps):
+                dt, mv, ranges = scan(pool[i % len(pool)]["auction"],
+                                      make_ts(i), t)
+                times.append(dt)
+                moves.append(mv)
+            out[name][f"threads_{t}"] = {
+                "ranges": ranges,
+                "ns_per_record_best": 1e9 * min(times) / n,
+                "ns_per_record_median": 1e9 * statistics.median(times) / n,
+                "ms_per_batch_median": 1e3 * statistics.median(times),
+                "pane_moves_per_batch": statistics.mean(moves)}
     out["keys"] = directory.num_keys()
     out["machine"] = machine()
     print(json.dumps(out))
