@@ -169,6 +169,10 @@ def _merge_directory(snaps: Sequence[Dict[str, np.ndarray]], g: _Geo,
     else:
         _clear_outside_range(rev_keys, g.tgt_slot_lo, g.tgt_slot_hi, 0)
         _clear_outside_range(rev_used, g.tgt_slot_lo, g.tgt_slot_hi, False)
+    # the allocator's free lists and the keys' newest panes are not
+    # merged: restore() takes every unused slot below its shard's free
+    # pointer as free, and the window operator falls back to the newest
+    # pane seen for every key (KeyDirectory.restore / note_all)
     return {"rev_keys": rev_keys, "rev_used": rev_used,
             "next_free": next_free}
 

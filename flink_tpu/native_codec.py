@@ -157,6 +157,15 @@ def _bind(lib, i64p, f32p) -> None:
         ctypes.c_void_p, i64p, ctypes.c_int64, i64p, u8p]
     lib.ht_insert.restype = None
     lib.ht_insert.argtypes = [ctypes.c_void_p, i64p, i64p, ctypes.c_int64]
+    lib.ht_lookup_claim.restype = ctypes.c_int64
+    lib.ht_lookup_claim.argtypes = [
+        ctypes.c_void_p, i64p, ctypes.c_int64, i64p, i64p]
+    lib.ht_delete.restype = ctypes.c_int64
+    lib.ht_delete.argtypes = [ctypes.c_void_p, i64p, ctypes.c_int64]
+    lib.ht_longest_run.restype = ctypes.c_int64
+    lib.ht_longest_run.argtypes = [ctypes.c_void_p]
+    lib.slot_panes_note.restype = None
+    lib.slot_panes_note.argtypes = [ctypes.c_int64, i64p, i64p, u8p, i64p]
     lib.hash_keys.restype = None
     lib.hash_keys.argtypes = [i64p, ctypes.c_int64, i64p]
     lib.crc32_zlib.restype = ctypes.c_uint32
@@ -211,6 +220,9 @@ def _bind(lib, i64p, f32p) -> None:
     lib.ingest_fused_finalize_pairs.restype = None
     lib.ingest_fused_finalize_pairs.argtypes = [
         ctypes.c_int64, i32p, i32p, i32p]
+    lib.slot_panes_note_pairs.restype = None
+    lib.slot_panes_note_pairs.argtypes = [
+        ctypes.c_int64, i32p, ctypes.c_int64, ctypes.c_int64, i64p]
 
 
 def native_available() -> bool:
@@ -397,6 +409,53 @@ class NativeHashTable:
         keys = np.ascontiguousarray(keys, np.int64)
         vals = np.ascontiguousarray(vals, np.int64)
         self._lib.ht_insert(self._h, keys, vals, len(keys))
+
+    PENDING = -16   # codec.cc HT_PENDING
+
+    def lookup_claim(self, keys: np.ndarray):
+        """``(values, distinct missed keys)``: a lookup that enters each
+        absent key with the placeholder ``PENDING - u`` (``u`` its index
+        among the distinct misses, in first-occurrence order), which
+        every record of that key reads back. The caller stores a real
+        value for each of them (``insert_batch``) before anything else
+        reads the table, and resolves the placeholders it holds."""
+        keys = np.ascontiguousarray(keys, np.int64)
+        vals = np.empty(len(keys), np.int64)
+        uniq = np.empty(len(keys), np.int64)
+        n = self._lib.ht_lookup_claim(self._h, keys, len(keys), vals, uniq)
+        return vals, uniq[:n]
+
+    def delete_batch(self, keys: np.ndarray) -> int:
+        """Delete by backward shift (codec.cc ht_delete): no tombstones,
+        probes stay as short as the load allows. Absent keys are
+        skipped; returns how many were there."""
+        keys = np.ascontiguousarray(keys, np.int64)
+        return int(self._lib.ht_delete(self._h, keys, len(keys)))
+
+    def longest_run(self) -> int:
+        """The longest run of occupied buckets: a probe's worst case."""
+        return int(self._lib.ht_longest_run(self._h))
+
+
+def slot_panes_note_native(slots: np.ndarray, panes: np.ndarray,
+                           valid: np.ndarray, newest: np.ndarray) -> bool:
+    """``newest[slot] = max(newest[slot], pane)`` over a batch's valid
+    records, in C; False when the library is unavailable."""
+    lib = _load()
+    if lib is None:
+        return False
+    lib.slot_panes_note(
+        len(slots), np.ascontiguousarray(slots, np.int64),
+        np.ascontiguousarray(panes, np.int64),
+        np.ascontiguousarray(valid).view(np.uint8), newest)
+    return True
+
+
+def slot_panes_note_pairs_native(pairs: np.ndarray, ring: int, pane_lo: int,
+                                 newest: np.ndarray) -> None:
+    """The same from a fused scan's distinct (slot * ring + column)
+    pairs, whose panes lie in ``[pane_lo, pane_lo + ring)``."""
+    _load().slot_panes_note_pairs(len(pairs), pairs, ring, pane_lo, newest)
 
 
 class NativeSocketReader:

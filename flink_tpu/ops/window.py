@@ -528,6 +528,7 @@ def ring_append_topn_kernel(
     sel_cap: int,
     by: str,
     topn: int,
+    fire_pad: int = 0,
 ) -> jax.Array:
     """Top-n fire that APPENDS winners to a device-resident emit ring
     instead of returning a fresh buffer. The host polls the ring — one
@@ -540,8 +541,16 @@ def ring_append_topn_kernel(
 
     Overflow (appends since last poll > row_cap) is detected host-side
     from the counter, never silent. ref role: RecordWriter's buffer ring
-    + PipelinedSubpartition, collapsed into device memory."""
+    + PipelinedSubpartition, collapsed into device memory.
+
+    ``fire_pad``: how many of the params' window-end slots this program
+    READS (0: all), as in the fused step (``_fused_fire_clear``): the
+    upload keeps its fixed MIN_FIRE_PAD ends, the fire's rows x W
+    subgraph is as wide as the ends that are real. At 16.8 M rows the
+    full 64 does not fit the device."""
     pane_lo, pane_hi, anchor, end_panes, w_valid = _unpack_fire_params(params)
+    if fire_pad:
+        end_panes, w_valid = end_panes[:fire_pad], w_valid[:fire_pad]
     return _ring_append_topn_core(
         state, emit_ring, pane_lo, pane_hi, anchor, end_panes, w_valid,
         used_mask, agg=agg, panes_per_window=panes_per_window, ring=ring,
@@ -768,7 +777,8 @@ _JIT_FIRE_PACK = jax.jit(
 # the (small, fixed) ring on device instead.
 _JIT_RING_TOPN = jax.jit(
     ring_append_topn_kernel,
-    static_argnames=("agg", "panes_per_window", "ring", "sel_cap", "by", "topn"))
+    static_argnames=("agg", "panes_per_window", "ring", "sel_cap", "by",
+                     "topn", "fire_pad"))
 _JIT_CLEAR = jax.jit(clear_kernel, donate_argnums=(0,))
 
 
@@ -807,6 +817,13 @@ MAX_FIRE_CHUNK = 4
 # the ring/top-n path appends in HBM (no per-fire fetch buffer), so it
 # takes a steady advance's whole window list in ONE dispatch
 MAX_FIRE_CHUNK_RING = 16
+# the most cells a chunked top-n fire's slots x MIN_FIRE_PAD grid may
+# have before the fire narrows to the ends that are real (_fire_ends):
+# up to 32,768 slots (2^21 cells, 8 MB an intermediate) the full width
+# costs little and one program serves every end count; the cost grows
+# with the slots, and at 16.8 M of them the 64 columns are 4 GB an
+# intermediate and do not compile for a 16 GB chip
+FIRE_GRID_CELLS = 1 << 21
 
 
 def _next_pow2(n: int) -> int:
@@ -1403,6 +1420,27 @@ class WindowOperator:
                     f"but this process's range {shard_range} spans "
                     f"{shard_range[1] - shard_range[0]}")
         self.directory = KeyDirectory(num_shards, slots_per_shard, shard_range)
+        # keys leave: a key whose newest pane has been purged gives its
+        # slot back (_release_dead_keys; the reuse rule is there). Not
+        # under a spill store: a key that failed allocation lives on the
+        # host from then on, and the two stores' key sets must stay
+        # disjoint (state/spill.py), so there nothing is released.
+        self._releases = self._spill is None
+        if self._releases:
+            self.directory.track_panes()
+        # released slots the allocator may not have yet, oldest first:
+        # (fires dispatched at the release, slots)
+        self._waiting: collections.deque = collections.deque()
+        self._n_waiting = 0
+        self.slots_waiting_peak = 0
+        # stays 0 while the reuse rule holds (see _return_released)
+        self.slots_returned_early = 0
+        # every fire numbered up to this has had its rows decoded
+        self._fires_decoded = 0
+        # pack-mode fires (no top-n): the latest's number, and those
+        # whose buffers the drain has not decoded yet
+        self._pack_no = 0
+        self._packs_open: set = set()
         per_block_slots = (
             mesh_plan.slots_per_device if mesh_plan else self.directory.local_slots)
         self.layout = PaneStateLayout(
@@ -1632,20 +1670,30 @@ class WindowOperator:
         did not make."""
         ph = self.phases.phase
         self._flush_stash()
+        self._return_released()
         self.state_version += 1
         keys = np.asarray(keys, dtype=np.int64)
         ts = np.asarray(ts, dtype=np.int64)
-        valid = np.ones(len(ts), bool) if valid is None else np.asarray(valid, bool)
+        # ``whole``: every record is valid (none masked by the caller,
+        # none late, none without a slot): the masks below then cost no
+        # copies of the batch
+        whole = valid is None and len(ts) > 0
+        valid = (np.ones(len(ts), bool) if valid is None
+                 else np.asarray(valid, bool))
         panes = self.plan.pane_of(ts)
 
         dead = self._cleared_below
         late_mask = valid & (panes < dead)
-        self.late_records += int(late_mask.sum())
-        valid = valid & ~late_mask
+        n_late = int(np.count_nonzero(late_mask))
+        if n_late:
+            self.late_records += n_late
+            valid = valid & ~late_mask
+            whole = False
 
-        if valid.any():
-            mn = int(panes[valid].min())
-            mx = int(panes[valid].max())
+        if whole or valid.any():
+            pv = panes if whole else panes[valid]
+            mn = int(pv.min())
+            mx = int(pv.max())
             prev_min = self._min_pane_seen
             prev_max = self._max_pane_seen
             if prev_min is None or mn < prev_min:
@@ -1701,6 +1749,9 @@ class WindowOperator:
             if bad.any():
                 account_full_drop(self, int(bad.sum()))
             valid = valid & ~bad & ~full
+            whole = False
+        if self._releases:
+            self.directory.note_panes(slots, panes, valid)
         if self._preagg_dispatch(slots, panes, valid, data):
             self._throttle_unless_external()
             return
@@ -1716,8 +1767,17 @@ class WindowOperator:
         ring = self.plan.ring
         local_split = self.mesh_plan is None and self._split_upload
         if not local_split:
-            packed = slots * ring + panes % ring
-            packed[~valid] = -1
+            if whole:
+                # a batch's valid panes lie within one ring's length of
+                # its lowest (the ring guard above): a subtraction and a
+                # wrap where ``%`` would divide 2^20 times
+                cols = panes - (mn - mn % ring)
+                cols[cols >= ring] -= ring
+                packed = slots * ring
+                packed += cols
+            else:
+                packed = slots * ring + panes % ring
+                packed[~valid] = -1
             # dtype bound uses GLOBAL rows: in mesh mode slots are global
             # (apply_shard routes by slot // spd), so the max packed value
             # is n_devices × the local-block bound
@@ -1831,6 +1891,7 @@ class WindowOperator:
             bits = int(span)
         prev_min, prev_max = self._min_pane_seen, self._max_pane_seen
         ph = self.phases.phase
+        self._return_released()
         for _attempt in (0, 1):
             domain = self.directory.local_slots * self.plan.ring
             if (self._preagg_ws is None or self._preagg_ws.domain != domain
@@ -1896,6 +1957,10 @@ class WindowOperator:
                 late_panes, self._fired_below_end, self.watermark))
         if n_valid == 0:
             return True
+        if self._releases:
+            # once a distinct (slot, pane) pair, not once a record
+            self.directory.note_pairs(
+                res.out_pairs[:res.npairs], self.plan.ring, pmin)
         ph("window.pack")
         self.prof["preagg_batches"] += 1
         if self.mesh_plan is not None:
@@ -2378,7 +2443,102 @@ class WindowOperator:
             self._cleared_below = new_dead
             if self._spill is not None:
                 self._spill.purge_below(new_dead)
+            self._release_dead_keys()
         return out
+
+    # -- keys that leave, and the reuse rule ------------------------------
+    def _release_dead_keys(self) -> None:
+        """After a purge has moved ``_cleared_below``: release every key
+        whose newest pane lies below it. ``first_dead_pane`` already
+        holds allowed lateness and refires, so no record that the
+        operator would still accept can reach such a key's panes: its
+        next record starts a new life in a pane that is alive, in
+        whatever slot the allocator then gives it. The device rows of a
+        released slot are identities already: the purge that moved the
+        horizon cleared every column its key wrote (relied on by the
+        fire, which tells a row that counts from one that does not by
+        its counts alone, and asserted in tests/test_key_release.py, not
+        here). Costs what the directory examines (keys born or last
+        seen alive in the purged panes), never the slot space.
+
+        THE REUSE RULE. Fired rows leave the device as ROW numbers and
+        become keys on the host only when the drain decodes them
+        (``drain_ring``, ``_decode_packs``), after its deferral and on
+        another thread; a fire, the purge of its oldest pane, this
+        release and the next batch's allocation all come before that. So
+        a released slot is stamped with the number of fires dispatched
+        so far, and goes back to its shard's allocator
+        (``_return_released``) only once every fire up to that number
+        has had its rows decoded (``_drained_through``): the one-chip
+        emit ring, the mesh's ring blocks, the chunked ``_fire_ends``
+        and the fused step all number their fires by the ring version
+        they bump, pack-mode fires by ``_pack_no``. Fires dispatched
+        after the release cannot name the slot for its old key: its
+        rows count nothing until a new key is given it."""
+        if not self._releases:
+            return
+        with self.phases.span("state.release"):
+            rel = self.directory.release_below(self._cleared_below)
+            if len(rel):
+                self._waiting.append((self._fires_so_far(), rel))
+                self._n_waiting += len(rel)
+                if self._n_waiting > self.slots_waiting_peak:
+                    self.slots_waiting_peak = self._n_waiting
+
+    def _fires_so_far(self) -> int:
+        """The number of the latest fire dispatched."""
+        return (self._ring_version_no if self._topn is not None
+                else self._pack_no)
+
+    def _drained_through(self) -> int:
+        """Every fire numbered up to this has had its rows decoded into
+        keys by the drain (the reuse rule's other half)."""
+        return self._fires_decoded
+
+    def _note_decoded(self, fire_no: int) -> None:
+        """The drain has turned the rows of every fire up to ``fire_no``
+        into keys (called by it, after the decode)."""
+        if fire_no > self._fires_decoded:
+            self._fires_decoded = fire_no
+
+    def _note_pack_decoded(self, pack_no: int) -> None:
+        """A pack-mode fire's buffers were decoded (or dropped unread):
+        decoded runs through the fire before the oldest still open."""
+        with self._ring_lock:
+            self._packs_open.discard(pack_no)
+            self._note_decoded(min(self._packs_open) - 1
+                               if self._packs_open else self._pack_no)
+
+    def _return_released(self) -> None:
+        """Ahead of a batch's allocations: hand the allocator every
+        waiting slot the reuse rule lets go. One comparison when none
+        is due."""
+        if not self._waiting:
+            return
+        through = self._drained_through()
+        if self._waiting[0][0] > through:
+            return
+        with self.phases.span("state.reclaim"):
+            back = []
+            while self._waiting and self._waiting[0][0] <= through:
+                stamp, slots = self._waiting.popleft()
+                if stamp > self._fires_decoded:
+                    self.slots_returned_early += len(slots)
+                back.append(slots)
+            slots = back[0] if len(back) == 1 else np.concatenate(back)
+            self._n_waiting -= len(slots)
+            self.directory.reclaim(slots)
+
+    def state_counters(self) -> Dict[str, int]:
+        """The keyed state's life so far (``JobResult.metrics``)."""
+        d = self.directory
+        return {"state.slots_allocated": d.slots_allocated,
+                "state.slots_reused": d.slots_reused,
+                "state.slots_released": d.slots_released,
+                "state.slots_returned_early": self.slots_returned_early,
+                "state.live_keys": d.num_keys(),
+                "state.live_keys_peak": d.keys_peak,
+                "state.slots_waiting_peak": self.slots_waiting_peak}
 
     def _fused_fill_header(self, wm: int, ends: List[int],
                            buf: np.ndarray) -> Optional[Tuple[List[int], int]]:
@@ -2453,8 +2613,12 @@ class WindowOperator:
         # consume of that in-flight copy, and its head words stand in
         # for a ring-header poll
         self._note_dispatch(token, head=(0, 1))
+        purged = cleared_after > self._cleared_below
         self._cleared_below = cleared_after
-        return self._ring_after_fire(ends_f, covered=True)
+        out = self._ring_after_fire(ends_f, covered=True)
+        if purged:
+            self._release_dead_keys()
+        return out
 
     def _fire_cohort(self, end_panes: List[int]) -> Dict[str, Any]:
         """The record of one fire dispatch: its window ends (ms) and
@@ -2534,9 +2698,17 @@ class WindowOperator:
             params = jnp.asarray(np.asarray(
                 [lo, hi, self._ring_anchor or 0] + ends_padded, dtype=np.int64))
             if self._topn is not None:
+                # the fire's rows x W grid is all MIN_FIRE_PAD ends of
+                # the params where that is small (ONE program, whatever
+                # the end count: nothing to build when a catch-up brings
+                # two ends), and the real ends' pow2 bucket where 64
+                # columns over the rows would not fit the device
+                width = ({"fire_pad": Wp} if self.mesh_plan is None
+                         and self.layout.slots * MIN_FIRE_PAD > FIRE_GRID_CELLS
+                         else {})
                 self._emit_ring = self._ring_topn(
                     self.state, self._ensure_ring(), params, used,
-                    sel_cap=self._topn_cap(Wp))
+                    sel_cap=self._topn_cap(Wp), **width)
             else:
                 buf = self._fire_pack(
                     self.state, params, used, out_cap=self._fire_cap(Wp))
@@ -2547,7 +2719,10 @@ class WindowOperator:
                 packs.append((lo, buf))
         if self._topn is not None:
             return self._ring_after_fire(ends)
-        return FiredWindows(op=self, packs=packs,
+        with self._ring_lock:
+            self._pack_no += 1
+            self._packs_open.add(self._pack_no)
+        return FiredWindows(op=self, packs=packs, pack_no=self._pack_no,
                             cohort=self._fire_cohort(ends))
 
     def _fire_packed2(self) -> bool:
@@ -2711,6 +2886,9 @@ class WindowOperator:
             # per-fire ring segmentation
             extras = list(self._pending_ring_extras)
             self._pending_ring_extras.clear()
+            # the fire (ring version) through which this call has every
+            # row in hand; None when it fetched nothing
+            seen_no = None
             if self._emit_ring is None or self._ring_anchor is None:
                 arr = None
             elif (min_no == 0 and self.mesh_plan is None
@@ -2728,7 +2906,8 @@ class WindowOperator:
                 # deliver the stamps NOW — a zero-row fire cohort's
                 # latency sample must not age across skipped polls.
                 now = time.perf_counter()
-                self._deliver_stamps(self._ring_version_no, now, now)
+                seen_no = self._ring_version_no
+                self._deliver_stamps(seen_no, now, now)
                 self.prof["drain_skips"] += 1
                 arr = None
             else:
@@ -2741,9 +2920,12 @@ class WindowOperator:
                     # just became host-visible — hand it, with this
                     # fetch's stamps, to the latency accounting
                     self._deliver_stamps(no_read, fetch.t0, fetch.t1)
+                    seen_no = no_read
                 self.prof["drain_fetch"] += fetch.seconds
                 self.prof["drain_fetches"] += 1
         if arr is None:
+            if seen_no is not None:
+                self._note_decoded(seen_no)
             out = dict(self._empty())
             if extras:
                 out = _drain_merge_extras(out, extras, self._topn)
@@ -2790,6 +2972,9 @@ class WindowOperator:
             "window_end": window_end,
             "count": body[:, 2],
         }
+        # rows are keys now: the reuse rule may let go of slots released
+        # up to the fire this fetch read through
+        self._note_decoded(seen_no)
         for i, k in enumerate(fields):
             col = np.ascontiguousarray(body[:, 3 + i])
             out[k] = col if self._res_is_int[k] else col.view(np.float32)
@@ -2829,20 +3014,27 @@ class WindowOperator:
                 "(top-n tie explosion); raise n or aggregate first")
 
     def _used_mask_device(self) -> jax.Array:
-        """(rows,) bool on device, marking registered-key rows; re-pushed
-        only when the directory registered new keys (h2d is cheap and
-        one-way; the d2h round trip is what the packed fire avoids)."""
-        nk = self.directory.num_keys()
+        """(rows,) bool on device, marking the rows of slots that have
+        held a key; re-pushed only when the directory took a never-used
+        slot (h2d is cheap and one-way; the d2h round trip is what the
+        packed fire avoids)."""
+        nk = self.directory.slots_ever_used()
         if getattr(self, "_used_pushed", -1) != nk:
             n_rows = self.layout.rows * (
                 self.mesh_plan.n_devices if self.mesh_plan else 1)
             used = np.zeros(n_rows, dtype=bool)
-            used_slots = np.nonzero(self.directory.used_mask())[0]
-            used[self._row_of_slots(used_slots)] = True
-            if self.mesh_plan is not None:
-                self._used_dev = jax.device_put(used, self.mesh_plan.row_sharding())
-            else:
+            # every slot that EVER held a key: a released slot's rows
+            # are identities (_release_dead_keys) and fire nothing, so
+            # the mask need not follow the keys that come and go, only
+            # the allocator's high-water marks
+            ever = self.directory.ever_used_mask()
+            if self.mesh_plan is None:
+                used[:len(ever)] = ever
                 self._used_dev = jnp.asarray(used)
+            else:
+                used[self._row_of_slots(np.nonzero(ever)[0])] = True
+                self._used_dev = jax.device_put(
+                    used, self.mesh_plan.row_sharding())
             self._used_pushed = nk
         return self._used_dev
 
@@ -2903,7 +3095,11 @@ class WindowOperator:
             # deleted buffers.
             "panes": jax.tree_util.tree_map(
                 lambda x: jnp.array(x, copy=True), self.state),
-            "directory": self.directory.snapshot(),
+            # slots waiting on the reuse rule go into the snapshot as
+            # free ones: a checkpoint flushes the emits first, and a
+            # restore starts a new emit ring, so no row of the snapshot's
+            # timeline can name them any more
+            "directory": self._directory_snapshot(),
             "watermark": self.watermark,
             "cleared_below": self._cleared_below,
             "fired_below_end": self._fired_below_end,
@@ -2915,6 +3111,13 @@ class WindowOperator:
         }
         if aux_files:
             out["__aux_files__"] = aux_files
+        return out
+
+    def _directory_snapshot(self) -> Dict[str, np.ndarray]:
+        out = self.directory.snapshot()
+        if self._waiting:
+            out["free_slots"] = np.concatenate(
+                [out["free_slots"]] + [sl for _no, sl in self._waiting])
         return out
 
     def restore_state(self, snap: Dict[str, Any]) -> None:
@@ -2942,14 +3145,31 @@ class WindowOperator:
         if self.mesh_plan is not None:
             state = jax.device_put(state, self.mesh_plan.row_sharding())
         self.state = state
+        old = self.directory
         self.directory = KeyDirectory.restore(
-            self.directory.num_shards, self.directory.slots_per_shard,
-            snap["directory"], (self.directory.shard_lo, self.directory.shard_hi))
+            old.num_shards, old.slots_per_shard,
+            snap["directory"], (old.shard_lo, old.shard_hi))
+        for k in ("slots_allocated", "slots_reused", "slots_released"):
+            setattr(self.directory, k, getattr(old, k))   # the job's
         self.watermark = snap["watermark"]
         self._cleared_below = snap["cleared_below"]
         self._fired_below_end = snap["fired_below_end"]
         self._min_pane_seen = snap["min_pane_seen"]
         self._max_pane_seen = snap["max_pane_seen"]
+        if self._releases:
+            bare = self.directory._newest is None
+            self.directory.track_panes()
+            if bare and self._max_pane_seen is not None:
+                # a snapshot without newest panes (an older one, one of
+                # a spill backend, one merged for a rescale): no key has
+                # a pane past the newest seen
+                self.directory.note_all(self._max_pane_seen)
+        # pre-restore fires are a dead timeline, and the snapshot holds
+        # what waited as free (see snapshot_state)
+        self._waiting.clear()
+        self._n_waiting = 0
+        self._packs_open.clear()
+        self._fires_decoded = self._fires_so_far()
         self._refire = set(snap["refire"])
         self.late_records = snap["late_records"]
         self.records_dropped_full = snap.get("records_dropped_full", 0)
@@ -3053,7 +3273,8 @@ class FiredWindows(Mapping):
 
     def __init__(self, data: Optional[Dict[str, np.ndarray]] = None,
                  fetch=None, op=None, packs=None, ring: bool = False,
-                 ring_no: int = 0, cohort: Optional[Dict] = None):
+                 ring_no: int = 0, cohort: Optional[Dict] = None,
+                 pack_no: int = 0):
         # the fire's record (WindowOperator._fire_cohort), None for a
         # batch that fired no window end
         self.cohort = cohort
@@ -3063,6 +3284,9 @@ class FiredWindows(Mapping):
         self._packs = packs
         self._ring = ring
         self._ring_no = ring_no
+        # a pack-mode fire's number (WindowOperator._pack_no): told to
+        # the operator once the buffers are decoded, or dropped unread
+        self._pack_no = pack_no
         # host-spill rows fired alongside this batch (disjoint keys);
         # merged in at materialization, reranked if a top-n is active
         self._extra: Optional[Dict[str, np.ndarray]] = None
@@ -3093,7 +3317,15 @@ class FiredWindows(Mapping):
             self.cohort["t_fetch0"] = fetch.t0
             self.cohort["t_fetch1"] = fetch.t1
         self._data = self._op._decode_packs(self._packs, bufs)
+        self._op._note_pack_decoded(self._pack_no)
         self._packs = self._op = None
+
+    def __del__(self) -> None:
+        # buffers nobody will read name no key: the reuse rule need not
+        # wait for them
+        if getattr(self, "_packs", None) is not None \
+                and self._op is not None:
+            self._op._note_pack_decoded(self._pack_no)
 
     @staticmethod
     def materialize_many(fireds: List["FiredWindows"],

@@ -51,7 +51,7 @@ PHASE_LEAVES = {
                  "window.step_dispatch"),
     "throttle": ("ingest.throttle",),
     "drain": ("drain.fetch",),
-    "advance": ("wm.advance",),
+    "advance": ("wm.advance", "state.release", "state.reclaim"),
     "fire": ("window.fire_dispatch",),
 }
 
@@ -1832,6 +1832,23 @@ class Driver:
                     self.metrics[counter] = (
                         self.metrics.get(counter, 0) + getattr(op, counter))
         self.metrics.update(self._exchange_metrics())
+        # keyed state's comings and goings (WindowOperator
+        # .state_counters): slots handed out, reused, released; keys
+        # live now and at most; the most slots the reuse rule held back
+        for op in self._ops.values():
+            if hasattr(op, "state_counters"):
+                for k, v in op.state_counters().items():
+                    self.metrics[k] = self.metrics.get(k, 0) + v
+        if self.metrics.get("state.slots_allocated"):
+            # allocations served from a released slot, as a share
+            self.metrics["state.reuse_share"] = (
+                self.metrics["state.slots_reused"]
+                / self.metrics["state.slots_allocated"])
+        if self.metrics.get("state.slots_allocated"):
+            # allocations served from a released slot, as a share
+            self.metrics["state.reuse_share"] = (
+                self.metrics["state.slots_reused"]
+                / self.metrics["state.slots_allocated"])
         final = dict(self.metrics)
         final.update(self.registry.snapshot())
         # the per-phase breakdown (dispatch/throttle/drain/advance/fire)
@@ -2177,7 +2194,8 @@ class Driver:
                      keying, packing, upload and the step's launch
           throttle — backpressure waits
           drain    — emit-ring / pack fetches, whichever thread makes them
-          advance  — watermark propagation outside the fire
+          advance  — watermark propagation outside the fire, and the
+                     purge's host side: keys released, slots returned
           fire     — the operator's advance_watermark: fire-list header
                      and the fire / fused-step launch"""
         snap = self.phases.snapshot()

@@ -6,7 +6,9 @@ CopyOnWriteStateMap}.java — a per-record nested-hash-map probe), redesigned
 for TPU: state lives as dense ``(slots, panes, width)`` accumulator
 tensors in HBM so a whole microbatch folds in with three scatters, and the
 hash-map role (key → state address) moves to a **host-side directory**
-that assigns each distinct key a stable slot inside its key shard.
+that assigns each distinct key a slot inside its key shard, its own for
+as long as the key is registered (a window operator releases a key once
+its last pane has been purged; the slot is then handed out again).
 
 Key shards (ref: runtime/state/KeyGroupRangeAssignment.java — key groups,
 default max-parallelism 128) decouple the logical key space from physical
@@ -175,6 +177,46 @@ class _NumpyHashTable:
             pending = pending[~settled[pending]]
             ix[pending] = (ix[pending] + 1) & mask
 
+    def delete_batch(self, keys: np.ndarray) -> int:
+        """Delete by backward shift, the native table's algorithm
+        (codec.cc ht_delete) bucket for bucket, so the two stay in
+        parity: the hole a deleted entry leaves is filled by the next
+        entry of its run whose home bucket does not lie after the hole,
+        to the run's end. No tombstones; a probe is never longer than
+        the load (<= 0.5) makes it. One Python step per deleted key and
+        per shifted entry: this is the fallback, not the hot path.
+        Absent keys are skipped; returns how many were there."""
+        keys = np.asarray(keys, np.int64)
+        mask = len(self._keys) - 1
+        home = (hash_keys_numpy(keys) & mask).astype(np.int64)
+        gone = 0
+        for key, ix in zip(keys.tolist(), home.tolist()):
+            while self._used[ix] and self._keys[ix] != key:
+                ix = (ix + 1) & mask
+            if not self._used[ix]:
+                continue
+            hole, j = ix, (ix + 1) & mask
+            while self._used[j]:
+                h = int(hash_keys_numpy(self._keys[j:j + 1])[0]) & mask
+                if ((j - h) & mask) >= ((j - hole) & mask):
+                    self._keys[hole] = self._keys[j]
+                    self._vals[hole] = self._vals[j]
+                    hole = j
+                j = (j + 1) & mask
+            self._used[hole] = False
+            self._count -= 1
+            gone += 1
+        return gone
+
+    def longest_run(self) -> int:
+        """The longest run of occupied buckets: a probe's worst case."""
+        if self._used.all():
+            return len(self._used)
+        # start after an empty bucket, so no run wraps the end
+        u = np.roll(self._used, -(int(np.argmin(self._used)) + 1))
+        edges = np.flatnonzero(np.diff(np.r_[0, u.astype(np.int8), 0]))
+        return int((edges[1::2] - edges[::2]).max(initial=0))
+
     def _grow(self) -> None:
         old_keys, old_vals, old_used = self._keys, self._vals, self._used
         self.__init__(capacity_hint=len(old_keys))
@@ -184,6 +226,20 @@ class _NumpyHashTable:
                 old_keys[live], hash_keys_numpy(old_keys[live]), old_vals[live])
 
 
+# "no pane noted": below every pane, so such a key is released at the
+# next purge (a key whose every record was late or invalid)
+_NO_PANE = np.iinfo(np.int64).min
+
+
+def _runs(sorted_ids: np.ndarray):
+    """(starts, lengths, ranks) of the equal-id runs of a sorted array:
+    rank = an element's place within its run."""
+    starts = np.r_[0, np.nonzero(np.diff(sorted_ids))[0] + 1]
+    lens = np.diff(np.r_[starts, len(sorted_ids)])
+    ranks = np.arange(len(sorted_ids)) - np.repeat(starts, lens)
+    return starts, lens, ranks
+
+
 class KeyDirectory:
     """Host-side key → slot mapping (the hash-map half of the state
     backend; ref role: CopyOnWriteStateMap.get/put, but amortized over a
@@ -191,8 +247,20 @@ class KeyDirectory:
 
     Batch lookups are fully vectorized over a numpy open-addressing
     table; only never-before-seen keys take the per-key insert path.
-    Slot ids are stable for the life of the job (and across checkpoints —
-    the directory is part of the snapshot manifest).
+
+    A key keeps its slot for as long as it is registered, across
+    checkpoints too (the directory is part of the snapshot manifest).
+    An owner that tracks panes (``track_panes``: the window operator on
+    the hbm backend) releases a key once its newest pane has been
+    purged (``release_below``): the key leaves the table, its slot
+    leaves ``used_mask``, and the OWNER holds the slot back until no
+    fired row that names it can still be read (ops/window.py, "the
+    reuse rule") before it hands it to ``reclaim``. The allocator then
+    takes a reclaimed slot of the key's shard before a never-used one,
+    so the rows the device scatter touches stay as few as the live keys
+    need. Owners that never call ``track_panes`` (count windows, global
+    aggregates, process functions, CEP) keep every key for the life of
+    the job, as before.
     """
 
     FULL = -2  # sentinel: shard out of slots (spill backend takes over)
@@ -213,6 +281,26 @@ class KeyDirectory:
         n_local = (self.shard_hi - self.shard_lo) * slots_per_shard
         self._rev_keys = np.zeros(n_local, dtype=np.int64)
         self._rev_used = np.zeros(n_local, dtype=bool)
+        self._n_keys = 0
+        # per local shard, a stack of reclaimed local indices and its
+        # depth; the stacks are made when the first slot comes back
+        self._free: Optional[np.ndarray] = None
+        self._n_free = np.zeros(self.shard_hi - self.shard_lo, np.int64)
+        # pane tracking (track_panes): per slot the newest pane a record
+        # of its key was folded into, and where release_below looks:
+        # slots allocated since it last ran, and slots it found alive,
+        # by the newest pane they had then. A slot is in exactly one of
+        # those lists, so a purge costs what it examines, not the space.
+        self._newest: Optional[np.ndarray] = None
+        self._fresh: list = []
+        self._by_pane: Dict[int, list] = {}
+        # over the directory's life: slots handed out, those of them
+        # that were reclaimed ones, keys released, and the most keys
+        # registered at once
+        self.slots_allocated = 0
+        self.slots_reused = 0
+        self.slots_released = 0
+        self.keys_peak = 0
 
     @property
     def local_slots(self) -> int:
@@ -229,6 +317,22 @@ class KeyDirectory:
         slots (spill-layer responsibility).
         """
         keys = np.asarray(keys, dtype=np.int64)
+        claim = getattr(self._table, "lookup_claim", None)
+        if claim is not None:
+            # native: ONE pass finds the slots and the distinct new keys
+            # (a lookup and then np.unique over the missed records — half
+            # a batch and more where keys come and go — cost five times
+            # the lookup); their records read placeholders, resolved here
+            slots, uniq = claim(keys)
+            if len(uniq):
+                # slots go out in key order within a shard, as the
+                # sorted-unique path below hands them out
+                order = np.argsort(uniq)
+                alloc = np.empty(len(uniq), np.int64)
+                alloc[order] = self._register(uniq[order])
+                pend = np.flatnonzero(slots <= self._table.PENDING)
+                slots[pend] = alloc[self._table.PENDING - slots[pend]]
+            return slots
         slots, found = self._table.lookup_keys(keys)
         if not found.all():
             miss_ix = np.nonzero(~found)[0]
@@ -238,26 +342,30 @@ class KeyDirectory:
             # only the DISTINCT misses are hashed on the Python side —
             # the hit path's hashes live inside the table lookup
             uniq, inv = np.unique(keys[miss_ix], return_inverse=True)
-            uh = hash_keys_numpy(uniq)
-            alloc = self._alloc_slots(uniq, uh)
-            self._table.insert_batch(uniq, uh, alloc)
-            slots[miss_ix] = alloc[inv]
+            slots[miss_ix] = self._register(uniq)[inv]
         return slots
 
     def register_misses(self, miss_keys: np.ndarray) -> None:
         """Register keys KNOWN to be absent (the fused C scan already
         probed them — codec.cc ingest_fused_scan): allocate + insert
         without repeating the lookup pass."""
-        uniq = np.unique(np.asarray(miss_keys, np.int64))
+        self._register(np.unique(np.asarray(miss_keys, np.int64)))
+
+    def _register(self, uniq: np.ndarray) -> np.ndarray:
+        """Slots for DISTINCT absent keys. A key whose shard is full (or
+        not this directory's) is entered with that verdict, as it always
+        was: it stays refused (or on the host, under a spill store) even
+        if slots of its shard come back later."""
         uh = hash_keys_numpy(uniq)
         alloc = self._alloc_slots(uniq, uh)
         self._table.insert_batch(uniq, uh, alloc)
+        return alloc
 
     def _alloc_slots(self, keys: np.ndarray, hashes: np.ndarray) -> np.ndarray:
         """Assign shard-local slots to a batch of DISTINCT new keys:
-        group by shard, hand out contiguous indices from each shard's
-        free pointer, mark FULL past capacity. Pure numpy — no per-key
-        Python."""
+        group by shard; within a shard the first keys take its reclaimed
+        slots (newest first), the rest contiguous indices from its free
+        pointer; FULL past capacity. Pure numpy — no per-key Python."""
         shards = (hashes % self.num_shards).astype(np.int64)
         out = np.full(len(keys), -1, dtype=np.int64)
         inr = (shards >= self.shard_lo) & (shards < self.shard_hi)
@@ -267,18 +375,40 @@ class KeyDirectory:
         order = np.argsort(shards[sub], kind="stable")
         sub = sub[order]
         sh = shards[sub]
-        # rank of each key within its equal-shard run
-        starts = np.r_[0, np.nonzero(np.diff(sh))[0] + 1]
-        run_lens = np.diff(np.r_[starts, len(sh)])
-        ranks = np.arange(len(sh)) - np.repeat(starts, run_lens)
-        local_ix = self._next_free[sh] + ranks
-        full = local_ix >= self.slots_per_shard
+        starts, run_lens, ranks = _runs(sh)
+        run_sh = sh[starts]
+        room = self.slots_per_shard - self._next_free[run_sh]
+        if self._free is not None and self._n_free.any():
+            run_ls = run_sh - self.shard_lo
+            depth = self._n_free[run_ls]
+            taken = np.minimum(depth, run_lens)
+            self._n_free[run_ls] = depth - taken
+            taken_k = np.repeat(taken, run_lens)
+            reuse = ranks < taken_k
+            fresh_rank = ranks - taken_k
+            top = np.repeat(depth, run_lens) - 1 - ranks
+            local_ix = np.where(
+                reuse, self._free[sh - self.shard_lo, np.maximum(top, 0)],
+                self._next_free[sh] + fresh_rank)
+            self.slots_reused += int(taken.sum())
+        else:
+            taken = 0
+            reuse = np.zeros(len(sh), bool)
+            fresh_rank = ranks
+            local_ix = self._next_free[sh] + ranks
+        full = ~reuse & (fresh_rank >= np.repeat(room, run_lens))
+        self._next_free[run_sh] += np.minimum(run_lens - taken, room)
         slot = (sh - self.shard_lo) * self.slots_per_shard + local_ix
         slot[full] = self.FULL
-        np.add.at(self._next_free, sh[~full], 1)
         ok = slot[~full]
         self._rev_keys[ok] = keys[sub[~full]]
         self._rev_used[ok] = True
+        self._n_keys += len(ok)
+        self.slots_allocated += len(ok)
+        if self._n_keys > self.keys_peak:
+            self.keys_peak = self._n_keys
+        if self._newest is not None and len(ok):
+            self._fresh.append(ok)
         out[sub] = slot
         return out
 
@@ -289,29 +419,154 @@ class KeyDirectory:
         """(local_slots,) bool — which slots hold a registered key."""
         return self._rev_used
 
+    def ever_used_mask(self) -> np.ndarray:
+        """(local_slots,) bool — which slots have ever held a key (those
+        below their shard's free pointer). Grows only; what the device
+        needs to tell pane rows from rows nothing ever wrote."""
+        lo, hi = self.shard_lo, self.shard_hi
+        return (np.arange(self.slots_per_shard)[None, :]
+                < self._next_free[lo:hi, None]).reshape(-1)
+
+    def slots_ever_used(self) -> int:
+        return int(self._next_free[self.shard_lo:self.shard_hi].sum())
+
     def num_keys(self) -> int:
-        return int(self._rev_used.sum())
+        return self._n_keys
+
+    # -- release and reuse ----------------------------------------------
+    def track_panes(self) -> None:
+        """From here on the owner tells the newest pane of every key it
+        folds (``note_panes`` / ``note_pairs``) and asks at each purge
+        which keys have none left (``release_below``)."""
+        if self._newest is None:
+            self._newest = np.full(self.local_slots, _NO_PANE, np.int64)
+            used = np.flatnonzero(self._rev_used)
+            self._fresh = [used] if len(used) else []
+            self._by_pane = {}
+
+    def note_panes(self, slots: np.ndarray, panes: np.ndarray,
+                   valid: np.ndarray) -> None:
+        """``newest[slot] = max(newest[slot], pane)`` for a batch's valid
+        records that have a slot."""
+        from flink_tpu.native_codec import slot_panes_note_native
+
+        if slot_panes_note_native(slots, panes, valid, self._newest):
+            return
+        ok = valid & (slots >= 0)
+        sl, pn = slots[ok], panes[ok]
+        if len(pn) > 1 and (pn[1:] < pn[:-1]).any():
+            order = np.argsort(pn, kind="stable")
+            sl, pn = sl[order], pn[order]
+        # panes ascend, and of a repeated index the last value is the
+        # one assigned: each slot gets its largest pane of the batch
+        self._newest[sl] = np.maximum(self._newest[sl], pn)
+
+    def note_pairs(self, pairs: np.ndarray, ring: int, pane_lo: int) -> None:
+        """The same from a fused scan's distinct (slot * ring + column)
+        pairs (native only: that scan is), whose panes lie in
+        ``[pane_lo, pane_lo + ring)``."""
+        from flink_tpu.native_codec import slot_panes_note_pairs_native
+
+        slot_panes_note_pairs_native(pairs, ring, pane_lo, self._newest)
+
+    def note_all(self, pane: int) -> None:
+        """Every registered key as if seen in ``pane``: what a restore
+        from a snapshot without newest panes falls back to (an upper
+        bound keeps a key too long, never too short)."""
+        self._newest[self._rev_used] = pane
+
+    def release_below(self, dead: int) -> np.ndarray:
+        """Release every key whose newest pane is below ``dead`` (the
+        first pane still alive): out of the table, out of ``used_mask``.
+        Returns their slots, which stay out of the allocator until
+        ``reclaim``. Examines the slots allocated since the last call
+        and those last seen alive in a pane below ``dead``; a slot found
+        alive goes to the list of the pane it now has."""
+        cands = self._fresh
+        self._fresh = []
+        for p in [p for p in self._by_pane if p < dead]:
+            cands.extend(self._by_pane.pop(p))
+        if not cands:
+            return np.zeros(0, np.int64)
+        c = cands[0] if len(cands) == 1 else np.concatenate(cands)
+        newest = self._newest[c]
+        gone = newest < dead
+        if not gone.all():
+            stay, pn = c[~gone], newest[~gone]
+            order = np.argsort(pn, kind="stable")
+            stay, pn = stay[order], pn[order]
+            starts, lens, _ = _runs(pn)
+            for a, n in zip(starts.tolist(), lens.tolist()):
+                self._by_pane.setdefault(int(pn[a]), []).append(
+                    stay[a:a + n])
+            if not gone.any():
+                return np.zeros(0, np.int64)
+        rel = c[gone]
+        self._table.delete_batch(self._rev_keys[rel])
+        self._rev_used[rel] = False
+        self._newest[rel] = _NO_PANE
+        self._n_keys -= len(rel)
+        self.slots_released += len(rel)
+        return rel
+
+    def reclaim(self, slots: np.ndarray) -> None:
+        """Released slots back to their shards' allocators."""
+        if not len(slots):
+            return
+        spd = self.slots_per_shard
+        if self._free is None:
+            self._free = np.empty((len(self._n_free), spd), np.int32)
+        ls = slots // spd
+        order = np.argsort(ls, kind="stable")
+        ls, li = ls[order], (slots % spd)[order]
+        starts, lens, ranks = _runs(ls)
+        self._free[ls, self._n_free[ls] + ranks] = li
+        self._n_free[ls[starts]] += lens
+
+    def free_slots(self) -> np.ndarray:
+        """The reclaimed slots the allocator holds, each shard's in the
+        order ``reclaim`` would need to give them back."""
+        if self._free is None:
+            return np.zeros(0, np.int64)
+        return np.concatenate([np.zeros(0, np.int64)] + [
+            s * self.slots_per_shard + self._free[s, :n].astype(np.int64)
+            for s, n in enumerate(self._n_free.tolist()) if n])
 
     # -- snapshot (part of the checkpoint manifest) ----------------------
     def snapshot(self) -> Dict[str, np.ndarray]:
-        return {
+        out = {
             "rev_keys": self._rev_keys.copy(),
             "rev_used": self._rev_used.copy(),
             "next_free": self._next_free.copy(),
+            "free_slots": self.free_slots(),
         }
+        if self._newest is not None:
+            out["newest_pane"] = self._newest.copy()
+        return out
 
     @classmethod
     def restore(cls, num_shards: int, slots_per_shard: int,
                 snap: Dict[str, np.ndarray],
                 shard_range: Tuple[int, int] | None = None) -> "KeyDirectory":
         d = cls(num_shards, slots_per_shard, shard_range)
-        d._rev_keys = snap["rev_keys"].copy()
-        d._rev_used = snap["rev_used"].copy()
-        d._next_free = snap["next_free"].copy()
+        d._rev_keys = np.array(snap["rev_keys"], np.int64)
+        d._rev_used = np.array(snap["rev_used"], bool)
+        d._next_free = np.array(snap["next_free"], np.int64)
         used = np.nonzero(d._rev_used)[0]
         keys = d._rev_keys[used]
         if len(used):
             d._table.insert_batch(keys, hash_keys_numpy(keys), used)
+        d._n_keys = d.keys_peak = len(used)
+        free = snap.get("free_slots")
+        if free is None:
+            # a snapshot that carries no allocator state (an older one,
+            # or one merged for a rescale): every slot below its shard's
+            # free pointer that holds no key is one that was released
+            free = np.flatnonzero(d.ever_used_mask() & ~d._rev_used)
+        d.reclaim(np.asarray(free, np.int64))
+        if snap.get("newest_pane") is not None:
+            d._newest = np.array(snap["newest_pane"], np.int64)
+            d._fresh = [used] if len(used) else []
         return d
 
 
@@ -328,8 +583,13 @@ def account_full_drop(op, n: int) -> None:
             f"key directory shard full: {n} record(s) have no state "
             "slot (state.num-key-shards x state.slots-per-shard "
             "exceeded, or keys routed outside this worker's shard "
-            "range). The default policy never drops data - use "
-            "state.backend='spill' for exact host-side degradation, "
-            "raise the slot budget, or set state.allow-drops=true to "
-            "drop with accounting (records_dropped_full).")
+            "range). A window operator on the hbm backend releases a "
+            "key's slot once its last pane has been purged, and hands "
+            "it out again once every fire dispatched before that has "
+            "been drained: the budget has to hold the keys alive at "
+            "once, plus those waiting for the drain. The default "
+            "policy never drops data - use state.backend='spill' for "
+            "exact host-side degradation, raise the slot budget, or "
+            "set state.allow-drops=true to drop with accounting "
+            "(records_dropped_full).")
     op.records_dropped_full += n

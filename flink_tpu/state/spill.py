@@ -10,8 +10,12 @@ lane aggregate is a commutative monoid (sum/max/min/count): records
 whose keys cannot get an HBM slot are aggregated ON THE HOST in
 vectorized numpy, per (key, pane), and the host partials fire
 alongside the device partials. A key lives in
-exactly one store (a key that failed slot allocation once can never be
-resident later — the directory is insert-only), so the two stores'
+exactly one store: the directory enters a key that failed slot
+allocation with that verdict, and it keeps it; and under a spill store
+the window operator releases no slot (on the plain hbm backend a key's
+slot is released when its last pane is purged and handed out again:
+here that could bring a host-resident key a slot and split its panes
+over both stores). So the two stores'
 key sets are disjoint and their fired rows simply concatenate: exact
 results, no cross-store merge. Hot early keys keep HBM speed; overflow
 keys degrade to host speed. (LRU slot eviction — promoting a late-hot

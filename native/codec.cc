@@ -243,6 +243,125 @@ void ht_insert(void* h, const int64_t* keys, const int64_t* vals, int64_t n) {
   }
 }
 
+// Lookup that also CLAIMS what it misses: a key that is absent is
+// entered with the placeholder value HT_PENDING - u, u its index among
+// the batch's distinct misses in first-occurrence order, and written to
+// out_uniq[u]; every record of that key reads the same placeholder. The
+// caller allocates a value for each of the out_uniq keys, stores them
+// (ht_insert updates in place) and resolves the placeholders it was
+// given: one pass over the batch finds the distinct new keys, where a
+// lookup followed by a sort of the missed records (np.unique) cost five
+// times the lookup at ~68,000 new keys a 2^20-record batch. Placeholders
+// never outlive the caller's batch. Returns the number of distinct
+// misses; out_uniq holds room for n.
+static const int64_t HT_PENDING = -16;   // below the callers' sentinels
+
+int64_t ht_lookup_claim(void* h, const int64_t* keys, int64_t n,
+                        int64_t* out_vals, int64_t* out_uniq) {
+  FtHashTable* t = (FtHashTable*)h;
+  int64_t u = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    if ((t->count + 1) * 2 > (int64_t)(t->mask + 1)) ht_grow(t);
+    uint64_t ix = ht_mix((uint64_t)keys[i]) & t->mask;
+    for (;;) {
+      if (!t->used[ix]) {
+        t->keys[ix] = keys[i]; t->vals[ix] = HT_PENDING - u; t->used[ix] = 1;
+        ++t->count;
+        out_vals[i] = HT_PENDING - u;
+        out_uniq[u++] = keys[i];
+        break;
+      }
+      if (t->keys[ix] == keys[i]) { out_vals[i] = t->vals[ix]; break; }
+      ix = (ix + 1) & t->mask;
+    }
+  }
+  return u;
+}
+
+// Batch delete by BACKWARD SHIFT (Knuth 6.4 algorithm R): the hole a
+// deleted entry leaves is filled by the next entry of its run whose
+// home bucket does not lie cyclically after the hole, and so on to the
+// run's end. No tombstones: ``used`` stays a plain occupancy byte, every
+// probe (ht_lookup, ht_insert, scan_range's inline one) is unchanged,
+// and a probe never walks further than a table that never held the
+// deleted keys would make it: run lengths are those of load <= 0.5
+// whatever the number of deletes before. Keys that are absent are
+// skipped. Returns how many were deleted. The table does not shrink.
+int64_t ht_delete(void* h, const int64_t* keys, int64_t n) {
+  FtHashTable* t = (FtHashTable*)h;
+  int64_t gone = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    uint64_t ix = ht_mix((uint64_t)keys[i]) & t->mask;
+    for (;;) {
+      if (!t->used[ix]) { ix = UINT64_MAX; break; }
+      if (t->keys[ix] == keys[i]) break;
+      ix = (ix + 1) & t->mask;
+    }
+    if (ix == UINT64_MAX) continue;
+    uint64_t hole = ix;
+    for (uint64_t j = (hole + 1) & t->mask; t->used[j];
+         j = (j + 1) & t->mask) {
+      const uint64_t home = ht_mix((uint64_t)t->keys[j]) & t->mask;
+      // entry j may move to the hole unless its home lies in (hole, j]
+      if (((j - home) & t->mask) >= ((j - hole) & t->mask)) {
+        t->keys[hole] = t->keys[j];
+        t->vals[hole] = t->vals[j];
+        hole = j;
+      }
+    }
+    t->used[hole] = 0;
+    --t->count;
+    ++gone;
+  }
+  return gone;
+}
+
+// The longest run of occupied buckets (a probe's worst case), for the
+// tests that hold deletes to the bound above.
+int64_t ht_longest_run(void* h) {
+  FtHashTable* t = (FtHashTable*)h;
+  int64_t best = 0, run = 0;
+  // twice around: a run may wrap past the table's end
+  for (uint64_t i = 0; i <= 2 * t->mask + 1 && run <= (int64_t)t->mask; ++i) {
+    if (t->used[i & t->mask]) { if (++run > best) best = run; }
+    else run = 0;
+  }
+  return best;
+}
+
+// newest[slot] = max(newest[slot], pane) for every valid record with a
+// slot (>= 0): what the key directory keeps to tell when a key's last
+// pane has been purged (state/keyed.py KeyDirectory.note_panes).
+void slot_panes_note(int64_t n, const int64_t* slots, const int64_t* panes,
+                     const uint8_t* valid, int64_t* newest) {
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t s = slots[i];
+    if (s >= 0 && valid[i] && panes[i] > newest[s]) newest[s] = panes[i];
+  }
+}
+
+// The same from a fused scan's distinct (slot * ring + column) pairs:
+// the batch's panes lie in [pane_lo, pane_lo + ring), so a column names
+// one pane. Once a distinct pair, not once a record.
+void slot_panes_note_pairs(int64_t np_, const int32_t* pairs, int64_t ring,
+                           int64_t pane_lo, int64_t* newest) {
+  const int64_t lo_col = ((pane_lo % ring) + ring) % ring;
+  const uint64_t r = (uint64_t)ring;
+  // p / r as a multiply and a shift: exact while p * r < 2^40, and a
+  // pair id is under the fused lanes' domain of 2^23 (a division a pair
+  // was most of this pass, which every batch of the fused lanes makes)
+  const bool fast = r < (1u << 16);
+  const uint64_t inv = (UINT64_C(1) << 40) / r + 1;
+  for (int64_t j = 0; j < np_; ++j) {
+    const uint64_t p = (uint32_t)pairs[j];
+    const uint64_t slot = fast && p < (1u << 24) ? (p * inv) >> 40 : p / r;
+    const int64_t col = (int64_t)(p - slot * r);
+    const int64_t pane = pane_lo + (col >= lo_col ? col - lo_col
+                                                  : col + ring - lo_col);
+    if (pane > newest[slot]) newest[slot] = pane;
+  }
+}
+
 // splitmix64 finalizer over a batch (hash_keys_numpy fast path).
 void hash_keys(const int64_t* keys, int64_t n, int64_t* out) {
   for (int64_t i = 0; i < n; ++i)

@@ -877,7 +877,13 @@ class TestLsmChaos:
                     extra={"state.lsm.compact-min-runs": 2})
 
     def test_changelog_link_fault_exactly_once(self, tmp_path):
-        self._drive(tmp_path, "state.changelog.link", after=2)
+        # the second link, not the third: how many checkpoints (1 ms
+        # apart, persisted off the loop) link a run at all depends on
+        # the machine: 4 to 8 in a fault-free run alone, parent and PR 31
+        # alike, and under six busy workers one full run saw fewer than
+        # three, so the fault never fired and the schedule assertion
+        # failed. Two are always there.
+        self._drive(tmp_path, "state.changelog.link", after=1)
 
 
 @pytest.mark.slow

@@ -139,6 +139,41 @@ class TestNativeHashTable:
             assert np.array_equal(f1, f2)
             assert np.array_equal(v1[f1], v2[f2])
             assert t._count == ref._count
+            # delete (backward shift, the same algorithm bucket for
+            # bucket): a third of what was asked for, present or not
+            gone = q[::3]
+            assert t.delete_batch(gone) == ref.delete_batch(gone)
+            v1, f1 = t.lookup_keys(q)
+            v2, f2 = ref.lookup_keys(q)
+            assert np.array_equal(f1, f2) and not f1[::3].any()
+            assert np.array_equal(v1[f1], v2[f2])
+            assert t._count == ref._count
+            # no tombstones: runs as short as the load (<= 0.5) makes them
+            assert max(t.longest_run(), ref.longest_run()) < 40
+
+    def test_lookup_claim_finds_the_distinct_misses_in_one_pass(self):
+        import numpy as np
+        from flink_tpu import native_codec as nc
+
+        t = nc.NativeHashTable.create(16)
+        if t is None:
+            import pytest
+            pytest.skip("codec library unavailable")
+        have = np.arange(0, 400, 2, dtype=np.int64)
+        t.insert_batch(have, None, have + 7)
+        rng = np.random.default_rng(8)
+        q = rng.integers(0, 400, 5_000)
+        vals, uniq = t.lookup_claim(q)
+        odd = q % 2 == 1
+        assert np.array_equal(vals[~odd], q[~odd] + 7)
+        # the misses, once each, in the order they first appear
+        _, first = np.unique(q[odd], return_index=True)
+        assert np.array_equal(uniq, q[odd][np.sort(first)])
+        # every record of a missed key reads that key's placeholder
+        assert np.array_equal(uniq[t.PENDING - vals[odd]], q[odd])
+        t.insert_batch(uniq, None, uniq + 7)       # the caller's half
+        v, f = t.lookup_keys(q)
+        assert f.all() and np.array_equal(v, q + 7)
 
     def test_directory_native_vs_numpy(self):
         import numpy as np

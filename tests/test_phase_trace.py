@@ -25,7 +25,7 @@ from flink_tpu.obs.tracing import PhaseClock, tracer
 from flink_tpu.runtime.driver import FIRE_RECORDS, PHASE_LEAVES
 
 BATCH = 4096
-PHASE_PREFIXES = ("ingest.", "window.", "wm.", "drain.")
+PHASE_PREFIXES = ("ingest.", "window.", "wm.", "drain.", "state.")
 
 
 def q5_job(n_batches=40, sleep_s=0.0, **conf):
