@@ -60,11 +60,11 @@ def bench_state_update(batch: int = 1 << 20, iters: int = 12) -> None:
     import jax.numpy as jnp
 
     # warmup
-    op.state = op._apply_split(op.state, jnp.asarray(sc_host), {})
+    op.state, _ = op._apply_split(op.state, jnp.asarray(sc_host), {})
     jax.block_until_ready(op.state.counts)
     t0 = time.perf_counter()
     for _ in range(iters):
-        op.state = op._apply_split(op.state, jnp.asarray(sc_host), {})
+        op.state, _ = op._apply_split(op.state, jnp.asarray(sc_host), {})
     total = int(op.state.counts[0, 0])  # force full sync
     el = time.perf_counter() - t0
     _line("state_update_ops_per_sec", batch * iters / el, "records/sec",

@@ -10,10 +10,12 @@ TPU-first redesign (SURVEY §6.7, §8): no per-element window lists, no
 timer heap, no per-key callbacks. Three dense kernels over a
 ``(slots, pane_ring)`` accumulator tensor:
 
-- ``apply``: one microbatch → pane index per record → masked scatter
-  add/max/min into (slot, pane) cells. Sliding windows cost ONE write
-  per element (the Table-runtime slicing trick, ref SliceAssigner), not
-  ``size/slide`` writes like the reference's DataStream WindowOperator.
+- ``apply``: one microbatch → pane index per record → the batch
+  combined per (slot, pane) cell on the device (a sort and a segmented
+  add/max/min) → one update per distinct cell. Sliding windows cost ONE
+  write per element (the Table-runtime slicing trick, ref
+  SliceAssigner), not ``size/slide`` writes like the reference's
+  DataStream WindowOperator.
 - ``fire``: a watermark advance makes whole *windows* fireable at once;
   each is a gather of its ``panes_per_window`` ring columns + a
   sum/max/min reduction over the pane axis — vectorized over every key
@@ -68,14 +70,17 @@ def apply_kernel(
     agg: LaneAggregate,
     ring: int,
     dump_row: int,
-) -> PaneState:
+) -> Tuple[PaneState, jax.Array]:
     """Fold one microbatch into pane state (the processElement hot loop,
     batched). The host pre-packs each record's (slot, pane-ring column)
-    into ONE integer — the only per-record value the scatter needs — so
+    into ONE integer — the only per-record value the device needs — so
     ingest ships a single narrow array instead of (slots, timestamps,
     validity) three-wide: fewer host→device bytes per record.
-    Negative = invalid → scatters into the
-    dump row with identity lane values (doubly safe)."""
+    Negative = invalid: the record takes part in nothing. The batch is
+    combined per (slot, ring column) on the device and pane state is
+    updated once per DISTINCT cell (see ``_scatter_panes``). Returns the
+    new state and the int32 pair (distinct cells updated, valid records
+    folded), which no later step donates."""
     valid = packed >= 0
     p = jnp.where(valid, packed, 0)
     rows = jnp.where(valid, p // ring, dump_row).astype(jnp.int32)
@@ -83,17 +88,163 @@ def apply_kernel(
     return _scatter_panes(state, rows, ring_ix, valid, data, agg)
 
 
+NO_CELL = np.iinfo(np.int32).max  # sorts past every real cell key
+
+
+def apply_chunk(batch: int) -> int:
+    """Distinct cells ONE trip of the apply's chunk loop can update,
+    from the batch's shape alone: an eighth of the batch (a batch whose
+    keys recur, the common case, is one trip; a batch of all-distinct
+    cells in one ring column is eight), the whole of a small batch."""
+    return max(batch // 8, min(batch, 1024))
+
+
+def _run_scan(op, heads: jax.Array, x: jax.Array) -> jax.Array:
+    """Inclusive scan of ``op`` over the 1-D ``x`` that starts anew at
+    every element whose ``heads`` flag is set (element 0's must be): at
+    a run's last element, the run's reduction. log2(len) doubling steps
+    of shifted elementwise ops: a float32 sum is added pairwise, by
+    blocks that double back from the run's END, so it is a function of
+    the run's own elements in their order, wherever the run lies in
+    ``x`` (a job cut into other batches or over other devices adds the
+    same floats the same way). ``lax.associative_scan``'s tree is laid
+    over absolute positions, and takes the chip's compiler a minute at
+    2^20."""
+    d = 1
+    while d < x.shape[0]:
+        # an element within d of the front has its flag set by now
+        x = jnp.where(heads, x, op(jnp.concatenate([x[:d], x[:-d]]), x))
+        heads = heads | jnp.concatenate([heads[:d], heads[:-d]])
+        d *= 2
+    return x
+
+
+# a lane family of PaneState: its reduction over a cell's records, and
+# the indexed update that folds the result into the cell
+LANE_OPS = (("sums", jnp.add, "add"), ("maxs", jnp.maximum, "max"),
+            ("mins", jnp.minimum, "min"))
+
+
+def combine_cells(n_rows: int, rows, ring_ix, valid, lanes):
+    """Combine a batch per (row, ring column) cell: two sorts and no
+    scatter. ``lanes``: the lifted (B, width) arrays by the name of
+    their PaneState family (see ``LANE_OPS``). Returns
+
+    - ``cells`` (B,): the batch's DISTINCT cell keys ``ring_ix * n_rows
+      + row`` ascending (column by column, rows ascending within one),
+      then ``NO_CELL``;
+    - ``starts`` (B + 1,): cell j's records are ``starts[j] ..
+      starts[j + 1]`` of the batch sorted by key (its count: their
+      difference);
+    - ``scans``: by family, per lane of its width the ``_run_scan``
+      over the sorted batch, whose element ``starts[j + 1] - 1`` is
+      cell j's reduction;
+    - ``n_cells``, ``n_records``: distinct cells, valid records."""
+    batch = rows.shape[0]
+    key = jnp.where(valid, ring_ix * n_rows + rows, NO_CELL)
+    cols = [l[:, j] for l in lanes.values() for j in range(l.shape[1])]
+    # stable where lanes ride along: a cell's records then keep their
+    # arrival order, so its float sum depends on them alone (_run_scan)
+    key, *cols = lax.sort((key, *cols), num_keys=1, is_stable=len(lanes) > 0)
+    live = key != NO_CELL
+    first = jnp.concatenate(
+        [jnp.ones(1, bool), key[1:] != key[:-1]])
+    head = first & live
+    n_records = jnp.sum(live, dtype=jnp.int32)
+    n_cells = jnp.sum(head, dtype=jnp.int32)
+    scans, at = {}, 0
+    for name, op, _ in LANE_OPS:
+        if name in lanes:
+            w = lanes[name].shape[1]
+            scans[name] = [_run_scan(op, first, c) for c in cols[at:at + w]]
+            at += w
+    # the run heads to the front, in key order: a second sort, whose
+    # payload is each head's position in the sorted batch
+    cells, starts = lax.sort(
+        (jnp.where(head, key, NO_CELL),
+         jnp.where(head, jnp.arange(batch, dtype=jnp.int32), batch)),
+        num_keys=1, is_stable=False)
+    j = jnp.arange(batch + 1, dtype=jnp.int32)
+    starts = jnp.where(
+        j < n_cells, jnp.concatenate([starts, starts[:1]]), n_records)
+    return cells, starts, scans, n_cells, n_records
+
+
+def _update_column(arr, col, rows, vals, kind: str):
+    """``arr[rows, col] <kind>= vals`` for ONE ring column ``col`` and
+    ascending, distinct ``rows`` (out of range = dropped): the column is
+    lifted out of the donated tensor, updated as a 1-D array and put
+    back. A scatter into the whole (rows, ring[, width]) tensor makes
+    XLA copy all of it to a flat layout and back (on the chip two
+    passes over the state a batch); this touches one column of it."""
+    n_rows = arr.shape[0]
+    zero = jnp.zeros((), col.dtype)
+    start = (zero, col) + (zero,) * (arr.ndim - 2)
+    size = (n_rows, 1) + arr.shape[2:]
+    column = lax.dynamic_slice(arr, start, size).reshape(
+        (n_rows,) + arr.shape[2:])
+    column = getattr(column.at[rows], kind)(
+        vals, indices_are_sorted=True, unique_indices=True, mode="drop")
+    return lax.dynamic_update_slice(arr, column.reshape(size), start)
+
+
+def apply_cells(state: PaneState, cells, starts, scans,
+                n_cells) -> Tuple[PaneState, jax.Array]:
+    """Add ``combine_cells``' distinct cells to pane state, ``apply_chunk``
+    of them a trip and one ring column a trip: trips = the sum over the
+    columns the batch names of ceil(its cells / chunk), whatever the
+    batch holds. Returns the state and the trips made."""
+    n_rows = state.counts.shape[0]
+    batch = cells.shape[0]
+    chunk = apply_chunk(batch)
+    lane = jnp.arange(chunk, dtype=jnp.int32)
+    # a slice of `chunk` from any cell index stays inside
+    cells = jnp.concatenate([cells, jnp.full(chunk, NO_CELL, jnp.int32)])
+    starts = jnp.concatenate([starts, jnp.zeros(chunk, jnp.int32)])
+
+    def trip(carry):
+        state, done, trips = carry
+        k = lax.dynamic_slice(cells, (done,), (chunk,))
+        s = lax.dynamic_slice(starts, (done,), (chunk + 1,))
+        col = k[0] // n_rows
+        # the chunk's cells of its first cell's column: a prefix (the
+        # keys ascend, and NO_CELL lies past every column's end)
+        mine = (done + lane < n_cells) & (k < (col + 1) * n_rows)
+        # padding takes distinct rows past the last: uniqueness is true
+        rows = jnp.where(mine, k - col * n_rows, n_rows + lane)
+        last = jnp.maximum(s[1:] - 1, 0)
+        lanes = {name: _update_column(
+                     getattr(state, name), col, rows,
+                     jnp.stack([c[last] for c in scans[name]], axis=1), kind)
+                 for name, _, kind in LANE_OPS if name in scans}
+        counts = _update_column(
+            state.counts, col, rows, jnp.where(mine, s[1:] - s[:-1], 0),
+            "add")
+        return (PaneState(sums=lanes.get("sums"), maxs=lanes.get("maxs"),
+                          mins=lanes.get("mins"), counts=counts),
+                done + jnp.sum(mine, dtype=jnp.int32), trips + 1)
+
+    zero = n_cells - n_cells    # under shard_map: varying as n_cells is
+    state, _, trips = lax.while_loop(
+        lambda c: c[1] < n_cells, trip, (state, zero, zero))
+    return state, trips
+
+
 def _scatter_panes(state, rows, ring_ix, valid, data, agg):
-    s_l, mx_l, mn_l = agg.lift_masked(data, valid)
-    return PaneState(
-        sums=(state.sums.at[rows, ring_ix].add(s_l)
-              if state.sums is not None else None),
-        maxs=(state.maxs.at[rows, ring_ix].max(mx_l)
-              if state.maxs is not None else None),
-        mins=(state.mins.at[rows, ring_ix].min(mn_l)
-              if state.mins is not None else None),
-        counts=state.counts.at[rows, ring_ix].add(valid.astype(jnp.int32)),
-    )
+    """The one place the per-record lanes meet (``apply_kernel``,
+    ``apply_kernel_split``, the mesh's ``apply_shard``): combine the
+    batch per (row, ring column) on the device, then update pane state
+    once per distinct cell with sorted, unique indices. Counts are run
+    lengths; sums are added in float32 by a segmented scan, maxs and
+    mins likewise. The same adds reach the same cells as a scatter of
+    the records would make. Returns (state, [cells, records])."""
+    lifted = zip(LANE_OPS, agg.lift_masked(data, valid))
+    lanes = {name: lane for (name, _, _), lane in lifted
+             if getattr(state, name) is not None}
+    cells, starts, scans, n_cells, n_records = combine_cells(
+        state.counts.shape[0], rows, ring_ix, valid, lanes)
+    state, _ = apply_cells(state, cells, starts, scans, n_cells)
+    return state, jnp.stack([n_cells, n_records])
 
 
 INVALID_SLOT_U16 = 0xFFFF  # sentinel slot for invalid rows in split uploads
@@ -1054,15 +1205,19 @@ def _sharded_kernels(mp: MeshPlan, agg, layout: PaneStateLayout,
             counts=jnp.zeros((total_rows, ring_len), jnp.int32),
         )
 
-    def exchange_report(overflow, records):
+    def exchange_report(overflow, records, cells=None):
         # what the exchange did, for the host to read when the step has
-        # retired (see _resolve_overflow): word 0 the entries the
+        # retired (see _resolve_reports): word 0 the entries the
         # all_to_all dropped over all devices, word 1 + d the RECORDS
-        # device d received and scattered — one psum
-        report = (jnp.zeros(1 + n_dev, jnp.int32)
+        # device d received and scattered and, on the per-record lane,
+        # a last word: the distinct cells the devices' applies updated
+        # (see _scatter_panes) — one psum
+        report = (jnp.zeros(1 + n_dev + (cells is not None), jnp.int32)
                   .at[0].set(jnp.sum(overflow).astype(jnp.int32))
                   .at[1 + lax.axis_index(AXIS)].set(
                       jnp.sum(records).astype(jnp.int32)))
+        if cells is not None:
+            report = report.at[1 + n_dev].set(cells)
         return lax.psum(report, AXIS)
 
     def apply_shard(state, packed, data):
@@ -1084,11 +1239,11 @@ def _sharded_kernels(mp: MeshPlan, agg, layout: PaneStateLayout,
             rvalid,
             (rq // ring_len - my * spd) * ring_len + rq % ring_len,
             -1)
-        new_state = apply_kernel(
+        new_state, applied = apply_kernel(
             state, local_packed,
             {k: v for k, v in recv.items() if not k.startswith("__")},
             agg=agg, ring=ring_len, dump_row=layout.slots)
-        return new_state, exchange_report(overflow, rvalid)
+        return new_state, exchange_report(overflow, rvalid, applied[0])
 
     def lane_spec(width):
         return None if width == 0 else P(AXIS)
@@ -1366,9 +1521,10 @@ class WindowOperator:
         # calling ``throttle()`` outside its push lock (see throttle())
         self.external_throttle = False
         self._inflight = collections.deque()
-        # device scalars from sharded steps, resolved lazily (see
-        # _resolve_overflow) — never block the pipeline per batch
-        self._overflow_markers = collections.deque()
+        # small device outputs of the per-record applies and the
+        # sharded steps, read when their step has retired (see
+        # _resolve_reports) — never block the pipeline per batch
+        self._step_reports = collections.deque()
         # state.backend='spill': keys past HBM capacity aggregate on the
         # host (exact, slower) instead of dropping with a counter; the
         # shared host pool parallelizes its per-pane merges and
@@ -1462,7 +1618,7 @@ class WindowOperator:
         # dispatched (a batch is one chunk unless a capacity splits it),
         # bytes handed to the upload, and the records each mesh device
         # received — counted on the device that scattered them and read
-        # with the overflow word (see _resolve_overflow)
+        # with the overflow word (see _resolve_reports)
         self.exchange_chunks: int = 0
         self.exchange_upload_bytes: int = 0
         # valid entries handed to the all_to_all, padding not counted: a
@@ -1792,8 +1948,14 @@ class WindowOperator:
             dpacked = jnp.asarray(packed)
             ddata = {k: jnp.asarray(v) for k, v in data.items()}
             ph("window.step_dispatch")
-            self.state = (self._apply_split if local_split
-                          else self._apply)(self.state, dpacked, ddata)
+            self.state, report = (
+                self._apply_split if local_split
+                else self._apply)(self.state, dpacked, ddata)
+            # what the apply combined (cells, records), read when the
+            # step has retired; also the in-flight token: an output of
+            # the step that is not donated
+            self._step_reports.append(report)
+            self._note_dispatch(report)
         else:
             n_dev = self.mesh_plan.n_devices
             ov_total = None
@@ -1841,12 +2003,12 @@ class WindowOperator:
                 ov_total = report if ov_total is None else ov_total + report
                 ph("window.exchange_split")   # the next chunk's padding
             if ov_total is not None:
-                self._overflow_markers.append(ov_total)
-        ph("window.step_dispatch")
-        # inflight token: a tiny scalar DERIVED from the new state — the
-        # state buffers themselves are donated to the next step, so
-        # holding them would read deleted buffers
-        self._note_dispatch(self.state.counts[0, 0])
+                self._step_reports.append(ov_total)
+            ph("window.step_dispatch")
+            # inflight token: a tiny scalar DERIVED from the new state —
+            # the state buffers themselves are donated to the next step,
+            # so holding them would read deleted buffers
+            self._note_dispatch(self.state.counts[0, 0])
         self._throttle_unless_external()
 
     def _throttle_unless_external(self) -> None:
@@ -2121,7 +2283,7 @@ class WindowOperator:
         ph("window.step_dispatch")
         # one report a push (see the per-record lane), which is also the
         # in-flight token: an output of the step that is not donated
-        self._overflow_markers.append(report)
+        self._step_reports.append(report)
         self._note_dispatch(report)
 
     def hbm_bytes(self) -> int:
@@ -2149,7 +2311,7 @@ class WindowOperator:
         mp = self.mesh_plan
         if mp is None:
             return None
-        self._resolve_overflow()
+        self._resolve_reports()
         rows = {sh.device: int(sh.data.shape[0])
                 for sh in self.state.counts.addressable_shards}
         return {"chunks": self.exchange_chunks,
@@ -2226,7 +2388,7 @@ class WindowOperator:
         # overflow markers older than the steps just retired are ready
         # (int() is a cheap host read); draining to the same bound keeps
         # the deque finite in jobs that never checkpoint
-        self._resolve_overflow(bound=self.max_inflight_steps)
+        self._resolve_reports(bound=self.max_inflight_steps)
 
     def quiesce(self) -> None:
         """Block until every dispatched step has completed. The driver
@@ -2237,16 +2399,28 @@ class WindowOperator:
         while self._inflight:
             self._retire_step()
         ready_wait(self.state.counts)
-        self._resolve_overflow()
+        self._resolve_reports()
 
-    def _resolve_overflow(self, bound: int = 0) -> None:
-        """Materialize pending exchange-overflow markers (beyond
-        ``bound``) into the counter. With the host-side batch split, any
-        non-zero value is a routing bug — fail loudly, not under-count."""
-        while len(self._overflow_markers) > bound:
-            report = np.asarray(self._overflow_markers.popleft())
-            self.exchange_overflow += int(report[0])
-            self.exchange_records += report[1:]
+    def _resolve_reports(self, bound: int = 0) -> None:
+        """Read the reports of retired steps (all but the newest
+        ``bound``) into the counters: what the per-record apply
+        combined (``profile.opN.apply_cells`` distinct cells updated,
+        ``apply_records`` valid records folded) and, on a mesh, what the
+        exchange did. With the host-side batch split, any non-zero
+        overflow is a routing bug — fail loudly, not under-count."""
+        n_dev = len(self.exchange_records)
+        while len(self._step_reports) > bound:
+            report = np.asarray(self._step_reports.popleft())
+            if self.mesh_plan is None:
+                cells, records = report
+            else:
+                self.exchange_overflow += int(report[0])
+                self.exchange_records += report[1:1 + n_dev]
+                if len(report) == 1 + n_dev:    # the pair lane
+                    continue
+                cells, records = report[1 + n_dev], report[1:1 + n_dev].sum()
+            self.prof["apply_cells"] += int(cells)
+            self.prof["apply_records"] += int(records)
         if self.exchange_overflow:
             raise RuntimeError(
                 f"exchange overflow: {self.exchange_overflow} records "
@@ -3074,7 +3248,7 @@ class WindowOperator:
     def snapshot_state(self) -> Dict[str, Any]:
         # the snapshot must include stashed records
         self._flush_stash()
-        self._resolve_overflow()  # a checkpoint must not hide pending loss
+        self._resolve_reports()  # a checkpoint must not hide pending loss
         spill_snap = (self._spill.snapshot()
                       if self._spill is not None else None)
         # lsm changelog cut: sealed-run files ride the checkpoint as

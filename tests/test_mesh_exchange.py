@@ -335,10 +335,11 @@ def test_float_sums_of_a_high_cardinality_batch_cross_as_records():
     """The per-record lane is still there and chosen by the batch: over
     five panes the batch's pair bound (2,000 of 12 bytes) fails the
     gate, every record crosses the exchange as one entry and its price
-    is added to its pane in float32 in arrival order — bit for bit what
-    ``float_sum_mesh.mesh_lane_sums`` does in numpy — so a sum is off
-    the float64 reference by float32 accumulation, parts in 10^6, not
-    the 10^3 of a rounded dot."""
+    is added to its pane in float32 on the device that owns it — by a
+    segmented add over the records sorted by cell, no worse than the
+    arrival-order float32 add ``float_sum_mesh.mesh_lane_sums`` makes
+    in numpy — so a sum is off the float64 reference by float32
+    accumulation, parts in 10^6, not the 10^3 of a rounded dot."""
     from benchmark.probes import float_sum, float_sum_mesh
 
     res, out, batch, ts = float_sum_job(4, events_per_ms=1)
@@ -347,8 +348,10 @@ def test_float_sums_of_a_high_cardinality_batch_cross_as_records():
     ppw = PARAMS["window_ms"] // PARAMS["slide_ms"]
     ref = float_sum.sliding(float_sum.pane_sums(batch, ts, PARAMS)[0], ppw)
     lane = float_sum_mesh.mesh_lane_sums(batch, ts, PARAMS)
-    assert out["sum_max_rel_err"] == float_sum.gap(
+    in_arrival_order = float_sum.gap(
         float_sum.window_sums_f32(lane, ppw), ref)
+    assert 0 < out["sum_max_rel_err"] <= in_arrival_order \
+        < float_sum_mesh.SUM_RTOL
     assert float_sum.SUM_RTOL < float_sum_mesh.SUM_RTOL
 
 

@@ -103,7 +103,7 @@ def main():
     state = _mk_state(layout)
     import functools
     apply_split = functools.partial(_JIT_APPLY_SPLIT, agg=agg, dump_row=SLOTS)
-    dt, state = time_chain(lambda s, b: apply_split(s, b, {}), state, sc_d)
+    dt, state = time_chain(lambda s, b: apply_split(s, b, {})[0], state, sc_d)
     # traffic floor: read 3B*B input + counts r/w is sparse (<= B cells)
     out["apply_split_ms"] = dt * 1e3
     out["apply_split_Mrec_s"] = B / dt / 1e6
@@ -114,7 +114,7 @@ def main():
     jax.block_until_ready(pk_d)
     apply_p = functools.partial(_JIT_APPLY, agg=agg, ring=RING, dump_row=SLOTS)
     state2 = _mk_state(layout)
-    dt, state2 = time_chain(lambda s, b: apply_p(s, b, {}), state2, pk_d)
+    dt, state2 = time_chain(lambda s, b: apply_p(s, b, {})[0], state2, pk_d)
     out["apply_packed_ms"] = dt * 1e3
     out["apply_packed_Mrec_s"] = B / dt / 1e6
 
