@@ -74,12 +74,17 @@ class _Encoder:
                 self.pickle_escapes += 1
                 return {"__pickle__": base64.b64encode(pickle.dumps(
                     v, protocol=pickle.HIGHEST_PROTOCOL)).decode()}
-            # ascontiguousarray promotes 0-d to (1,) — restore the shape
-            self.arrays.append(np.ascontiguousarray(v).reshape(v.shape))
+            self.arrays.append(_laid_out(v))
+            return {"__nd__": len(self.arrays) - 1}
+        # an array that hands over its C-order bytes piece by piece as
+        # the blob is written (the coordinator's DeviceRows, still on
+        # its device): placed in the array section like any other
+        if hasattr(v, "raw_pieces"):
+            self.arrays.append(v)
             return {"__nd__": len(self.arrays) - 1}
         # jax arrays (avoid importing jax here for tool-side reuse)
         if type(v).__module__.startswith("jax") and hasattr(v, "dtype"):
-            self.arrays.append(np.ascontiguousarray(np.asarray(v)))
+            self.arrays.append(_laid_out(np.asarray(v)))
             return {"__nd__": len(self.arrays) - 1}
         if isinstance(v, tuple):
             return {"__tup__": [self.enc(x) for x in v]}
@@ -110,8 +115,92 @@ def _as_panestate_fields(v: Any):
     return None
 
 
-def encode(payload: Any) -> bytes:
-    """Payload tree → self-describing v3 bytes."""
+def _laid_out(v: np.ndarray) -> np.ndarray:
+    """``v`` as the array section can take it: C-contiguous, or
+    column-major (what a device->host fetch of a TPU's pane tensor
+    hands back: the device's own layout), which ``EncodedBlob`` turns
+    into C order block by block as it writes; any other strides are
+    copied here."""
+    if v.flags.c_contiguous or (v.ndim >= 2 and v.flags.f_contiguous):
+        return v
+    # ascontiguousarray promotes 0-d to (1,) — restore the shape
+    return np.ascontiguousarray(v).reshape(v.shape)
+
+
+# a piece handed to a file at once, and the rows turned into C order at
+# once (a block that stays in cache while its columns are gathered)
+_PIECE_BYTES = 32 << 20
+_TURN_ROWS = 1 << 16
+
+
+class EncodedBlob:
+    """A payload encoded but not yet laid out as ONE bytes object: the
+    prefix (magic, header length, header JSON) and the arrays the header
+    places in the array section. ``write_to`` hands the file each
+    array's raw C-order bytes in pieces of at most ``_PIECE_BYTES``: a
+    C-contiguous array's own buffer, sliced; a column-major one's rows
+    turned into C order in ONE reused buffer; and from an array that
+    brings its own ``raw_pieces`` (one still on its device, which
+    fetches them as they are asked for) whatever it yields, its
+    ``dtype``, ``shape`` and ``nbytes`` in the header. So a checkpoint of a GB of
+    state is written without a second copy of it in host memory, and
+    without the passes over one (each of them seconds of page faults,
+    some with the interpreter lock held) that building the whole blob
+    took. ``tobytes`` is the one-object form, byte for byte what
+    ``write_to`` writes."""
+
+    def __init__(self, prefix: bytes, arrays: List[np.ndarray],
+                 offsets: List[int]) -> None:
+        self.prefix, self.arrays, self.offsets = prefix, arrays, offsets
+        self.nbytes = len(prefix) + (
+            offsets[-1] + arrays[-1].nbytes if arrays else 0)
+
+    @staticmethod
+    def _raw(a: np.ndarray):
+        """``a``'s C-order bytes as buffers; one that is yielded is
+        valid until the next is asked for."""
+        if hasattr(a, "raw_pieces"):
+            yield from a.raw_pieces()
+            return
+        if a.flags.c_contiguous:
+            flat = a.reshape(-1).view(np.uint8)
+            for o in range(0, len(flat), _PIECE_BYTES):
+                yield memoryview(flat[o:o + _PIECE_BYTES])
+            return
+        rows = max(1, _PIECE_BYTES // (a.nbytes // a.shape[0]))
+        buf = np.empty((min(rows, a.shape[0]),) + a.shape[1:], a.dtype)
+        for i in range(0, a.shape[0], rows):
+            block = a[i:i + rows]
+            out = buf[:len(block)]
+            for j in range(0, len(block), _TURN_ROWS):
+                np.copyto(out[j:j + _TURN_ROWS], block[j:j + _TURN_ROWS])
+            yield memoryview(out.reshape(-1).view(np.uint8))
+
+    def _pieces(self):
+        """The blob as buffers, in file order: the prefix, then per array
+        the zero padding up to its aligned offset and its raw C-order
+        bytes (an empty array has none)."""
+        yield self.prefix
+        pos = 0
+        for a, off in zip(self.arrays, self.offsets):
+            if off > pos:
+                yield b"\0" * (off - pos)
+            if a.nbytes:
+                yield from self._raw(a)
+            pos = off + a.nbytes
+
+    def write_to(self, f) -> None:
+        for piece in self._pieces():
+            f.write(piece)
+
+    def tobytes(self) -> bytes:
+        # each piece copied as it comes: the next may reuse its buffer
+        return b"".join(bytes(piece) for piece in self._pieces())
+
+
+def encode_lazy(payload: Any) -> EncodedBlob:
+    """Payload tree -> the self-describing v3 blob, arrays by reference
+    (the caller must not change them before the blob is written)."""
     e = _Encoder()
     tree = e.enc(payload)
     offsets = []
@@ -127,15 +216,13 @@ def encode(payload: Any) -> bytes:
                    for a, off in zip(e.arrays, offsets)],
         "pickle_escapes": e.pickle_escapes,
     }).encode()
-    out = bytearray()
-    out += MAGIC
-    out += struct.pack("<I", len(header))
-    out += header
-    base = len(out)
-    out += b"\0" * (pos if e.arrays else 0)
-    for a, off in zip(e.arrays, offsets):
-        out[base + off:base + off + a.nbytes] = a.tobytes()
-    return bytes(out)
+    return EncodedBlob(MAGIC + struct.pack("<I", len(header)) + header,
+                       e.arrays, offsets)
+
+
+def encode(payload: Any) -> bytes:
+    """Payload tree → self-describing v3 bytes."""
+    return encode_lazy(payload).tobytes()
 
 
 class _Decoder:

@@ -15,42 +15,158 @@ Asynchrony (the HeapSnapshotStrategy async-part analogue, SURVEY §6.4):
 the in-loop part of a checkpoint is only the FREEZE — sink staging plus
 per-operator snapshots whose device leaves are dispatched on-device
 clones (no device→host transfer, no serialization). The expensive part
-— fetching the clones to host, pickling, writing, fsync — runs on a
+— fetching the clones to host, encoding, writing, fsync — runs on a
 background thread via ``trigger_async``; the 2PC commit happens only
 after the manifest is durable, applied back on the loop thread when it
 polls ``PendingCheckpoint`` (the asynchronous notifyCheckpointComplete
 of the reference). Ingest never waits on storage.
+
+Where the time goes is on the run's ``PhaseClock`` (``phases``; the
+driver puts its own there), a leaf a part: on the caller's thread
+``ingest.checkpoint_stage`` (the sinks stage their epoch) and
+``ingest.checkpoint_snapshot`` (the snapshot tree; an operator times its
+own parts inside it), on the executor's ``persist.fetch`` (device →
+host of the small leaves; a large pane tensor stays on its device as
+``DeviceRows``), ``persist.encode`` (blob headers; the arrays stay where
+they are) and ``persist.write`` (arrays to the files from their own
+buffers, a ``DeviceRows`` fetched piece by piece as it is written,
+fsyncs, manifest last, rename; the waits for those pieces are
+``persist.fetch`` intervals inside it).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import pickle
 import time
 from concurrent.futures import Future
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from flink_tpu.checkpoint.storage import (
     CheckpointHandle, FsCheckpointStorage, ReusedOpState)
+from flink_tpu.obs.tracing import PhaseClock
 
 
-def materialize_snapshot(obj: Any) -> Any:
+# a two-axis device leaf at least this large on ONE device is laid out
+# row-major there, this many rows a trip, and comes to the host piece by
+# piece as its blob is written (DeviceRows)
+ROW_MAJOR_ON_DEVICE_MIN_BYTES = 64 << 20
+_ROW_MAJOR_BLOCK_ROWS = 1 << 18
+# under the 32 MB above which glibc maps every allocation anew: a
+# piece's host buffer is then memory the one before it gave back
+_FETCH_PIECE_BYTES = 8 << 20
+
+
+@jax.jit
+def _row_major(x: jax.Array) -> jax.Array:
+    """``x`` ``[rows, cols]`` as the flat array of its C-order
+    elements. Block by block: turned in one piece, a tensor of few
+    columns passes through a layout that pads them to a tile's 128
+    lanes (8.6 GB for 16.8 M rows x 12); a block's share of that is
+    134 MB."""
+    rows, cols = x.shape
+    block = min(_ROW_MAJOR_BLOCK_ROWS, rows)
+    whole = rows // block
+
+    def turn(i, out):
+        piece = jax.lax.dynamic_slice(x, (i * block, 0), (block, cols))
+        return jax.lax.dynamic_update_slice(
+            out, piece.reshape(-1), (i * block * cols,))
+
+    out = jax.lax.fori_loop(
+        0, whole, turn, jnp.zeros((rows * cols,), x.dtype))
+    if rows % block:
+        out = jax.lax.dynamic_update_slice(
+            out, x[whole * block:].reshape(-1), (whole * block * cols,))
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _piece(flat: jax.Array, start, n: int) -> jax.Array:
+    return jax.lax.dynamic_slice(flat, (start,), (n,))
+
+
+class DeviceRows:
+    """A large two-axis leaf of a snapshot that STAYS on its device
+    until its blob is written, and then crosses piece by piece
+    (``blobformat`` asks ``raw_pieces`` for an array's C-order bytes).
+
+    Why not one fetch: it hands back the device's own layout (a TPU
+    keeps a pane tensor ``[rows, ring]`` column-major), so the host had
+    to turn every byte into C order (numpy, ~0.5 s a 0.8 GB tensor), and
+    it lands in 0.8 GB of memory never touched before: 200 k page
+    faults and as many pages zeroed, beside a loop that lives on the
+    host's memory and stood at half speed meanwhile. Here the device
+    lays the leaf out row-major (``_row_major``: some ten ms on a device
+    that waits for the host anyway, a second buffer of the leaf's size
+    until the blob is written), and pieces of ``_FETCH_PIECE_BYTES``
+    are cut from that, fetched one ahead of the one being written, each
+    into memory the one before it gave back. The bytes are the ones the
+    whole fetch and the host's turn gave."""
+
+    def __init__(self, x: jax.Array) -> None:
+        self.shape, self.dtype = tuple(x.shape), np.dtype(x.dtype)
+        self.size = int(np.prod(self.shape))
+        self.nbytes = self.size * self.dtype.itemsize
+        self._flat = _row_major(x)
+        # the run's clock, where the coordinator has one: a wait for a
+        # piece is a persist.fetch interval of the writing thread
+        self.phases: Optional[PhaseClock] = None
+
+    @staticmethod
+    def wanted(x: Any) -> bool:
+        return (x.ndim == 2 and x.nbytes >= ROW_MAJOR_ON_DEVICE_MIN_BYTES
+                and len(x.sharding.device_set) == 1)
+
+    def raw_pieces(self):
+        """The C-order bytes as buffers, in order; one that is yielded
+        is valid until the next is asked for."""
+        n = min(self.size, max(1, _FETCH_PIECE_BYTES // self.dtype.itemsize))
+        # the last piece starts early enough to be whole, and its head
+        # (bytes the piece before it held) is dropped here
+        starts = [min(o, self.size - n) for o in range(0, self.size, n)]
+
+        def ask(i):
+            p = _piece(self._flat, starts[i], n)
+            p.copy_to_host_async()
+            return p
+
+        ahead = ask(0) if starts else None
+        for i, start in enumerate(starts):
+            cur, ahead = ahead, (ask(i + 1) if i + 1 < len(starts)
+                                 else None)
+            with (self.phases.span("persist.fetch") if self.phases
+                  else contextlib.nullcontext()):
+                host = np.asarray(cur)
+            yield memoryview(host[i * n - start:].view(np.uint8))
+
+
+def materialize_snapshot(obj: Any, lazy: Optional[List[DeviceRows]] = None
+                         ) -> Any:
     """Recursively fetch device leaves of a frozen snapshot to host.
     Runs on the BACKGROUND thread — the freeze left cloned jax arrays in
-    the tree precisely so this transfer leaves the hot loop."""
+    the tree precisely so this transfer leaves the hot loop. With a
+    ``lazy`` list, a leaf that ``DeviceRows`` wants becomes one (and is
+    appended to the list): it crosses when its blob is written."""
     if isinstance(obj, jax.Array):
+        if lazy is not None and DeviceRows.wanted(obj):
+            lazy.append(DeviceRows(obj))
+            return lazy[-1]
         return jax.device_get(obj)
     if isinstance(obj, dict):
-        return {k: materialize_snapshot(v) for k, v in obj.items()}
+        return {k: materialize_snapshot(v, lazy) for k, v in obj.items()}
     if isinstance(obj, tuple):
-        return tuple(materialize_snapshot(v) for v in obj)
+        return tuple(materialize_snapshot(v, lazy) for v in obj)
     if isinstance(obj, list):
-        return [materialize_snapshot(v) for v in obj]
+        return [materialize_snapshot(v, lazy) for v in obj]
     if dataclasses.is_dataclass(obj) and not isinstance(obj, type):
         return dataclasses.replace(obj, **{
-            f.name: materialize_snapshot(getattr(obj, f.name))
+            f.name: materialize_snapshot(getattr(obj, f.name), lazy)
             for f in dataclasses.fields(obj)})
     return obj
 
@@ -130,6 +246,8 @@ class CheckpointStats:
 class CheckpointCoordinator:
     def __init__(self, storage: FsCheckpointStorage) -> None:
         self.storage = storage
+        # a driver puts its run's clock here; a bare coordinator's own
+        self.phases = PhaseClock()
         self._next_id = 1
         self.stats: List[CheckpointStats] = []
 
@@ -173,13 +291,17 @@ class CheckpointCoordinator:
         # checkpoint spans (ref: CheckpointStatsTracker reporting
         # checkpointing spans through the trace reporters, SURVEY §6.1):
         # 'checkpoint.freeze' = the sync part stalling the loop,
-        # 'checkpoint.persist' = the async upload — the two durations
-        # that matter are separate spans, not one blended number
+        # 'checkpoint.persist' = the async upload (persist.fetch,
+        # persist.encode, persist.write) — the two durations that matter
+        # are separate spans, not one blended number
+        phases = self.phases
         with tracer.span("checkpoint.freeze", checkpoint_id=cid,
                          savepoint=savepoint):
-            for p in prepare_fns:
-                p(cid)
-            payload = snapshot_fn()
+            with phases.span("ingest.checkpoint_stage"):
+                for p in prepare_fns:
+                    p(cid)
+            with phases.span("ingest.checkpoint_snapshot"):
+                payload = snapshot_fn()
         payload["checkpoint_id"] = cid
         end_cell: List[Optional[float]] = [None]
 
@@ -196,26 +318,38 @@ class CheckpointCoordinator:
                                 checkpoint_id=cid)
                     from flink_tpu.fs import enospc_retry
 
-                    mat = materialize_snapshot(payload)
-                    ops = mat.pop("operators", None)
+                    # the operators' large device leaves stay where
+                    # they are until their blob is written (DeviceRows)
+                    waiting: List[DeviceRows] = []
+                    with phases.span("persist.fetch"):
+                        ops = payload.get("operators")
+                        mat = materialize_snapshot({
+                            k: v for k, v in payload.items()
+                            if k != "operators"})
+                        if ops is not None:
+                            ops = materialize_snapshot(ops, waiting)
+                        for rows in waiting:
+                            rows.phases = phases
                     if ops is None:
                         # whole-save ENOSPC retry (storage.enospc-
                         # policy=retry): each attempt writes a FRESH
                         # unique tmp dir, so a failed attempt leaves
                         # only sweepable debris — retention freeing
                         # space between attempts is the degrade path
-                        h = enospc_retry(lambda: self.storage.save(
-                            cid, mat, savepoint=savepoint))
+                        with phases.span("persist.write"):
+                            h = enospc_retry(lambda: self.storage.save(
+                                cid, mat, savepoint=savepoint))
                     else:
-                        blobs: Dict[str, bytes] = {}
+                        blobs: Dict[str, Any] = {}
                         reuse: Dict[str, ReusedOpState] = {}
                         op_aux: Dict[str, Dict[str, str]] = {}
                         from flink_tpu.checkpoint import blobformat
 
-                        for nid, snap in ops.items():
-                            if isinstance(snap, ReusedOpState):
-                                reuse[str(nid)] = snap
-                            else:
+                        with phases.span("persist.encode"):
+                            for nid, snap in ops.items():
+                                if isinstance(snap, ReusedOpState):
+                                    reuse[str(nid)] = snap
+                                    continue
                                 # changelog plane (lsm runs): the files
                                 # named here ride as hardlinks, never
                                 # through the serializer
@@ -224,11 +358,15 @@ class CheckpointCoordinator:
                                     if aux:
                                         op_aux[str(nid)] = aux
                                 # self-describing v3 blob, not pickle
-                                # (schema evolution; SURVEY §3.1)
-                                blobs[str(nid)] = blobformat.encode(snap)
-                        h = enospc_retry(lambda: self.storage.save_v2(
-                            cid, mat, blobs, reuse, savepoint=savepoint,
-                            op_aux=op_aux))
+                                # (schema evolution; SURVEY §3.1): the
+                                # header now, the arrays from their own
+                                # buffers when storage writes the file
+                                blobs[str(nid)] = blobformat.encode_lazy(
+                                    snap)
+                        with phases.span("persist.write"):
+                            h = enospc_retry(lambda: self.storage.save_v2(
+                                cid, mat, blobs, reuse,
+                                savepoint=savepoint, op_aux=op_aux))
                     psp.set("bytes", getattr(h, "size_bytes", None))
                     return h
             finally:

@@ -225,7 +225,7 @@ class FsCheckpointStorage:
                                 epoch=self.epoch, size_bytes=_dir_size(d))
 
     def save_v2(self, checkpoint_id: int, meta_payload: Dict[str, Any],
-                op_blobs: Dict[str, bytes],
+                op_blobs: Dict[str, Any],
                 op_reuse: Dict[str, "ReusedOpState"],
                 savepoint: bool = False,
                 op_aux: Optional[Dict[str, Dict[str, str]]] = None
@@ -252,7 +252,7 @@ class FsCheckpointStorage:
             fn = f"op-{nid}.blob"
             with open_write_sync(self.fs, os.path.join(tmp, fn),
                                  sync=True) as f:
-                f.write(self._pack(blob))
+                self._write_blob(f, blob)
             op_files[nid] = fn
             versions[nid] = meta_payload.get(
                 "op_versions", {}).get(nid, -1)
@@ -416,6 +416,18 @@ class FsCheckpointStorage:
 
     def _pack(self, raw: bytes) -> bytes:
         return zlib.compress(raw, 6) if self.compression == "zlib" else raw
+
+    def _write_blob(self, f, blob) -> None:
+        """An operator's blob into its open file: ``bytes``, or a
+        ``blobformat.EncodedBlob``, whose arrays go to the file from
+        their own buffers when nothing has to see the blob whole
+        (compression does)."""
+        if isinstance(blob, (bytes, bytearray)):
+            f.write(self._pack(blob))
+        elif self.compression == "none":
+            blob.write_to(f)
+        else:
+            f.write(self._pack(blob.tobytes()))
 
     def _retire_old(self) -> None:
         """Best-effort retention: a retire/sweep failure must never fail

@@ -960,6 +960,19 @@ def ring_remap_kernel(state: PaneState, src: jax.Array,
 # input, so XLA could never reuse the buffers anyway (it would only warn)
 _JIT_RING_REMAP = jax.jit(ring_remap_kernel)
 
+
+def snapshot_clone_kernel(state: PaneState) -> PaneState:
+    """A copy of every pane tensor in buffers of its own: what a
+    checkpoint's freeze keeps while later steps donate ``state``'s. One
+    program with a name (``jit_snapshot_clone_kernel``), so a device
+    trace can be searched for it; it reads and writes the tensors once,
+    as laid out. The input is not donated and ``copy`` is an operation
+    of the program, so the output never aliases it."""
+    return jax.tree_util.tree_map(jnp.copy, state)
+
+
+_JIT_SNAPSHOT_CLONE = jax.jit(snapshot_clone_kernel)
+
 # catch-up fires are evaluated in chunks of this many windows so they
 # reuse the steady-state compiled kernels (pow2 pads: 1,2,4) and keep
 # each packed buffer bounded — device→host bandwidth is the emit ceiling
@@ -1479,6 +1492,9 @@ class WindowOperator:
         # never read a version older than the rows it must deliver.
         self._ring_versions: collections.deque = collections.deque(maxlen=4)
         self._ring_version_no = 0
+        # the version the drain fetched last: a periodic poll gains
+        # nothing from that one or an older one (see _fetch_ring_version)
+        self._ring_read_no = 0
         # fire-cohort bookkeeping (the driver's "trace.fires" records
         # and emit_latency_ms): a (ring_version, cohort) entry per
         # row-carrying fire — the cohort (_fire_cohort) holds its window
@@ -1542,6 +1558,9 @@ class WindowOperator:
         # so host.parallelism = 1 (or no pool) is the serial scan
         self._scan_threads = (host_pool.parallelism
                               if host_pool is not None else 1)
+        # a snapshot copies the directory's slot-sized arrays in ranges
+        # through the shared pool
+        self._host_pool = host_pool
         # top-n + spill: host rows can't ride per-fire markers because
         # device rows flow through the SHARED emit ring (a coalesced
         # drain would re-rank against the wrong fires). They queue here
@@ -2712,7 +2731,11 @@ class WindowOperator:
                 "state.slots_returned_early": self.slots_returned_early,
                 "state.live_keys": d.num_keys(),
                 "state.live_keys_peak": d.keys_peak,
-                "state.slots_waiting_peak": self.slots_waiting_peak}
+                "state.slots_waiting_peak": self.slots_waiting_peak,
+                # the pane tensors' geometry on one device: what a byte
+                # model of a program over the whole state needs
+                "state.pane_rows": self.layout.rows,
+                "state.ring_columns": self.plan.ring}
 
     def _fused_fill_header(self, wm: int, ends: List[int],
                            buf: np.ndarray) -> Optional[Tuple[List[int], int]]:
@@ -3012,9 +3035,16 @@ class WindowOperator:
         landed — never park behind the in-flight compute of a
         just-dispatched fire (a barrier's rows must be present, hence
         ``need``) — or ``(None, None)`` when an opportunistic poll finds
-        nothing announced."""
+        nothing announced. A version this drain has read already holds
+        no row it has not seen: an opportunistic poll passes it over,
+        and where only such versions have landed it waits for the OLDEST
+        it has not read (the soonest) instead of reading nothing. With
+        a fire that outlasts the drain's deferral (~0.1 s over 16.8 M
+        rows) and the next poll a window's slide away, reading the
+        version before it held the fired rows back by that slide."""
+        floor = max(need, self._ring_read_no + 1) if opportunistic else need
         acceptable = [(no, arr_) for no, arr_ in
-                      self._ring_versions if no >= need]
+                      self._ring_versions if no >= floor]
         target = None
         no_read = None
         for no, cand in reversed(acceptable):
@@ -3040,6 +3070,7 @@ class WindowOperator:
             self._last_announce = time.perf_counter()
             self._rows_bound_since_announce = 0
         ready_wait(target)
+        self._ring_read_no = max(self._ring_read_no, no_read)
         return np.asarray(target), no_read         # ONE round trip
 
     def drain_ring(self, min_no: Optional[int] = None) -> Dict[str, np.ndarray]:
@@ -3257,23 +3288,27 @@ class WindowOperator:
         # op_aux plane (checkpoint/storage.py save_v2)
         aux_files = (spill_snap.pop("aux_files", None)
                      if isinstance(spill_snap, dict) else None)
+        # on-device CLONE, not a fetch: the freeze stays in-loop and
+        # cheap; the checkpoint executor's fetch (persist.fetch) does the
+        # device→host transfer off the hot path (SURVEY §6.4 async
+        # snapshot part). A clone is required — later steps DONATE
+        # self.state's buffers, so holding the refs would read deleted
+        # buffers. One program, jit_snapshot_clone_kernel; this leaf is
+        # its DISPATCH, the device runs it behind the steps in flight.
+        with self.phases.span("state.snapshot_clone"):
+            panes = _JIT_SNAPSHOT_CLONE(self.state)
+        # slots waiting on the reuse rule go into the snapshot as free
+        # ones: a checkpoint flushes the emits first, and a restore
+        # starts a new emit ring, so no row of the snapshot's timeline
+        # can name them any more
+        with self.phases.span("state.snapshot_directory"):
+            directory = self._directory_snapshot()
         out = {
             "spill": spill_snap,
             "n_dev": self.mesh_plan.n_devices if self.mesh_plan else 1,
             "ring": self.plan.ring,
-            # on-device CLONE, not a fetch: the freeze stays in-loop and
-            # cheap; the checkpoint executor's materialize pass does the
-            # device→host transfer off the hot path (SURVEY §6.4 async
-            # snapshot part). A clone is required — later steps DONATE
-            # self.state's buffers, so holding the refs would read
-            # deleted buffers.
-            "panes": jax.tree_util.tree_map(
-                lambda x: jnp.array(x, copy=True), self.state),
-            # slots waiting on the reuse rule go into the snapshot as
-            # free ones: a checkpoint flushes the emits first, and a
-            # restore starts a new emit ring, so no row of the snapshot's
-            # timeline can name them any more
-            "directory": self._directory_snapshot(),
+            "panes": panes,
+            "directory": directory,
             "watermark": self.watermark,
             "cleared_below": self._cleared_below,
             "fired_below_end": self._fired_below_end,
@@ -3288,7 +3323,8 @@ class WindowOperator:
         return out
 
     def _directory_snapshot(self) -> Dict[str, np.ndarray]:
-        out = self.directory.snapshot()
+        out = self.directory.snapshot(
+            None if self._host_pool is None else self._host_pool.run_tasks)
         if self._waiting:
             out["free_slots"] = np.concatenate(
                 [out["free_slots"]] + [sl for _no, sl in self._waiting])

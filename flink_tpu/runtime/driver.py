@@ -55,6 +55,23 @@ PHASE_LEAVES = {
     "fire": ("window.fire_dispatch",),
 }
 
+# a checkpoint's freeze on the loop's thread: the trigger and what no
+# other leaf names (ingest.checkpoint), the barrier on the drain
+# (_flush_emits), the sinks' staging, the snapshot tree, and inside it
+# the window operator's device clone (its dispatch) and directory copy.
+# Their sum over the loop's wall is ``checkpoint.loop_share``. The
+# persist runs beside the loop on the checkpoint executor's thread:
+# persist.fetch, persist.encode, persist.write (checkpoint/coordinator.py)
+CHECKPOINT_FREEZE_LEAVES = (
+    "ingest.checkpoint", "ingest.checkpoint_flush",
+    "ingest.checkpoint_stage", "ingest.checkpoint_snapshot",
+    "state.snapshot_clone", "state.snapshot_directory")
+CHECKPOINT_PERSIST_LEAVES = ("persist.fetch", "persist.encode",
+                             "persist.write")
+CHECKPOINT_COUNTERS = (
+    "checkpoint.triggered", "checkpoint.completed", "checkpoint.failed",
+    "checkpoint.aborted", "checkpoint.bytes_last", "checkpoint.bytes_total")
+
 Batch = Tuple[Dict[str, np.ndarray], np.ndarray, np.ndarray]  # data, ts, valid
 
 
@@ -471,7 +488,18 @@ class Driver:
             # not clobber its successor's checkpoints (see
             # FsCheckpointStorage._check_fence); 0 = local unfenced
             epoch=int(self.config.get_raw("cluster.attempt", 0)))
-        return CheckpointCoordinator(storage)
+        coordinator = CheckpointCoordinator(storage)
+        found = None if restore or nproc > 1 else storage.latest()
+        if found is not None:
+            # an earlier job of this name wrote here and this one does
+            # not restore from it: its own checkpoints are numbered past
+            # what it found, since retention keeps the newest ids and a
+            # chk-1 beside an older job's chk-7 would be retired as it
+            # landed. (A restore resumes the numbering itself; across
+            # processes the ids must stay aligned, so each starts at 1.)
+            coordinator.resume_numbering(
+                {"checkpoint_id": found.checkpoint_id})
+        return coordinator
 
     def _snapshot(self, allow_reuse: bool = True) -> Dict[str, Any]:
         from flink_tpu.checkpoint.storage import ReusedOpState
@@ -682,7 +710,11 @@ class Driver:
         """In-loop freeze + background persistence kickoff. The only
         loop-thread work is the emit flush, sink staging, and the
         snapshot freeze (device leaves are dispatched on-device clones);
-        fetching/serializing/writing runs on the checkpoint executor."""
+        fetching/serializing/writing runs on the checkpoint executor.
+        Each part is a leaf of the run's clock
+        (``CHECKPOINT_FREEZE_LEAVES``); what none of them names stays in
+        the caller's ``ingest.checkpoint``."""
+        self.metrics["checkpoint.triggered"] += 1
         # barrier part 1: in-flight async-I/O batches are NOT in the
         # snapshot (their source positions already advanced) — drain
         # them downstream first so the checkpoint covers their effects
@@ -691,7 +723,10 @@ class Driver:
                 if self.plan.node(nid).kind == "async_io":
                     for b in op.poll(drain=True):
                         self._push_downstream(nid, b)
-        self._flush_emits()  # barrier: staged epoch must be complete
+        # barrier: staged epoch must be complete. A fire in flight is
+        # waited for here, on the loop
+        with self.phases.span("ingest.checkpoint_flush"):
+            self._flush_emits()
         sinks = [n.sink for n in self.plan.nodes.values() if n.kind == "sink"]
         commit_fns = [s.notify_checkpoint_complete for s in sinks]
         commit_fns.extend(self._source_offset_committers())
@@ -1026,7 +1061,7 @@ class Driver:
                 if st.pending is not None:
                     # end of input doubles as the final barrier: every
                     # process reached it, so the last cut is global
-                    st.pending.complete()
+                    self._count_completed(st.pending.complete())
                     self._ckpt_pending = None
                 return
 
@@ -1076,7 +1111,7 @@ class Driver:
         if (st.pending is not None
                 and all(int(m.get("persisted", -1)) >= st.pending_id
                         for m in metas)):
-            st.pending.complete()
+            self._count_completed(st.pending.complete())
             self._ckpt_pending = None
             st.pending = None
         ckpt_req = (not absorb) and any(bool(m.get("ckpt")) for m in metas)
@@ -1263,11 +1298,19 @@ class Driver:
         p = self._ckpt_pending
         if p is None:
             return None
-        if not wait and not p.done():
-            return None
+        if not p.done():
+            if not wait:
+                return None
+            # a synchronous checkpoint (the job's last, a savepoint):
+            # the loop's thread stands until the persist is durable
+            from concurrent.futures import wait as _fwait
+
+            with self.phases.span("ingest.checkpoint_wait"):
+                _fwait([p.future])
         try:
             handle = p.complete()
         except Exception as e:  # noqa: BLE001 — persist/commit failure
+            self.metrics["checkpoint.failed"] += 1
             self._ckpt_pending = None
             if p.is_savepoint:
                 # savepoints neither count toward nor reset the
@@ -1294,6 +1337,7 @@ class Driver:
             # not reset the consecutive-periodic counter either
             self._ckpt_failures = 0
         self._ckpt_pending = None
+        self._count_completed(handle)
         if not p.is_savepoint:
             names = handle.op_files or {}
             aux_names = handle.op_aux or {}
@@ -1309,6 +1353,13 @@ class Driver:
                         if aux_names.get(str(nid))},
             }
         return handle
+
+    def _count_completed(self, handle) -> None:
+        """A checkpoint is durable and its 2PC epoch committed."""
+        size = max(handle.size_bytes, 0)
+        self.metrics["checkpoint.completed"] += 1
+        self.metrics["checkpoint.bytes_last"] = size
+        self.metrics["checkpoint.bytes_total"] += size
 
     # -- run loop --------------------------------------------------------
     def run(self, job_name: str = "job", cancel=None,
@@ -1432,6 +1483,10 @@ class Driver:
         self.phases = PhaseClock()
         for op in self._ops.values():
             op.phases = self.phases
+        if self._coordinator is not None:
+            self._coordinator.phases = self.phases
+            for k in CHECKPOINT_COUNTERS:
+                self.metrics.setdefault(k, 0)
         self._fires.clear()
         # per-op device profiling window (pipeline.profile-dir): wraps
         # N warm driver steps in jax.profiler.trace and reduces the
@@ -1482,6 +1537,7 @@ class Driver:
             # crash between manifest and commit.
             if getattr(self, "_ckpt_pending", None) is not None:
                 self._ckpt_pending.abandon()
+                self.metrics["checkpoint.aborted"] += 1
                 # bounded wait for a persist already running: the next
                 # attempt may reuse this checkpoint id, and two live
                 # writers on one id is the corruption the unique tmp
@@ -1757,8 +1813,13 @@ class Driver:
         # land (bounded inputs can finish before the next loop pass)
         self._maybe_take_savepoint()
         if self._coordinator is not None and interval_ms > 0:
-            self.checkpoint_now()  # final epoch commit for 2PC sinks
-            # (completes any pending background checkpoint first)
+            # final epoch commit for 2PC sinks (completes any pending
+            # background checkpoint first). Its freeze, and the wait for
+            # its persist, are the loop thread's too: its leaves extend
+            # the loop's wall, so they still sum to it
+            t_last = self.phases.phase("ingest.checkpoint")
+            self.checkpoint_now()
+            self._loop_wall_s += self.phases.stop() - t_last
         else:
             # bounded job WITHOUT checkpointing: transactional sinks
             # still owe a final commit — end of input is the terminal
@@ -1844,11 +1905,6 @@ class Driver:
             self.metrics["state.reuse_share"] = (
                 self.metrics["state.slots_reused"]
                 / self.metrics["state.slots_allocated"])
-        if self.metrics.get("state.slots_allocated"):
-            # allocations served from a released slot, as a share
-            self.metrics["state.reuse_share"] = (
-                self.metrics["state.slots_reused"]
-                / self.metrics["state.slots_allocated"])
         final = dict(self.metrics)
         final.update(self.registry.snapshot())
         # the per-phase breakdown (dispatch/throttle/drain/advance/fire)
@@ -1866,6 +1922,16 @@ class Driver:
             final[f"profile.phase.{leaf}"] = round(st["seconds"], 6)
             final[f"profile.phase.{leaf}.n"] = st["count"]
             final[f"profile.phase.longest_ms.{leaf}"] = st["longest_ms"]
+        if self._coordinator is not None:
+            # per-checkpoint values are these over checkpoint.completed
+            freeze_s, persist_s = (
+                sum(leaves[n]["seconds"] for n in names if n in leaves)
+                for names in (CHECKPOINT_FREEZE_LEAVES,
+                              CHECKPOINT_PERSIST_LEAVES))
+            final["checkpoint.freeze_s"] = round(freeze_s, 6)
+            final["checkpoint.persist_s"] = round(persist_s, 6)
+            if self._loop_wall_s > 0:
+                final["checkpoint.loop_share"] = freeze_s / self._loop_wall_s
         worst = max(leaves.values(), key=lambda st: st["longest_ms"],
                     default={"longest_ms": 0.0, "longest_at_s": 0.0})
         final["profile.phase.longest_ms"] = worst["longest_ms"]

@@ -21,6 +21,8 @@ at the batch's advance, on one device and on a mesh of four, to the
 plain numpy answer — the guard under which the encodings and the two
 fire programs can be collapsed.
 """
+import threading
+
 import jax
 import numpy as np
 import pytest
@@ -99,10 +101,15 @@ def reference_rows(stream):
 
 class Spy:
     """Records, in order, the batches the operator takes and the
-    programs and fire paths it runs."""
+    programs and fire paths it runs: those of the calling thread, which
+    is the job's loop (``env.execute`` runs it on its caller). The
+    patches are class- and module-wide, so a job that another thread of
+    the process still runs would otherwise number its batches here too
+    (one whole tier-1 run of PR 34 failed four cases so)."""
 
     def __init__(self, monkeypatch):
         self.events = []
+        self._thread = threading.get_ident()
         for name, label in (("_JIT_PREAGG_U16", "apply_u16"),
                             ("_JIT_PREAGG_U32", "apply_u32"),
                             ("_JIT_PREAGG_I32", "apply_i32"),
@@ -123,7 +130,7 @@ class Spy:
 
         def advance_fused(op, wm, ends):
             out = fused(op, wm, ends)
-            if out is not None:
+            if out is not None and threading.get_ident() == self._thread:
                 self.events.append(("fused_fire", len(ends)))
             return out
 
@@ -131,9 +138,11 @@ class Spy:
 
     def _noting(self, fn, label):
         def wrapped(*args, **kwargs):
-            event = label(*args, **kwargs) if callable(label) else label
-            if event is not None:
-                self.events.append(event)
+            if threading.get_ident() == self._thread:
+                event = (label(*args, **kwargs) if callable(label)
+                         else label)
+                if event is not None:
+                    self.events.append(event)
             return fn(*args, **kwargs)
         return wrapped
 
