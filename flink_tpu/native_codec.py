@@ -160,6 +160,12 @@ def _bind(lib, i64p, f32p) -> None:
     lib.ht_lookup_claim.restype = ctypes.c_int64
     lib.ht_lookup_claim.argtypes = [
         ctypes.c_void_p, i64p, ctypes.c_int64, i64p, i64p]
+    lib.ht_assign.restype = None
+    lib.ht_assign.argtypes = [
+        ctypes.c_void_p, i64p, ctypes.c_int64, i64p, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, i64p, i64p,
+        np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"), i64p, u8p,
+        i64p, i64p]
     lib.ht_delete.restype = ctypes.c_int64
     lib.ht_delete.argtypes = [ctypes.c_void_p, i64p, ctypes.c_int64]
     lib.ht_longest_run.restype = ctypes.c_int64
@@ -370,6 +376,11 @@ def hash_keys_native(keys: np.ndarray) -> Optional[np.ndarray]:
     return out
 
 
+#: records between two looks of ``NativeHashTable.assign``'s memo at its
+#: own hit share (codec.cc MEMO_STRETCH)
+MEMO_STRETCH = 4096
+
+
 class NativeHashTable:
     """int64 → int64 open-addressing table in C (the KeyDirectory probe
     loop; ref role: CopyOnWriteStateMap.get/put batched). Interface
@@ -380,6 +391,10 @@ class NativeHashTable:
     def __init__(self, lib, capacity_hint: int) -> None:
         self._lib = lib
         self._h = lib.ht_new(capacity_hint)
+        # assign's: the slots a call handed out (grows to the longest
+        # batch) and its four counts
+        self._handed_out = np.empty(0, np.int64)
+        self._stats = np.zeros(4, np.int64)
 
     @classmethod
     def create(cls, capacity_hint: int = 1024) -> Optional["NativeHashTable"]:
@@ -411,6 +426,7 @@ class NativeHashTable:
         self._lib.ht_insert(self._h, keys, vals, len(keys))
 
     PENDING = -16   # codec.cc HT_PENDING
+    _NO_STACKS = np.empty(0, np.int32)   # no slot has come back yet
 
     def lookup_claim(self, keys: np.ndarray):
         """``(values, distinct missed keys)``: a lookup that enters each
@@ -418,12 +434,41 @@ class NativeHashTable:
         among the distinct misses, in first-occurrence order), which
         every record of that key reads back. The caller stores a real
         value for each of them (``insert_batch``) before anything else
-        reads the table, and resolves the placeholders it holds."""
+        reads the table, and resolves the placeholders it holds. What
+        ``KeyDirectory.assign`` was built from before ``assign`` below;
+        its parity test and ``tools/scan_micro.py`` still build that."""
         keys = np.ascontiguousarray(keys, np.int64)
         vals = np.empty(len(keys), np.int64)
         uniq = np.empty(len(keys), np.int64)
         n = self._lib.ht_lookup_claim(self._h, keys, len(keys), vals, uniq)
         return vals, uniq[:n]
+
+    def assign(self, keys: np.ndarray, num_shards: int, shard_lo: int,
+               shard_hi: int, slots_per_shard: int, next_free: np.ndarray,
+               n_free: np.ndarray, free_stacks: Optional[np.ndarray],
+               rev_keys: np.ndarray, rev_used: np.ndarray):
+        """``KeyDirectory.assign`` in one native call (codec.cc
+        ``ht_assign``): the slot of every key, absent keys given slots
+        from the directory's allocator arrays (updated in place, with
+        ``_alloc_slots``' outcome to the slot) and entered. Behind a
+        memo of the call's own that serves a record whose key a record
+        shortly before it had, and steps aside where a stretch of
+        ``MEMO_STRETCH`` records shows it does not pay. Returns ``(slots,
+        slots handed out, of them reclaimed ones, memo hits, records
+        that consulted the memo)``."""
+        keys = np.ascontiguousarray(keys, np.int64)
+        n = len(keys)
+        if len(self._handed_out) < n:
+            self._handed_out = np.empty(n, np.int64)
+        slots = np.empty(n, np.int64)
+        st = self._stats
+        self._lib.ht_assign(
+            self._h, keys, n, slots, num_shards, shard_lo, shard_hi,
+            slots_per_shard, next_free, n_free,
+            self._NO_STACKS if free_stacks is None else free_stacks,
+            rev_keys, rev_used.view(np.uint8), self._handed_out, st)
+        return (slots, self._handed_out[:st[2]].copy(), int(st[3]), int(st[0]),
+                int(st[1]))
 
     def delete_batch(self, keys: np.ndarray) -> int:
         """Delete by backward shift (codec.cc ht_delete): no tombstones,

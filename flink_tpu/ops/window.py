@@ -1904,6 +1904,8 @@ class WindowOperator:
                     panes[late_ok], self._fired_below_end, self.watermark))
 
         slots = self.directory.assign(keys)
+        self.prof["assign_records"] = self.directory.assign_records
+        self.prof["assign_memo_hits"] = self.directory.assign_memo_hits
         bad = valid & (slots < 0)
         if bad.any():
             full = bad & (slots == KeyDirectory.FULL)
@@ -3359,7 +3361,8 @@ class WindowOperator:
         self.directory = KeyDirectory.restore(
             old.num_shards, old.slots_per_shard,
             snap["directory"], (old.shard_lo, old.shard_hi))
-        for k in ("slots_allocated", "slots_reused", "slots_released"):
+        for k in ("slots_allocated", "slots_reused", "slots_released",
+                  "assign_records", "assign_memo_looks", "assign_memo_hits"):
             setattr(self.directory, k, getattr(old, k))   # the job's
         self.watermark = snap["watermark"]
         self._cleared_below = snap["cleared_below"]

@@ -1945,12 +1945,15 @@ class Driver:
             for k, v in getattr(op, "prof", {}).items():
                 final[f"profile.op{nid}.{k}"] = final.get(
                     f"profile.op{nid}.{k}", 0.0) + v
-                if k in ("scan_pane_moves", "scan_ranges"):
+                if k in ("scan_pane_moves", "scan_ranges",
+                         "assign_records", "assign_memo_hits"):
                     # once more beside the leaf they explain: whatever
                     # reads profile.phase.* (bench artifacts, the
                     # benchmark's detail line) then shows whether
                     # window.key_scan ran on the pane cursor's cheap
-                    # path, and over how many record ranges at once
+                    # path, over how many record ranges at once, and how
+                    # many of the general lane's records the directory's
+                    # memo served
                     final[f"profile.phase.{k}"] = final.get(
                         f"profile.phase.{k}", 0.0) + v
         return JobResult(job_name, final)

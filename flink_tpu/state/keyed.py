@@ -280,7 +280,7 @@ class KeyDirectory:
         self.shard_lo, self.shard_hi = shard_range or (0, num_shards)
         # C fast path when the codec library is available (same probe
         # semantics, same splitmix64 hash — parity-tested); numpy
-        # otherwise. ~90ms → ~10ms per 2^20-record batch.
+        # otherwise
         from flink_tpu.native_codec import NativeHashTable
 
         self._table = NativeHashTable.create() or _NumpyHashTable()
@@ -308,6 +308,11 @@ class KeyDirectory:
         self.slots_reused = 0
         self.slots_released = 0
         self.keys_peak = 0
+        # the native assign's: records it was handed, those of them its
+        # memo was consulted for, and those the memo served
+        self.assign_records = 0
+        self.assign_memo_looks = 0
+        self.assign_memo_hits = 0
 
     @property
     def local_slots(self) -> int:
@@ -324,21 +329,20 @@ class KeyDirectory:
         slots (spill-layer responsibility).
         """
         keys = np.asarray(keys, dtype=np.int64)
-        claim = getattr(self._table, "lookup_claim", None)
-        if claim is not None:
-            # native: ONE pass finds the slots and the distinct new keys
-            # (a lookup and then np.unique over the missed records — half
-            # a batch and more where keys come and go — cost five times
-            # the lookup); their records read placeholders, resolved here
-            slots, uniq = claim(keys)
-            if len(uniq):
-                # slots go out in key order within a shard, as the
-                # sorted-unique path below hands them out
-                order = np.argsort(uniq)
-                alloc = np.empty(len(uniq), np.int64)
-                alloc[order] = self._register(uniq[order])
-                pend = np.flatnonzero(slots <= self._table.PENDING)
-                slots[pend] = alloc[self._table.PENDING - slots[pend]]
+        native = getattr(self._table, "assign", None)
+        if native is not None:
+            # ONE native call, per record a memo hit and a store: the
+            # lookup, the allocation below for the distinct new keys and
+            # their entry, slot for slot (tests/test_directory_assign.py)
+            slots, ok, reused, hits, looks = native(
+                keys, self.num_shards, self.shard_lo, self.shard_hi,
+                self.slots_per_shard, self._next_free, self._n_free,
+                self._free, self._rev_keys, self._rev_used)
+            self.slots_reused += reused
+            self._note_allocated(ok)
+            self.assign_records += len(keys)
+            self.assign_memo_hits += hits
+            self.assign_memo_looks += looks
             return slots
         slots, found = self._table.lookup_keys(keys)
         if not found.all():
@@ -410,14 +414,18 @@ class KeyDirectory:
         ok = slot[~full]
         self._rev_keys[ok] = keys[sub[~full]]
         self._rev_used[ok] = True
+        self._note_allocated(ok)
+        out[sub] = slot
+        return out
+
+    def _note_allocated(self, ok: np.ndarray) -> None:
+        """Slots just handed out, in the allocator's order."""
         self._n_keys += len(ok)
         self.slots_allocated += len(ok)
         if self._n_keys > self.keys_peak:
             self.keys_peak = self._n_keys
         if self._newest is not None and len(ok):
             self._fresh.append(ok)
-        out[sub] = slot
-        return out
 
     def key_of_slots(self, slots: np.ndarray) -> np.ndarray:
         return self._rev_keys[slots]

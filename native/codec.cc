@@ -14,6 +14,7 @@
 // Hash: 63-bit FNV-1a, BIT-IDENTICAL to records.hash_string_key — keys
 // encoded here and keys hashed in Python MUST route identically.
 
+#include <algorithm>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
@@ -149,9 +150,8 @@ int64_t encode_i64_rows(const int64_t* vals, int64_t n_rows, int64_t n_cols,
 // ---------------------------------------------------------------------------
 // int64 -> int64 open-addressing hash table: the key-directory probe loop
 // (ref role: CopyOnWriteStateMap.get/put — the per-record state-map probe —
-// batched and compiled; the numpy fallback in state/keyed.py costs ~90ms
-// per 2^20-record batch, this path ~10ms). The mix MUST stay bit-identical
-// to records.hash_keys_numpy / hash_keys_device: host ingest, device keyBy,
+// batched and compiled). The mix MUST stay bit-identical to
+// records.hash_keys_numpy / hash_keys_device: host ingest, device keyBy,
 // and this table all route by the same splitmix64 finalizer.
 
 static inline uint64_t ht_mix(uint64_t x) {
@@ -161,12 +161,34 @@ static inline uint64_t ht_mix(uint64_t x) {
   return x & 0x7FFFFFFFFFFFFFFFULL;
 }
 
+// A buffer that lives as long as its owner and only grows; what it
+// holds is never read before it is written.
+struct Scratch {
+  std::unique_ptr<char[]> buf;
+  int64_t len = 0;
+  void* get(int64_t bytes) {
+    if (bytes > len) { buf.reset(new char[bytes]); len = bytes; }
+    return buf.get();
+  }
+};
+
+// What ht_assign (below) keeps between calls: its memo and the lists of
+// a batch's distinct new keys. Made at a table's first ht_assign.
+static const int64_t MEMO_SIZE = 1024;     // entries of 16 bytes: L1-sized
+struct MemoEntry { int64_t key, val; };
+struct KeyRef { int64_t key, u; };         // a distinct miss and its index
+struct AssignScratch {
+  MemoEntry memo[MEMO_SIZE];
+  Scratch uniq, bucket, alloc, refs, starts;
+};
+
 struct FtHashTable {
   int64_t* keys;
   int64_t* vals;
   uint8_t* used;
   uint64_t mask;   // size - 1
   int64_t count;
+  AssignScratch* assign;   // ht_assign's workspace, made at its first call
 };
 
 static void ht_alloc(FtHashTable* t, uint64_t size) {
@@ -192,16 +214,23 @@ static void ht_grow(FtHashTable* t) {
   free(old.keys); free(old.vals); free(old.used);
 }
 
+// Load factor at most one half: doubles before the entry that would pass it.
+static inline void ht_grow_if_due(FtHashTable* t) {
+  if ((t->count + 1) * 2 > (int64_t)(t->mask + 1)) ht_grow(t);
+}
+
 void* ht_new(int64_t capacity_hint) {
   uint64_t size = 16;
   while ((int64_t)size < capacity_hint * 2) size *= 2;
   FtHashTable* t = (FtHashTable*)malloc(sizeof(FtHashTable));
   ht_alloc(t, size);
+  t->assign = nullptr;
   return t;
 }
 
 void ht_free(void* h) {
   FtHashTable* t = (FtHashTable*)h;
+  delete t->assign;
   free(t->keys); free(t->vals); free(t->used); free(t);
 }
 
@@ -229,7 +258,7 @@ void ht_lookup(void* h, const int64_t* keys, int64_t n,
 void ht_insert(void* h, const int64_t* keys, const int64_t* vals, int64_t n) {
   FtHashTable* t = (FtHashTable*)h;
   for (int64_t i = 0; i < n; ++i) {
-    if ((t->count + 1) * 2 > (int64_t)(t->mask + 1)) ht_grow(t);
+    ht_grow_if_due(t);
     uint64_t ix = ht_mix((uint64_t)keys[i]) & t->mask;
     for (;;) {
       if (!t->used[ix]) {
@@ -249,11 +278,11 @@ void ht_insert(void* h, const int64_t* keys, const int64_t* vals, int64_t n) {
 // out_uniq[u]; every record of that key reads the same placeholder. The
 // caller allocates a value for each of the out_uniq keys, stores them
 // (ht_insert updates in place) and resolves the placeholders it was
-// given: one pass over the batch finds the distinct new keys, where a
-// lookup followed by a sort of the missed records (np.unique) cost five
-// times the lookup at ~68,000 new keys a 2^20-record batch. Placeholders
-// never outlive the caller's batch. Returns the number of distinct
-// misses; out_uniq holds room for n.
+// given. Placeholders never outlive the caller's batch. Returns the
+// number of distinct misses; out_uniq holds room for n. This is the
+// claim as ht_assign makes it, a full probe a record and nothing after
+// it: the key directory no longer calls it; the parity test and
+// tools/scan_micro.py build the two-step assign of before from it.
 static const int64_t HT_PENDING = -16;   // below the callers' sentinels
 
 int64_t ht_lookup_claim(void* h, const int64_t* keys, int64_t n,
@@ -261,7 +290,7 @@ int64_t ht_lookup_claim(void* h, const int64_t* keys, int64_t n,
   FtHashTable* t = (FtHashTable*)h;
   int64_t u = 0;
   for (int64_t i = 0; i < n; ++i) {
-    if ((t->count + 1) * 2 > (int64_t)(t->mask + 1)) ht_grow(t);
+    ht_grow_if_due(t);
     uint64_t ix = ht_mix((uint64_t)keys[i]) & t->mask;
     for (;;) {
       if (!t->used[ix]) {
@@ -276,6 +305,179 @@ int64_t ht_lookup_claim(void* h, const int64_t* keys, int64_t n,
     }
   }
   return u;
+}
+
+// ---------------------------------------------------------------------------
+// KeyDirectory.assign (state/keyed.py) in one call: per RECORD a memo
+// hit and a store, everything else once per DISTINCT key.
+//
+//   1. the claim, as ht_lookup_claim makes it, behind a memo: a direct-
+//      mapped table of MEMO_SIZE (key -> the value or placeholder the
+//      probe gave), empty at the call's start and dead at its end, so
+//      nothing that happens to the table between calls can leave it
+//      stale; it holds values, never buckets, so a doubling mid-call
+//      does not touch it. A key's bids arrive together (a NEXmark
+//      auction's within ~1,700 events): of a 2^20-bid batch 93 % of the
+//      records repeat a key of a few hundred records before, and the
+//      probes left are the ~68 k that touch a cold bucket. Those are
+//      fetched CLAIM_AHEAD records early. The memo counts its hits;
+//      where a stretch of MEMO_STRETCH records shows under one hit in
+//      MEMO_PAYS it steps aside for the rest of the call (keys without
+//      locality pay the compare for one stretch, then only the probe);
+//   2. slots for the distinct misses, with the outcome of
+//      KeyDirectory._alloc_slots to the slot: per shard in ascending key
+//      order, reclaimed slots first (newest first), then the shard's
+//      free pointer, FULL past capacity, -1 outside [shard_lo,
+//      shard_hi); the slot goes to the key's bucket, which is where the
+//      claim left it unless the table doubled since (then by a probe);
+//   3. the records' placeholders replaced by their slots, in place.
+static const int64_t MEMO_STRETCH = 4096;
+static const int64_t MEMO_PAYS = 8;
+static const int64_t CLAIM_AHEAD = 64;
+static const int64_t DIRECTORY_FULL = -2;   // KeyDirectory.FULL
+
+// Records [i0, i1) of the claim: values or placeholders to out_vals,
+// each distinct miss to uniq[*u] with the bucket it was entered at.
+// Returns the memo's hits (0 when ``memo`` is off).
+static inline __attribute__((always_inline)) int64_t claim_range(
+    FtHashTable* t, MemoEntry* memo_tab, const bool memo,
+    const int64_t* keys, int64_t i0, int64_t i1, int64_t n,
+    int64_t* out_vals, int64_t* uniq, uint64_t* bucket, int64_t* u) {
+  int64_t hits = 0;
+  for (int64_t i = i0; i < i1; ++i) {
+    if (i + CLAIM_AHEAD < n) {
+      const int64_t ka = keys[i + CLAIM_AHEAD];
+      if (!memo || memo_tab[ka & (MEMO_SIZE - 1)].key != ka) {
+        const uint64_t ia = ht_mix((uint64_t)ka) & t->mask;
+        __builtin_prefetch(&t->used[ia]);
+        __builtin_prefetch(&t->keys[ia]);
+        __builtin_prefetch(&t->vals[ia]);
+      }
+    }
+    const int64_t k = keys[i];
+    MemoEntry* m = &memo_tab[k & (MEMO_SIZE - 1)];
+    if (memo && m->key == k) { out_vals[i] = m->val; ++hits; continue; }
+    uint64_t ix = ht_mix((uint64_t)k) & t->mask;
+    int64_t v;
+    for (;;) {
+      if (!t->used[ix]) {
+        v = HT_PENDING - *u;
+        t->keys[ix] = k; t->vals[ix] = v; t->used[ix] = 1;
+        ++t->count;
+        uniq[*u] = k; bucket[*u] = ix; ++*u;
+        // where ht_lookup_claim would double before its next probe
+        ht_grow_if_due(t);
+        break;
+      }
+      if (t->keys[ix] == k) { v = t->vals[ix]; break; }
+      ix = (ix + 1) & t->mask;
+    }
+    out_vals[i] = v;
+    if (memo) { m->key = k; m->val = v; }
+  }
+  return hits;
+}
+
+// out_stats: [memo hits, records that consulted the memo, slots handed
+// out (out_fresh holds them, by shard, ascending key within; room for
+// n), of them reclaimed ones]. free_stacks is read only where n_free
+// says a shard has slots back.
+void ht_assign(void* h, const int64_t* keys, int64_t n, int64_t* out_slots,
+               int64_t num_shards, int64_t shard_lo, int64_t shard_hi,
+               int64_t slots_per_shard, int64_t* next_free, int64_t* n_free,
+               const int32_t* free_stacks, int64_t* rev_keys,
+               uint8_t* rev_used, int64_t* out_fresh, int64_t* out_stats) {
+  FtHashTable* t = (FtHashTable*)h;
+  out_stats[0] = out_stats[1] = out_stats[2] = out_stats[3] = 0;
+  if (n == 0) return;
+  if (!t->assign) t->assign = new AssignScratch;
+  AssignScratch* ws = t->assign;
+  // an entry's key never indexes to the entry: it matches no lookup
+  for (int64_t j = 0; j < MEMO_SIZE; ++j) ws->memo[j].key = j ^ 1;
+  int64_t* uniq = (int64_t*)ws->uniq.get(n * sizeof(int64_t));
+  uint64_t* bucket = (uint64_t*)ws->bucket.get(n * sizeof(uint64_t));
+
+  ht_grow_if_due(t);
+  const uint64_t mask0 = t->mask;
+  int64_t u = 0;
+  bool memo = true;
+  for (int64_t i0 = 0; i0 < n; i0 += MEMO_STRETCH) {
+    const int64_t i1 = n - i0 < MEMO_STRETCH ? n : i0 + MEMO_STRETCH;
+    // two loops, one with the memo compiled out
+    const int64_t hits =
+        memo ? claim_range(t, ws->memo, true, keys, i0, i1, n, out_slots,
+                           uniq, bucket, &u)
+             : claim_range(t, ws->memo, false, keys, i0, i1, n, out_slots,
+                           uniq, bucket, &u);
+    if (!memo) continue;
+    out_stats[0] += hits;
+    out_stats[1] += i1 - i0;
+    memo = hits * MEMO_PAYS >= i1 - i0;
+  }
+  if (u == 0) return;
+
+  // the distinct misses by local shard (a counting sort), each shard's
+  // by key
+  const int64_t n_local = shard_hi - shard_lo;
+  int64_t* alloc = (int64_t*)ws->alloc.get(u * sizeof(int64_t));
+  KeyRef* refs = (KeyRef*)ws->refs.get(u * sizeof(KeyRef));
+  int64_t* starts =
+      (int64_t*)ws->starts.get((n_local + 1) * sizeof(int64_t));
+  memset(starts, 0, (n_local + 1) * sizeof(int64_t));
+  // alloc[j] holds key j's local shard until its slot replaces it, and
+  // the verdict -1 from the start where the shard is not this range's
+  for (int64_t j = 0; j < u; ++j) {
+    const int64_t s =
+        (int64_t)(ht_mix((uint64_t)uniq[j]) % (uint64_t)num_shards);
+    alloc[j] = s >= shard_lo && s < shard_hi ? s - shard_lo : -1;
+    if (alloc[j] >= 0) ++starts[alloc[j] + 1];
+  }
+  for (int64_t ls = 0; ls < n_local; ++ls) starts[ls + 1] += starts[ls];
+  for (int64_t j = 0; j < u; ++j)
+    if (alloc[j] >= 0) refs[starts[alloc[j]]++] = KeyRef{uniq[j], j};
+  // starts[ls] is now the END of shard ls's run
+  int64_t n_fresh = 0, n_reused = 0;
+  for (int64_t ls = 0, a = 0; ls < n_local; a = starts[ls++]) {
+    const int64_t b = starts[ls];
+    if (a == b) continue;
+    std::sort(refs + a, refs + b,
+              [](const KeyRef& x, const KeyRef& y) { return x.key < y.key; });
+    const int64_t depth = n_free[ls];
+    const int64_t taken = depth < b - a ? depth : b - a;
+    const int64_t free_ptr = next_free[shard_lo + ls];
+    const int64_t room = slots_per_shard - free_ptr;
+    for (int64_t r = 0; r < b - a; ++r) {
+      int64_t local;
+      if (r < taken) local = free_stacks[ls * slots_per_shard + depth - 1 - r];
+      else if (r - taken < room) local = free_ptr + (r - taken);
+      else { alloc[refs[a + r].u] = DIRECTORY_FULL; continue; }
+      const int64_t slot = ls * slots_per_shard + local;
+      rev_keys[slot] = refs[a + r].key;
+      rev_used[slot] = 1;
+      out_fresh[n_fresh++] = slot;
+      alloc[refs[a + r].u] = slot;
+    }
+    const int64_t fresh = b - a - taken;
+    n_free[ls] = depth - taken;
+    next_free[shard_lo + ls] = free_ptr + (fresh < room ? fresh : room);
+    n_reused += taken;
+  }
+  out_stats[2] = n_fresh;
+  out_stats[3] = n_reused;
+
+  if (t->mask == mask0) {
+    for (int64_t j = 0; j < u; ++j) t->vals[bucket[j]] = alloc[j];
+  } else {
+    for (int64_t j = 0; j < u; ++j) {
+      uint64_t ix = ht_mix((uint64_t)uniq[j]) & t->mask;
+      while (!(t->used[ix] && t->keys[ix] == uniq[j])) ix = (ix + 1) & t->mask;
+      t->vals[ix] = alloc[j];
+    }
+  }
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t v = out_slots[i];
+    if (v <= HT_PENDING) out_slots[i] = alloc[HT_PENDING - v];
+  }
 }
 
 // Batch delete by BACKWARD SHIFT (Knuth 6.4 algorithm R): the hole a
@@ -925,17 +1127,6 @@ static int64_t merge_range(int32_t* hist, int32_t* out_pairs, int64_t np_,
   for (int64_t b = 0; b < bitmap_len; ++b) bitmap[b] |= r->bitmap[b];
   return np_;
 }
-
-// A buffer that lives as long as its owner and only grows; what it
-// holds is never read before it is written.
-struct Scratch {
-  std::unique_ptr<char[]> buf;
-  int64_t len = 0;
-  void* get(int64_t bytes) {
-    if (bytes > len) { buf.reset(new char[bytes]); len = bytes; }
-    return buf.get();
-  }
-};
 
 // Threads that wait between batches for the later ranges of a split
 // scan, and those ranges' scratch. Worker w runs range w of a round

@@ -122,7 +122,8 @@ class TestJobPhases:
                    if k.startswith("profile.op")}
         assert op_keys <= {"drain_fetch", "drain_fetches", "drain_skips",
                            "preagg_batches", "scan_pane_moves",
-                           "scan_ranges"}
+                           "scan_ranges", "assign_records",
+                           "assign_memo_hits"}
         fetch = sum(v for k, v in res.metrics.items()
                     if k.startswith("profile.op")
                     and k.endswith(".drain_fetch"))

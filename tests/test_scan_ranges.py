@@ -110,6 +110,14 @@ def test_rows_and_upload_buffers_do_not_depend_on_host_parallelism(uploads):
     assert op_counter(m1, "scan_ranges") == N_SCANNED * 1
     assert op_counter(m4, "scan_ranges") == N_SCANNED * 4
     assert m4["profile.phase.scan_ranges"] == N_SCANNED * 4
+    # the job's first batch went the general lane, through the
+    # directory's native assign; its ~300 keys fit the memo
+    for m in (m1, m4):
+        assert op_counter(m, "assign_records") == N
+        assert N - 2 * KEYS_AT_START < op_counter(m, "assign_memo_hits") < N
+        assert m["profile.phase.assign_records"] == N
+        assert (m["profile.phase.assign_memo_hits"]
+                == op_counter(m, "assign_memo_hits"))
     # a range that starts inside a pane seeks once more; the serial
     # cursor moves once a pane of the batch
     assert op_counter(m1, "scan_pane_moves") < 3 * N_SCANNED
