@@ -288,8 +288,8 @@ class CheckpointCoordinator:
         cid = self._next_id
         self._next_id += 1
         t0 = time.time()
-        # checkpoint spans (ref: CheckpointStatsTracker reporting
-        # checkpointing spans through the trace reporters, SURVEY §6.1):
+        # checkpoint spans (ref: CheckpointStatsTracker's checkpointing
+        # spans, SURVEY §6.1):
         # 'checkpoint.freeze' = the sync part stalling the loop,
         # 'checkpoint.persist' = the async upload (persist.fetch,
         # persist.encode, persist.write) — the two durations that matter

@@ -152,6 +152,8 @@ def _bind(lib, i64p, f32p) -> None:
     lib.ht_free.argtypes = [ctypes.c_void_p]
     lib.ht_count.restype = ctypes.c_int64
     lib.ht_count.argtypes = [ctypes.c_void_p]
+    lib.ht_growth.restype = None
+    lib.ht_growth.argtypes = [ctypes.c_void_p, i64p]
     lib.ht_lookup.restype = None
     lib.ht_lookup.argtypes = [
         ctypes.c_void_p, i64p, ctypes.c_int64, i64p, u8p]
@@ -409,6 +411,14 @@ class NativeHashTable:
     @property
     def _count(self) -> int:
         return int(self._lib.ht_count(self._h))
+
+    def growth(self):
+        """``(doublings so far, the seconds they took, buckets now)``,
+        counted inside the table (codec.cc ``ht_grow``), whichever call
+        grew it."""
+        out = np.empty(3, np.int64)
+        self._lib.ht_growth(self._h, out)
+        return int(out[0]), out[1] / 1e9, int(out[2])
 
     def lookup_keys(self, keys: np.ndarray):
         """(values, found) — hashes computed inline in C."""

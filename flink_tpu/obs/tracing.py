@@ -11,9 +11,8 @@ Design: a process-global ``Tracer`` with a bounded ring of completed
 spans. Spans are cheap (one dataclass + two clock reads) and the ring
 is lock-guarded but uncontended — span starts/ends happen on the
 driver loop and checkpoint threads at human frequencies, never per
-record. Reporters get each completed span synchronously (the
-TraceReporter seam); the REST server exposes the ring at /traces and
-aggregated thread stacks at /flamegraph.
+record. The REST server exposes the ring at /traces and aggregated
+thread stacks at /flamegraph.
 
 The data path has a ``PhaseClock`` per run (the driver's; a bare
 operator has its own): per thread at most ONE phase is open, so a
@@ -25,6 +24,12 @@ profiler session runs (``pipeline.profile-dir``, ``jax.profiler
 trace's clock. There is no switch: with no session a span is one
 object and two calls. Phases are per batch or per fire, never per
 record.
+
+One level below a leaf: ``PhaseClock.detail(name)`` times a stretch of
+the open leaf WITHOUT switching the phase (seconds, count, longest,
+read by ``details()``; a host event ``<leaf>/<name>`` nested in the
+leaf's). With no leaf open, as on a thread that waits between its
+spans, a detail is a counter only.
 """
 from __future__ import annotations
 
@@ -33,7 +38,7 @@ import dataclasses
 import sys
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
 
@@ -52,7 +57,7 @@ class _ThreadPhases:
     """One thread's side of a PhaseClock: the open phase and this
     thread's totals (single writer; ``PhaseClock.snapshot`` merges)."""
 
-    __slots__ = ("name", "t0", "ann", "stats")
+    __slots__ = ("name", "t0", "ann", "stats", "detail", "details")
 
     def __init__(self) -> None:
         self.name: Optional[str] = None
@@ -60,6 +65,9 @@ class _ThreadPhases:
         self.ann = None
         # name -> [seconds, count, longest_s, longest_began]
         self.stats: Dict[str, List[float]] = {}
+        # the open detail, and key -> [seconds, count, longest_s]
+        self.detail: Optional["_Detail"] = None
+        self.details: Dict[str, List[float]] = {}
 
 
 class _PhaseSpan:
@@ -85,6 +93,60 @@ class _PhaseSpan:
         return self.t1 - self.t0
 
 
+class _Detail:
+    """``with clock.detail(name):`` — a stretch of the leaf open on the
+    calling thread (``leaf``; None where none is), under the key
+    ``<leaf>/<name>``. Its clock runs only while that leaf is the open
+    phase: a phase the block switches to is that phase's time, and the
+    detail goes on when its leaf is open again."""
+
+    __slots__ = ("_tp", "_name", "leaf", "key", "t0", "ann", "acc")
+
+    def __init__(self, tp: _ThreadPhases, name: str) -> None:
+        self._tp, self._name = tp, name
+
+    def __enter__(self) -> "_Detail":
+        tp = self._tp
+        if tp.detail is not None:
+            raise RuntimeError(
+                f"detail {self._name!r} opened inside {tp.detail.key!r}: "
+                "there is one level below a leaf")
+        self.leaf = tp.name
+        self.key = (self._name if self.leaf is None
+                    else f"{self.leaf}/{self._name}")
+        self.acc = 0.0
+        tp.detail = self
+        self.resume()
+        return self
+
+    def resume(self) -> None:
+        # a thread that waits with no leaf open must not put its name on
+        # the device's idle gaps: no host event there
+        self.ann = (None if self.leaf is None
+                    else _annotation(self.key, {}))
+        self.t0 = time.perf_counter()
+
+    def pause(self, now: float) -> None:
+        self.acc += now - self.t0
+        self.t0 = None
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self.t0 is not None:
+            self.pause(time.perf_counter())
+        tp, acc = self._tp, self.acc
+        tp.detail = None
+        st = tp.details.get(self.key)
+        if st is None:
+            tp.details[self.key] = [acc, 1, acc]
+        else:
+            st[0] += acc
+            st[1] += 1
+            if acc > st[2]:
+                st[2] = acc
+
+
 class PhaseClock:
     """Where each thread of one run spends its wall time.
 
@@ -93,7 +155,10 @@ class PhaseClock:
     ``stop()`` closes it and opens none; ``span(name)``
     is ``name`` for a ``with`` block and the previous phase after it. No
     phase encloses another, so a thread's phases sum to its wall time
-    between its first ``phase()`` and its ``stop()``."""
+    between its first ``phase()`` and its ``stop()``. ``detail(name)``
+    times a ``with`` block as a child of the open leaf and leaves the
+    partition alone: a leaf's self time is its seconds less its
+    details'."""
 
     def __init__(self) -> None:
         self.t_start = time.perf_counter()
@@ -117,6 +182,9 @@ class PhaseClock:
         prev, t_open = tp.name, tp.t0
         if prev == name and not attrs:
             return prev, t_open, now    # already open: one interval
+        detail = tp.detail
+        if detail is not None and detail.t0 is not None:
+            detail.pause(now)           # a child ends before its leaf
         if prev is not None:
             tp.ann.__exit__(None, None, None)
             dt = now - t_open
@@ -130,6 +198,8 @@ class PhaseClock:
                     st[2], st[3] = dt, t_open
         tp.name, tp.t0 = name, now
         tp.ann = None if name is None else _annotation(name, attrs)
+        if detail is not None and detail.leaf == name:
+            detail.resume()
         return prev, t_open, now
 
     def phase(self, name: str, **attributes: Any) -> float:
@@ -144,6 +214,9 @@ class PhaseClock:
 
     def span(self, name: str, **attributes: Any) -> _PhaseSpan:
         return _PhaseSpan(self, name, attributes)
+
+    def detail(self, name: str) -> _Detail:
+        return _Detail(self._mine(), name)
 
     def open_phase(self) -> Optional[str]:
         """The phase open on the calling thread."""
@@ -166,6 +239,22 @@ class PhaseClock:
                 if longest * 1e3 > o["longest_ms"]:
                     o["longest_ms"] = longest * 1e3
                     o["longest_at_s"] = began - self.t_start
+        return out
+
+    def details(self) -> Dict[str, Dict[str, float]]:
+        """``<leaf>/<name>`` (the name alone where no leaf was open) ->
+        ``seconds``, ``count`` and ``longest_ms`` over all threads'
+        CLOSED details."""
+        with self._lock:
+            threads = list(self._threads)
+        out: Dict[str, Dict[str, float]] = {}
+        for tp in threads:
+            for key, (secs, n, longest) in list(tp.details.items()):
+                o = out.setdefault(key, {"seconds": 0.0, "count": 0,
+                                         "longest_ms": 0.0})
+                o["seconds"] += secs
+                o["count"] += n
+                o["longest_ms"] = max(o["longest_ms"], longest * 1e3)
         return out
 
 
@@ -211,27 +300,16 @@ class _SpanHandle:
 class Tracer:
     def __init__(self, capacity: int = 512) -> None:
         self._done: collections.deque = collections.deque(maxlen=capacity)
-        self._reporters: List[Callable[[Span], None]] = []
         self._lock = threading.Lock()
 
     def span(self, name: str, **attributes: Any) -> _SpanHandle:
         return _SpanHandle(self, Span(name, time.time(),
                                       attributes=dict(attributes)))
 
-    def add_reporter(self, fn: Callable[[Span], None]) -> None:
-        with self._lock:
-            self._reporters.append(fn)
-
     def _finish(self, span: Span) -> None:
         span.end = time.time()
         with self._lock:
             self._done.append(span)
-            reporters = list(self._reporters)
-        for r in reporters:
-            try:
-                r(span)
-            except Exception:  # noqa: BLE001 — reporters must not break jobs
-                pass
 
     def spans(self, name_prefix: str = "") -> List[Dict[str, Any]]:
         with self._lock:

@@ -1843,93 +1843,111 @@ class WindowOperator:
         """The numpy lane of ``process_batch``: any aggregate, validity
         mask or spill store, and every batch whose pairs the fused scan
         did not make."""
-        ph = self.phases.phase
-        self._flush_stash()
-        self._return_released()
-        self.state_version += 1
-        keys = np.asarray(keys, dtype=np.int64)
-        ts = np.asarray(ts, dtype=np.int64)
-        # ``whole``: every record is valid (none masked by the caller,
-        # none late, none without a slot): the masks below then cost no
-        # copies of the batch
-        whole = valid is None and len(ts) > 0
-        valid = (np.ones(len(ts), bool) if valid is None
-                 else np.asarray(valid, bool))
-        panes = self.plan.pane_of(ts)
+        # the stretch of ``window.key_scan`` from here to the first phase
+        # switch is named below the leaf: each statement lies in one
+        # detail (``window.key_scan/<name>``); what a block spends in
+        # another leaf (a stashed upload, ``state.reclaim``, the pack
+        # the gate lets through) is that leaf's
+        ph, detail = self.phases.phase, self.phases.detail
+        with detail("prepare"):
+            self._flush_stash()
+            self._return_released()
+            self.state_version += 1
+            keys = np.asarray(keys, dtype=np.int64)
+            ts = np.asarray(ts, dtype=np.int64)
+            # ``whole``: every record is valid (none masked by the
+            # caller, none late, none without a slot): the masks below
+            # then cost no copies of the batch
+            whole = valid is None and len(ts) > 0
+            valid = (np.ones(len(ts), bool) if valid is None
+                     else np.asarray(valid, bool))
+        with detail("panes"):
+            panes = self.plan.pane_of(ts)
 
-        dead = self._cleared_below
-        late_mask = valid & (panes < dead)
-        n_late = int(np.count_nonzero(late_mask))
-        if n_late:
-            self.late_records += n_late
-            valid = valid & ~late_mask
-            whole = False
+            dead = self._cleared_below
+            late_mask = valid & (panes < dead)
+            n_late = int(np.count_nonzero(late_mask))
+            if n_late:
+                self.late_records += n_late
+                valid = valid & ~late_mask
+                whole = False
 
-        if whole or valid.any():
-            pv = panes if whole else panes[valid]
-            mn = int(pv.min())
-            mx = int(pv.max())
-            prev_min = self._min_pane_seen
-            prev_max = self._max_pane_seen
-            if prev_min is None or mn < prev_min:
-                self._min_pane_seen = mn
-            if prev_max is None or mx > prev_max:
-                self._max_pane_seen = mx
+            if whole or valid.any():
+                pv = panes if whole else panes[valid]
+                mn = int(pv.min())
+                mx = int(pv.max())
+                prev_min = self._min_pane_seen
+                prev_max = self._max_pane_seen
+                if prev_min is None or mn < prev_min:
+                    self._min_pane_seen = mn
+                if prev_max is None or mx > prev_max:
+                    self._max_pane_seen = mx
 
-            # ring capacity guard: at most one live pane per ring column.
-            # When event time runs ahead of the watermark clock beyond
-            # plan bounds (big microbatches, stalled watermark), GROW the
-            # ring and remap live columns instead of failing — the
-            # backpressure answer is more memory, not a crash. The remap
-            # range must cover only panes ALREADY APPLIED to state
-            # (prev_min..prev_max) — this batch's panes land after the
-            # grow, and remapping their columns would alias unrelated
-            # live panes' data into them.
-            # the live span runs to the OPERATOR max (not just this
-            # batch's): a late-but-allowed record far below the live
-            # range must also trigger growth, or its column write would
-            # alias a newer live pane
-            live_lo = max(dead, self._min_pane_seen)
-            live_hi = self._max_pane_seen
-            if live_hi - live_lo >= self.plan.ring:
-                self._grow_ring(live_hi - live_lo + 1, prev_min, prev_max)
+                # ring capacity guard: at most one live pane per ring
+                # column. When event time runs ahead of the watermark
+                # clock beyond plan bounds (big microbatches, stalled
+                # watermark), GROW the ring and remap live columns
+                # instead of failing — the backpressure answer is more
+                # memory, not a crash. The remap range must cover only
+                # panes ALREADY APPLIED to state (prev_min..prev_max) —
+                # this batch's panes land after the grow, and remapping
+                # their columns would alias unrelated live panes' data
+                # into them.
+                # the live span runs to the OPERATOR max (not just this
+                # batch's): a late-but-allowed record far below the live
+                # range must also trigger growth, or its column write
+                # would alias a newer live pane
+                live_lo = max(dead, self._min_pane_seen)
+                live_hi = self._max_pane_seen
+                if live_hi - live_lo >= self.plan.ring:
+                    self._grow_ring(
+                        live_hi - live_lo + 1, prev_min, prev_max)
 
-        # late-but-allowed → re-fire affected, already-fired windows with
-        # updated contents (ref: EventTimeTrigger.onElement fires
-        # immediately for late elements within allowed lateness)
-        if self._fired_below_end is not None:
-            late_ok = valid & (panes < self._fired_below_end)
-            if late_ok.any():
-                self._refire.update(self.plan.late_refire_ends(
-                    panes[late_ok], self._fired_below_end, self.watermark))
+            # late-but-allowed → re-fire affected, already-fired windows
+            # with updated contents (ref: EventTimeTrigger.onElement
+            # fires immediately for late elements within allowed
+            # lateness)
+            if self._fired_below_end is not None:
+                late_ok = valid & (panes < self._fired_below_end)
+                if late_ok.any():
+                    self._refire.update(self.plan.late_refire_ends(
+                        panes[late_ok], self._fired_below_end,
+                        self.watermark))
 
-        slots = self.directory.assign(keys)
-        self.prof["assign_records"] = self.directory.assign_records
-        self.prof["assign_memo_hits"] = self.directory.assign_memo_hits
-        bad = valid & (slots < 0)
-        if bad.any():
-            full = bad & (slots == KeyDirectory.FULL)
-            if self._spill is not None and full.any():
-                # shard full: the key aggregates on the host instead —
-                # exact results at host speed (see state/spill.py)
-                sub = {k: data[k][full] for k in
-                       (self.agg.fields if self.agg.fields is not None
-                        else data)}
-                self._spill.absorb(keys[full], panes[full], sub)
-                bad = bad & ~full
-            # remaining negatives: shard-full without a spill store, or
-            # misrouted (-1: key outside this operator's shard_range —
-            # a routing error the spill store must NOT absorb, or the
-            # key would aggregate on two workers at once). Default
-            # policy FAILS the job; state.allow-drops=true drops with
-            # accounting (see account_full_drop).
+        with detail("assign"):
+            slots = self.directory.assign(keys)
+            self.prof["assign_records"] = self.directory.assign_records
+            self.prof["assign_memo_hits"] = self.directory.assign_memo_hits
+        with detail("slot_mask"):
+            bad = valid & (slots < 0)
             if bad.any():
-                account_full_drop(self, int(bad.sum()))
-            valid = valid & ~bad & ~full
-            whole = False
+                full = bad & (slots == KeyDirectory.FULL)
+                if self._spill is not None and full.any():
+                    # shard full: the key aggregates on the host instead
+                    # — exact results at host speed (see state/spill.py)
+                    sub = {k: data[k][full] for k in
+                           (self.agg.fields if self.agg.fields is not None
+                            else data)}
+                    self._spill.absorb(keys[full], panes[full], sub)
+                    bad = bad & ~full
+                # remaining negatives: shard-full without a spill store,
+                # or misrouted (-1: key outside this operator's
+                # shard_range — a routing error the spill store must NOT
+                # absorb, or the key would aggregate on two workers at
+                # once). Default policy FAILS the job;
+                # state.allow-drops=true drops with accounting (see
+                # account_full_drop).
+                if bad.any():
+                    account_full_drop(self, int(bad.sum()))
+                valid = valid & ~bad & ~full
+                whole = False
         if self._releases:
-            self.directory.note_panes(slots, panes, valid)
-        if self._preagg_dispatch(slots, panes, valid, data):
+            with detail("note_panes"):
+                self.directory.note_panes(slots, panes, valid)
+        with detail("preagg_gate"):
+            # up to the gate's "no", or to its switch to window.pack
+            took = self._preagg_dispatch(slots, panes, valid, data)
+        if took:
             self._throttle_unless_external()
             return
         ph("window.pack")
@@ -2727,6 +2745,7 @@ class WindowOperator:
     def state_counters(self) -> Dict[str, int]:
         """The keyed state's life so far (``JobResult.metrics``)."""
         d = self.directory
+        grows, grow_s, buckets = d.table_growth()
         return {"state.slots_allocated": d.slots_allocated,
                 "state.slots_reused": d.slots_reused,
                 "state.slots_released": d.slots_released,
@@ -2734,6 +2753,11 @@ class WindowOperator:
                 "state.live_keys": d.num_keys(),
                 "state.live_keys_peak": d.keys_peak,
                 "state.slots_waiting_peak": self.slots_waiting_peak,
+                # the key table's doublings, each a standstill of the
+                # batch that met it, and its buckets now
+                "state.table_grows": grows,
+                "state.table_grow_s": grow_s,
+                "state.table_buckets": buckets,
                 # the pane tensors' geometry on one device: what a byte
                 # model of a program over the whole state needs
                 "state.pane_rows": self.layout.rows,
@@ -2822,9 +2846,9 @@ class WindowOperator:
     def _fire_cohort(self, end_panes: List[int]) -> Dict[str, Any]:
         """The record of one fire dispatch: its window ends (ms) and
         ``t_fire``, on ``time.perf_counter()``. The fetch that makes its
-        rows host-visible adds ``t_fetch0`` / ``t_fetch1``; the driver
-        adds ``op``, ``t_input`` and ``t_sink`` (see
-        ``Driver.fire_records``)."""
+        rows host-visible adds ``t_fetch0`` / ``t_ready`` / ``t_fetch1``;
+        the driver adds ``op``, ``t_input``, ``t_queued``, ``t_push0``
+        and ``t_sink`` (see ``Driver.fire_records``)."""
         return {"window_ends": [e * self.plan.pane_ms + self.plan.offset_ms
                                 for e in end_panes],
                 "t_fire": time.perf_counter()}
@@ -3032,12 +3056,12 @@ class WindowOperator:
         return self._emit_ring
 
     def _fetch_ring_version(self, need: int, opportunistic: bool):
-        """Under the ring lock: ``(ring array, its version)`` of the
-        newest ANNOUNCED version >= ``need`` whose async copy already
-        landed — never park behind the in-flight compute of a
-        just-dispatched fire (a barrier's rows must be present, hence
-        ``need``) — or ``(None, None)`` when an opportunistic poll finds
-        nothing announced. A version this drain has read already holds
+        """Under the ring lock: ``(ring array, its version, when the
+        wait for it ended)`` of the newest ANNOUNCED version >= ``need``
+        whose async copy already landed — never park behind the
+        in-flight compute of a just-dispatched fire (a barrier's rows
+        must be present, hence ``need``) — or ``(None, None, None)``
+        when an opportunistic poll finds nothing announced. A version this drain has read already holds
         no row it has not seen: an opportunistic poll passes it over,
         and where only such versions have landed it waits for the OLDEST
         it has not read (the soonest) instead of reading nothing. With
@@ -3061,7 +3085,7 @@ class WindowOperator:
             if opportunistic:
                 # nothing announced yet (or announce cadence not due):
                 # fetch nothing; the next poll gets it
-                return None, None
+                return None, None, None
             # barrier needs a version newer than any announced copy:
             # announce the live ring now so the fetch is a landed-copy
             # read, not an unannounced round trip
@@ -3072,8 +3096,11 @@ class WindowOperator:
             self._last_announce = time.perf_counter()
             self._rows_bound_since_announce = 0
         ready_wait(target)
+        # the device's work and the copy are done: what is left of the
+        # fetch is a local read
+        t_ready = time.perf_counter()
         self._ring_read_no = max(self._ring_read_no, no_read)
-        return np.asarray(target), no_read         # ONE round trip
+        return np.asarray(target), no_read, t_ready   # ONE round trip
 
     def drain_ring(self, min_no: Optional[int] = None) -> Dict[str, np.ndarray]:
         """Fetch the emit ring ONCE and decode every row appended since
@@ -3114,19 +3141,20 @@ class WindowOperator:
                 # latency sample must not age across skipped polls.
                 now = time.perf_counter()
                 seen_no = self._ring_version_no
-                self._deliver_stamps(seen_no, now, now)
+                self._deliver_stamps(seen_no, now, now, now)
                 self.prof["drain_skips"] += 1
                 arr = None
             else:
                 need = self._ring_version_no if min_no is None else min_no
                 with self.phases.span("drain.fetch", ring=need) as fetch:
-                    arr, no_read = self._fetch_ring_version(
+                    arr, no_read, t_ready = self._fetch_ring_version(
                         need, opportunistic=(min_no == 0))
                 if no_read is not None:
                     # every fire cohort at or below the fetched version
                     # just became host-visible — hand it, with this
                     # fetch's stamps, to the latency accounting
-                    self._deliver_stamps(no_read, fetch.t0, fetch.t1)
+                    self._deliver_stamps(
+                        no_read, fetch.t0, t_ready, fetch.t1)
                     seen_no = no_read
                 self.prof["drain_fetch"] += fetch.seconds
                 self.prof["drain_fetches"] += 1
@@ -3190,13 +3218,15 @@ class WindowOperator:
         return out
 
     def _deliver_stamps(self, no_read: int, t_fetch0: float,
-                        t_fetch1: float) -> None:
+                        t_ready: float, t_fetch1: float) -> None:
         """Under the ring lock: every fire cohort at or below ring
         version ``no_read`` is host-visible as of the fetch that ran
-        from ``t_fetch0`` to ``t_fetch1``."""
+        from ``t_fetch0`` to ``t_fetch1`` and whose wait for the device
+        ended at ``t_ready``."""
         while self._fire_stamps and self._fire_stamps[0][0] <= no_read:
             cohort = self._fire_stamps.popleft()[1]
-            cohort["t_fetch0"], cohort["t_fetch1"] = t_fetch0, t_fetch1
+            cohort.update(t_fetch0=t_fetch0, t_ready=t_ready,
+                          t_fetch1=t_fetch1)
             self._delivered_stamps.append(cohort)
 
     def take_delivered_fires(self) -> List[Dict[str, Any]]:
@@ -3514,21 +3544,29 @@ class FiredWindows(Mapping):
                 self._data = self._op.drain_ring()
                 self._op = None
             else:
-                self._fetch_packs(lambda packs: jax.device_get(
-                    [b for _, b in packs]))
+                self._fetch_packs(polled=False)
         if self._extra is not None:
             self._data = _merge_spill_rows(
                 self._data, self._extra, self._topn_spec)
             self._extra = None
         return self._data
 
-    def _fetch_packs(self, get) -> None:
-        """Fetch (``drain.fetch``) and decode this fire's pack buffers."""
+    def _fetch_packs(self, polled: bool) -> None:
+        """Fetch (``drain.fetch``) and decode this fire's pack buffers.
+        ``polled``, the drain's way: wait for the copies the fire
+        started, then read them locally; else one blocking get."""
+        bufs = [b for _, b in self._packs]
         with self._op.phases.span("drain.fetch") as fetch:
-            bufs = get(self._packs)
+            if polled:
+                ready_wait(bufs)
+                t_ready = time.perf_counter()
+                bufs = [np.asarray(b) for b in bufs]
+            else:
+                bufs = jax.device_get(bufs)
+                t_ready = time.perf_counter()
         if self.cohort is not None:
-            self.cohort["t_fetch0"] = fetch.t0
-            self.cohort["t_fetch1"] = fetch.t1
+            self.cohort.update(t_fetch0=fetch.t0, t_ready=t_ready,
+                               t_fetch1=fetch.t1)
         self._data = self._op._decode_packs(self._packs, bufs)
         self._op._note_pack_decoded(self._pack_no)
         self._packs = self._op = None
@@ -3578,8 +3616,7 @@ class FiredWindows(Mapping):
                 f._op = None
         for f in fireds:
             if f._data is None and f._packs is not None:
-                f._fetch_packs(lambda packs: [
-                    np.asarray(ready_wait(b)) for _, b in packs])
+                f._fetch_packs(polled=True)
 
     def __getitem__(self, key: str) -> np.ndarray:
         return self.materialize()[key]

@@ -40,6 +40,9 @@ from flink_tpu.time.watermarks import LONG_MIN, WatermarkTracker, make_generator
 # fire cohorts kept for JobResult "trace.fires" (and its records: the
 # newest this many)
 FIRE_RECORDS = 4096
+# a record's stamps, in the order a fired row meets them
+FIRE_STAMPS = ("t_input", "t_fire", "t_queued", "t_fetch0", "t_ready",
+               "t_fetch1", "t_push0", "t_sink")
 
 # the six phases of phase_breakdown(), each the sum of these leaves of
 # the run's PhaseClock. "ingest.bookkeeping" and "drain.deliver" are in
@@ -73,6 +76,27 @@ CHECKPOINT_COUNTERS = (
     "checkpoint.aborted", "checkpoint.bytes_last", "checkpoint.bytes_total")
 
 Batch = Tuple[Dict[str, np.ndarray], np.ndarray, np.ndarray]  # data, ts, valid
+
+
+class _LoopHold:
+    """``with`` a lock on the ingest loop's thread, adding up what the
+    loop waited for it (``waited_s``): two clock reads around an acquire
+    that did not succeed at once, none where it did."""
+
+    __slots__ = ("_lock", "waited_s")
+
+    def __init__(self, lock) -> None:
+        self._lock = lock
+        self.waited_s = 0.0
+
+    def __enter__(self) -> None:
+        if not self._lock.acquire(blocking=False):
+            t0 = time.perf_counter()
+            self._lock.acquire()
+            self.waited_s += time.perf_counter() - t0
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._lock.release()
 
 
 class JobCancelledError(RuntimeError):
@@ -223,6 +247,10 @@ class Driver:
         # drain thread (shared sinks + metrics are single-writer at a
         # time; the expensive materialization stays outside the lock)
         self._push_lock = threading.Lock()
+        # the loop thread's way to it: what the loop loses to the
+        # drain's delivery is profile.phase.push_wait_s (the drain's own
+        # wait is its detail drain/push_wait)
+        self._loop_push = _LoopHold(self._push_lock)
         # fair drain scheduling (session-cluster mode): co-resident
         # jobs' drain fetches take round-robin turns on the process-
         # global gate so one tenant's fire burst cannot starve a
@@ -718,7 +746,7 @@ class Driver:
         # barrier part 1: in-flight async-I/O batches are NOT in the
         # snapshot (their source positions already advanced) — drain
         # them downstream first so the checkpoint covers their effects
-        with self._push_lock:
+        with self._loop_push:
             for nid, op in self._ops.items():
                 if self.plan.node(nid).kind == "async_io":
                     for b in op.poll(drain=True):
@@ -1102,7 +1130,7 @@ class Driver:
         if gwm != _FINAL and gwm > self._out_wm[sid]:
             self._out_wm[sid] = gwm
         ph("wm.advance")
-        with self._push_lock:
+        with self._loop_push:
             self._propagate_watermarks()
         ph("ingest.bookkeeping")
         self._check_drain_error()
@@ -1136,19 +1164,19 @@ class Driver:
         valid = np.ones(nrec, bool)
         k = self._sub_batches
         if k <= 1 or nrec <= k:
-            with self._push_lock:
+            with self._loop_push:
                 self.metrics["records_in"] += nrec
                 self.metrics["batches"] += 1
                 self._push_downstream(sid, (md, mts, valid))
             return
-        with self._push_lock:
+        with self._loop_push:
             self.metrics["records_in"] += nrec
             self.metrics["batches"] += 1
         sub = -(-nrec // k)  # ceil: ragged tails allowed cross-host
         for lo in range(0, nrec, sub):
             hi = min(lo + sub, nrec)
             self.phases.phase("ingest.route")
-            with self._push_lock:
+            with self._loop_push:
                 self._push_downstream(
                     sid, ({kk: v[lo:hi] for kk, v in md.items()},
                           mts[lo:hi], valid[lo:hi]))
@@ -1481,6 +1509,7 @@ class Driver:
         self._emit_q = queue.Queue()
         self._drain_discard = [False]  # fresh cell per run (see __init__)
         self.phases = PhaseClock()
+        self._loop_push.waited_s = 0.0
         for op in self._ops.values():
             op.phases = self.phases
         if self._coordinator is not None:
@@ -1771,7 +1800,7 @@ class Driver:
                 # (ref: idle-channel handling in the valve)
                 self._recombine_source_wm(sid, splits_alive)
                 ph("wm.advance")
-                with self._push_lock:
+                with self._loop_push:
                     self._propagate_watermarks()
                 ph("ingest.bookkeeping")
                 self._check_drain_error()
@@ -1804,7 +1833,7 @@ class Driver:
         for sid in self.plan.sources:
             self._out_wm[sid] = _FINAL
         self._t_input = ph("wm.advance")
-        with self._push_lock:
+        with self._loop_push:
             self._propagate_watermarks(final=True)
         # the loop is over: what follows waits for the drain and commits
         self._loop_wall_s = self.phases.stop() - self._t_loop
@@ -1936,6 +1965,17 @@ class Driver:
                     default={"longest_ms": 0.0, "longest_at_s": 0.0})
         final["profile.phase.longest_ms"] = worst["longest_ms"]
         final["profile.phase.longest_at_s"] = worst["longest_at_s"]
+        # what the loop lost to the drain's delivery: its waits for
+        # _push_lock, which lie inside whichever leaf was open
+        final["profile.phase.push_wait_s"] = round(
+            self._loop_push.waited_s, 6)
+        # one level below the leaves (PhaseClock.detail): seconds under
+        # profile.detail.<leaf>/<name> and nothing else there, so a
+        # pattern over the prefix sums seconds
+        for key, st in self.phases.details().items():
+            final[f"profile.detail.{key}"] = round(st["seconds"], 6)
+            final[f"profile.detail_n.{key}"] = st["count"]
+            final[f"profile.detail_longest_ms.{key}"] = st["longest_ms"]
         final["trace.fires"] = self.fire_records()
         if self._profiler is not None:
             summary = self._profiler.close()
@@ -2100,7 +2140,7 @@ class Driver:
                     break
                 data, ts = nxt
                 ts = np.asarray(ts, np.int64)
-                with self._push_lock:
+                with self._loop_push:
                     self.metrics["records_in"] += len(ts)
                     self.metrics["batches"] += 1
                     self._push_downstream(
@@ -2136,7 +2176,7 @@ class Driver:
                 if self._cancel is not None and self._cancel.is_set():
                     raise JobCancelledError(job_name)
                 self._t_input = self.phases.phase("ingest.route")
-                with self._push_lock:
+                with self._loop_push:
                     self.metrics["shuffle_records_replayed"] = (
                         self.metrics.get("shuffle_records_replayed", 0)
                         + len(ts))
@@ -2165,7 +2205,7 @@ class Driver:
             if op is not None and hasattr(op, "quiesce"):
                 op.quiesce()
         self._t_input = self.phases.phase("wm.advance")
-        with self._push_lock:
+        with self._loop_push:
             self._propagate_watermarks(final=True, only=only)
         self.phases.phase("ingest.drain_wait")
         self._flush_emits()
@@ -2183,7 +2223,7 @@ class Driver:
             pass
         ph("ingest.route")   # the operator below opens its window.* phases
         valid = np.ones(len(ts_c), bool)
-        with self._push_lock:
+        with self._loop_push:
             self.metrics["records_in"] += len(ts_c)
             self.metrics["batches"] += 1
             self._push_downstream(sid, (dict(data_c), ts_c, valid))
@@ -2237,7 +2277,7 @@ class Driver:
                 gens[split_ix].on_batch(int(ts_s.max()))
             self._recombine_source_wm(sid, splits_alive)
             ph("wm.advance")
-            with self._push_lock:
+            with self._loop_push:
                 self._propagate_watermarks()
             ph("ingest.bookkeeping")
             self._check_drain_error()
@@ -2486,16 +2526,28 @@ class Driver:
             cohort["op"] = nid
             cohort["t_input"] = self._t_input
             self._fires.append(cohort)
+        # t_queued: the cohort leaves the advance that fired it (the
+        # clear's dispatch and the release of dead keys lie between
+        # t_fire and here) for the drain, or for the delivery below
+        stamp = time.perf_counter()
+        if cohort is not None:
+            cohort["t_queued"] = stamp
         if self._emit_q is not None and self._stateless_downstream(nid):
-            self._emit_q.put((nid, fired, time.perf_counter()))
+            self._emit_q.put((nid, fired, stamp))
             return
-        self._emit_fired_sync(nid, fired, time.perf_counter())
+        self._emit_fired_sync(nid, fired, stamp)
 
-    def _emit_fired_sync(self, nid: int, fired, stamp: float) -> None:
+    def _emit_fired_sync(self, nid: int, fired, stamp: float,
+                         t_push0: Optional[float] = None) -> None:
+        """``t_push0``: when the drain took ``_push_lock`` for the poll
+        this delivery belongs to; in line the loop holds it all along,
+        and the stamp is the rows' arrival in hand."""
         ring_origin = getattr(fired, "_ring", False)
         attrs = {"ring": fired._ring_no} if ring_origin else {}
         with self.phases.span("drain.deliver", **attrs):
             out = dict(fired)  # materializes lazy FiredWindows
+            if t_push0 is None:
+                t_push0 = time.perf_counter()
             # the fire cohorts whose rows this delivery makes visible at
             # the sink. Emit-ring fires: every cohort the drain's fetch
             # made host-visible (one poll coalesces several sub-batch
@@ -2525,7 +2577,7 @@ class Driver:
             # where it was handed to the drain
             now = time.perf_counter()
             for c in cohorts:
-                c["t_sink"] = now
+                c["t_push0"], c["t_sink"] = t_push0, now
                 self._lat_hist.update((now - c["t_fire"]) * 1000.0)
             if nrec and not cohorts and not ring_origin:
                 self._lat_hist.update((now - stamp) * 1000.0)
@@ -2533,15 +2585,25 @@ class Driver:
     def fire_records(self) -> List[Dict[str, Any]]:
         """One record per window end of each fire cohort (the newest
         ``FIRE_RECORDS``): ``op``, ``window_end`` and, on
-        ``time.perf_counter()``, ``t_input`` (the source handed over the
-        batch that carried the watermark past the end), ``t_fire`` (fire
-        dispatched), ``t_fetch0`` / ``t_fetch1`` (the fetch of its rows
-        began / ended), ``t_sink`` (``sink.write`` returned). A stamp
-        the cohort never reached is ``None``."""
-        stamps = ("t_input", "t_fire", "t_fetch0", "t_fetch1", "t_sink")
-        out = [{"op": c.get("op"), "window_end": int(we),
-                **{k: c.get(k) for k in stamps}}
-               for c in list(self._fires) for we in c["window_ends"]]
+        ``time.perf_counter()`` and in this order, ``t_input`` (the
+        source handed over the batch that carried the watermark past the
+        end), ``t_fire`` (fire dispatched), ``t_queued`` (the advance
+        that fired it is over and the cohort is handed to the drain),
+        ``t_fetch0`` (the fetch of its rows began), ``t_ready`` (its
+        wait for the device and the copy ended), ``t_fetch1`` (the rows
+        are host arrays), ``t_push0`` (the delivery holds
+        ``_push_lock``), ``t_sink`` (``sink.write`` returned). A stamp
+        the cohort never reached is ``None``, and so is ``t_queued``
+        where an EARLIER poll's fetch took the rows (a newer ring
+        version had landed) before the cohort was queued."""
+        out = []
+        for c in list(self._fires):
+            rec = {k: c.get(k) for k in FIRE_STAMPS}
+            if (rec["t_queued"] is not None and rec["t_fetch0"] is not None
+                    and rec["t_queued"] > rec["t_fetch0"]):
+                rec["t_queued"] = None
+            out.extend({"op": c.get("op"), "window_end": int(we), **rec}
+                       for we in c["window_ends"])
         return out[-FIRE_RECORDS:]
 
     def _stateless_downstream(self, nid: int) -> bool:
@@ -2594,6 +2656,10 @@ class Driver:
         emit_q = self._emit_q
         discard = self._drain_discard
         gate = self._drain_gate
+        # the drain's three waits, between its spans: no leaf is open,
+        # so each is a counter (profile.detail.drain/...) and no host
+        # event: a thread that waits names no idle gap of the device
+        detail = self.phases.detail
         while True:
             items = [emit_q.get()]
             # Deferral: the fire dispatch already issued copy_to_host_async
@@ -2605,7 +2671,8 @@ class Driver:
                 wait = self._emit_defer_s - (time.perf_counter()
                                              - items[0][2])
                 if wait > 0:
-                    self._flush_req.wait(wait)
+                    with detail("drain/defer"):
+                        self._flush_req.wait(wait)
             # opportunistically take the whole backlog: N queued fires
             # materialize in ONE device→host round trip instead of N
             while True:
@@ -2629,19 +2696,27 @@ class Driver:
                 # holds the shared device→host link — waits its round-
                 # robin turn among co-resident jobs; the host-side
                 # decode/push below stays outside the turn
-                with (gate.turn(self._gate_token) if gate is not None
-                      else contextlib.nullcontext()):
-                    with self._link_lock:
-                        FiredWindows.materialize_many(
-                            [f for _, f, _ in batch], barrier=barrier)
-                with self._push_lock:
+                with contextlib.ExitStack() as link:
+                    with detail("drain/link_wait"):
+                        if gate is not None:
+                            link.enter_context(gate.turn(self._gate_token))
+                        link.enter_context(self._link_lock)
+                    FiredWindows.materialize_many(
+                        [f for _, f, _ in batch], barrier=barrier)
+                with detail("drain/push_wait"):
+                    self._push_lock.acquire()
+                try:
+                    t_push0 = time.perf_counter()
                     # re-check under the push lock: the run may have
                     # aborted (and aborted the sinks) while this batch
                     # was wedged in the device fetch above — delivering
                     # it now would pollute a successor attempt's sinks
                     if not discard[0]:
                         for nid, fired, stamp in batch:
-                            self._emit_fired_sync(nid, fired, stamp)
+                            self._emit_fired_sync(
+                                nid, fired, stamp, t_push0)
+                finally:
+                    self._push_lock.release()
             except BaseException as e:  # surface at the next barrier —
                 # a silently-dead drain thread would deadlock join()
                 self._drain_error = e

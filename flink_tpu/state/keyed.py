@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -104,6 +105,12 @@ class _NumpyHashTable:
     Python loop per key."""
 
     def __init__(self, capacity_hint: int = 1024) -> None:
+        # doublings so far and the seconds they took (``growth``)
+        self._grows = 0
+        self._grow_s = 0.0
+        self._alloc(capacity_hint)
+
+    def _alloc(self, capacity_hint: int) -> None:
         size = 1
         while size < max(capacity_hint * 2, 16):
             size *= 2
@@ -111,6 +118,11 @@ class _NumpyHashTable:
         self._vals = np.zeros(size, dtype=np.int64)
         self._used = np.zeros(size, dtype=bool)
         self._count = 0
+
+    def growth(self) -> Tuple[int, float, int]:
+        """``(doublings so far, the seconds they took, buckets now)``:
+        what the native table counts in ``ht_grow``."""
+        return self._grows, self._grow_s, len(self._keys)
 
     def lookup_keys(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         return self.lookup(keys, hash_keys_numpy(keys))
@@ -219,12 +231,15 @@ class _NumpyHashTable:
         return int((edges[1::2] - edges[::2]).max(initial=0))
 
     def _grow(self) -> None:
+        began = time.perf_counter()
         old_keys, old_vals, old_used = self._keys, self._vals, self._used
-        self.__init__(capacity_hint=len(old_keys))
+        self._alloc(capacity_hint=len(old_keys))
         live = np.nonzero(old_used)[0]
         if len(live):
             self.insert_batch(
                 old_keys[live], hash_keys_numpy(old_keys[live]), old_vals[live])
+        self._grows += 1
+        self._grow_s += time.perf_counter() - began
 
 
 # "no pane noted": below every pane, so such a key is released at the
@@ -447,6 +462,12 @@ class KeyDirectory:
 
     def num_keys(self) -> int:
         return self._n_keys
+
+    def table_growth(self) -> Tuple[int, float, int]:
+        """``(doublings of the key table so far, the seconds they took,
+        its buckets now)``, counted inside the table whichever lane's
+        call grew it."""
+        return self._table.growth()
 
     # -- release and reuse ----------------------------------------------
     def track_panes(self) -> None:

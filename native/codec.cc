@@ -15,6 +15,7 @@
 // encoded here and keys hashed in Python MUST route identically.
 
 #include <algorithm>
+#include <chrono>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
@@ -189,6 +190,8 @@ struct FtHashTable {
   uint64_t mask;   // size - 1
   int64_t count;
   AssignScratch* assign;   // ht_assign's workspace, made at its first call
+  int64_t grows;           // doublings so far, and what they took on a
+  int64_t grow_ns;         // steady clock (ht_growth reads both)
 };
 
 static void ht_alloc(FtHashTable* t, uint64_t size) {
@@ -200,6 +203,7 @@ static void ht_alloc(FtHashTable* t, uint64_t size) {
 }
 
 static void ht_grow(FtHashTable* t) {
+  const auto began = std::chrono::steady_clock::now();
   FtHashTable old = *t;
   ht_alloc(t, (old.mask + 1) * 2);
   for (uint64_t i = 0; i <= old.mask; ++i) {
@@ -212,6 +216,9 @@ static void ht_grow(FtHashTable* t) {
     ++t->count;
   }
   free(old.keys); free(old.vals); free(old.used);
+  ++t->grows;
+  t->grow_ns += std::chrono::duration_cast<std::chrono::nanoseconds>(
+      std::chrono::steady_clock::now() - began).count();
 }
 
 // Load factor at most one half: doubles before the entry that would pass it.
@@ -225,6 +232,7 @@ void* ht_new(int64_t capacity_hint) {
   FtHashTable* t = (FtHashTable*)malloc(sizeof(FtHashTable));
   ht_alloc(t, size);
   t->assign = nullptr;
+  t->grows = t->grow_ns = 0;
   return t;
 }
 
@@ -235,6 +243,16 @@ void ht_free(void* h) {
 }
 
 int64_t ht_count(void* h) { return ((FtHashTable*)h)->count; }
+
+// The table's growth so far, counted where it happens: out[0] doublings
+// (whichever call's entry was due one), out[1] the nanoseconds they
+// took, out[2] the buckets it has now.
+void ht_growth(void* h, int64_t* out) {
+  FtHashTable* t = (FtHashTable*)h;
+  out[0] = t->grows;
+  out[1] = t->grow_ns;
+  out[2] = (int64_t)(t->mask + 1);
+}
 
 // Batch lookup; hashes computed inline. out_vals[i] untouched-where-miss
 // semantics are NOT provided: misses write -1 and out_found[i]=0 (vals may

@@ -5,8 +5,12 @@
   single interval is kept per leaf and per run;
 - the phases (and ``Tracer.span``) are host events of a ``jax.profiler``
   trace, never nested on the loop thread, none open while the drain waits;
-- ``trace.fires``: one record per committed window end, stamped in order;
-- the clock itself: switch, count, longest, threads, exceptions.
+- ``trace.fires``: one record per committed window end, its eight stamps
+  in order;
+- the clock itself: switch, count, longest, threads, exceptions;
+- one level below a leaf (``PhaseClock.detail``): a child of the open leaf
+  that leaves the partition alone, a host event nested in the leaf's, a
+  counter only where no leaf is open; the general lane's six, once a batch.
 """
 import glob
 import threading
@@ -22,18 +26,20 @@ from flink_tpu.api.sources import GeneratorSource
 from flink_tpu.config import Configuration
 from flink_tpu.nexmark.queries import q5_hot_items
 from flink_tpu.obs.tracing import PhaseClock, tracer
-from flink_tpu.runtime.driver import FIRE_RECORDS, PHASE_LEAVES
+from flink_tpu.runtime.driver import FIRE_RECORDS, FIRE_STAMPS, PHASE_LEAVES
 
 BATCH = 4096
 PHASE_PREFIXES = ("ingest.", "window.", "wm.", "drain.", "state.")
 
 
-def q5_job(n_batches=40, sleep_s=0.0, **conf):
+def q5_job(n_batches=40, sleep_s=0.0, auctions=50, **conf):
     """A small host-fed Q5 (hot items, 10 s windows sliding by 2 s, 500 ms
     of event time a batch). Every fifth batch repeats its predecessor's
     timestamps, so the watermark stands still and the stashed upload goes
     out as a step of its own (``window.step_dispatch``) instead of riding
-    the next fire. Returns (JobResult, committed rows, the driver)."""
+    the next fire. ``auctions``: the ids a batch draws from; with about as
+    many as records the fused scan's gate says no and every batch goes the
+    general lane. Returns (JobResult, committed rows, the driver)."""
 
     def gen(split, i):
         if i >= n_batches:
@@ -43,11 +49,13 @@ def q5_job(n_batches=40, sleep_s=0.0, **conf):
         rng = np.random.default_rng(i)
         at = i - 1 if i % 5 == 4 else i
         ts = np.sort(at * 500 + rng.integers(0, 500, BATCH)).astype(np.int64)
-        return {"auction": rng.integers(0, 50, BATCH).astype(np.int64)}, ts
+        return {"auction": rng.integers(0, auctions, BATCH).astype(np.int64)
+                }, ts
 
     env = StreamExecutionEnvironment(Configuration({
         "pipeline.microbatch-size": BATCH, "state.num-key-shards": 8,
-        "state.slots-per-shard": 64, "analysis.fail-on": "off", **conf}))
+        "state.slots-per-shard": max(64, auctions // 4),
+        "analysis.fail-on": "off", **conf}))
     sink = CollectSink()
     q5_hot_items(env, GeneratorSource(gen), sink, window_ms=10_000,
                  slide_ms=2_000, out_of_orderness_ms=1_000)
@@ -155,10 +163,39 @@ class TestFireRecords:
         ends = [f["window_end"] for f in fires]
         assert len(ends) == len(set(ends))      # in order: nothing refires
         assert committed <= set(ends)
+        assert FIRE_STAMPS == (
+            "t_input", "t_fire", "t_queued", "t_fetch0", "t_ready",
+            "t_fetch1", "t_push0", "t_sink")
+        queued = 0
         for f in fires:
             assert f["op"] is not None
-            assert (f["t_input"] <= f["t_fire"] <= f["t_fetch0"]
-                    <= f["t_fetch1"] <= f["t_sink"]), f
+            assert set(f) == {"op", "window_end", *FIRE_STAMPS}
+            # t_queued alone may be missing: where an earlier poll's fetch
+            # took the rows before the cohort was handed to the drain
+            assert all(f[k] is not None for k in FIRE_STAMPS
+                       if k != "t_queued"), f
+            at = [f[k] for k in FIRE_STAMPS if f[k] is not None]
+            assert at == sorted(at), f
+            queued += f["t_queued"] is not None
+        assert queued >= len(fires) // 2
+
+    def test_t_queued_is_left_out_where_the_fetch_began_before_it(self, job):
+        driver = job[2]
+        kept = list(driver._fires)
+        stamps = dict(zip(FIRE_STAMPS, map(float, range(8))))
+        try:
+            driver._fires.clear()
+            driver._fires.append({"window_ends": [7], "op": 0, **stamps})
+            # the drain's poll for the cohort before it read a newer ring
+            # version: fetched at 2.5, handed over (t_queued) at 9
+            driver._fires.append({"window_ends": [8], "op": 0, **stamps,
+                                  "t_fetch0": 2.5, "t_queued": 9.0})
+            first, second = driver.fire_records()
+            assert first["t_queued"] == 2.0
+            assert second["t_queued"] is None and second["t_fetch0"] == 2.5
+        finally:
+            driver._fires.clear()
+            driver._fires.extend(kept)
 
     def test_emit_latency_samples_are_t_sink_minus_t_fire(self, job):
         res, _rows, driver = job
@@ -185,6 +222,74 @@ class TestFireRecords:
         finally:
             driver._fires.clear()
             driver._fires.extend(kept)
+
+
+def details_of(metrics):
+    """``<leaf>/<name>`` -> (seconds, count, longest ms)."""
+    pre = "profile.detail."
+    return {k[len(pre):]: (v, metrics["profile.detail_n." + k[len(pre):]],
+                           metrics["profile.detail_longest_ms."
+                                   + k[len(pre):]])
+            for k, v in metrics.items() if k.startswith(pre)}
+
+
+GENERAL_LANE = {"window.key_scan/" + n for n in (
+    "prepare", "panes", "assign", "slot_mask", "note_panes", "preagg_gate")}
+DRAIN_WAITS = {"drain/defer", "drain/link_wait", "drain/push_wait"}
+
+
+class TestJobDetails:
+    def test_the_general_lanes_six_count_once_a_batch(self):
+        """4,096 records over 3,000 auctions: the fused scan's gate says no
+        to every batch once the keys are registered, so the numpy lane
+        runs, named below its leaf."""
+        q5_job(n_batches=8, auctions=3000)      # compiles
+        res, rows, _driver = q5_job(n_batches=30, auctions=3000)
+        m = res.metrics
+        assert rows
+        details = details_of(m)
+        assert GENERAL_LANE <= set(details)
+        assert set(details) <= GENERAL_LANE | DRAIN_WAITS
+        (assigned,) = [v for k, v in m.items()
+                       if k.startswith("profile.op")
+                       and k.endswith(".assign_records")]
+        general = assigned // BATCH         # batches the directory keyed
+        assert general >= 29
+        for key in GENERAL_LANE:
+            secs, n, longest_ms = details[key]
+            assert n == general, key
+            assert 0 < longest_ms <= 1e3 * secs + 1e-3, key
+        # children of the leaf: together no more than the leaf, and the
+        # leaf is still every batch's, whatever ran below it
+        inside = sum(details[k][0] for k in GENERAL_LANE)
+        assert 0 < inside <= m["profile.phase.window.key_scan"] + 1e-5
+        assert m["profile.phase.window.key_scan.n"] >= 30
+        # the partition is what it was: the loop's leaves sum to its wall
+        leaves = leaves_of(m)
+        loop = sum(v for k, v in leaves.items() if not k.startswith("drain."))
+        wall = m["profile.phase.loop_wall_s"]
+        assert abs(loop - wall) <= 0.03 * wall, (loop, wall)
+        assert not [k for k in leaves if "/" in k]
+
+    def test_the_fused_lane_has_none_after_its_first_batch(self, job):
+        m = job[0].metrics
+        details = details_of(m)
+        assert m["profile.phase.window.key_scan.n"] >= 40
+        for key in set(details) & GENERAL_LANE:
+            assert details[key][1] <= 1, key
+        assert set(details) <= GENERAL_LANE | DRAIN_WAITS
+
+    def test_the_drains_waits_are_counters(self, job):
+        """The deferral is 0 on the CPU, so ``drain/defer`` may be absent;
+        the two locks are taken once a poll."""
+        m = job[0].metrics
+        details = details_of(m)
+        polls = details["drain/push_wait"][1]
+        assert polls == details["drain/link_wait"][1] >= 1
+        assert polls >= m["profile.phase.drain.deliver.n"] / 40
+        # the loop's side of the same lock: a plain counter of seconds
+        assert m["profile.phase.push_wait_s"] >= 0.0
+        assert "profile.phase.push_wait_s.n" not in m
 
 
 def read_host_lines(trace_dir):
@@ -230,8 +335,9 @@ def test_phases_are_host_events_of_a_profiler_trace(tmp_path):
     lines = read_host_lines(str(tmp_path / "trace"))
 
     def program(events):
+        # the leaves: a detail (``<leaf>/<name>``) lies inside its leaf
         return sorted((s, e, n) for n, s, e, _st in events
-                      if n.startswith(PHASE_PREFIXES))
+                      if n.startswith(PHASE_PREFIXES) and "/" not in n)
 
     names = {n for evs in lines for n, *_ in evs}
     for want in ("ingest.route", "window.key_scan", "window.step_dispatch",
@@ -263,6 +369,11 @@ def test_phases_are_host_events_of_a_profiler_trace(tmp_path):
     assert busy < 0.5 * (dspans[-1][1] - dspans[0][0])
     ring = [st for n, _s, _e, st in drain if n == "drain.fetch"]
     assert ring and all("ring" in st for st in ring)
+    # its waits (drain/defer, drain/link_wait, drain/push_wait) are
+    # counters with no leaf open: no event carries their names
+    assert not [n for n in names if n.startswith("drain/")]
+    assert set(details_of(out["job"][0].metrics)) >= {
+        "drain/link_wait", "drain/push_wait"}
 
     # Tracer.span joins in: the checkpoint's spans with their attributes,
     # and /traces' durations on the monotonic clock
@@ -384,3 +495,201 @@ class TestPhaseClock:
         snap = c.snapshot()
         assert {k: v["count"] for k, v in snap.items()} == {
             "main.work": 1, "main.more": 1, "other.work": 1}
+
+
+class TestDetail:
+    def test_a_detail_leaves_the_partition_alone(self):
+        """Two clocks driven alike, one with details: the same leaves, the
+        same counts, and every reading of the leaves is the phase
+        switches' own, which a detail does not make."""
+        plain, detailed = PhaseClock(), PhaseClock()
+        for c in (plain, detailed):
+            t0 = c.phase("leaf.a")
+            if c is detailed:
+                with c.detail("x"):
+                    assert c.open_phase() == "leaf.a"
+                with c.detail("x"):
+                    pass
+                with c.detail("y"):
+                    pass
+            t1 = c.phase("leaf.b")
+            t2 = c.stop()
+            snap = c.snapshot()
+            assert set(snap) == {"leaf.a", "leaf.b"}
+            assert snap["leaf.a"]["count"] == snap["leaf.b"]["count"] == 1
+            assert snap["leaf.a"]["seconds"] == pytest.approx(
+                t1 - t0, abs=1e-9)
+            assert snap["leaf.a"]["longest_ms"] == pytest.approx(
+                1e3 * (t1 - t0), abs=1e-6)
+            assert sum(v["seconds"] for v in snap.values()) == pytest.approx(
+                t2 - t0, abs=1e-9)
+        assert plain.details() == {}
+        d = detailed.details()
+        assert set(d) == {"leaf.a/x", "leaf.a/y"}
+        assert d["leaf.a/x"]["count"] == 2 and d["leaf.a/y"]["count"] == 1
+        for st in d.values():
+            assert 0 <= st["longest_ms"] <= 1e3 * st["seconds"] + 1e-9
+        # children: together no more than their leaf
+        assert sum(st["seconds"] for st in d.values()) <= \
+            detailed.snapshot()["leaf.a"]["seconds"]
+
+    def test_time_in_another_leaf_is_that_leafs(self):
+        """A block may switch the phase (the general lane's ``prepare``
+        meets ``state.reclaim``): the detail stands still meanwhile and
+        goes on when its leaf is open again; one that never sees its leaf
+        again (``preagg_gate`` past its switch to ``window.pack``) ends
+        where the leaf did."""
+        c = PhaseClock()
+        c.phase("leaf.a")
+        with c.detail("x"):
+            with c.span("leaf.other") as other:
+                time.sleep(0.01)
+            assert c.open_phase() == "leaf.a"
+        with c.detail("gate"):
+            t_switch = c.phase("leaf.pack")
+            time.sleep(0.01)
+        t_end = c.stop()
+        snap, d = c.snapshot(), c.details()
+        assert d["leaf.a/x"]["count"] == d["leaf.a/gate"]["count"] == 1
+        assert snap["leaf.a"]["count"] == 2
+        assert other.seconds >= 0.01 and t_end - t_switch >= 0.01
+        # neither sleep is the details': they fit into what is left of
+        # the leaf, which holds no sleep
+        assert d["leaf.a/x"]["seconds"] + d["leaf.a/gate"]["seconds"] <= \
+            snap["leaf.a"]["seconds"] + 1e-9
+        assert snap["leaf.other"]["seconds"] == pytest.approx(
+            other.seconds, abs=1e-9)
+
+    def test_no_leaf_open_is_a_counter_under_its_own_name(self):
+        c = PhaseClock()
+        with c.detail("drain/defer"):
+            pass
+        with c.span("drain.fetch"):
+            pass
+        with c.detail("drain/defer"):
+            with c.span("drain.deliver"):    # a leaf's time is not a wait
+                time.sleep(0.005)
+        assert set(c.snapshot()) == {"drain.fetch", "drain.deliver"}
+        d = c.details()
+        assert set(d) == {"drain/defer"} and d["drain/defer"]["count"] == 2
+        assert c.open_phase() is None
+
+    def test_one_level_and_exceptions(self):
+        c = PhaseClock()
+        c.phase("leaf.a")
+        with pytest.raises(RuntimeError, match="one level"):
+            with c.detail("x"):
+                with c.detail("y"):
+                    pass
+        with pytest.raises(ValueError):
+            with c.detail("x"):
+                raise ValueError("boom")
+        with c.detail("x"):         # none was left open
+            pass
+        c.stop()
+        assert c.details()["leaf.a/x"]["count"] == 3
+        assert set(c.details()) == {"leaf.a/x"}
+
+    def test_threads_add_up(self):
+        c = PhaseClock()
+
+        def work():
+            c.phase("leaf.a")
+            for _ in range(50):
+                with c.detail("x"):
+                    pass
+            c.stop()
+
+        threads = [threading.Thread(target=work, daemon=True)
+                   for _ in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+        assert c.details()["leaf.a/x"]["count"] == 200
+        assert c.snapshot()["leaf.a"]["count"] == 4
+
+
+def test_a_detail_is_a_host_event_nested_in_its_leafs(tmp_path):
+    """Under ``jax.profiler``: with a leaf open a detail is the host event
+    ``<leaf>/<name>`` inside the leaf's own, on the same thread; with none
+    open (a thread that waits between its spans) it leaves no event."""
+    import jax
+
+    c = PhaseClock()
+
+    def traced():
+        c.phase("leaf.a")
+        time.sleep(0.002)
+        with c.detail("x"):
+            time.sleep(0.002)
+        with c.detail("y"):
+            with c.span("leaf.other"):      # the child ends before its leaf
+                time.sleep(0.002)
+            time.sleep(0.002)
+        c.stop()
+        with c.detail("wait/idle"):
+            time.sleep(0.002)
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+    try:
+        worker = threading.Thread(target=traced, daemon=True)
+        worker.start()
+        worker.join(timeout=60)
+        assert not worker.is_alive()
+    finally:
+        jax.profiler.stop_trace()
+    (line,) = [evs for evs in read_host_lines(str(tmp_path / "trace"))
+               if any(n == "leaf.a" for n, *_ in evs)]
+    mine = sorted((s, e, n) for n, s, e, _st in line
+                  if n.startswith(("leaf.", "wait/")))
+    assert [n for _s, _e, n in mine] == [
+        "leaf.a", "leaf.a/x", "leaf.a/y", "leaf.other", "leaf.a", "leaf.a/y"]
+    (a0s, a0e, _), (xs, xe, _), (y0s, y0e, _), (os_, _oe, _), \
+        (a1s, a1e, _), (y1s, y1e, _) = mine
+    assert a0s <= xs <= xe <= y0s <= y0e <= a0e <= os_
+    assert a1s <= y1s <= y1e <= a1e
+    assert set(c.details()) == {"leaf.a/x", "leaf.a/y", "wait/idle"}
+    assert c.details()["leaf.a/y"]["count"] == 1
+
+
+def test_nested_events_change_no_reading_of_the_benchmarks():
+    """A trace in which ``window.key_scan`` holds its details, as this
+    program's now does: ``hostkey.ms_per_batch.*``'s reader still sums the
+    leaf alone (its pattern is anchored), and an idle gap of the device
+    under the key scan is still the leaf's (the parent covers at least
+    what its child covers, and comes first)."""
+    import importlib
+
+    from benchmark import trace_reduce as tr
+
+    def host(details):
+        evs = [("ingest.route", 0.0, 100.0),
+               ("window.key_scan", 100.0, 1000.0),
+               ("window.step_dispatch", 1100.0, 100.0)]
+        if details:
+            evs += [("window.key_scan/panes", 110.0, 300.0),
+                    ("window.key_scan/assign", 420.0, 500.0),
+                    ("window.key_scan/slot_mask", 930.0, 160.0)]
+        return evs
+
+    def trace_of(details):
+        # the device works 1150..1200: idle 0..1150, all but 150 of it
+        # under the key scan, 500 of those under its longest detail
+        dev = tr.DeviceTrace("/device:TPU:0", [("jit_step(1)", 1150.0, 50.0)],
+                             [("fusion.1", 1150.0, 50.0)])
+        return tr.Trace([dev], host(details), (0.0, 1200.0))
+
+    read = importlib.import_module("benchmark.readers.trace_host").read
+    args = {"match": r"^window\.key_scan$", "per": "batches", "scale": 1000.0}
+    plain, nested = trace_of(False), trace_of(True)
+    want = read({"trace": plain, "trace_batches": 1}, **args)
+    assert want == pytest.approx(1000.0 / 1e9 / 1.0 * 1000.0)
+    assert read({"trace": nested, "trace_batches": 1}, **args) == want
+    for trace in (plain, nested):
+        gaps = dict(trace.labelled_gaps(trace.busiest()))
+        assert gaps == {"window.key_scan": pytest.approx(1150.0 / 1e9)}
