@@ -535,6 +535,44 @@ def _unpack_fire_params(params: jax.Array):
     return pane_lo, pane_hi, anchor, end_panes, w_valid
 
 
+def first_true_indices(flat: jax.Array, cap: int) -> jax.Array:
+    """(cap,) int32: the positions of the first ``cap`` True entries of
+    the 1-D mask ``flat`` in ascending order, ``len(flat)`` where it has
+    fewer — the fire's compaction (selected candidates in row-major
+    order, padded to a fixed shape). Two forms, chosen by the static
+    shapes. FEW WINNERS OF MANY CANDIDATES (every top-n fire of a grid
+    worth naming: ``cap`` 256 of 32,769 to 16.8 M): a prefix sum of the
+    mask and a binary search of it for the j-th winner, j = 1..cap; at
+    16,777,217 candidates the fire takes 19.0 ms a call where the
+    stable argsort of the negated mask it replaced took 95.6, 80.1 of
+    them the sort, and at 32,769 x 64 0.56 against 5.9. AS MANY WINNERS
+    AS CANDIDATES (``fire_pack_kernel``, whose ``cap`` bounds every row
+    that may fire; tiny grids): ``cap`` searches are ``cap`` x log2 k
+    gathers, ~7.5 ns each on the chip (18.3 ms a call at 131,076
+    candidates and a cap of 131,072, where the argsort's call took
+    2.9), so there the positions are sorted once, the unselected ones
+    masked to ``len(flat)``: a sort costs what the candidates cost,
+    whatever the cap. (``tools/fire_micro.py``; my chip runs, PR 40.)"""
+    k = flat.shape[0]
+    if 2 * cap * k.bit_length() <= k:
+        return searched_true_indices(flat, cap)
+    return sorted_true_indices(flat, cap)
+
+
+def searched_true_indices(flat: jax.Array, cap: int) -> jax.Array:
+    c = jnp.cumsum(flat, dtype=jnp.int32)
+    return jnp.searchsorted(
+        c, jnp.arange(1, cap + 1, dtype=jnp.int32), side="left")
+
+
+def sorted_true_indices(flat: jax.Array, cap: int) -> jax.Array:
+    k = flat.shape[0]
+    idx = lax.sort(jnp.where(flat, jnp.arange(k, dtype=jnp.int32), k))[:cap]
+    if cap > k:  # tiny grids: pad to the fixed selection shape
+        idx = jnp.concatenate([idx, jnp.full(cap - k, k, jnp.int32)])
+    return idx
+
+
 def fire_pack_kernel(
     state: PaneState,
     params: jax.Array,      # packed: see _unpack_fire_params
@@ -568,15 +606,7 @@ def fire_pack_kernel(
     nz = (counts > 0) & used_mask[:, None] & w_valid[None, :]
     flat = nz.reshape(-1)
     k = rows * W
-    # stable-argsort compaction instead of jnp.nonzero — identical
-    # semantics (selected indices in row-major order, k-padded), but a
-    # sort is a cheap TPU primitive where nonzero's lowering is not
-    # (cost not measured on the current chip)
-    m = min(k, out_cap)
-    idx = jnp.argsort(~flat, stable=True)[:m]
-    idx = jnp.where(flat[idx], idx, k)
-    if m < out_cap:
-        idx = jnp.concatenate([idx, jnp.full(out_cap - m, k, idx.dtype)])
+    idx = first_true_indices(flat, out_cap)
     row = jnp.minimum(idx // W, rows - 1).astype(jnp.int32)
     wi = (idx % W).astype(jnp.int32)
     sel_counts = jnp.where(idx < k, counts[row, wi], 0)
@@ -630,16 +660,7 @@ def _topn_select_append(
     sel = nz & (v >= thresh[None, :])
     flat = sel.reshape(-1)
     K = rows * W
-    # compact via a stable ARGSORT of the negated mask instead of
-    # jnp.nonzero (a sort is a cheap TPU primitive where nonzero's
-    # lowering is not; cost not measured on the current chip); the
-    # first sel_cap positions are exactly the selected indices in
-    # row-major order
-    m = min(K, sel_cap)
-    idx = jnp.argsort(~flat, stable=True)[:m]
-    idx = jnp.where(flat[idx], idx, K)
-    if m < sel_cap:  # tiny grids: pad to the fixed selection shape
-        idx = jnp.concatenate([idx, jnp.full(sel_cap - m, K, idx.dtype)])
+    idx = first_true_indices(flat, sel_cap)
     row = jnp.minimum(idx // W, rows - 1).astype(jnp.int32)
     wi = (idx % W).astype(jnp.int32)
     total_sel = jnp.sum(flat).astype(jnp.int32)
@@ -803,12 +824,12 @@ def _fused_fire_clear(state, emit_ring, hdr, used_mask, *, agg,
     """Fire + clear tail of the one-dispatch step kernel: the fire
     parameters and the purge mask ride the FUSED_HDR header.
 
-    The fire/top-n/ring-append subgraph — whose stable argsort + top_k
+    The fire/top-n/ring-append subgraph — whose selection scan + top_k
     would otherwise run on every dispatch whether or not any window
     fires — runs under a ``lax.cond`` keyed on the header's window-end
     list, and the pane purge under a second cond keyed on the clear
     words. The host fills both header fields before dispatch
-    (``_fused_fill_header``), so a non-firing sub-batch skips the sort
+    (``_fused_fill_header``), so a non-firing sub-batch skips the fire
     entirely. Byte-identical by construction: with no valid ends the
     fire core selects zero rows and leaves ring bytes and head counters
     unchanged, and a zero clear mask is the identity — the cond only
@@ -817,7 +838,7 @@ def _fused_fire_clear(state, emit_ring, hdr, used_mask, *, agg,
     ``fire_pad``: how many of the header's MIN_FIRE_PAD window-end
     slots this program READS — the static width of the whole fire
     subgraph (fire_kernel's rows×W reductions, the rows×W selection
-    argsort, the W-way top_k). The host buckets it to the next power
+    scan, the W-way top_k). The host buckets it to the next power
     of two ≥ the sub-batch's real end count (``_fire_pad_bucket``), so
     K sub-batch dispatches of ~W/K ends each cost ≈ ONE W-wide fire —
     without this, every dispatch paid the full 64-wide subgraph and
@@ -1610,6 +1631,14 @@ class WindowOperator:
         self.slots_waiting_peak = 0
         # stays 0 while the reuse rule holds (see _return_released)
         self.slots_returned_early = 0
+        # a purge whose release has not run yet (_defer_release), the
+        # fire cohort of the advance that purged, and how many releases
+        # ran, how many of them after their cohort had been queued for
+        # the drain (``t_queued`` stamped)
+        self._release_pending = False
+        self._release_cohort: Optional[Dict[str, Any]] = None
+        self.releases = 0
+        self.releases_after_queue = 0
         # every fire numbered up to this has had its rows decoded
         self._fires_decoded = 0
         # pack-mode fires (no top-n): the latest's number, and those
@@ -1751,7 +1780,7 @@ class WindowOperator:
         log2(MIN_FIRE_PAD)+1 compiled buckets, shared process-wide
         through the module-level jit cache, and a steady cadence hits
         one or two of them. The fire cost (fire_kernel's rows×W
-        reductions, the rows×W selection argsort, the W-way top_k)
+        reductions, the rows×W selection scan, the W-way top_k)
         scales with the bucket, so K sub-batch dispatches of ~W/K real
         ends each cost ≈ one W-wide fire instead of K full-pad fires —
         the other half of the sub-batching tax next to the zero-end
@@ -1828,6 +1857,7 @@ class WindowOperator:
             # the record's time axis IS the clock at ingest
             ts = np.full(len(np.asarray(ts)), self.clock.now_ms(),
                          np.int64)
+        self.run_pending_release()    # ahead of this batch's allocations
         # count-only fused fast lane: ONE native scan does panes, late
         # masking, drop accounting, min/max, refire candidates, and the
         # pre-agg histogram (the numpy path below makes ~6 full-array
@@ -2434,6 +2464,7 @@ class WindowOperator:
         calls this before the FINAL watermark advance so the flush fires
         dispatch onto an idle device — their emit latency then measures
         fire+fetch, not the whole tail of the ingest pipeline."""
+        self.run_pending_release()
         self._flush_stash()
         while self._inflight:
             self._retire_step()
@@ -2580,6 +2611,7 @@ class WindowOperator:
             return self._advance_watermark(wm)
 
     def _advance_watermark(self, wm: int) -> "FiredWindows":
+        self.run_pending_release()    # one purge, one release
         self.state_version += 1
         prev = self.watermark
         self.watermark = wm
@@ -2656,10 +2688,33 @@ class WindowOperator:
             self._cleared_below = new_dead
             if self._spill is not None:
                 self._spill.purge_below(new_dead)
-            self._release_dead_keys()
+            self._defer_release(out)
         return out
 
     # -- keys that leave, and the reuse rule ------------------------------
+    def _defer_release(self, fired: "FiredWindows") -> None:
+        """A purge has moved ``_cleared_below``: its release is left
+        PENDING, and the advance returns. The release is the long part
+        of a purging advance (~1.2 M deletes, ~0.1 s, at 8.5 M live
+        keys) and no fired row needs it: the caller first hands
+        ``fired`` to whoever delivers it, then calls
+        ``run_pending_release`` (the driver: outside its push lock, so
+        the drain decodes and delivers meanwhile). Whatever needs the
+        directory up to date runs it first: the next batch, the next
+        advance, a snapshot, ``quiesce``."""
+        if self._releases:
+            self._release_pending = True
+            self._release_cohort = getattr(fired, "cohort", None)
+
+    def run_pending_release(self) -> None:
+        """Run the release a purging advance left pending; nothing
+        where none is. On the thread that drives the operator: the
+        directory keeps its one writer. The drain thread may decode
+        fired rows meanwhile: of the directory it reads ``_rev_keys``
+        alone (``key_of_slots``), which a release does not write."""
+        if self._release_pending:
+            self._release_dead_keys()
+
     def _release_dead_keys(self) -> None:
         """After a purge has moved ``_cleared_below``: release every key
         whose newest pane lies below it. ``first_dead_pane`` already
@@ -2680,7 +2735,9 @@ class WindowOperator:
         another thread; a fire, the purge of its oldest pane, this
         release and the next batch's allocation all come before that. So
         a released slot is stamped with the number of fires dispatched
-        so far, and goes back to its shard's allocator
+        when the release RUNS (never earlier than at its purge: it runs
+        before the next batch and the next advance), and goes back to
+        its shard's allocator
         (``_return_released``) only once every fire up to that number
         has had its rows decoded (``_drained_through``): the one-chip
         emit ring, the mesh's ring blocks, the chunked ``_fire_ends``
@@ -2688,8 +2745,11 @@ class WindowOperator:
         they bump, pack-mode fires by ``_pack_no``. Fires dispatched
         after the release cannot name the slot for its old key: its
         rows count nothing until a new key is given it."""
-        if not self._releases:
-            return
+        cohort, self._release_cohort = self._release_cohort, None
+        self._release_pending = False
+        self.releases += 1
+        if cohort is not None and cohort.get("t_queued") is not None:
+            self.releases_after_queue += 1
         with self.phases.span("state.release"):
             rel = self.directory.release_below(self._cleared_below)
             if len(rel):
@@ -2750,6 +2810,10 @@ class WindowOperator:
                 "state.slots_reused": d.slots_reused,
                 "state.slots_released": d.slots_released,
                 "state.slots_returned_early": self.slots_returned_early,
+                # purges whose release ran, and those of them that ran
+                # once their fire's cohort was with the drain
+                "state.releases": self.releases,
+                "state.releases_after_queue": self.releases_after_queue,
                 "state.live_keys": d.num_keys(),
                 "state.live_keys_peak": d.keys_peak,
                 "state.slots_waiting_peak": self.slots_waiting_peak,
@@ -2840,7 +2904,7 @@ class WindowOperator:
         self._cleared_below = cleared_after
         out = self._ring_after_fire(ends_f, covered=True)
         if purged:
-            self._release_dead_keys()
+            self._defer_release(out)
         return out
 
     def _fire_cohort(self, end_panes: List[int]) -> Dict[str, Any]:
@@ -3309,6 +3373,8 @@ class WindowOperator:
         return self._spill.records_spilled if self._spill is not None else 0
 
     def snapshot_state(self) -> Dict[str, Any]:
+        # the snapshot holds no key a purge has left behind
+        self.run_pending_release()
         # the snapshot must include stashed records
         self._flush_stash()
         self._resolve_reports()  # a checkpoint must not hide pending loss
@@ -3409,6 +3475,7 @@ class WindowOperator:
                 self.directory.note_all(self._max_pane_seen)
         # pre-restore fires are a dead timeline, and the snapshot holds
         # what waited as free (see snapshot_state)
+        self._release_pending, self._release_cohort = False, None
         self._waiting.clear()
         self._n_waiting = 0
         self._packs_open.clear()

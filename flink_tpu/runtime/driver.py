@@ -1130,8 +1130,7 @@ class Driver:
         if gwm != _FINAL and gwm > self._out_wm[sid]:
             self._out_wm[sid] = gwm
         ph("wm.advance")
-        with self._loop_push:
-            self._propagate_watermarks()
+        self._advance_time()
         ph("ingest.bookkeeping")
         self._check_drain_error()
         # commit the PREVIOUS checkpoint once every process acked
@@ -1180,8 +1179,8 @@ class Driver:
                 self._push_downstream(
                     sid, ({kk: v[lo:hi] for kk, v in md.items()},
                           mts[lo:hi], valid[lo:hi]))
-                self.phases.phase("wm.advance")
-                self._propagate_watermarks()
+            self.phases.phase("wm.advance")
+            self._advance_time()
             self._check_drain_error()
 
     def _enumerate_owned(self, sid: int, n_splits: int) -> List[int]:
@@ -1800,8 +1799,7 @@ class Driver:
                 # (ref: idle-channel handling in the valve)
                 self._recombine_source_wm(sid, splits_alive)
                 ph("wm.advance")
-                with self._loop_push:
-                    self._propagate_watermarks()
+                self._advance_time()
                 ph("ingest.bookkeeping")
                 self._check_drain_error()
             if self._profiler is not None:
@@ -1833,8 +1831,7 @@ class Driver:
         for sid in self.plan.sources:
             self._out_wm[sid] = _FINAL
         self._t_input = ph("wm.advance")
-        with self._loop_push:
-            self._propagate_watermarks(final=True)
+        self._advance_time(final=True)
         # the loop is over: what follows waits for the drain and commits
         self._loop_wall_s = self.phases.stop() - self._t_loop
         self._flush_emits()
@@ -2205,8 +2202,7 @@ class Driver:
             if op is not None and hasattr(op, "quiesce"):
                 op.quiesce()
         self._t_input = self.phases.phase("wm.advance")
-        with self._loop_push:
-            self._propagate_watermarks(final=True, only=only)
+        self._advance_time(final=True, only=only)
         self.phases.phase("ingest.drain_wait")
         self._flush_emits()
         self.phases.phase("ingest.bookkeeping")
@@ -2277,8 +2273,7 @@ class Driver:
                 gens[split_ix].on_batch(int(ts_s.max()))
             self._recombine_source_wm(sid, splits_alive)
             ph("wm.advance")
-            with self._loop_push:
-                self._propagate_watermarks()
+            self._advance_time()
             ph("ingest.bookkeeping")
             self._check_drain_error()
 
@@ -2445,6 +2440,19 @@ class Driver:
             raise AssertionError(f"unroutable node kind {n.kind}")
 
     # -- time plane ------------------------------------------------------
+    def _advance_time(self, final: bool = False, only=None) -> None:
+        """One watermark pass under the push lock and then, the lock let
+        go and the fired cohorts with the drain (``t_queued``), the
+        releases that the purging advances left pending
+        (``WindowOperator._defer_release``): the long part of such an
+        advance holds back neither the cohort nor the drain's delivery.
+        On the loop's thread, as every other write of a key directory."""
+        with self._loop_push:
+            self._propagate_watermarks(final=final, only=only)
+        for op in self._ops.values():
+            if hasattr(op, "run_pending_release"):
+                op.run_pending_release()
+
     def _propagate_watermarks(self, final: bool = False,
                               only=None) -> None:
         """Advance node watermarks in topo order (the StatusWatermarkValve
@@ -2527,8 +2535,9 @@ class Driver:
             cohort["t_input"] = self._t_input
             self._fires.append(cohort)
         # t_queued: the cohort leaves the advance that fired it (the
-        # clear's dispatch and the release of dead keys lie between
-        # t_fire and here) for the drain, or for the delivery below
+        # clear's dispatch lies between t_fire and here; the release of
+        # dead keys comes after, see _advance_time) for the drain, or
+        # for the delivery below
         stamp = time.perf_counter()
         if cohort is not None:
             cohort["t_queued"] = stamp
@@ -2588,7 +2597,8 @@ class Driver:
         ``time.perf_counter()`` and in this order, ``t_input`` (the
         source handed over the batch that carried the watermark past the
         end), ``t_fire`` (fire dispatched), ``t_queued`` (the advance
-        that fired it is over and the cohort is handed to the drain),
+        that fired it has returned, its release of dead keys still
+        pending, and the cohort is handed to the drain),
         ``t_fetch0`` (the fetch of its rows began), ``t_ready`` (its
         wait for the device and the copy ended), ``t_fetch1`` (the rows
         are host arrays), ``t_push0`` (the delivery holds

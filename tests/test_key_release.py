@@ -15,8 +15,15 @@ plain reference, which is numpy only and takes nothing from the program:
   switched off the reference catches a wrong one;
 - through ``env.execute``, on one device and on a mesh of four; a
   snapshot in mid-churn restored into a fresh operator; and recurring
-  keys, which release nothing while they live.
+  keys, which release nothing while they live;
+- THE RELEASE'S PLACE (PR 40): a purging advance returns with its
+  release pending; the driver queues the fired cohort first and runs it
+  outside its push lock, and a snapshot, ``quiesce``, the next batch
+  and the next advance each run it before they look at the directory.
 """
+import threading
+import time
+
 import jax
 import numpy as np
 import pytest
@@ -75,20 +82,26 @@ def as_sink_batch(fired):
             "bid_count": np.asarray(fired["count"])}
 
 
-def drive(op, batches, hold=0, after_advance=None):
+def drive(op, batches, hold=0, after_advance=None, release_at="queued"):
     """Feed ``batches``, advancing the watermark after each; the fired
     batches are materialised ``hold`` advances late (the drain held
-    back), then the end-of-input flush. -> sink batches."""
+    back), then the end-of-input flush. ``release_at``: the release a
+    purging advance left pending runs once its fired batch is
+    ``"queued"``, as the driver has it, or is left to whatever needs the
+    directory next (``"next_batch"``). -> sink batches."""
     held, out = [], []
     for i, (data, ts) in enumerate(batches):
         op.process_batch(data["auction"], ts, {})
         held.append(op.advance_watermark(
             int(ts[-1]) - PARAMS["out_of_orderness_ms"]))
+        if release_at == "queued":
+            op.run_pending_release()
         while len(held) > hold:
             out.append(as_sink_batch(held.pop(0)))
         if after_advance is not None:
             after_advance(i)
     held.append(op.advance_watermark(op.final_watermark()))
+    op.run_pending_release()
     out.extend(as_sink_batch(f) for f in held)
     return out
 
@@ -204,10 +217,12 @@ HOLD = 5          # advances the drain lags behind
 N_BATCHES = 40
 
 
-def test_rows_name_the_right_key_with_the_drain_held_back():
+@pytest.mark.parametrize("release_at", ["queued", "next_batch"])
+def test_rows_name_the_right_key_with_the_drain_held_back(release_at):
     """A slot budget under the keys offered, the drain ``HOLD`` advances
     (each a fire, a purge, a release and an allocation) behind: reuse
-    waits for it, and every committed row names the right auction."""
+    waits for it, and every committed row names the right auction,
+    wherever between its purge and the next batch the release runs."""
     batches = stream(N_BATCHES)
     op = q5_operator(slots_per_shard=1024)
     assert distinct_keys(batches) > 2 * op.directory.local_slots
@@ -221,17 +236,24 @@ def test_rows_name_the_right_key_with_the_drain_held_back():
         peak_free_rows.append(op.directory.num_keys())
 
     rows = drive(op, batches, hold=HOLD,
-                 after_advance=free_rows_are_identities)
+                 after_advance=free_rows_are_identities,
+                 release_at=release_at)
     assert_equal_to_reference(verdict(batches, rows))
     c = op.state_counters()
     assert max(peak_free_rows) > 1000
     assert c["state.slots_reused"] > 2 * op.directory.local_slots
     assert c["state.slots_returned_early"] == 0
     assert c["state.slots_waiting_peak"] >= 500 * (HOLD - 1)
+    # one release a purge; none of these cohorts was stamped t_queued
+    # (no driver), so none counts as run after it
+    assert c["state.releases"] == N_BATCHES + 1
+    assert c["state.releases_after_queue"] == 0
     assert op.records_dropped_full == 0 and op.late_records == 0
 
 
-def test_the_control_without_the_rule_a_wrong_key_is_caught(monkeypatch):
+@pytest.mark.parametrize("release_at", ["queued", "next_batch"])
+def test_the_control_without_the_rule_a_wrong_key_is_caught(
+        monkeypatch, release_at):
     """The same run with the waiting switched off (a released slot goes
     straight back to the allocator): rows decode to the auction that now
     holds the slot, the reference refuses them, and the program's own
@@ -240,9 +262,118 @@ def test_the_control_without_the_rule_a_wrong_key_is_caught(monkeypatch):
                         lambda self: 1 << 62)
     batches = stream(N_BATCHES)
     op = q5_operator(slots_per_shard=1024)
-    cmp_ = verdict(batches, drive(op, batches, hold=HOLD))
+    cmp_ = verdict(batches, drive(op, batches, hold=HOLD,
+                                  release_at=release_at))
     assert cmp_["rows_not_in_reference"] > 0 and cmp_["rows_missing"] > 0
     assert op.state_counters()["state.slots_returned_early"] > 0
+
+
+# -- (b2) the release's place ------------------------------------------------
+
+def purged_with_release_pending(n_batches=12):
+    """An operator whose last advance fired, purged ~500 dead keys'
+    panes and returned: the keys are still in the directory."""
+    op = q5_operator(slots_per_shard=1024)
+    batches = stream(n_batches + 1)
+    for data, ts in batches[:n_batches]:
+        op.process_batch(data["auction"], ts, {})
+        fired = op.advance_watermark(
+            int(ts[-1]) - PARAMS["out_of_orderness_ms"])
+    assert op._release_pending and fired.cohort is not None
+    return op, batches[n_batches]
+
+
+def next_batch(op, batch):
+    op.process_batch(batch[0]["auction"], batch[1], {})
+
+
+def next_advance(op, batch):
+    op.advance_watermark(op.watermark + PARAMS["slide_ms"])
+    op.run_pending_release()        # the one this advance left
+
+
+@pytest.mark.parametrize("needs_the_directory", [
+    lambda op, batch: op.snapshot_state(),
+    lambda op, batch: op.quiesce(),
+    next_batch, next_advance, lambda op, batch: op.run_pending_release()],
+    ids=["snapshot", "quiesce", "next_batch", "next_advance", "driver"])
+def test_whatever_needs_the_directory_runs_the_pending_release_first(
+        needs_the_directory):
+    op, batch = purged_with_release_pending()
+    released, keys, done = (op.directory.slots_released,
+                            op.directory.num_keys(), op.releases)
+    needs_the_directory(op, batch)
+    assert not op._release_pending and op._release_cohort is None
+    assert op.releases >= done + 1
+    assert op.directory.slots_released > released + 300
+    assert op.directory.num_keys() < keys + BATCH // 8
+    # stamped with the fires dispatched when it ran: never earlier
+    assert all(stamp <= op._fires_so_far() for stamp, _ in op._waiting)
+
+
+def test_the_snapshot_after_the_end_of_input_flush_holds_no_key():
+    """The end-of-input checkpoint: the flush purges every pane, and the
+    snapshot that follows it runs the release the flush left pending."""
+    batches = stream(12)
+    op = q5_operator(slots_per_shard=1024)
+    for data, ts in batches:
+        op.process_batch(data["auction"], ts, {})
+    op.quiesce()
+    dict(op.advance_watermark(op.final_watermark()))
+    assert op._release_pending and op.directory.num_keys() > 1000
+    snap = op.snapshot_state()
+    assert not snap["directory"]["rev_used"].any()
+    assert not np.asarray(snap["panes"].counts).any()
+    assert op.directory.num_keys() == 0
+
+
+def test_the_driver_queues_the_cohort_then_releases_outside_its_push_lock(
+        monkeypatch):
+    """Through ``env.execute``: every purging advance's cohort carries
+    ``t_queued`` before its release starts, and while the release runs
+    another thread gets the driver's ``_push_lock`` (the drain could
+    deliver)."""
+    from flink_tpu.runtime.driver import Driver
+
+    drivers, starts, lock_free = [], [], []
+    emit = Driver._emit_fired
+    release = WindowOperator._release_dead_keys
+    release_below = KeyDirectory.release_below
+
+    def spy_emit(self, nid, fired):
+        drivers.append(self)
+        return emit(self, nid, fired)
+
+    def spy_release(self):
+        starts.append((self._release_cohort, time.perf_counter()))
+        return release(self)
+
+    def spy_release_below(self, dead):
+        got = []
+        t = threading.Thread(target=lambda: got.append(
+            drivers[-1]._push_lock.acquire(timeout=10)))
+        t.start()
+        t.join()
+        if got[0]:
+            drivers[-1]._push_lock.release()
+        lock_free.append(got[0])
+        return release_below(self, dead)
+
+    monkeypatch.setattr(Driver, "_emit_fired", spy_emit)
+    monkeypatch.setattr(WindowOperator, "_release_dead_keys", spy_release)
+    monkeypatch.setattr(KeyDirectory, "release_below", spy_release_below)
+    n = 30
+    res, sink, op = run_job(large, PARAMS, n,
+                            **{"state.slots-per-shard": 2048})
+    assert_equal_to_reference(verdict(stream(n), sink.batches))
+    with_cohort = [(c, t) for c, t in starts if c is not None]
+    assert len(with_cohort) >= n - 4 and len(lock_free) == len(starts)
+    assert all(c["t_fire"] < c["t_queued"] < t for c, t in with_cohort)
+    assert all(lock_free)
+    m = res.metrics
+    assert m["state.releases"] == len(starts)
+    assert m["state.releases_after_queue"] == len(with_cohort)
+    assert m["state.slots_returned_early"] == 0 and m["state.live_keys"] == 0
 
 
 # -- (c) (e) through env.execute, one device and a mesh of four ------------
@@ -304,6 +435,7 @@ def test_restore_in_mid_churn_gives_the_uninterrupted_rows():
         first.process_batch(data["auction"], ts, {})
         held.append(first.advance_watermark(
             int(ts[-1]) - PARAMS["out_of_orderness_ms"]))
+        first.run_pending_release()
         if len(held) > 2:
             head.append(as_sink_batch(held.pop(0)))
     # a checkpoint flushes the emits, then freezes

@@ -377,7 +377,9 @@ def fired_rows(op, batches, final_wm):
         op.process_batch(keys, ts, data)
     fired = op.advance_watermark(final_wm)
     fields = sorted(fired)
-    return sorted(zip(*(fired[f].tolist() for f in fields))), fields
+    rows = sorted(zip(*(fired[f].tolist() for f in fields)))
+    op.run_pending_release()    # the rows in hand, as the driver has it
+    return rows, fields
 
 
 def mixed_batches(rng):
