@@ -233,8 +233,12 @@ class SlidingProcessingTimeWindows(WindowAssigner):
 class EventTimeSessionWindows(WindowAssigner):
     """Gap-merged sessions (ref: assigners/EventTimeSessionWindows.java,
     runtime merge logic in MergingWindowSet.java). Dynamic merging cannot
-    be a static pane layout; the session operator keeps a host-side span
-    registry and device-side per-span accumulators (SURVEY §8.4 item 3).
+    be a static pane layout. A job without allowed lateness, retraction
+    or a mesh keeps its sessions on the device (ops/session_device.py:
+    per key slot a few session lanes, a batch sorted by (slot, ts) and
+    merged into them in one program, the host keeping the key directory
+    alone); every other session job runs on the host's columnar span
+    registry (ops/session.py). The driver chooses by what the job is.
     """
 
     gap: int

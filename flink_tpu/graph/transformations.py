@@ -196,7 +196,9 @@ class WindowJoinTransformation(Transformation):
 @dataclasses.dataclass(eq=False)
 class SessionAggregateTransformation(Transformation):
     """Keyed session windows (ref: EventTimeSessionWindows +
-    MergingWindowSet) — host span registry + device accumulators.
+    MergingWindowSet) — session lanes on the device, or the host's span
+    registry where the job retracts, re-fires within allowed lateness or
+    runs on a mesh (the driver chooses).
     ``retract=True`` op-types the output: a merge that consumes an
     already-fired span retracts its stale row (-U) before the merged
     session (re)fires (+U)."""

@@ -1,8 +1,9 @@
 """NEXMark query pipelines over the DataStream API.
 
 ref: BASELINE.json configs — Q5 sliding hot items, Q7 tumbling highest
-bid, Q8 tumbling new-user join; semantics per the nexmark/nexmark query
-definitions (SQL in the external repo; validated shapes in SURVEY §7).
+bid, Q8 tumbling new-user join, Q11 user sessions; semantics per the
+nexmark/nexmark query definitions (SQL in the external repo; validated
+shapes in SURVEY §7).
 """
 from __future__ import annotations
 
@@ -11,7 +12,8 @@ from typing import Optional
 from flink_tpu.api.datastream import DataStream
 from flink_tpu.api.environment import StreamExecutionEnvironment
 from flink_tpu.api.sinks import Sink
-from flink_tpu.api.windowing import SlidingEventTimeWindows, TumblingEventTimeWindows
+from flink_tpu.api.windowing import (
+    EventTimeSessionWindows, SlidingEventTimeWindows, TumblingEventTimeWindows)
 from flink_tpu.ops import aggregates
 from flink_tpu.time.watermarks import WatermarkStrategy
 
@@ -95,5 +97,40 @@ def q8_monitor_new_users(
         .window(TumblingEventTimeWindows.of(window_ms))
         .apply(left_fields=("state_id",), right_fields=("reserve",))
     )
+    out.add_sink(sink)
+    return out
+
+
+def q11_user_sessions(
+    env: StreamExecutionEnvironment,
+    bids,
+    sink: Sink,
+    *,
+    gap_ms: int = 10_000,
+    out_of_orderness_ms: int = 0,
+) -> DataStream:
+    """Q11: how many bids did a user make in each session they were
+    active? (nexmark-flink ``queries/q11.sql``: ``SELECT B.bidder,
+    count(*) AS bid_count, SESSION_START(..), SESSION_END(..) FROM bid B
+    GROUP BY B.bidder, SESSION(B.dateTime, INTERVAL '10' SECOND)``.)
+
+    Per-bidder COUNT over gap-merged session windows; a session ends
+    ``gap_ms`` after its last bid. With no allowed lateness and no
+    retraction the session state lives on the device
+    (ops/session_device.py; the driver chooses the lane)."""
+    stream = env.from_source(
+        bids, WatermarkStrategy.for_bounded_out_of_orderness(out_of_orderness_ms))
+    sessions = (
+        stream.key_by("bidder")
+        .window(EventTimeSessionWindows.with_gap(gap_ms))
+        .count()
+    )
+
+    def rename(data):
+        return {"bidder": data["key"], "bid_count": data["count"],
+                "starttime": data["window_start"],
+                "endtime": data["window_end"]}
+
+    out = sessions.map(rename, name="q11_rename")
     out.add_sink(sink)
     return out

@@ -5,8 +5,14 @@ the merge machinery MergingWindowSet.java + WindowOperator's merging
 branch (each element opens [ts, ts+gap) and overlapping windows merge,
 state merges via namespace re-targeting).
 
-TPU-first redesign (SURVEY §8.4 item 3): dynamic merging cannot be a
-static pane layout, so the decomposition is:
+This module is the HOST lane: the session operator of jobs with allowed
+lateness, retract rows or a mesh. Every other session job keeps its
+sessions on the device (``ops/session_device.py``; the driver chooses,
+``device_lane_fits``); the two give the same rows for a job both can
+run and write one snapshot format (``_merged_columns``).
+
+Dynamic merging cannot be a static pane layout (SURVEY §8.4 item 3), so
+the decomposition here is:
 - **batch sessionization is vectorized**: sort the microbatch by
   (key, ts); session boundaries are where the key changes or the time
   gap exceeds ``gap``; per-batch-session aggregates come from numpy
@@ -560,6 +566,12 @@ class SessionOperator:
         # deterministic emission order across host-pool shard counts
         order = np.lexsort((rows["window_start"], rows["key"]))
         return FiredWindows(data={k: v[order] for k, v in rows.items()})
+
+    def state_counters(self) -> Dict[str, int]:
+        """``JobResult.metrics``: this job's sessions are on the host
+        (the device operator reports 0 under the same name until it
+        hands its sessions over)."""
+        return {"session.on_registry": 1}
 
     def final_watermark(self) -> int:
         lasts = [int(st.last.max()) for st in self._shards if len(st)]

@@ -35,7 +35,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import threading
 import time
 from collections.abc import Mapping
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
@@ -51,9 +50,11 @@ from flink_tpu.hostsync import ready_wait
 from flink_tpu.obs.tracing import PhaseClock
 from flink_tpu.utils.jaxcompat import shard_map
 from flink_tpu.ops.aggregates import LaneAggregate
+from flink_tpu.ops.emit_ring import EmitRing
 from flink_tpu.parallel.mesh import AXIS, MeshPlan
 from flink_tpu.state.keyed import (
-    KeyDirectory, PaneState, PaneStateLayout, account_full_drop, init_state)
+    KeyDirectory, PaneState, PaneStateLayout, ReuseRule, account_full_drop,
+    init_state)
 from flink_tpu.state.spill import HostSpillStore
 from flink_tpu.time.watermarks import LONG_MIN
 
@@ -1427,7 +1428,7 @@ def _sharded_kernels(mp: MeshPlan, agg, layout: PaneStateLayout,
                            fire_shard, topn_shard, clear)
 
 
-class WindowOperator:
+class WindowOperator(ReuseRule):
     """Drives the kernels for one keyed window aggregation.
 
     Semantics golden-checked against the reference's WindowOperatorTest
@@ -1499,51 +1500,15 @@ class WindowOperator:
         self._topn = top_n
         # device-resident emit ring (top-n path): fires append winners in
         # HBM; the host polls one array at its own cadence (see
-        # ring_append_topn_kernel). Lazy — shape needs result arity.
-        self._emit_ring: Optional[jax.Array] = None
+        # ring_append_topn_kernel). The array (``emit_ring.live``, lazy:
+        # its shape needs the result arity), its announced versions,
+        # the version counter, the fetch and the fire cohorts on their
+        # way to delivery are ``ops/emit_ring.py``'s, which the device
+        # session operator holds too; the names below are this
+        # operator's views of them.
+        self.emit_ring = EmitRing()
         self._ring_drained = 0
         self._ring_anchor: Optional[int] = None
-        # recent ANNOUNCED ring versions as (version_no, array):
-        # copy_to_host_async is issued at fire dispatch, and the ring is
-        # never donated, so every version stays valid. A periodic drain
-        # fetches the newest version whose copy already landed instead
-        # of parking on the latest one's still-running compute; rows it
-        # misses are monotone-counter rows the next poll picks up. A
-        # barrier drain passes min_no (its fire's version) so it can
-        # never read a version older than the rows it must deliver.
-        self._ring_versions: collections.deque = collections.deque(maxlen=4)
-        self._ring_version_no = 0
-        # the version the drain fetched last: a periodic poll gains
-        # nothing from that one or an older one (see _fetch_ring_version)
-        self._ring_read_no = 0
-        # fire-cohort bookkeeping (the driver's "trace.fires" records
-        # and emit_latency_ms): a (ring_version, cohort) entry per
-        # row-carrying fire — the cohort (_fire_cohort) holds its window
-        # ends and its dispatch stamp — popped to _delivered_stamps,
-        # with the fetch's own stamps, by the drain_ring call whose
-        # fetched version first makes those rows HOST-VISIBLE. The
-        # driver stamps the sink and records one histogram sample per
-        # delivered cohort — without this, a drain poll that coalesces
-        # several sub-batch fires would attribute every row to the
-        # OLDEST marker's stamp and overstate p99 (under-reporting the
-        # sub-batch cadence win).
-        # Both deques are bounded: in modes where nothing pops them
-        # (the synchronous spill+top-n drain), old entries fall off —
-        # lost samples, never lost rows.
-        self._fire_stamps: collections.deque = collections.deque(
-            maxlen=4096)
-        self._delivered_stamps: collections.deque = collections.deque(
-            maxlen=512)
-        # device→host copies are stream ops with a fixed cost each
-        # (not measured on the current chip): announce the ring at a
-        # TIME/FILL cadence, not per fire. The drain's
-        # periodic poll reads only announced-and-landed versions, so
-        # cadence bounds d2h cost without losing rows; the fill bound
-        # (conservative per-fire append estimate) forces an announce
-        # before the ring could wrap un-polled.
-        self.emit_announce_interval_s = 0.05
-        self._last_announce = 0.0
-        self._rows_bound_since_announce = 0
         # 2048 rows ≈ 33KB: large against the tens of rows a steady
         # advance appends between polls, small against the ~1MB/s
         # effective cost of each announced device→host ring copy
@@ -1591,10 +1556,6 @@ class WindowOperator:
         # by the next advance's single fused dispatch (see
         # fused_step_kernel) or flushed by _flush_stash
         self._stash_u32: Optional[np.ndarray] = None
-        # RLock: the spill+top-n sync path holds it across
-        # _fire_ends → drain_ring, and _fire_ends' announce block
-        # takes it again (ingest vs drain-thread deque race)
-        self._ring_lock = threading.RLock()
         self.plan = WindowPlan.plan(
             assigner,
             allowed_lateness_ms=allowed_lateness_ms,
@@ -1624,13 +1585,7 @@ class WindowOperator:
         self._releases = self._spill is None
         if self._releases:
             self.directory.track_panes()
-        # released slots the allocator may not have yet, oldest first:
-        # (fires dispatched at the release, slots)
-        self._waiting: collections.deque = collections.deque()
-        self._n_waiting = 0
-        self.slots_waiting_peak = 0
-        # stays 0 while the reuse rule holds (see _return_released)
-        self.slots_returned_early = 0
+        self._init_reuse()      # state/keyed.py ReuseRule
         # a purge whose release has not run yet (_defer_release), the
         # fire cohort of the advance that purged, and how many releases
         # ran, how many of them after their cohort had been queued for
@@ -1639,8 +1594,6 @@ class WindowOperator:
         self._release_cohort: Optional[Dict[str, Any]] = None
         self.releases = 0
         self.releases_after_queue = 0
-        # every fire numbered up to this has had its rows decoded
-        self._fires_decoded = 0
         # pack-mode fires (no top-n): the latest's number, and those
         # whose buffers the drain has not decoded yet
         self._pack_no = 0
@@ -2439,7 +2392,7 @@ class WindowOperator:
         truncated = int(arr[head[1]])
         if truncated > 0:
             self._raise_truncation(truncated)
-        with self._ring_lock:
+        with self.emit_ring.lock:
             if seq >= self._rowfire_token_seq and seq > self._ring_head_seq:
                 self._ring_head_seq = seq
                 self._ring_head_known = True
@@ -2653,7 +2606,7 @@ class WindowOperator:
             # trivially exact. The cost — a blocking ring fetch per
             # advance — lands only in spill mode, which has already
             # traded peak speed for capacity.
-            with self._ring_lock:
+            with self.emit_ring.lock:
                 out = self._fire_ends(ends)
                 if extra is not None:
                     self._pending_ring_extras.append(extra)
@@ -2729,22 +2682,14 @@ class WindowOperator:
         here). Costs what the directory examines (keys born or last
         seen alive in the purged panes), never the slot space.
 
-        THE REUSE RULE. Fired rows leave the device as ROW numbers and
-        become keys on the host only when the drain decodes them
-        (``drain_ring``, ``_decode_packs``), after its deferral and on
-        another thread; a fire, the purge of its oldest pane, this
-        release and the next batch's allocation all come before that. So
-        a released slot is stamped with the number of fires dispatched
-        when the release RUNS (never earlier than at its purge: it runs
-        before the next batch and the next advance), and goes back to
-        its shard's allocator
-        (``_return_released``) only once every fire up to that number
-        has had its rows decoded (``_drained_through``): the one-chip
-        emit ring, the mesh's ring blocks, the chunked ``_fire_ends``
-        and the fused step all number their fires by the ring version
-        they bump, pack-mode fires by ``_pack_no``. Fires dispatched
-        after the release cannot name the slot for its old key: its
-        rows count nothing until a new key is given it."""
+        The released slots wait under THE REUSE RULE (``state/keyed.py``
+        ``ReuseRule``), stamped with the number of fires dispatched when
+        the release RUNS (never earlier than at its purge: it runs
+        before the next batch and the next advance): the one-chip emit
+        ring, the mesh's ring blocks, the chunked ``_fire_ends`` and the
+        fused step all number their fires by the ring version they bump,
+        pack-mode fires by ``_pack_no``; the drain's decodes are
+        ``drain_ring`` and ``_decode_packs``."""
         cohort, self._release_cohort = self._release_cohort, None
         self._release_pending = False
         self.releases += 1
@@ -2753,54 +2698,20 @@ class WindowOperator:
         with self.phases.span("state.release"):
             rel = self.directory.release_below(self._cleared_below)
             if len(rel):
-                self._waiting.append((self._fires_so_far(), rel))
-                self._n_waiting += len(rel)
-                if self._n_waiting > self.slots_waiting_peak:
-                    self.slots_waiting_peak = self._n_waiting
+                self._hold_released(self._fires_so_far(), rel)
 
     def _fires_so_far(self) -> int:
         """The number of the latest fire dispatched."""
-        return (self._ring_version_no if self._topn is not None
+        return (self.emit_ring.version_no if self._topn is not None
                 else self._pack_no)
-
-    def _drained_through(self) -> int:
-        """Every fire numbered up to this has had its rows decoded into
-        keys by the drain (the reuse rule's other half)."""
-        return self._fires_decoded
-
-    def _note_decoded(self, fire_no: int) -> None:
-        """The drain has turned the rows of every fire up to ``fire_no``
-        into keys (called by it, after the decode)."""
-        if fire_no > self._fires_decoded:
-            self._fires_decoded = fire_no
 
     def _note_pack_decoded(self, pack_no: int) -> None:
         """A pack-mode fire's buffers were decoded (or dropped unread):
         decoded runs through the fire before the oldest still open."""
-        with self._ring_lock:
+        with self.emit_ring.lock:
             self._packs_open.discard(pack_no)
-            self._note_decoded(min(self._packs_open) - 1
+            self.emit_ring.note_decoded(min(self._packs_open) - 1
                                if self._packs_open else self._pack_no)
-
-    def _return_released(self) -> None:
-        """Ahead of a batch's allocations: hand the allocator every
-        waiting slot the reuse rule lets go. One comparison when none
-        is due."""
-        if not self._waiting:
-            return
-        through = self._drained_through()
-        if self._waiting[0][0] > through:
-            return
-        with self.phases.span("state.reclaim"):
-            back = []
-            while self._waiting and self._waiting[0][0] <= through:
-                stamp, slots = self._waiting.popleft()
-                if stamp > self._fires_decoded:
-                    self.slots_returned_early += len(slots)
-                back.append(slots)
-            slots = back[0] if len(back) == 1 else np.concatenate(back)
-            self._n_waiting -= len(slots)
-            self.directory.reclaim(slots)
 
     def state_counters(self) -> Dict[str, int]:
         """The keyed state's life so far (``JobResult.metrics``)."""
@@ -2891,7 +2802,7 @@ class WindowOperator:
         self.phases.phase("window.h2d")
         dbuf = jnp.asarray(buf)
         self.phases.phase("window.fire_dispatch")
-        self.state, self._emit_ring, token = self._fused_step(
+        self.state, self.emit_ring.live, token = self._fused_step(
             self.state, self._ensure_ring(), dbuf, used,
             sel_cap=self._topn_cap(MIN_FIRE_PAD),
             fire_pad=self._fire_pad_bucket(len(ends_f)))
@@ -2921,38 +2832,35 @@ class WindowOperator:
                          covered: bool = False) -> "FiredWindows":
         """Post-fire ring bookkeeping shared by the fused and chunked
         top-n paths: version bump + cadenced announce (see
-        _ring_versions). ``covered``: this fire rode a dispatch whose
+        EmitRing.versions). ``covered``: this fire rode a dispatch whose
         token carries the POST-fire ring head (the fused step) — that
         token (or any later one) re-validates the
         piggybacked head; a chunked fire has no token of its own, so
         only a FUTURE dispatch's token can."""
         n_ends = len(ends)
         cohort = None
-        with self._ring_lock:
-            self._ring_version_no += 1
+        with self.emit_ring.lock:
+            self.emit_ring.version_no += 1
             if n_ends > 0:
                 # row-carrying fire: stamp the cohort for host-visibility
-                # latency attribution (see _fire_stamps above)
+                # latency attribution (EmitRing.fire_stamps)
                 cohort = self._fire_cohort(ends)
-                self._fire_stamps.append((self._ring_version_no, cohort))
+                self.emit_ring.stamp(cohort)
                 # rows may have been appended: the piggybacked ring head
                 # goes stale until a token at/after this fire lands
                 self._ring_head_known = False
                 self._rowfire_token_seq = (
                     self._token_seq if covered else self._token_seq + 1)
-            self._rows_bound_since_announce += max(n_ends, 0) * (
+            ring = self.emit_ring
+            ring.rows_bound_since_announce += max(n_ends, 0) * (
                 self._topn[1] * 8)
-            now = time.perf_counter()
-            if (now - self._last_announce >= self.emit_announce_interval_s
-                    or self._rows_bound_since_announce
+            if (time.perf_counter() - ring.last_announce
+                    >= ring.announce_interval_s
+                    or ring.rows_bound_since_announce
                     >= self.EMIT_RING_ROWS // 2):
-                self._emit_ring.copy_to_host_async()
-                self._ring_versions.append(
-                    (self._ring_version_no, self._emit_ring))
-                self._last_announce = now
-                self._rows_bound_since_announce = 0
+                ring.announce(ring.live)
             return FiredWindows(op=self, ring=True,
-                                ring_no=self._ring_version_no,
+                                ring_no=self.emit_ring.version_no,
                                 cohort=cohort)
 
     def _fire_ends(self, ends: List[int]) -> "FiredWindows":
@@ -2993,7 +2901,7 @@ class WindowOperator:
                 width = ({"fire_pad": Wp} if self.mesh_plan is None
                          and self.layout.slots * MIN_FIRE_PAD > FIRE_GRID_CELLS
                          else {})
-                self._emit_ring = self._ring_topn(
+                self.emit_ring.live = self._ring_topn(
                     self.state, self._ensure_ring(), params, used,
                     sel_cap=self._topn_cap(Wp), **width)
             else:
@@ -3006,7 +2914,7 @@ class WindowOperator:
                 packs.append((lo, buf))
         if self._topn is not None:
             return self._ring_after_fire(ends)
-        with self._ring_lock:
+        with self.emit_ring.lock:
             self._pack_no += 1
             self._packs_open.add(self._pack_no)
         return FiredWindows(op=self, packs=packs, pack_no=self._pack_no,
@@ -3106,65 +3014,18 @@ class WindowOperator:
     def _ensure_ring(self) -> jax.Array:
         """Lazily allocate the device emit ring: row 0 = monotone counter
         head, rows 1..cap = data, last row = scatter dump."""
-        if self._emit_ring is None:
+        if self.emit_ring.live is None:
             C = 3 + len(self._pack_fields())
             shape = (self.EMIT_RING_ROWS + 2, C)
             if self.mesh_plan is not None:
                 n_dev = self.mesh_plan.n_devices
-                self._emit_ring = jax.device_put(
+                self.emit_ring.live = jax.device_put(
                     np.zeros((n_dev * shape[0], C), np.int32),
                     self.mesh_plan.row_sharding())
                 self._ring_drained_blocks = [0] * n_dev
             else:
-                self._emit_ring = jnp.zeros(shape, jnp.int32)
-        return self._emit_ring
-
-    def _fetch_ring_version(self, need: int, opportunistic: bool):
-        """Under the ring lock: ``(ring array, its version, when the
-        wait for it ended)`` of the newest ANNOUNCED version >= ``need``
-        whose async copy already landed — never park behind the
-        in-flight compute of a just-dispatched fire (a barrier's rows
-        must be present, hence ``need``) — or ``(None, None, None)``
-        when an opportunistic poll finds nothing announced. A version this drain has read already holds
-        no row it has not seen: an opportunistic poll passes it over,
-        and where only such versions have landed it waits for the OLDEST
-        it has not read (the soonest) instead of reading nothing. With
-        a fire that outlasts the drain's deferral (~0.1 s over 16.8 M
-        rows) and the next poll a window's slide away, reading the
-        version before it held the fired rows back by that slide."""
-        floor = max(need, self._ring_read_no + 1) if opportunistic else need
-        acceptable = [(no, arr_) for no, arr_ in
-                      self._ring_versions if no >= floor]
-        target = None
-        no_read = None
-        for no, cand in reversed(acceptable):
-            if cand.is_ready():
-                target, no_read = cand, no
-                break
-        else:
-            if acceptable:
-                # oldest OK = soonest
-                no_read, target = acceptable[0]
-        if target is None:
-            if opportunistic:
-                # nothing announced yet (or announce cadence not due):
-                # fetch nothing; the next poll gets it
-                return None, None, None
-            # barrier needs a version newer than any announced copy:
-            # announce the live ring now so the fetch is a landed-copy
-            # read, not an unannounced round trip
-            target = self._emit_ring
-            no_read = self._ring_version_no
-            target.copy_to_host_async()
-            self._ring_versions.append((self._ring_version_no, target))
-            self._last_announce = time.perf_counter()
-            self._rows_bound_since_announce = 0
-        ready_wait(target)
-        # the device's work and the copy are done: what is left of the
-        # fetch is a local read
-        t_ready = time.perf_counter()
-        self._ring_read_no = max(self._ring_read_no, no_read)
-        return np.asarray(target), no_read, t_ready   # ONE round trip
+                self.emit_ring.live = jnp.zeros(shape, jnp.int32)
+        return self.emit_ring.live
 
     def drain_ring(self, min_no: Optional[int] = None) -> Dict[str, np.ndarray]:
         """Fetch the emit ring ONCE and decode every row appended since
@@ -3175,8 +3036,8 @@ class WindowOperator:
         ``min_no``: the oldest ring version this drain may read (a
         barrier passes its fire's version so its rows are guaranteed
         present; None = latest). The fetch prefers the newest version
-        whose announced copy already landed — see _ring_versions."""
-        with self._ring_lock:
+        whose announced copy already landed — see EmitRing.versions."""
+        with self.emit_ring.lock:
             # pop pending host-spill extras together with the ring read:
             # the appender holds the same lock across (ring dispatch,
             # extra enqueue), so the rows observed here are exactly the
@@ -3187,7 +3048,7 @@ class WindowOperator:
             # the fire (ring version) through which this call has every
             # row in hand; None when it fetched nothing
             seen_no = None
-            if self._emit_ring is None or self._ring_anchor is None:
+            if self.emit_ring.live is None or self._ring_anchor is None:
                 arr = None
             elif (min_no == 0 and self.mesh_plan is None
                   and self._ring_head_known
@@ -3204,27 +3065,27 @@ class WindowOperator:
                 # deliver the stamps NOW — a zero-row fire cohort's
                 # latency sample must not age across skipped polls.
                 now = time.perf_counter()
-                seen_no = self._ring_version_no
-                self._deliver_stamps(seen_no, now, now, now)
+                seen_no = self.emit_ring.version_no
+                self.emit_ring.deliver_stamps(seen_no, now, now, now)
                 self.prof["drain_skips"] += 1
                 arr = None
             else:
-                need = self._ring_version_no if min_no is None else min_no
+                need = self.emit_ring.version_no if min_no is None else min_no
                 with self.phases.span("drain.fetch", ring=need) as fetch:
-                    arr, no_read, t_ready = self._fetch_ring_version(
+                    arr, no_read, t_ready = self.emit_ring.fetch_version(
                         need, opportunistic=(min_no == 0))
                 if no_read is not None:
                     # every fire cohort at or below the fetched version
                     # just became host-visible — hand it, with this
                     # fetch's stamps, to the latency accounting
-                    self._deliver_stamps(
+                    self.emit_ring.deliver_stamps(
                         no_read, fetch.t0, t_ready, fetch.t1)
                     seen_no = no_read
                 self.prof["drain_fetch"] += fetch.seconds
                 self.prof["drain_fetches"] += 1
         if arr is None:
             if seen_no is not None:
-                self._note_decoded(seen_no)
+                self.emit_ring.note_decoded(seen_no)
             out = dict(self._empty())
             if extras:
                 out = _drain_merge_extras(out, extras, self._topn)
@@ -3273,7 +3134,7 @@ class WindowOperator:
         }
         # rows are keys now: the reuse rule may let go of slots released
         # up to the fire this fetch read through
-        self._note_decoded(seen_no)
+        self.emit_ring.note_decoded(seen_no)
         for i, k in enumerate(fields):
             col = np.ascontiguousarray(body[:, 3 + i])
             out[k] = col if self._res_is_int[k] else col.view(np.float32)
@@ -3281,28 +3142,10 @@ class WindowOperator:
             out = _drain_merge_extras(out, extras, self._topn)
         return out
 
-    def _deliver_stamps(self, no_read: int, t_fetch0: float,
-                        t_ready: float, t_fetch1: float) -> None:
-        """Under the ring lock: every fire cohort at or below ring
-        version ``no_read`` is host-visible as of the fetch that ran
-        from ``t_fetch0`` to ``t_fetch1`` and whose wait for the device
-        ended at ``t_ready``."""
-        while self._fire_stamps and self._fire_stamps[0][0] <= no_read:
-            cohort = self._fire_stamps.popleft()[1]
-            cohort.update(t_fetch0=t_fetch0, t_ready=t_ready,
-                          t_fetch1=t_fetch1)
-            self._delivered_stamps.append(cohort)
-
     def take_delivered_fires(self) -> List[Dict[str, Any]]:
         """Pop the fire cohorts (``_fire_cohort``) whose rows became
-        host-visible since the last call (see ``_fire_stamps``). The
-        driver stamps ``t_sink`` on each and records one emit-latency
-        sample per cohort at delivery time — host-visibility-accurate
-        even when one drain poll coalesces many sub-batch fires."""
-        with self._ring_lock:
-            out = list(self._delivered_stamps)
-            self._delivered_stamps.clear()
-            return out
+        host-visible since the last call (``EmitRing.take_delivered``)."""
+        return self.emit_ring.take_delivered()
 
     def _check_fire_cap(self, n: int, cap: int) -> None:
         """A packed buffer reporting more fired rows than its capacity
@@ -3476,10 +3319,9 @@ class WindowOperator:
         # pre-restore fires are a dead timeline, and the snapshot holds
         # what waited as free (see snapshot_state)
         self._release_pending, self._release_cohort = False, None
-        self._waiting.clear()
-        self._n_waiting = 0
+        self._forget_waiting()
         self._packs_open.clear()
-        self._fires_decoded = self._fires_so_far()
+        self.emit_ring.fires_decoded = self._fires_so_far()
         self._refire = set(snap["refire"])
         self.late_records = snap["late_records"]
         self.records_dropped_full = snap.get("records_dropped_full", 0)
@@ -3521,12 +3363,9 @@ class WindowOperator:
         self._used_pushed = -1  # directory changed: invalidate device used-mask
         # emit ring resets: everything it held was delivered before the
         # snapshot (checkpoint flushes emits first); replay re-fires
-        self._emit_ring = None
+        self.emit_ring.reset()
         self._ring_drained = 0
         self._ring_anchor = None
-        self._ring_versions.clear()
-        self._fire_stamps.clear()
-        self._delivered_stamps.clear()
         # piggybacked ring-head facts describe the pre-restore timeline
         self._ring_head_known = False
         self._ring_head_seq = self._token_seq

@@ -450,16 +450,41 @@ class Driver:
                     retract=getattr(t, "retract", False))
             elif n.kind == "session":
                 from flink_tpu.ops.session import SessionOperator
+                from flink_tpu.ops.session_device import (
+                    DeviceSessionOperator, device_lane_fits)
 
                 t = n.window_transform
-                self._ops[n.id] = SessionOperator(
-                    gap_ms=t.gap_ms, agg=t.aggregate,
-                    allowed_lateness_ms=t.allowed_lateness_ms,
-                    num_shards=num_shards, slots_per_shard=slots,
-                    max_out_of_orderness_ms=max(wm.max_out_of_orderness_ms, 0),
-                    host_pool=self.host_pool,
-                    retract=getattr(t, "retract", False),
-                )
+                delay = max(wm.max_out_of_orderness_ms, 0)
+                retract = getattr(t, "retract", False)
+                # the ONE place the session lane is chosen, by what the
+                # job is: state on the device where it can hold it, the
+                # host registry for retract rows, re-fires within
+                # allowed lateness and a mesh. (What the DATA asks for
+                # the device operator meets itself, batch by batch: more
+                # lanes a slot, or its sessions handed to a registry of
+                # its own for the rest of the job.)
+                if device_lane_fits(
+                        gap_ms=t.gap_ms, agg=t.aggregate,
+                        allowed_lateness_ms=t.allowed_lateness_ms,
+                        retract=retract, mesh=self.mesh_plan is not None,
+                        max_out_of_orderness_ms=delay,
+                        slots=num_shards * slots):
+                    op = self._ops[n.id] = DeviceSessionOperator(
+                        gap_ms=t.gap_ms, agg=t.aggregate,
+                        num_shards=num_shards, slots_per_shard=slots,
+                        max_out_of_orderness_ms=delay,
+                        max_inflight_steps=inflight,
+                        host_pool=self.host_pool)
+                    # as the window factory: the loop throttles outside
+                    # its push lock
+                    op.external_throttle = True
+                else:
+                    self._ops[n.id] = SessionOperator(
+                        gap_ms=t.gap_ms, agg=t.aggregate,
+                        allowed_lateness_ms=t.allowed_lateness_ms,
+                        num_shards=num_shards, slots_per_shard=slots,
+                        max_out_of_orderness_ms=delay,
+                        host_pool=self.host_pool, retract=retract)
             elif n.kind == "evicting_window":
                 from flink_tpu.ops.evicting_window import (
                     EvictingWindowOperator)
@@ -2767,8 +2792,9 @@ class Driver:
                 from flink_tpu.ops.window import FiredWindows
                 extra = False
                 for nid, op in self._ops.items():
-                    no = getattr(op, "_ring_version_no", 0)
-                    if no and getattr(op, "_emit_ring", None) is not None:
+                    ring = getattr(op, "emit_ring", None)
+                    no = ring.pending_marker_no() if ring is not None else 0
+                    if no:
                         self._emit_q.put(
                             (nid, FiredWindows(op=op, ring=True, ring_no=no),
                              time.perf_counter()))

@@ -23,6 +23,7 @@ role collapses into XLA donation semantics).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import time
@@ -484,18 +485,7 @@ class KeyDirectory:
                    valid: np.ndarray) -> None:
         """``newest[slot] = max(newest[slot], pane)`` for a batch's valid
         records that have a slot."""
-        from flink_tpu.native_codec import slot_panes_note_native
-
-        if slot_panes_note_native(slots, panes, valid, self._newest):
-            return
-        ok = valid & (slots >= 0)
-        sl, pn = slots[ok], panes[ok]
-        if len(pn) > 1 and (pn[1:] < pn[:-1]).any():
-            order = np.argsort(pn, kind="stable")
-            sl, pn = sl[order], pn[order]
-        # panes ascend, and of a repeated index the last value is the
-        # one assigned: each slot gets its largest pane of the batch
-        self._newest[sl] = np.maximum(self._newest[sl], pn)
+        note_newest(self._newest, slots, panes, valid)
 
     def note_pairs(self, pairs: np.ndarray, ring: int, pane_lo: int) -> None:
         """The same from a fused scan's distinct (slot * ring + column)
@@ -538,12 +528,22 @@ class KeyDirectory:
             if not gone.any():
                 return np.zeros(0, np.int64)
         rel = c[gone]
-        self._table.delete_batch(self._rev_keys[rel])
-        self._rev_used[rel] = False
-        self._newest[rel] = _NO_PANE
-        self._n_keys -= len(rel)
-        self.slots_released += len(rel)
+        self.release_slots(rel)
         return rel
+
+    def release_slots(self, slots: np.ndarray) -> None:
+        """Release the keys of ``slots`` (registered, distinct): out of
+        the table, out of ``used_mask``. The slots stay out of the
+        allocator until ``reclaim``. For an owner that knows itself
+        which keys have nothing left (the device session operator: a
+        key whose last session has fired), as ``release_below`` is for
+        one that asks by pane."""
+        self._table.delete_batch(self._rev_keys[slots])
+        self._rev_used[slots] = False
+        if self._newest is not None:
+            self._newest[slots] = _NO_PANE
+        self._n_keys -= len(slots)
+        self.slots_released += len(slots)
 
     def reclaim(self, slots: np.ndarray) -> None:
         """Released slots back to their shards' allocators."""
@@ -625,6 +625,89 @@ class KeyDirectory:
             d._newest = np.array(snap["newest_pane"], np.int64)
             d._fresh = [used] if len(used) else []
         return d
+
+
+class ReuseRule:
+    """THE REUSE RULE, written once for every operator that releases
+    keys while fired rows still name their slots (``WindowOperator``:
+    by pane purges; the device session operator: by the fire itself).
+    Fired rows leave the device as SLOT numbers and become keys on the
+    host only when the drain decodes them, after its deferral and on
+    another thread; a fire, the release of its keys and the next
+    batch's allocation can all come before that. So a released slot is
+    stamped with the number of fires dispatched when its release runs
+    (``_hold_released``) and goes back to its shard's allocator
+    (``_return_released``) only once every fire up to that number has
+    had its rows decoded (``_drained_through``). Fires dispatched after
+    the release cannot name the slot for its old key. The operator
+    brings ``directory``, ``emit_ring`` (whose ``fires_decoded`` the
+    drain moves) and ``phases``."""
+
+    def _init_reuse(self) -> None:
+        # released slots the allocator may not have yet, oldest first:
+        # (fires dispatched at the release, slots)
+        self._waiting: collections.deque = collections.deque()
+        self._n_waiting = 0
+        self.slots_waiting_peak = 0
+        # the rule's tripwire: stays 0 while it holds
+        self.slots_returned_early = 0
+
+    def _hold_released(self, fires_so_far: int, slots: np.ndarray) -> None:
+        """``slots`` were released with ``fires_so_far`` fires
+        dispatched: they wait for those fires' decode."""
+        self._waiting.append((fires_so_far, slots))
+        self._n_waiting += len(slots)
+        if self._n_waiting > self.slots_waiting_peak:
+            self.slots_waiting_peak = self._n_waiting
+
+    def _drained_through(self) -> int:
+        """Every fire numbered up to this has had its rows decoded into
+        keys by the drain (the reuse rule's other half)."""
+        return self.emit_ring.fires_decoded
+
+    def _return_released(self) -> None:
+        """Ahead of a batch's allocations: hand the allocator every
+        waiting slot the reuse rule lets go. One comparison when none
+        is due."""
+        if not self._waiting:
+            return
+        through = self._drained_through()
+        if self._waiting[0][0] > through:
+            return
+        with self.phases.span("state.reclaim"):
+            back = []
+            while self._waiting and self._waiting[0][0] <= through:
+                stamp, slots = self._waiting.popleft()
+                if stamp > self.emit_ring.fires_decoded:
+                    self.slots_returned_early += len(slots)
+                back.append(slots)
+            slots = back[0] if len(back) == 1 else np.concatenate(back)
+            self._n_waiting -= len(slots)
+            self.directory.reclaim(slots)
+
+    def _forget_waiting(self) -> None:
+        """After a restore: pre-restore fires are a dead timeline."""
+        self._waiting.clear()
+        self._n_waiting = 0
+
+
+def note_newest(newest: np.ndarray, slots: np.ndarray, values: np.ndarray,
+                valid: np.ndarray) -> None:
+    """``newest[slot] = max(newest[slot], value)`` over a batch's valid
+    records that have a slot (int64 all: a pane number, a timestamp), in
+    C where the codec library is there."""
+    from flink_tpu.native_codec import slot_panes_note_native
+
+    if slot_panes_note_native(slots, values, valid, newest):
+        return
+    ok = valid & (slots >= 0)
+    sl, v = slots[ok], values[ok]
+    if len(v) > 1 and (v[1:] < v[:-1]).any():
+        order = np.argsort(v, kind="stable")
+        sl, v = sl[order], v[order]
+    # the values ascend, and of a repeated index the last value is the
+    # one assigned: each slot gets its largest of the batch
+    newest[sl] = np.maximum(newest[sl], v)
 
 
 def account_full_drop(op, n: int) -> None:

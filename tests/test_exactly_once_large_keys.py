@@ -518,21 +518,21 @@ def test_a_poll_passes_over_a_version_it_has_read_and_waits_for_the_next():
                         num_shards=8, slots_per_shard=64, top_n=("count", 1))
     old, new = _Version([[1]]), _Version([[2]])
     old.landed = True
-    op._ring_versions.extend([(6, old), (7, new)])
-    op._ring_read_no = 6
-    arr, no, _t_ready = op._fetch_ring_version(0, opportunistic=True)
+    op.emit_ring.versions.extend([(6, old), (7, new)])
+    op.emit_ring.read_no = 6
+    arr, no, _t_ready = op.emit_ring.fetch_version(0, opportunistic=True)
     assert (no, arr.tolist(), new.waited) == (7, [[2]], True)
-    assert op._ring_read_no == 7
+    assert op.emit_ring.read_no == 7
     # nothing it has not read: nothing to fetch, as before
-    assert op._fetch_ring_version(0, opportunistic=True) == (None, None, None)
+    assert op.emit_ring.fetch_version(0, opportunistic=True) == (None, None, None)
     # of several it has not read, the newest that has landed
     newer, newest = _Version([[3]]), _Version([[4]])
     newer.landed = True
-    op._ring_versions.extend([(8, newer), (9, newest)])
-    arr, no, _t_ready = op._fetch_ring_version(0, opportunistic=True)
+    op.emit_ring.versions.extend([(8, newer), (9, newest)])
+    arr, no, _t_ready = op.emit_ring.fetch_version(0, opportunistic=True)
     assert (no, arr.tolist(), newest.waited) == (8, [[3]], False)
     # a barrier names its version and gets it, read before or not
-    arr, no, _t_ready = op._fetch_ring_version(7, opportunistic=False)
+    arr, no, _t_ready = op.emit_ring.fetch_version(7, opportunistic=False)
     assert no in (8, 9)
 
 

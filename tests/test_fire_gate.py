@@ -203,7 +203,7 @@ class TestCoalescedReadback:
         # barrier drain proves they are there.)
         feed_and_fire(6)
         op.quiesce()
-        nxt = op.drain_ring(min_no=op._ring_version_no)
+        nxt = op.drain_ring(min_no=op.emit_ring.version_no)
         assert len(nxt["window_end"]) > 0
 
     def test_every_step_announces_a_token_the_throttle_consumes(self):
@@ -240,7 +240,7 @@ class TestCoalescedReadback:
         op.drain_ring(min_no=0)
         skips = op.prof.get("drain_skips", 0.0)
         # a barrier drain pins a version: it must fetch, not skip
-        op.drain_ring(min_no=op._ring_version_no)
+        op.drain_ring(min_no=op.emit_ring.version_no)
         assert op.prof.get("drain_skips", 0.0) == skips
 
 
