@@ -672,10 +672,9 @@ def subbatch_invalid(plan, config) -> Iterable[Finding]:
     count that does not divide pipeline.microbatch-size (sub-batches
     are EQUAL slices of the logical batch — ragged configured slices
     would compile extra kernel buckets and skew the fire cadence), or
-    an explicit emit deferral at logical-batch scale (>= the 100ms
-    accelerator deferral) that re-serializes fire visibility to
-    full-batch cadence — the emit-defer floor sub-batching exists to
-    get under."""
+    an explicit emit deferral at logical-batch scale (>= 100ms) that
+    re-serializes fire visibility to full-batch cadence — the
+    emit-defer floor sub-batching exists to get under."""
     from flink_tpu.config import PipelineOptions
 
     try:
@@ -712,9 +711,9 @@ def subbatch_invalid(plan, config) -> Iterable[Finding]:
                 "sub-batch cadence, re-serializing emit visibility to "
                 "logical-batch latency — the exact tax sub-batching "
                 "removes",
-                fix="leave pipeline.emit-defer on auto (-1, 10ms on "
-                    "accelerators) or set it well below the sub-batch "
-                    "wall time")
+                fix="leave pipeline.emit-defer on auto (-1: no age "
+                    "floor, the drain waits for the rows' landing) or "
+                    "set it well below the sub-batch wall time")
 
 
 @config_rule("CHECKPOINT_IN_BATCH", "error",

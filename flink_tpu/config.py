@@ -244,14 +244,14 @@ class PipelineOptions:
         "property, SURVEY §3.6).")
     EMIT_DEFER_MS = duration_option(
         "pipeline.emit-defer", -1,
-        "How long the emit drain thread lets a fired batch age before "
-        "fetching it, so the async device→host copy issued at dispatch "
-        "completes in the background and the fetch is a local read "
-        "instead of a blocking transfer (the latency/throughput knob of "
-        "the emit path; ref role: BufferDebloater's in-flight target). "
-        "-1 = auto: 0 on CPU hosts (device→host is a memcpy), 100ms on "
-        "accelerator backends. A checkpoint barrier or end-of-input "
-        "flush overrides the deferral immediately.")
+        "An age floor for a fired batch: how long the emit drain thread "
+        "lets it age before it waits for the rows' device→host copy "
+        "(issued at dispatch) to land and reads it (ref role: "
+        "BufferDebloater's in-flight target). -1 = auto: none, on every "
+        "backend: the drain waits for the landing itself, under no "
+        "lock, so a fired row leaves when its copy is there. A "
+        "checkpoint barrier or end-of-input flush ends either wait "
+        "immediately.")
     TARGET_LATENCY = duration_option(
         "pipeline.target-latency", 0,
         "Adaptive microbatch debloater (ref: BufferDebloater — auto-"

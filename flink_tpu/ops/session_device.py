@@ -745,15 +745,20 @@ class DeviceSessionOperator(ReuseRule):
         ring = self.emit_ring
         with ring.lock:
             need = ring.version_no if min_no is None else min_no
+            # the driver's drain has waited for these passes already,
+            # under no lock (EmitRing.await_landing): the fetch began
+            # where it began to want them
+            wanted = ring.take_wanted(min_no == 0)
             bufs, no_read = ring.fetch_unread(opportunistic=(min_no == 0))
         if no_read is None:
             return self._empty().materialize()
         with self.phases.span("drain.fetch", ring=need) as fetch:
             ready_wait(bufs)
-            t_ready = time.perf_counter()
+            t_ready = wanted.t_landed if wanted else time.perf_counter()
             host = [(np.asarray(h), np.asarray(r)) for h, r in bufs]
         with ring.lock:
-            ring.deliver_stamps(no_read, fetch.t0, t_ready, fetch.t1)
+            ring.deliver_stamps(no_read, wanted.t_want if wanted
+                                else fetch.t0, t_ready, fetch.t1)
         self.prof["drain_fetch"] += fetch.seconds
         self.prof["drain_fetches"] += 1
         body = np.concatenate([r[:, :int(h[0])] for h, r in host], axis=1)

@@ -35,6 +35,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import threading
 import time
 from collections.abc import Mapping
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
@@ -50,7 +51,7 @@ from flink_tpu.hostsync import ready_wait
 from flink_tpu.obs.tracing import PhaseClock
 from flink_tpu.utils.jaxcompat import shard_map
 from flink_tpu.ops.aggregates import LaneAggregate
-from flink_tpu.ops.emit_ring import EmitRing
+from flink_tpu.ops.emit_ring import EmitRing, await_arrays
 from flink_tpu.parallel.mesh import AXIS, MeshPlan
 from flink_tpu.state.keyed import (
     KeyDirectory, PaneState, PaneStateLayout, ReuseRule, account_full_drop,
@@ -2831,8 +2832,8 @@ class WindowOperator(ReuseRule):
     def _ring_after_fire(self, ends: List[int],
                          covered: bool = False) -> "FiredWindows":
         """Post-fire ring bookkeeping shared by the fused and chunked
-        top-n paths: version bump + cadenced announce (see
-        EmitRing.versions). ``covered``: this fire rode a dispatch whose
+        top-n paths: version bump + announce (see EmitRing.versions).
+        ``covered``: this fire rode a dispatch whose
         token carries the POST-fire ring head (the fused step) — that
         token (or any later one) re-validates the
         piggybacked head; a chunked fire has no token of its own, so
@@ -2851,13 +2852,12 @@ class WindowOperator(ReuseRule):
                 self._ring_head_known = False
                 self._rowfire_token_seq = (
                     self._token_seq if covered else self._token_seq + 1)
+            # a fire that carries rows announces the version that holds
+            # them: the drain waits for THAT copy to land, and must find
+            # it begun; a zero-row advance keeps the cadence
             ring = self.emit_ring
-            ring.rows_bound_since_announce += max(n_ends, 0) * (
-                self._topn[1] * 8)
-            if (time.perf_counter() - ring.last_announce
-                    >= ring.announce_interval_s
-                    or ring.rows_bound_since_announce
-                    >= self.EMIT_RING_ROWS // 2):
+            if (n_ends > 0 or time.perf_counter() - ring.last_announce
+                    >= ring.announce_interval_s):
                 ring.announce(ring.live)
             return FiredWindows(op=self, ring=True,
                                 ring_no=self.emit_ring.version_no,
@@ -3036,8 +3036,13 @@ class WindowOperator(ReuseRule):
         ``min_no``: the oldest ring version this drain may read (a
         barrier passes its fire's version so its rows are guaranteed
         present; None = latest). The fetch prefers the newest version
-        whose announced copy already landed — see EmitRing.versions."""
+        whose announced copy already landed — see EmitRing.versions;
+        the driver's drain chooses and waits ahead of the poll, under
+        no lock (``FiredWindows.await_landing``)."""
         with self.emit_ring.lock:
+            # the version the drain chose ahead of this poll and has
+            # waited for (EmitRing.await_landing), where it did
+            wanted = self.emit_ring.take_wanted(min_no == 0)
             # pop pending host-spill extras together with the ring read:
             # the appender holds the same lock across (ring dispatch,
             # extra enqueue), so the rows observed here are exactly the
@@ -3073,13 +3078,15 @@ class WindowOperator(ReuseRule):
                 need = self.emit_ring.version_no if min_no is None else min_no
                 with self.phases.span("drain.fetch", ring=need) as fetch:
                     arr, no_read, t_ready = self.emit_ring.fetch_version(
-                        need, opportunistic=(min_no == 0))
+                        need, opportunistic=(min_no == 0), wanted=wanted)
                 if no_read is not None:
                     # every fire cohort at or below the fetched version
                     # just became host-visible — hand it, with this
-                    # fetch's stamps, to the latency accounting
+                    # fetch's stamps (it began where the drain began to
+                    # want the rows), to the latency accounting
                     self.emit_ring.deliver_stamps(
-                        no_read, fetch.t0, t_ready, fetch.t1)
+                        no_read, wanted.t_want if wanted else fetch.t0,
+                        t_ready, fetch.t1)
                     seen_no = no_read
                 self.prof["drain_fetch"] += fetch.seconds
                 self.prof["drain_fetches"] += 1
@@ -3440,6 +3447,21 @@ class FiredWindows(Mapping):
         # merged in at materialization, reranked if a top-n is active
         self._extra: Optional[Dict[str, np.ndarray]] = None
         self._topn_spec: Optional[Tuple[str, int]] = None
+        # a pack fire's landing wait (await_landing): when the drain
+        # began to want its buffers, and when they had landed
+        self._t_want = self._t_landed = None
+
+    @property
+    def rowless(self) -> bool:
+        """Provably nothing for a sink: the ring marker of an advance
+        that fired no window end, or host columns without a row. The
+        driver's drain hurries for no such marker."""
+        if (self.cohort is not None or self._packs is not None
+                or self._fetch is not None or self._extra is not None):
+            return False
+        if self._data is not None:
+            return not any(len(v) for v in self._data.values())
+        return self._ring
 
     def materialize(self) -> Dict[str, np.ndarray]:
         if self._data is None:
@@ -3460,19 +3482,20 @@ class FiredWindows(Mapping):
     def _fetch_packs(self, polled: bool) -> None:
         """Fetch (``drain.fetch``) and decode this fire's pack buffers.
         ``polled``, the drain's way: wait for the copies the fire
-        started, then read them locally; else one blocking get."""
+        started (where ``await_landing`` has not already, under no
+        lock), then read them locally; else one blocking get."""
         bufs = [b for _, b in self._packs]
         with self._op.phases.span("drain.fetch") as fetch:
             if polled:
                 ready_wait(bufs)
-                t_ready = time.perf_counter()
+                t_ready = self._t_landed or time.perf_counter()
                 bufs = [np.asarray(b) for b in bufs]
             else:
                 bufs = jax.device_get(bufs)
                 t_ready = time.perf_counter()
         if self.cohort is not None:
-            self.cohort.update(t_fetch0=fetch.t0, t_ready=t_ready,
-                               t_fetch1=fetch.t1)
+            self.cohort.update(t_fetch0=self._t_want or fetch.t0,
+                               t_ready=t_ready, t_fetch1=fetch.t1)
         self._data = self._op._decode_packs(self._packs, bufs)
         self._op._note_pack_decoded(self._pack_no)
         self._packs = self._op = None
@@ -3483,6 +3506,34 @@ class FiredWindows(Mapping):
         if getattr(self, "_packs", None) is not None \
                 and self._op is not None:
             self._op._note_pack_decoded(self._pack_no)
+
+    @staticmethod
+    def await_landing(fireds: List["FiredWindows"],
+                      until: threading.Event) -> None:
+        """The drain's step ahead of a periodic ``materialize_many``,
+        holding NO lock the loop can need: wait until the rows of the
+        fires in ``fireds`` have landed on the host, or ``until`` (a
+        barrier, a stop) is set. Per ring operator once, for the
+        version that holds the rows of its newest row-carrying fire
+        here (``EmitRing.await_landing``: the choice the poll then
+        reads); per pack fire for its buffers. A marker of a fire
+        without rows waits for nothing."""
+        need: Dict[int, int] = {}
+        ops = {}
+        for f in fireds:
+            if f._data is None and f._ring:
+                ops[id(f._op)] = f._op
+                if f.cohort is not None:
+                    need[id(f._op)] = max(need.get(id(f._op), 0), f._ring_no)
+        for i, op in ops.items():
+            op.emit_ring.await_landing(
+                need.get(i, 0), until, op.phases, op.prof)
+        for f in fireds:
+            if f._data is None and f._packs is not None:
+                f._t_want = time.perf_counter()
+                await_arrays([b for _, b in f._packs], until,
+                             f._op.phases, f._op.prof)
+                f._t_landed = time.perf_counter()
 
     @staticmethod
     def materialize_many(fireds: List["FiredWindows"],
@@ -3499,10 +3550,10 @@ class FiredWindows(Mapping):
         # ring-mode entries: ONE ring poll per operator serves every
         # pending marker of that operator (later markers read empty —
         # the first drain already took the appended rows)
-        # A periodic drain fetches whatever announced ring version has
-        # already landed (min_no=0) — rows still in flight are simply
-        # picked up by the next poll, so it NEVER parks behind a
-        # just-dispatched fire's compute. A barrier drain (checkpoint
+        # A periodic drain reads the version await_landing chose and
+        # waited for (min_no=0): its rows have landed, so under the
+        # drain's locks it NEVER parks behind a just-dispatched fire's
+        # compute. A barrier drain (checkpoint
         # flush, end of job) pins each op's newest marker version so
         # every enqueued row is guaranteed fetched.
         need: Dict[int, int] = {}

@@ -219,29 +219,34 @@ class Driver:
         self._batch_capture: Dict[Tuple[int, int], Any] = {}
         import threading
 
-        # set while a barrier (checkpoint / end-of-input) is waiting on
-        # the emit queue: overrides the drain deferral immediately
+        # set while a barrier (checkpoint / end-of-input) or a stop is
+        # waiting on the emit queue: ends the drain's wait for a landing
+        # (and an explicit deferral) at once
         self._flush_req = threading.Event()
+        # set where the drain has something to hurry for: a marker that
+        # may carry rows was queued, a barrier, a stop. A batch of
+        # markers without rows hurries no one, and the drain holds it
+        # asleep on this, for as long as a ring lets pass between two
+        # announces without rows (see _drain_loop)
+        self._drain_wake = threading.Event()
+        from flink_tpu.ops.emit_ring import ANNOUNCE_INTERVAL_S
+
+        self._rowless_hold_s = ANNOUNCE_INTERVAL_S
         # Link-quiet handshake: a device→host fetch can starve behind
         # continuous host→device ingest traffic and dispatches (whether
         # it does on the current chip: not measured). The drain holds
         # this lock during its
         # fetch; the ingest loop acquires it once per batch boundary —
         # so a pending fetch gets a quiet link within one batch, and
-        # ingest resumes the moment the fetch lands.
+        # ingest resumes the moment the fetch lands. The drain's wait
+        # for the device comes BEFORE it (FiredWindows.await_landing):
+        # what it holds the lock for is a local read.
         self._link_lock = threading.Lock()
-        defer = self.config.get(PipelineOptions.EMIT_DEFER_MS)
-        if defer < 0:
-            import jax
-
-            # accelerator default 10ms: periodic polls read only
-            # ANNOUNCED-and-landed ring versions (drain_ring min_no=0),
-            # so a poll can never park behind in-flight compute — the
-            # deferral only sets the emit-latency floor (p50 ≈ defer/2
-            # + decode). The value's effect on the current chip: not
-            # measured.
-            defer = 0 if jax.default_backend() == "cpu" else 10
-        self._emit_defer_s = defer / 1000.0
+        # an explicit pipeline.emit-defer is an age floor ahead of the
+        # drain's wait for the landing; auto (-1) is none, whatever the
+        # backend: the wait is as long as the rows' copy takes
+        self._emit_defer_s = max(
+            0, self.config.get(PipelineOptions.EMIT_DEFER_MS)) / 1000.0
 
         # serializes downstream pushes from the ingest thread and the
         # drain thread (shared sinks + metrics are single-writer at a
@@ -1608,7 +1613,7 @@ class Driver:
             # recovery (exactly-once ref: StreamTask.cleanUpInternal
             # cancels the mailbox + output flusher before failover).
             self._drain_discard[0] = True
-            self._flush_req.set()
+            self._hurry_drain()
             if self._emit_q is not None:
                 self._emit_q.put(None)
                 # bounded: the drain may be wedged inside the very device
@@ -1925,8 +1930,10 @@ class Driver:
         JobResult."""
         from flink_tpu.api.environment import JobResult
 
+        self._hurry_drain()   # a stop ends the drain's waits at once
         self._emit_q.put(None)
         drain.join()
+        self._flush_req.clear()
         self._emit_q = None
         self._check_drain_error()
         for n in self.plan.nodes.values():
@@ -2568,6 +2575,8 @@ class Driver:
             cohort["t_queued"] = stamp
         if self._emit_q is not None and self._stateless_downstream(nid):
             self._emit_q.put((nid, fired, stamp))
+            if not getattr(fired, "rowless", False):
+                self._drain_wake.set()
             return
         self._emit_fired_sync(nid, fired, stamp)
 
@@ -2624,9 +2633,9 @@ class Driver:
         end), ``t_fire`` (fire dispatched), ``t_queued`` (the advance
         that fired it has returned, its release of dead keys still
         pending, and the cohort is handed to the drain),
-        ``t_fetch0`` (the fetch of its rows began), ``t_ready`` (its
-        wait for the device and the copy ended), ``t_fetch1`` (the rows
-        are host arrays), ``t_push0`` (the delivery holds
+        ``t_fetch0`` (the drain began to want its rows: its wait for
+        their landing began, under no lock), ``t_ready`` (they had
+        landed), ``t_fetch1`` (the rows are host arrays), ``t_push0`` (the delivery holds
         ``_push_lock``), ``t_sink`` (``sink.write`` returned). A stamp
         the cohort never reached is ``None``, and so is ``t_queued``
         where an EARLIER poll's fetch took the rows (a newer ring
@@ -2691,17 +2700,16 @@ class Driver:
         emit_q = self._emit_q
         discard = self._drain_discard
         gate = self._drain_gate
-        # the drain's three waits, between its spans: no leaf is open,
-        # so each is a counter (profile.detail.drain/...) and no host
-        # event: a thread that waits names no idle gap of the device
+        # the drain's waits, between its spans: no leaf is open, so each
+        # is a counter (profile.detail.drain/...) and no host event: a
+        # thread that waits names no idle gap of the device. The wait
+        # for a landing (drain/landing_wait) is counted where it is
+        # made, in ops/emit_ring.py
         detail = self.phases.detail
         while True:
             items = [emit_q.get()]
-            # Deferral: the fire dispatch already issued copy_to_host_async
-            # on its buffers; letting the batch age lets that background
-            # copy finish, so the device_get below is a local read instead
-            # of a blocking device round trip.
-            # A pending barrier (_flush_req) cancels the wait instantly.
+            # an explicit pipeline.emit-defer: the marker ages first. A
+            # pending barrier (_flush_req) cancels the wait instantly.
             if self._emit_defer_s > 0 and items[0] is not None:
                 wait = self._emit_defer_s - (time.perf_counter()
                                              - items[0][2])
@@ -2709,24 +2717,55 @@ class Driver:
                     with detail("drain/defer"):
                         self._flush_req.wait(wait)
             # opportunistically take the whole backlog: N queued fires
-            # materialize in ONE device→host round trip instead of N
+            # materialize in ONE device→host round trip instead of N.
+            # A batch in which no marker carries rows has nothing that
+            # anyone waits for, and no poll between two announces of a
+            # ring could read anything new: the drain holds it, asleep
+            # on _drain_wake, until a marker that may carry rows is
+            # queued, a barrier, a stop, or the batch is as old as the
+            # announce cadence. A loop that fires no window end for many
+            # batches (a replay: one end per ~86) then meets the drain
+            # once per cadence and not in the gap after every batch.
             while True:
-                try:
-                    items.append(emit_q.get_nowait())
-                except _q.Empty:
+                self._drain_wake.clear()
+                while True:
+                    try:
+                        items.append(emit_q.get_nowait())
+                    except _q.Empty:
+                        break
+                if (self._flush_req.is_set() or not all(
+                        i is not None and getattr(i[1], "rowless", False)
+                        for i in items)):
                     break
+                hold = self._rowless_hold_s - (time.perf_counter()
+                                               - items[0][2])
+                if hold <= 0:
+                    break
+                with detail("drain/hold"):
+                    self._drain_wake.wait(hold)
             stop = any(i is None for i in items)
             # aborted run: the attempt's output must never reach sinks —
             # a later attempt may reuse them (exactly-once would break)
             batch = ([] if discard[0]
                      else [i for i in items if i is not None])
             # barrier batches (job end, checkpoint flush) must fetch
-            # every enqueued row; periodic ones fetch whatever announced
-            # ring copy has landed and leave the rest to the next poll.
+            # every enqueued row and wait for it under the locks, as
+            # they always have; a periodic one first waits, HOLDING
+            # NOTHING the loop can need (no gate turn, no _link_lock, no
+            # ring lock, no _push_lock), until the rows of its fires
+            # have landed: the device's time after a fire is spent
+            # here, and the fetch below is a local read. A barrier or a
+            # stop ends that wait at once.
             # Read the flag BEFORE materializing: _flush_emits closes
             # the set-after-read race with a second pinned-marker pass.
             barrier = stop or self._flush_req.is_set()
             try:
+                if batch and not barrier:
+                    FiredWindows.await_landing(
+                        [f for _, f, _ in batch], self._flush_req)
+                    barrier = self._flush_req.is_set()
+                    if discard[0]:
+                        batch = []
                 # fair-drain turn: the device fetch — the part that
                 # holds the shared device→host link — waits its round-
                 # robin turn among co-resident jobs; the host-side
@@ -2769,6 +2808,11 @@ class Driver:
             if stop:
                 return
 
+    def _hurry_drain(self) -> None:
+        """A barrier or a stop: end every wait of the drain at once."""
+        self._flush_req.set()
+        self._drain_wake.set()
+
     def _check_drain_error(self) -> None:
         if self._drain_error is not None:
             e = self._drain_error
@@ -2777,10 +2821,10 @@ class Driver:
 
     def _flush_emits(self) -> None:
         """Barrier: all enqueued fires fully delivered (checkpoint
-        consistency + end-of-job ordering). Cancels the drain deferral
-        for anything in flight."""
+        consistency + end-of-job ordering). Ends the drain's wait for a
+        landing, and an explicit deferral, for anything in flight."""
         if self._emit_q is not None:
-            self._flush_req.set()
+            self._hurry_drain()
             try:
                 self._emit_q.join()
                 # a drain batch already in flight when the flag was set

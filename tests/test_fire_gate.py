@@ -198,12 +198,13 @@ class TestCoalescedReadback:
         # a new row-carrying fire re-arms the fetch — the head fact
         # goes stale at the fire and is only re-trusted once the
         # fire-covering token lands, so the poll can never stale-skip
-        # rows. (Whether THIS opportunistic poll sees the rows depends
-        # on the announce cadence, exactly as before the gate; the
-        # barrier drain proves they are there.)
+        # rows. A fire that carries rows announces its own version
+        # (PR 42), whatever the announce cadence: once it has landed an
+        # opportunistic poll reads it.
+        op.emit_ring.announce_interval_s = float("inf")
         feed_and_fire(6)
         op.quiesce()
-        nxt = op.drain_ring(min_no=op.emit_ring.version_no)
+        nxt = op.drain_ring(min_no=0)
         assert len(nxt["window_end"]) > 0
 
     def test_every_step_announces_a_token_the_throttle_consumes(self):

@@ -129,7 +129,7 @@ class TestJobPhases:
         op_keys = {k.split(".", 2)[2] for k in res.metrics
                    if k.startswith("profile.op")}
         assert op_keys <= {"drain_fetch", "drain_fetches", "drain_skips",
-                           "preagg_batches", "scan_pane_moves",
+                           "drain_landed", "drain_waited", "preagg_batches", "scan_pane_moves",
                            "scan_ranges", "assign_records",
                            "assign_memo_hits"}
         fetch = sum(v for k, v in res.metrics.items()
@@ -235,7 +235,8 @@ def details_of(metrics):
 
 GENERAL_LANE = {"window.key_scan/" + n for n in (
     "prepare", "panes", "assign", "slot_mask", "note_panes", "preagg_gate")}
-DRAIN_WAITS = {"drain/defer", "drain/link_wait", "drain/push_wait"}
+DRAIN_WAITS = {"drain/defer", "drain/hold", "drain/landing_wait",
+               "drain/link_wait", "drain/push_wait"}
 
 
 class TestJobDetails:
@@ -280,12 +281,22 @@ class TestJobDetails:
         assert set(details) <= GENERAL_LANE | DRAIN_WAITS
 
     def test_the_drains_waits_are_counters(self, job):
-        """The deferral is 0 on the CPU, so ``drain/defer`` may be absent;
+        """``pipeline.emit-defer`` on auto is no age at all, whatever the
+        backend, so ``drain/defer`` is absent; ``drain/landing_wait``
+        counts the polls whose rows had not landed when the drain came
+        for them (``drain_waited``; the others are ``drain_landed``);
         the two locks are taken once a poll."""
         m = job[0].metrics
         details = details_of(m)
+        assert "drain/defer" not in details
+        landed, waited = (
+            sum(v for k, v in m.items() if k.startswith("profile.op")
+                and k.endswith("." + name))
+            for name in ("drain_landed", "drain_waited"))
+        assert details.get("drain/landing_wait", (0.0, 0, 0.0))[1] == waited
+        assert landed + waited >= 1     # the job fired rows
         polls = details["drain/push_wait"][1]
-        assert polls == details["drain/link_wait"][1] >= 1
+        assert polls == details["drain/link_wait"][1] >= landed + waited
         assert polls >= m["profile.phase.drain.deliver.n"] / 40
         # the loop's side of the same lock: a plain counter of seconds
         assert m["profile.phase.push_wait_s"] >= 0.0
@@ -369,8 +380,8 @@ def test_phases_are_host_events_of_a_profiler_trace(tmp_path):
     assert busy < 0.5 * (dspans[-1][1] - dspans[0][0])
     ring = [st for n, _s, _e, st in drain if n == "drain.fetch"]
     assert ring and all("ring" in st for st in ring)
-    # its waits (drain/defer, drain/link_wait, drain/push_wait) are
-    # counters with no leaf open: no event carries their names
+    # its waits (drain/landing_wait, drain/link_wait, drain/push_wait)
+    # are counters with no leaf open: no event carries their names
     assert not [n for n in names if n.startswith("drain/")]
     assert set(details_of(out["job"][0].metrics)) >= {
         "drain/link_wait", "drain/push_wait"}
@@ -562,16 +573,17 @@ class TestDetail:
 
     def test_no_leaf_open_is_a_counter_under_its_own_name(self):
         c = PhaseClock()
-        with c.detail("drain/defer"):
+        with c.detail("drain/landing_wait"):
             pass
         with c.span("drain.fetch"):
             pass
-        with c.detail("drain/defer"):
+        with c.detail("drain/landing_wait"):
             with c.span("drain.deliver"):    # a leaf's time is not a wait
                 time.sleep(0.005)
         assert set(c.snapshot()) == {"drain.fetch", "drain.deliver"}
         d = c.details()
-        assert set(d) == {"drain/defer"} and d["drain/defer"]["count"] == 2
+        assert set(d) == {"drain/landing_wait"}
+        assert d["drain/landing_wait"]["count"] == 2
         assert c.open_phase() is None
 
     def test_one_level_and_exceptions(self):
