@@ -237,7 +237,11 @@ def _check_fields(nf: NodeFacts, schema: Schema, fields, what: str,
 
 def _lane_bytes(agg) -> int:
     """Per-(key, cell) accumulator footprint of the dense lane layout:
-    f32 sum/max/min lanes + the always-present i64 count lane."""
+    f32 sum/max/min lanes (integer lanes at their own widths) + the
+    always-present i64 count lane."""
+    if getattr(agg, "lane_dtypes", None) is not None:
+        return sum(8 if dt == "int64" else 4
+                   for fam in agg.lane_dtypes for dt in fam) + 8
     return (agg.sum_width + agg.max_width + agg.min_width) * 4 + 8
 
 

@@ -219,8 +219,15 @@ class KeyedStream(DataStream):
         for every key it touched, each row replacing the previous one
         for its key (ref: table-runtime GroupAggFunction — the
         retract/changelog model degenerated to upserts for insert-only
-        input; see ops/global_agg.py). Materialize latest-by-key with
-        ``UpsertSink``.
+        input). Materialize latest-by-key with ``UpsertSink``.
+
+        Where it runs: the accumulators of a ``LaneAggregate`` live on
+        the device (ops/groupagg_device.py), one program a microbatch
+        folding it in and gathering the touched keys' rows; integer
+        lanes (``aggregates.count_if / int_sum_of / int_min_of /
+        int_max_of / latest_event_time``) are exact there. ``retract=
+        True`` and a job on a device mesh run the host operator
+        (ops/global_agg.py): the same rows, the same snapshot format.
 
         ``retract=True`` emits the full CHANGELOG instead: updates
         become -U (stale row out) / +U (replacement in) pairs, first

@@ -453,6 +453,13 @@ def _merge_global_agg(snaps: Sequence[Dict[str, Any]],
         "watermark": min(s["watermark"] for s in snaps),
         "records_dropped_full": sum(
             int(s.get("records_dropped_full", 0)) for s in snaps),
+        "lane_overflow": sum(int(s.get("lane_overflow", 0)) for s in snaps),
+        # integer lanes that hold an event time are timestamps in a
+        # snapshot (either lane's, ops/groupagg_device.py
+        # snapshot_lanes); the base is where the restored operator's
+        # 32-bit offsets start: the earliest any process had
+        "time_base": min((s["time_base"] for s in snaps
+                          if s.get("time_base") is not None), default=None),
     }
     # retract mode adds last-emitted bookkeeping; absent on append-mode
     # snapshots (and pre-retract checkpoints), so splice conditionally

@@ -234,6 +234,12 @@ def compile_job(
     next_id = [0]
 
     def new_node(kind: str, name: str, **kw) -> ExecNode:
+        wt = kw.get("window_transform")
+        if kind != "global_agg" and wt is not None:
+            from flink_tpu.ops.aggregates import require_float_lanes
+
+            require_float_lanes(getattr(wt, "aggregate", None),
+                                f"{kind} '{name}'")
         n = ExecNode(id=next_id[0], kind=kind, name=name, **kw)
         next_id[0] += 1
         nodes[n.id] = n

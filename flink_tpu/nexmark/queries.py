@@ -1,13 +1,16 @@
 """NEXMark query pipelines over the DataStream API.
 
 ref: BASELINE.json configs — Q5 sliding hot items, Q7 tumbling highest
-bid, Q8 tumbling new-user join, Q11 user sessions; semantics per the
-nexmark/nexmark query definitions (SQL in the external repo; validated
-shapes in SURVEY §7).
+bid, Q8 tumbling new-user join, Q11 user sessions, Q17 auction
+statistics (the unbounded GROUP BY); semantics per the nexmark/nexmark
+query definitions (SQL in the external repo; validated shapes in
+SURVEY §7).
 """
 from __future__ import annotations
 
 from typing import Optional
+
+import numpy as np
 
 from flink_tpu.api.datastream import DataStream
 from flink_tpu.api.environment import StreamExecutionEnvironment
@@ -134,3 +137,88 @@ def q11_user_sessions(
     out = sessions.map(rename, name="q11_rename")
     out.add_sink(sink)
     return out
+
+
+MS_PER_DAY = 86_400_000
+# (auction, day) as ONE int64 key: the day number above an auction id of
+# up to 40 bits (the generator's ids stay under 2^40 for 10^11 events)
+Q17_AUCTION_BITS = 40
+Q17_PRICE_BANDS = (10_000, 1_000_000)
+
+
+def q17_auction_stats(
+    env: StreamExecutionEnvironment,
+    bids,
+    sink: Sink,
+    *,
+    price_bands=Q17_PRICE_BANDS,
+) -> DataStream:
+    """Q17: auction statistics report, an UNBOUNDED group aggregation
+    (nexmark-flink ``queries/q17.sql``: ``SELECT auction, DATE_FORMAT(
+    dateTime, 'yyyy-MM-dd') AS day, count(*) AS total_bids, count(*)
+    FILTER (WHERE price < 10000) AS rank1_bids, count(*) FILTER (WHERE
+    price >= 10000 AND price < 1000000) AS rank2_bids, count(*) FILTER
+    (WHERE price >= 1000000) AS rank3_bids, min(price), max(price),
+    avg(price), sum(price) FROM bid GROUP BY auction, DATE_FORMAT(..)``).
+
+    No window and no TTL: after every microbatch (the suite's
+    mini-batch) one upsert row for each (auction, day) it touched, over
+    every bid of the key so far; a row replaces the key's earlier row.
+    Every column is BIGINT and exact (integer lanes: ``avg_price`` is
+    the integer quotient); ``day`` is the whole days since the epoch
+    where the source formats a string. ``last_bid_ms`` is the row's
+    event time, the newest ``dateTime`` among the key's bids, which
+    Flink carries beside the row and a SQL sink drops. The accumulators
+    live on the device (ops/groupagg_device.py; the driver chooses the
+    lane)."""
+    lo, hi = (int(b) for b in price_bands)
+    shift, mask = Q17_AUCTION_BITS, (1 << Q17_AUCTION_BITS) - 1
+
+    def keyed(data, ts, valid):
+        auction = np.asarray(data["auction"], np.int64)
+        if len(auction) and not 0 <= int(auction.min()) \
+                <= int(auction.max()) <= mask:
+            raise ValueError(
+                f"q17: an auction id outside [0, 2^{shift}) cannot share "
+                "an int64 key with its day")
+        out = dict(data)
+        t = np.asarray(ts, np.int64)
+        first, last = ((int(t.min()) // MS_PER_DAY, int(t.max()) // MS_PER_DAY)
+                       if len(t) else (0, 0))
+        # a batch within one day (all but one in 750,000 at the suite's
+        # rate) takes one add; the one that crosses midnight divides
+        out["auction_day"] = (auction + (first << shift) if first == last
+                              else ((t // MS_PER_DAY) << shift) | auction)
+        return out, ts, valid
+
+    stream = env.from_source(
+        bids, WatermarkStrategy.for_monotonous_timestamps())
+    stats = (
+        stream.map_with_timestamps(keyed, name="q17_key")
+        .key_by("auction_day")
+        .running_aggregate(aggregates.multi(
+            aggregates.count("total_bids"),
+            aggregates.count_if("price", None, lo, "rank1_bids"),
+            aggregates.count_if("price", lo, hi, "rank2_bids"),
+            aggregates.count_if("price", hi, None, "rank3_bids"),
+            aggregates.int_min_of("price", "min_price"),
+            aggregates.int_max_of("price", "max_price"),
+            aggregates.int_sum_of("price", "sum_price",
+                                  avg_field="avg_price"),
+            aggregates.latest_event_time("last_bid_ms")),
+            name="q17_auction_stats")
+    )
+
+    def rename(data):
+        out = {"auction": data["key"] & mask, "day": data["key"] >> shift}
+        out.update({k: data[k] for k in Q17_COLUMNS[2:]})
+        return out
+
+    out = stats.map(rename, name="q17_rename")
+    out.add_sink(sink)
+    return out
+
+
+Q17_COLUMNS = ("auction", "day", "total_bids", "rank1_bids", "rank2_bids",
+               "rank3_bids", "min_price", "max_price", "avg_price",
+               "sum_price", "last_bid_ms")

@@ -34,6 +34,24 @@ Arrays = Dict[str, jax.Array]
 F32_NEG_INF = float("-inf")
 F32_POS_INF = float("inf")
 
+LANE_FAMILIES = ("sums", "maxs", "mins")
+# the record field under which an operator hands integer lanes the
+# event time, as int32 offsets (LaneAggregate.time_lanes)
+EVENT_TIME_FIELD = "__event_time__"
+
+
+def lane_identity(family: str, dtype: str):
+    """The identity of a lane family's reduction at ``dtype``: 0 for a
+    sum; for max / min the float infinities or the integer type's
+    extremes (which a real value may equal: the reduction is still
+    exact)."""
+    if family == "sums":
+        return 0
+    if np.issubdtype(np.dtype(dtype), np.integer):
+        info = np.iinfo(dtype)
+        return info.min if family == "maxs" else info.max
+    return F32_NEG_INF if family == "maxs" else F32_POS_INF
+
 
 @dataclasses.dataclass(frozen=True)
 class LaneAggregate:
@@ -62,11 +80,42 @@ class LaneAggregate:
     # bytes and the device scatter from records to distinct pairs.
     # None = lift is opaque; the operator must ship raw records.
     sum_fields: Optional[Tuple[str, ...]] = None
+    # INTEGER lanes (exact at the source's widths): per family (sums,
+    # maxs, mins) the dtype name of each lane, "int32" or "int64". None
+    # = every lane float32, the (B, width) layout above. Where set,
+    # ``lift`` returns per family a TUPLE of 1-D columns of those dtypes
+    # and ``finalize`` receives such tuples (host arrays: numpy ops
+    # only). Run by the unwindowed aggregation (ops/groupagg_device.py,
+    # ops/global_agg.py); the windowed operators refuse them.
+    lane_dtypes: Optional[Tuple[Tuple[str, ...], Tuple[str, ...],
+                                Tuple[str, ...]]] = None
+    # record fields every lane reads at 32 bits: uploaded as int32, a
+    # record whose value does not fit is refused and counted by the
+    # operator (``groupagg.lane_overflow``), never wrapped
+    narrow_fields: Tuple[str, ...] = ()
+    # per family the lanes that hold an event time: the operator hands
+    # ``lift`` int32 offsets from the job's first timestamp under
+    # ``EVENT_TIME_FIELD`` and makes the lane a timestamp again (int64)
+    # before ``finalize`` and in a snapshot
+    time_lanes: Tuple[Tuple[int, ...], Tuple[int, ...],
+                      Tuple[int, ...]] = ((), (), ())
+
+    @property
+    def typed(self) -> bool:
+        return self.lane_dtypes is not None
 
     def lift_masked(self, data: Arrays, valid: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """Lift a batch, mapping invalid rows to identity elements.
         Normalizes shape to (B, width) even when lift can't know B
-        (e.g. count() over a batch with no data fields)."""
+        (e.g. count() over a batch with no data fields). Integer lanes:
+        per family a tuple of (B,) columns."""
+        if self.typed:
+            return tuple(
+                tuple(jnp.where(valid, c.astype(dt),
+                                jnp.asarray(lane_identity(fam, dt), dt))
+                      for c, dt in zip(cols, dts))
+                for fam, cols, dts in zip(LANE_FAMILIES, self.lift(data),
+                                          self.lane_dtypes))
         b = valid.shape[0]
         s, mx, mn = self.lift(data)
 
@@ -88,6 +137,17 @@ class LaneAggregate:
         )
 
 
+def require_float_lanes(agg: Any, where: str) -> None:
+    """Integer lanes are run by the unwindowed aggregation alone; the
+    windowed operators hold (rows, ring, width) float32 families."""
+    if getattr(agg, "typed", False):
+        raise NotImplementedError(
+            f"{where}: aggregate '{agg.name}' has integer lanes "
+            "(count_if / int_sum_of / int_min_of / int_max_of / "
+            "latest_event_time), which only KeyedStream.running_aggregate "
+            "runs; a window takes the float32 lanes (sum_of, max_of, ...)")
+
+
 def _empty_lanes(b: jax.Array) -> jax.Array:
     return jnp.zeros(b.shape[:1] + (0,), dtype=jnp.float32)
 
@@ -98,6 +158,10 @@ def probe_finalize(agg: LaneAggregate) -> Arrays:
     fired-row result columns: :func:`result_fields`, the compiler's
     ``ExecNode.out_schema`` recording (graph/compiler.py), and
     ``WindowOperator._result_fields``' dtype classification."""
+    if agg.typed:
+        return agg.finalize(
+            *(tuple(np.zeros(0, dt) for dt in dts)
+              for dts in agg.lane_dtypes), np.zeros((0,), np.int32))
     return agg.finalize(
         np.zeros((0, agg.sum_width), np.float32),
         np.zeros((0, agg.max_width), np.float32),
@@ -202,6 +266,117 @@ def avg_of(field: str, result_field: Optional[str] = None) -> LaneAggregate:
                          fields=(field,), sum_fields=(field,))
 
 
+# ---------------------------------------------------------------------------
+# Integer lanes: exact at the source's widths (LaneAggregate.lane_dtypes).
+# ---------------------------------------------------------------------------
+
+def _typed(sums=(), maxs=(), mins=()):
+    return (tuple(sums), tuple(maxs), tuple(mins))
+
+
+@_cached
+def count_if(field: str, lo: Optional[int] = None, hi: Optional[int] = None,
+             result_field: Optional[str] = None) -> LaneAggregate:
+    """``COUNT(*) FILTER (WHERE lo <= field < hi)`` (either bound may be
+    left out): an int32 lane that adds 1 for every record under the
+    predicate."""
+    out = result_field or f"count_{field}_{lo}_{hi}"
+
+    def lift(data: Arrays):
+        x = data[field]
+        ok = jnp.ones(x.shape, bool)
+        if lo is not None:
+            ok &= x >= lo
+        if hi is not None:
+            ok &= x < hi
+        return (ok.astype(jnp.int32),), (), ()
+
+    def finalize(sums, maxs, mins, counts):
+        return {out: sums[0]}
+
+    return LaneAggregate(1, 0, 0, lift, finalize,
+                         name=f"count_if({lo}<={field}<{hi})",
+                         fields=(field,), lane_dtypes=_typed(["int32"]),
+                         narrow_fields=(field,))
+
+
+@_cached
+def int_sum_of(field: str, result_field: Optional[str] = None,
+               avg_field: Optional[str] = None) -> LaneAggregate:
+    """SUM over an integer column, exact at 64 bits (the chip has no
+    native int64: XLA carries the lane as two words). ``avg_field``:
+    also the integer quotient ``sum // count`` under that name, SQL's
+    AVG over a BIGINT column, from the same lane."""
+    out = result_field or f"sum_{field}"
+
+    def lift(data: Arrays):
+        return (data[field].astype(jnp.int64),), (), ()
+
+    def finalize(sums, maxs, mins, counts):
+        res = {out: sums[0]}
+        if avg_field is not None:
+            res[avg_field] = sums[0] // np.maximum(
+                np.asarray(counts, np.int64), 1)
+        return res
+
+    return LaneAggregate(1, 0, 0, lift, finalize, name=f"int_sum({field})",
+                         fields=(field,), lane_dtypes=_typed(["int64"]))
+
+
+@_cached
+def int_max_of(field: str, result_field: Optional[str] = None
+               ) -> LaneAggregate:
+    """MAX over an integer column, exact: an int32 lane (a value that
+    32 bits cannot hold is refused by the operator, never wrapped)."""
+    out = result_field or f"max_{field}"
+
+    def lift(data: Arrays):
+        return (), (data[field].astype(jnp.int32),), ()
+
+    def finalize(sums, maxs, mins, counts):
+        return {out: maxs[0]}
+
+    return LaneAggregate(0, 1, 0, lift, finalize, name=f"int_max({field})",
+                         fields=(field,), lane_dtypes=_typed(maxs=["int32"]),
+                         narrow_fields=(field,))
+
+
+@_cached
+def int_min_of(field: str, result_field: Optional[str] = None
+               ) -> LaneAggregate:
+    """MIN over an integer column, exact: see :func:`int_max_of`."""
+    out = result_field or f"min_{field}"
+
+    def lift(data: Arrays):
+        return (), (), (data[field].astype(jnp.int32),)
+
+    def finalize(sums, maxs, mins, counts):
+        return {out: mins[0]}
+
+    return LaneAggregate(0, 0, 1, lift, finalize, name=f"int_min({field})",
+                         fields=(field,), lane_dtypes=_typed(mins=["int32"]),
+                         narrow_fields=(field,))
+
+
+@_cached
+def latest_event_time(result_field: str = "last_ts") -> LaneAggregate:
+    """The newest event time among a key's records (the row's rowtime,
+    which Flink carries beside the row): an int32 max lane over the
+    offsets the operator provides under ``EVENT_TIME_FIELD``; the
+    result is the timestamp itself (``time_lanes``)."""
+
+    def lift(data: Arrays):
+        return (), (data[EVENT_TIME_FIELD].astype(jnp.int32),), ()
+
+    def finalize(sums, maxs, mins, counts):
+        return {result_field: maxs[0]}
+
+    return LaneAggregate(0, 1, 0, lift, finalize, name="latest_event_time",
+                         fields=(EVENT_TIME_FIELD,),
+                         lane_dtypes=_typed(maxs=["int32"]),
+                         time_lanes=((), (0,), ()))
+
+
 @_cached
 def multi(*aggs: LaneAggregate) -> LaneAggregate:
     """Compose several aggregations over one window into one lane layout
@@ -209,6 +384,8 @@ def multi(*aggs: LaneAggregate) -> LaneAggregate:
     sw = sum(a.sum_width for a in aggs)
     mw = sum(a.max_width for a in aggs)
     nw = sum(a.min_width for a in aggs)
+    if any(a.typed for a in aggs):
+        return _multi_typed(aggs, sw, mw, nw)
 
     def lift(data: Arrays):
         ss, ms, ns = [], [], []
@@ -239,12 +416,7 @@ def multi(*aggs: LaneAggregate) -> LaneAggregate:
             no += a.min_width
         return out
 
-    comp_fields: Optional[Tuple[str, ...]] = ()
-    for a in aggs:
-        if a.fields is None:
-            comp_fields = None
-            break
-        comp_fields = tuple(dict.fromkeys(comp_fields + a.fields))
+    comp_fields = _merged_fields(aggs, "fields")
     comp_sum: Optional[Tuple[str, ...]] = ()
     for a in aggs:
         if a.sum_fields is None:
@@ -254,6 +426,62 @@ def multi(*aggs: LaneAggregate) -> LaneAggregate:
     return LaneAggregate(sw, mw, nw, lift, finalize,
                          name="+".join(a.name for a in aggs),
                          fields=comp_fields, sum_fields=comp_sum)
+
+
+def _merged_fields(aggs, attr: str) -> Optional[Tuple[str, ...]]:
+    out: Tuple[str, ...] = ()
+    for a in aggs:
+        if getattr(a, attr) is None:
+            return None
+        out = tuple(dict.fromkeys(out + getattr(a, attr)))
+    return out
+
+
+def _multi_typed(aggs, sw: int, mw: int, nw: int) -> LaneAggregate:
+    """``multi`` over integer-lane aggregates (and lane-less ones such
+    as ``count()``): the families' column tuples side by side."""
+    for a in aggs:
+        if not a.typed and a.sum_width + a.max_width + a.min_width:
+            raise ValueError(
+                f"multi: '{a.name}' has float32 lanes and cannot share a "
+                "layout with integer lanes; use the int_* aggregates "
+                "throughout")
+    widths = [(a.sum_width, a.max_width, a.min_width) for a in aggs]
+
+    def lift(data: Arrays):
+        fams = ([], [], [])
+        for a in aggs:
+            if a.typed:
+                for fam, cols in zip(fams, a.lift(data)):
+                    fam.extend(cols)
+        return tuple(tuple(f) for f in fams)
+
+    def finalize(sums, maxs, mins, counts):
+        out: Arrays = {}
+        at = [0, 0, 0]
+        for a, w in zip(aggs, widths):
+            if a.typed:
+                args = [fam[o:o + n] for fam, o, n in zip(
+                    (sums, maxs, mins), at, w)]
+            else:   # no lanes: whatever empty layout it expects
+                args = [np.zeros((len(counts), 0), np.float32)] * 3
+            out.update(a.finalize(*args, counts))
+            at = [o + n for o, n in zip(at, w)]
+        return out
+
+    dtypes = tuple(
+        tuple(dt for a in aggs if a.typed for dt in a.lane_dtypes[i])
+        for i in range(3))
+    times, at = ([], [], []), [0, 0, 0]
+    for a, w in zip(aggs, widths):
+        for i in range(3):
+            times[i].extend(at[i] + j for j in a.time_lanes[i])
+            at[i] += w[i]
+    return LaneAggregate(
+        sw, mw, nw, lift, finalize, name="+".join(a.name for a in aggs),
+        fields=_merged_fields(aggs, "fields"), lane_dtypes=dtypes,
+        narrow_fields=_merged_fields(aggs, "narrow_fields"),
+        time_lanes=tuple(tuple(t) for t in times))
 
 
 # ---------------------------------------------------------------------------

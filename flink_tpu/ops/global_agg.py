@@ -17,12 +17,23 @@ consumers need — window aggregation that SUBTRACTS retracted rows
 (ops/aggregates.changelog_* lanes), `RetractSink`, and the SQL
 HAVING-over-unwindowed-aggregation rewrite all fold these rows.
 
-TPU-first shape: per-key accumulators live in flat host arrays behind
+Which jobs run here: this is the HOST lane of the unwindowed
+aggregation. The driver builds it (``runtime/driver.py``, the one place
+the lane is chosen) for ``retract=True`` and under a device mesh; every
+other job with a ``LaneAggregate`` keeps its accumulators on the device
+(``ops/groupagg_device.py`` ``DeviceGroupAggOperator``, the same rows
+and the same snapshot format). ``groupagg.on_host`` reads 1 here.
+
+Host shape: per-key accumulators live in flat host arrays behind
 the same KeyDirectory slot map the pane backend uses; a batch folds in
 with one argsort + reduceat per lane (no per-record Python), and the
 upserts emitted per microbatch are exactly the keys the batch touched
 — the mini-batch aggregation emission model (ref: table-runtime
-MiniBatchGroupAggFunction).
+MiniBatchGroupAggFunction). Integer lanes (``LaneAggregate
+.lane_dtypes``) are held at int64 and are exact, as on the device; the
+float lanes add in float64 here and in float32 there, so a float SUM
+may differ between the lanes in its last bits (counts, maxs and mins
+never do).
 """
 from __future__ import annotations
 
@@ -32,6 +43,10 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from flink_tpu import faults
+from flink_tpu.ops.aggregates import LANE_FAMILIES, lane_identity
+from flink_tpu.ops.groupagg_device import (
+    finalize_rows, host_lane_dtypes, host_records, lane_layout,
+    restored_lanes, snapshot_lanes)
 from flink_tpu.ops.window import FiredWindows, account_full_drop
 from flink_tpu.records import (
     OP_DTYPE,
@@ -42,6 +57,21 @@ from flink_tpu.records import (
 )
 from flink_tpu.state.keyed import KeyDirectory
 from flink_tpu.time.watermarks import LONG_MIN
+
+
+def _columns(*arrays: np.ndarray):
+    """``(n, width)`` lane arrays as tuples of their columns."""
+    return [tuple(a[:, j] for j in range(a.shape[1])) for a in arrays]
+
+
+def _as_2d(family, n: int) -> np.ndarray:
+    """A lifted lane family as ``(n, width)``: it comes so for float
+    lanes, as a tuple of (n,) columns for integer ones."""
+    if not isinstance(family, tuple):
+        return np.asarray(family)
+    if not family:
+        return np.zeros((n, 0), np.int64)
+    return np.stack([np.asarray(c) for c in family], axis=1)
 
 
 class GlobalAggregateOperator:
@@ -66,25 +96,44 @@ class GlobalAggregateOperator:
         self.directory = KeyDirectory(num_shards, slots_per_shard)
         n = self.directory.local_slots
         self.counts = np.zeros(n, np.int64)
-        self.sums = np.zeros((n, agg.sum_width), np.float64)
-        self.maxs = np.full((n, agg.max_width), -np.inf, np.float32)
-        self.mins = np.full((n, agg.min_width), np.inf, np.float32)
+        self.sums, self.maxs, self.mins = self._identity_lanes(n)
         if self.retract:
             # accumulators AS EMITTED — the -U row's payload; a slot
             # retracts only after its first emission (emitted mask)
             self.prev_counts = np.zeros(n, np.int64)
-            self.prev_sums = np.zeros((n, agg.sum_width), np.float64)
-            self.prev_maxs = np.full((n, agg.max_width), -np.inf,
-                                     np.float32)
-            self.prev_mins = np.full((n, agg.min_width), np.inf,
-                                     np.float32)
+            self.prev_sums, self.prev_maxs, self.prev_mins = \
+                self._identity_lanes(n)
             self.emitted = np.zeros(n, bool)
+        # integer lanes that read the event time hold ``ts - _base``
+        # (the first batch's earliest), as the device lane's do
+        self._base: Optional[int] = None
+        self.lane_overflow = 0         # records a 32-bit lane refused
         self.watermark = LONG_MIN
         self.late_records = 0          # unwindowed: nothing is late
         self.records_dropped_full = 0
         self.allow_drops = False
         self.state_version = 0
         self._touched: Optional[np.ndarray] = None
+
+    def _identity_lanes(self, n: int):
+        """(sums, maxs, mins) of ``n`` untouched slots, ``(n, width)``
+        each: float64 / float32 / float32 for the float aggregates,
+        int64 for integer lanes (``host_lane_dtypes``)."""
+        dts = host_lane_dtypes(self.agg)
+        out = []
+        for fam, lanes in zip(LANE_FAMILIES, lane_layout(self.agg)):
+            arr = np.empty((n, len(lanes)), dts[fam])
+            for j, dt in enumerate(lanes):
+                arr[:, j] = lane_identity(fam, dt)
+            out.append(arr)
+        return out
+
+    def _finalize(self, counts, sums, maxs, mins) -> Dict[str, np.ndarray]:
+        if not self.agg.typed:
+            return {k: np.asarray(v) for k, v in self.agg.finalize(
+                sums.astype(np.float32), maxs, mins, counts).items()}
+        return finalize_rows(self.agg, counts, _columns(sums, maxs, mins),
+                             self._base or 0)
 
     # -- data plane ------------------------------------------------------
 
@@ -96,15 +145,25 @@ class GlobalAggregateOperator:
                  else np.asarray(valid, bool))
         if not valid.any():
             return
-        keys = keys[valid]
+        keys, ts = keys[valid], np.asarray(ts, np.int64)[valid]
         data = {k: np.asarray(v)[valid] for k, v in data.items()}
         slots = self.directory.assign(keys)
-        bad = slots < 0
-        if bad.any():
-            account_full_drop(self, int(bad.sum()))
-            keys, slots = keys[~bad], slots[~bad]
-            data = {k: v[~bad] for k, v in data.items()}
-            if not len(keys):
+        keep = slots >= 0
+        if not keep.all():
+            account_full_drop(self, int((~keep).sum()))
+        if self.agg.typed:
+            # the columns as the device lane uploads them: a value a
+            # 32-bit lane cannot hold is refused with its record
+            if self._base is None and any(self.agg.time_lanes):
+                self._base = int(ts.min())
+            data, over = host_records(self.agg, ts, data, self._base or 0)
+            if over is not None:
+                self.lane_overflow += int((over & keep).sum())
+                keep &= ~over
+        if not keep.all():
+            slots = slots[keep]
+            data = {k: v[keep] for k, v in data.items()}
+            if not len(slots):
                 return
         order = np.argsort(slots, kind="stable")
         so = slots[order]
@@ -116,13 +175,13 @@ class GlobalAggregateOperator:
         self.counts[uslots] += np.add.reduceat(
             np.ones(len(so), np.int64), starts)
         if self.agg.sum_width or self.agg.max_width or self.agg.min_width:
-            s_l, mx_l, mn_l = self.agg.lift_masked(
+            lifted = self.agg.lift_masked(
                 {k: v[order] for k, v in data.items()},
                 np.ones(len(so), bool))
-            s_l, mx_l, mn_l = (np.asarray(s_l), np.asarray(mx_l),
-                               np.asarray(mn_l))
+            s_l, mx_l, mn_l = (_as_2d(fam, len(so)) for fam in lifted)
             if self.agg.sum_width:
-                self.sums[uslots] += np.add.reduceat(s_l, starts, axis=0)
+                self.sums[uslots] += np.add.reduceat(
+                    s_l.astype(self.sums.dtype), starts, axis=0)
             if self.agg.max_width:
                 self.maxs[uslots] = np.maximum(
                     self.maxs[uslots],
@@ -144,14 +203,12 @@ class GlobalAggregateOperator:
         self._touched = None
         wm = self.watermark if self.watermark != LONG_MIN else 0
         if not self.retract:
-            res = self.agg.finalize(
-                self.sums[sl].astype(np.float32), self.maxs[sl],
-                self.mins[sl], self.counts[sl])
             out: Dict[str, np.ndarray] = {
                 "key": self.directory.key_of_slots(sl)}
             out["count"] = self.counts[sl]
-            for k, v in res.items():
-                out[k] = np.asarray(v)
+            out.update(self._finalize(
+                self.counts[sl], self.sums[sl], self.maxs[sl],
+                self.mins[sl]))
             # upserts carry the emission-time watermark as their
             # timestamp (the process-function emission contract,
             # driver _emit_fired)
@@ -167,25 +224,19 @@ class GlobalAggregateOperator:
         keys_new = self.directory.key_of_slots(sl)
         blocks = []
         if len(retr):
-            res_old = self.agg.finalize(
-                self.prev_sums[retr].astype(np.float32),
-                self.prev_maxs[retr], self.prev_mins[retr],
-                self.prev_counts[retr])
             old: Dict[str, np.ndarray] = {
                 "key": self.directory.key_of_slots(retr),
                 "count": self.prev_counts[retr]}
-            for k, v in res_old.items():
-                old[k] = np.asarray(v)
+            old.update(self._finalize(
+                self.prev_counts[retr], self.prev_sums[retr],
+                self.prev_maxs[retr], self.prev_mins[retr]))
             old[OP_FIELD] = np.full(len(retr), OP_UPDATE_BEFORE,
                                     OP_DTYPE)
             blocks.append(old)
-        res = self.agg.finalize(
-            self.sums[sl].astype(np.float32), self.maxs[sl],
-            self.mins[sl], self.counts[sl])
         new: Dict[str, np.ndarray] = {"key": keys_new,
                                       "count": self.counts[sl]}
-        for k, v in res.items():
-            new[k] = np.asarray(v)
+        new.update(self._finalize(
+            self.counts[sl], self.sums[sl], self.maxs[sl], self.mins[sl]))
         new[OP_FIELD] = np.where(self.emitted[sl], OP_UPDATE_AFTER,
                                  OP_INSERT).astype(OP_DTYPE)
         blocks.append(new)
@@ -208,15 +259,10 @@ class GlobalAggregateOperator:
         return FiredWindows(data=dict(self._empty()))
 
     def _empty(self) -> Dict[str, np.ndarray]:
-        res = self.agg.finalize(
-            np.zeros((0, self.agg.sum_width), np.float32),
-            np.zeros((0, self.agg.max_width), np.float32),
-            np.zeros((0, self.agg.min_width), np.float32),
-            np.zeros(0, np.int64))
         out = {"key": np.zeros(0, np.int64),
                "count": np.zeros(0, np.int64)}
-        for k, v in res.items():
-            out[k] = np.asarray(v)
+        out.update(self._finalize(
+            np.zeros(0, np.int64), *self._identity_lanes(0)))
         if self.retract:
             out[OP_FIELD] = np.zeros(0, OP_DTYPE)
         return out
@@ -232,22 +278,42 @@ class GlobalAggregateOperator:
 
     # -- snapshot seam ---------------------------------------------------
 
+    def _lanes_out(self, counts, sums, maxs, mins, prefix: str = ""):
+        """The snapshot's lane arrays (copies; an event-time lane as
+        timestamps: ``snapshot_lanes``, either lane's format)."""
+        lanes = snapshot_lanes(self.agg, counts, _columns(sums, maxs, mins),
+                               self._base or 0)
+        return {prefix + fam: a for fam, a in lanes.items()}
+
+    def _lanes_in(self, snap: Dict[str, Any], prefix: str = ""):
+        """(sums, maxs, mins) of a snapshot, either lane's."""
+        view = {fam: snap[prefix + fam] for fam in LANE_FAMILIES}
+        view["counts"] = snap[prefix + "counts"]
+        view["time_base"] = snap.get("time_base", self._base)
+        base, fams = restored_lanes(self.agg, view)
+        if self._base is None:
+            self._base = base
+        n, dts = len(np.asarray(view["counts"])), host_lane_dtypes(self.agg)
+        return [np.stack(cols, axis=1).astype(dts[fam]) if cols
+                else np.zeros((n, 0), dts[fam])
+                for fam, cols in zip(LANE_FAMILIES, fams)]
+
     def snapshot_state(self) -> Dict[str, Any]:
         snap = {
             "kind": "global_agg",
             "directory": self.directory.snapshot(),
             "counts": self.counts.copy(),
-            "sums": self.sums.copy(),
-            "maxs": self.maxs.copy(),
-            "mins": self.mins.copy(),
+            **self._lanes_out(self.counts, self.sums, self.maxs, self.mins),
+            "time_base": self._base,
             "watermark": self.watermark,
             "records_dropped_full": self.records_dropped_full,
+            "lane_overflow": self.lane_overflow,
         }
         if self.retract:
             snap["prev_counts"] = self.prev_counts.copy()
-            snap["prev_sums"] = self.prev_sums.copy()
-            snap["prev_maxs"] = self.prev_maxs.copy()
-            snap["prev_mins"] = self.prev_mins.copy()
+            snap.update(self._lanes_out(
+                self.prev_counts, self.prev_sums, self.prev_maxs,
+                self.prev_mins, "prev_"))
             snap["emitted"] = self.emitted.copy()
         return snap
 
@@ -256,24 +322,34 @@ class GlobalAggregateOperator:
             self.directory.num_shards, self.directory.slots_per_shard,
             snap["directory"],
             (self.directory.shard_lo, self.directory.shard_hi))
-        self.counts = np.asarray(snap["counts"]).copy()
-        self.sums = np.asarray(snap["sums"]).copy()
-        self.maxs = np.asarray(snap["maxs"]).copy()
-        self.mins = np.asarray(snap["mins"]).copy()
+        self.counts = np.asarray(snap["counts"]).astype(np.int64)
+        self._base = snap.get("time_base")
+        self.sums, self.maxs, self.mins = self._lanes_in(snap)
         if self.retract:
             # a pre-retract snapshot restoring into a retract-mode op:
             # treat the restored view as already emitted so the first
             # post-restore update retracts it (no double +I)
-            self.prev_counts = np.asarray(snap.get(
-                "prev_counts", self.counts)).copy()
-            self.prev_sums = np.asarray(snap.get(
-                "prev_sums", self.sums)).copy()
-            self.prev_maxs = np.asarray(snap.get(
-                "prev_maxs", self.maxs)).copy()
-            self.prev_mins = np.asarray(snap.get(
-                "prev_mins", self.mins)).copy()
+            if "prev_counts" in snap:
+                self.prev_counts = np.asarray(snap["prev_counts"]).copy()
+                self.prev_sums, self.prev_maxs, self.prev_mins = \
+                    self._lanes_in(snap, "prev_")
+            else:
+                self.prev_counts = self.counts.copy()
+                self.prev_sums, self.prev_maxs, self.prev_mins = (
+                    self.sums.copy(), self.maxs.copy(), self.mins.copy())
             self.emitted = np.asarray(snap.get(
                 "emitted", self.counts > 0)).copy()
         self.watermark = snap["watermark"]
         self.records_dropped_full = snap.get("records_dropped_full", 0)
+        self.lane_overflow = snap.get("lane_overflow", 0)
         self._touched = None
+
+    def state_counters(self) -> Dict[str, Any]:
+        """What the job reports of this lane (the device lane's names):
+        ``groupagg.on_host`` 1 = the driver built the host operator."""
+        d = self.directory
+        return {"groupagg.on_host": 1,
+                "groupagg.lane_overflow": self.lane_overflow,
+                "groupagg.keys_new": d.slots_allocated,
+                "groupagg.live_keys": d.num_keys(),
+                "groupagg.slots": d.local_slots}

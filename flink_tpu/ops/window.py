@@ -50,7 +50,7 @@ from flink_tpu.api.windowing import WindowAssigner
 from flink_tpu.hostsync import ready_wait
 from flink_tpu.obs.tracing import PhaseClock
 from flink_tpu.utils.jaxcompat import shard_map
-from flink_tpu.ops.aggregates import LaneAggregate
+from flink_tpu.ops.aggregates import LaneAggregate, require_float_lanes
 from flink_tpu.ops.emit_ring import EmitRing, await_arrays
 from flink_tpu.parallel.mesh import AXIS, MeshPlan
 from flink_tpu.state.keyed import (
@@ -127,6 +127,13 @@ LANE_OPS = (("sums", jnp.add, "add"), ("maxs", jnp.maximum, "max"),
             ("mins", jnp.minimum, "min"))
 
 
+def _lane_columns(lane) -> list:
+    """The 1-D columns of one lane family."""
+    if isinstance(lane, (tuple, list)):
+        return list(lane)
+    return [lane[:, j] for j in range(lane.shape[1])]
+
+
 def combine_cells(n_rows: int, rows, ring_ix, valid, lanes):
     """Combine a batch per (row, ring column) cell: two sorts and no
     scatter. ``lanes``: the lifted (B, width) arrays by the name of
@@ -144,7 +151,11 @@ def combine_cells(n_rows: int, rows, ring_ix, valid, lanes):
     - ``n_cells``, ``n_records``: distinct cells, valid records."""
     batch = rows.shape[0]
     key = jnp.where(valid, ring_ix * n_rows + rows, NO_CELL)
-    cols = [l[:, j] for l in lanes.values() for j in range(l.shape[1])]
+    # a family is a (B, width) float32 array or, for integer lanes
+    # (LaneAggregate.lane_dtypes), a tuple of (B,) columns of any dtype:
+    # the sort's payload and the scan take either
+    lanes = {name: _lane_columns(l) for name, l in lanes.items()}
+    cols = [c for l in lanes.values() for c in l]
     # stable where lanes ride along: a cell's records then keep their
     # arrival order, so its float sum depends on them alone (_run_scan)
     key, *cols = lax.sort((key, *cols), num_keys=1, is_stable=len(lanes) > 0)
@@ -157,7 +168,7 @@ def combine_cells(n_rows: int, rows, ring_ix, valid, lanes):
     scans, at = {}, 0
     for name, op, _ in LANE_OPS:
         if name in lanes:
-            w = lanes[name].shape[1]
+            w = len(lanes[name])
             scans[name] = [_run_scan(op, first, c) for c in cols[at:at + w]]
             at += w
     # the run heads to the front, in key order: a second sort, whose
@@ -1457,6 +1468,7 @@ class WindowOperator(ReuseRule):
         host_pool: Optional[Any] = None,
         fold_chunk_records: Optional[int] = None,
     ) -> None:
+        require_float_lanes(agg, "WindowOperator")
         self.assigner = assigner
         self.agg = agg
         self.mesh_plan = mesh_plan

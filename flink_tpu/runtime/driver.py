@@ -443,16 +443,33 @@ class Driver:
                     num_shards=num_shards, slots_per_shard=slots)
             elif n.kind == "global_agg":
                 from flink_tpu.ops.global_agg import GlobalAggregateOperator
+                from flink_tpu.ops.groupagg_device import (
+                    DeviceGroupAggOperator, device_lane_fits)
 
-                if self.mesh_plan is not None:
-                    raise NotImplementedError(
-                        "unwindowed aggregation on a device mesh is not "
-                        "yet supported; run without cluster.mesh-devices")
                 t = n.window_transform
-                self._ops[n.id] = GlobalAggregateOperator(
-                    t.aggregate, num_shards=num_shards,
-                    slots_per_shard=slots,
-                    retract=getattr(t, "retract", False))
+                retract = getattr(t, "retract", False)
+                # the ONE place the lane of an unwindowed aggregation
+                # is chosen, by what the job is: accumulators on the
+                # device for a lane aggregate; the host operator for
+                # retract rows (the -U row is the accumulators as last
+                # emitted) and under a mesh (no accumulators sharded
+                # over devices yet: the host folds them, where this
+                # refused the job before)
+                if device_lane_fits(
+                        agg=t.aggregate, retract=retract,
+                        mesh=self.mesh_plan is not None,
+                        slots=num_shards * slots):
+                    op = self._ops[n.id] = DeviceGroupAggOperator(
+                        t.aggregate, num_shards=num_shards,
+                        slots_per_shard=slots,
+                        max_inflight_steps=inflight)
+                    # as the window factory: the loop throttles outside
+                    # its push lock
+                    op.external_throttle = True
+                else:
+                    self._ops[n.id] = GlobalAggregateOperator(
+                        t.aggregate, num_shards=num_shards,
+                        slots_per_shard=slots, retract=retract)
             elif n.kind == "session":
                 from flink_tpu.ops.session import SessionOperator
                 from flink_tpu.ops.session_device import (

@@ -385,6 +385,51 @@ class TestGlobalAggRescale:
         assert _rows(new.take_fired()) == _rows(ref.take_fired())
 
 
+    @pytest.mark.parametrize("lane", ["host", "device"])
+    def test_merge_down_integer_lanes_and_their_event_times(self, lane):
+        """Integer lanes are int64 in the snapshot and an event-time
+        lane is a timestamp there, so two processes whose first
+        timestamps differ (each holds offsets from its own) merge into
+        one state, which either lane of the operator restores."""
+        from flink_tpu.ops import aggregates as A
+        from flink_tpu.ops.groupagg_device import DeviceGroupAggOperator
+
+        agg = A.multi(A.count(), A.int_sum_of("v", avg_field="avg_v"),
+                      A.int_max_of("v"), A.latest_event_time("last"))
+
+        def mk(cls=GlobalAggregateOperator):
+            return cls(agg, num_shards=NS, slots_per_shard=SPS)
+
+        def batch(seed, t0):
+            keys, ts, _ = _batch(seed, t0, n_keys=16)
+            v = np.random.default_rng(seed).integers(0, 2**31 - 1, len(keys))
+            return keys, ts + 10**12, {"v": v.astype(np.int64)}
+
+        ref, olds = mk(), [mk(), mk()]
+        keys, ts, data = batch(22, 0)
+        ref.process_batch(keys, ts, data)
+        ref.take_fired()
+        for op, (k, t, d) in zip(olds, _route(keys, ts, data, 2)):
+            op.process_batch(k, t, d)
+            op.take_fired()
+        assert olds[0]._base != olds[1]._base
+        payloads = [_payload({"g": op.snapshot_state()}, pid, 2)
+                    for pid, op in enumerate(olds)]
+        merged = _merge(payloads, 0, 1, {"g": "global_agg"})
+        snap = merged["operators"]["g"]
+        assert snap["sums"].dtype == np.int64
+        assert snap["time_base"] == min(op._base for op in olds)
+        new = mk(DeviceGroupAggOperator if lane == "device"
+                 else GlobalAggregateOperator)
+        new.restore_state(snap)
+        keys2, ts2, data2 = batch(23, 1000)
+        ref.process_batch(keys2, ts2, data2)
+        new.process_batch(keys2, ts2, data2)
+        got = _rows(new.take_fired())
+        assert got == _rows(ref.take_fired())
+        assert max(r[-1] for r in got) > 2**31     # a sum past one word
+
+
 class TestSessionRescale:
     def test_merge_down_closes_open_sessions(self):
         def mk():
