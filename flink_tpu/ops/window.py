@@ -447,6 +447,49 @@ def preagg_encode_i32(pairs: np.ndarray, cnts: np.ndarray,
     return buf
 
 
+def reads_live_columns(width: int) -> bool:
+    """Whether a fire of ``width`` window ends makes its counts by the
+    masked reduction over the ring axis (``fire_kernel``): the one-end
+    fire does, a wider one takes ``prefix_sum_counts``. Static, so the
+    operator knows at dispatch which form its program took
+    (``_count_fire``). The rule is the chip's (``tools/fire_micro.py``,
+    device ms a call, masked / prefix; my chip runs, PR 44): at
+    16,777,217 rows x 12 columns 5.9 / 17.4 at one end, and 89.6 /
+    76.9, 104.7 / 86.7, 118.2 / 108.0 at 2, 4 and 8; at 32,769 rows x 8
+    columns 0.050 / 0.057 at one end and 0.670 / 0.562 at 64."""
+    return width == 1
+
+
+def prefix_sum_counts(counts, end_panes, w_valid, pane_lo, *,
+                      panes_per_window, ring):
+    """The counts of a fire of SEVERAL window ends: roll the ring so
+    column j holds pane (pane_lo + j), cumsum along the ring, and each
+    window's count is one prefix difference. Integer prefix sums are
+    exact, and every column outside the live [pane_lo, pane_hi] span is
+    provably ZERO (purged panes are cleared, unwritten panes never
+    incremented — the same ring-aliasing invariant the mask form relies
+    on), so out-of-range prefixes contribute nothing. The roll and the
+    prefix sum are each a read and a write of the whole state (4.7 and
+    5.5 ms at 16,777,217 rows x 12 columns), whatever W is; the masked
+    reduction's rows x W x ring selects grow with W (0.10 ms against
+    ~0.03 at 32,769 rows x 64 ends) and its (rows, W) result comes out
+    column-major, so that the row-major grid behind it costs two more
+    passes of 10-13 ms at 16.8 M rows (``reads_live_columns`` has the
+    calls)."""
+    ppw = panes_per_window
+    roll_amt = (pane_lo % ring).astype(jnp.int32)
+    rolled = jnp.roll(counts, -roll_amt, axis=1)
+    cs = jnp.cumsum(rolled, axis=1)                                        # (rows, ring)
+    e_hi = jnp.clip(end_panes - 1 - pane_lo, -1, ring - 1).astype(jnp.int32)
+    e_lo = jnp.clip(end_panes - ppw - 1 - pane_lo, -1,
+                    ring - 1).astype(jnp.int32)
+    hiv = jnp.where(e_hi[None, :] >= 0,
+                    jnp.take(cs, jnp.clip(e_hi, 0, ring - 1), axis=1), 0)
+    lov = jnp.where(e_lo[None, :] >= 0,
+                    jnp.take(cs, jnp.clip(e_lo, 0, ring - 1), axis=1), 0)
+    return jnp.where(w_valid[None, :], hiv - lov, 0)
+
+
 def fire_kernel(
     state: PaneState,
     end_panes: jax.Array,  # (W,) int64 global pane ids (window end, exclusive)
@@ -476,8 +519,9 @@ def fire_kernel(
     # (W, ring) column-selection mask instead of a per-(window, pane)
     # GATHER: arr[:, ring_ix] gathers rows × W × ppw elements, and a
     # large gather is a slow op on TPU, while the mask form is a
-    # broadcast + reduce the fuser can stream (neither cost measured on
-    # the current chip). Within [pane_lo, pane_hi] at
+    # broadcast + reduce the fuser can stream (the max / min lanes'
+    # cost on the chip: not measured, no cell runs them; the COUNT
+    # lane's is below). Within [pane_lo, pane_hi] at
     # most one live pane occupies a column (the ingest ring guard), so
     # a window's reduction over its live COLUMNS equals the reduction
     # over its live panes.
@@ -496,7 +540,7 @@ def fire_kernel(
     # SUM lanes ride matmuls over the column mask — the MXU does the
     # window reduction without materializing the (rows, W, ring)
     # broadcast the mask-reduce form needs (33 MB per fire at Q5 shape).
-    # Counts take the ring-axis prefix-sum path below instead.
+    # Counts are integers: below.
     sel_t = colmask.astype(jnp.float32).T                                  # (ring, W)
     if state.sums is None:
         sums = jnp.zeros((rows_n, W, 0), jnp.float32)
@@ -509,27 +553,26 @@ def fire_kernel(
                           precision=lax.Precision.HIGHEST)
     maxs = lane_red(state.maxs, jnp.max, -jnp.inf)
     mins = lane_red(state.mins, jnp.min, jnp.inf)
-    # COUNTS ride ring-axis PREFIX SUMS: roll the ring so column j
-    # holds pane (pane_lo + j), cumsum along the ring, and each
-    # window's count is one prefix difference. Integer prefix sums are
-    # exact, and every column outside the live [pane_lo, pane_hi] span
-    # is provably ZERO (purged panes are cleared, unwritten panes never
-    # incremented — the same ring-aliasing invariant the mask form
-    # relied on), so out-of-range prefixes contribute nothing. Chosen
-    # over a dot or mask-reduce over the column mask, which composed
-    # badly with the ingest segment_sum in one program (cost not
-    # measured on the current chip).
-    roll_amt = (pane_lo % ring).astype(jnp.int32)
-    rolled = jnp.roll(state.counts, -roll_amt, axis=1)
-    cs = jnp.cumsum(rolled, axis=1)                                        # (rows, ring)
-    e_hi = jnp.clip(end_panes - 1 - pane_lo, -1, ring - 1).astype(jnp.int32)
-    e_lo = jnp.clip(end_panes - ppw - 1 - pane_lo, -1,
-                    ring - 1).astype(jnp.int32)
-    hiv = jnp.where(e_hi[None, :] >= 0,
-                    jnp.take(cs, jnp.clip(e_hi, 0, ring - 1), axis=1), 0)
-    lov = jnp.where(e_lo[None, :] >= 0,
-                    jnp.take(cs, jnp.clip(e_lo, 0, ring - 1), axis=1), 0)
-    counts = jnp.where(w_valid[None, :], hiv - lov, 0)
+    # COUNTS, integer adds in both forms, the same counts element for
+    # element (``tests/test_fire_reduction.py``). ONE WINDOW END (every
+    # fire of the large-keys cells but a catch-up's and the flush's; the
+    # fused step's one-end fires): one masked reduction over the ring
+    # axis under the same column mask, full tiles in, a (rows,) result
+    # in whole tiles out, a single read of the state: 1.42 ms over
+    # 1.07 GB as laid out (756 GB/s) at 16,777,217 rows x 12 columns,
+    # where the roll and the prefix sum took 4.7 + 5.5. (Lifting the
+    # window's five columns out one by one reads less and took 3.68: a
+    # lifted column fills one sublane in eight of the (8 columns x 128
+    # rows) tiles; my chip runs, PR 44.) SEVERAL ENDS: prefix sums.
+    if reads_live_columns(W):
+        counts = jnp.sum(
+            jnp.where((colmask & w_valid[:, None])[None, :, :],
+                      state.counts[:, None, :], 0),
+            axis=2, dtype=state.counts.dtype)
+    else:
+        counts = prefix_sum_counts(
+            state.counts, end_panes, w_valid, pane_lo,
+            panes_per_window=ppw, ring=ring)
     return sums, maxs, mins, counts
 
 
@@ -650,6 +693,17 @@ def fire_pack_kernel(
     return jnp.concatenate([head, body])                 # (out_cap+1, C)
 
 
+def top_values(v: jax.Array, k: int) -> jax.Array:
+    """(W, k): each window's ``k`` largest ranking values of the
+    (candidates, W) grid ``v``, largest first. The top 1 (Q5's
+    ``.top(1, by="count")``) is a max, one streaming reduction where
+    ``lax.top_k`` over 16.8 M candidates is a program of its own; the
+    same value, -inf where a window has no candidate."""
+    if k == 1:
+        return jnp.max(v, axis=0)[:, None]
+    return lax.top_k(v.T, k)[0]
+
+
 def _topn_select_append(
     emit_ring: jax.Array,
     sums, maxs, mins, counts,
@@ -678,7 +732,12 @@ def _topn_select_append(
     wi = (idx % W).astype(jnp.int32)
     total_sel = jnp.sum(flat).astype(jnp.int32)
     n = jnp.minimum(total_sel, sel_cap)
-    sel_counts = jnp.where(idx < K, counts[row, wi], 0)
+    # the winners' counts by their position in the flat grid, as ``flat``
+    # is read: at one window end a (rows, 1) array indexed [row, 0] is
+    # laid out anew on the chip, 128 words a row (13.3 ms and 8.6 GB at
+    # 16.8 M rows; ``tools/fire_micro.py``, my chip run, PR 44)
+    sel_counts = jnp.where(
+        idx < K, counts.reshape(-1)[jnp.minimum(idx, K - 1)], 0)
     res_sel = agg.finalize(sums[row, wi], maxs[row, wi], mins[row, wi], sel_counts)
     end_delta = (end_panes[wi] - anchor).astype(jnp.int32)
     cols = [row + row_offset, end_delta, sel_counts.astype(jnp.int32)]
@@ -754,11 +813,10 @@ def _ring_append_topn_core(
     res = agg.finalize(sums, maxs, mins, counts)
     v = jnp.where(nz, res[by].astype(jnp.float32), -jnp.inf)
     k = min(topn, rows)
-    topv = lax.top_k(v.T, k)[0]
-    # thresh = -inf when a window has fewer than n candidates (top_k
-    # pads with -inf); nz already excludes non-candidates, so
+    # thresh = -inf when a window has fewer than n candidates (the
+    # non-candidates' -inf fills the top k); nz already excludes them, so
     # v >= -inf correctly selects ALL of that window's real rows
-    thresh = topv[:, k - 1]
+    thresh = top_values(v, k)[:, k - 1]
     return _topn_select_append(
         emit_ring, sums, maxs, mins, counts, nz, v, thresh,
         end_panes, anchor, agg=agg, sel_cap=sel_cap,
@@ -1411,12 +1469,12 @@ def _sharded_kernels(mp: MeshPlan, agg, layout: PaneStateLayout,
                 res = agg.finalize(sums, maxs, mins, counts)
                 v = jnp.where(nz, res[by].astype(jnp.float32), -jnp.inf)
                 k = min(topn, rows)
-                local_top = lax.top_k(v.T, k)[0]               # (W, k)
+                local_top = top_values(v, k)                   # (W, k)
                 all_top = lax.all_gather(
                     local_top, AXIS, axis=1, tiled=True)       # (W, n_dev*k)
                 # -inf thresh (< n global candidates) selects all real
                 # rows — nz masks out non-candidates
-                thresh = lax.top_k(all_top, k)[0][:, k - 1]
+                thresh = top_values(all_top.T, k)[:, k - 1]
                 my = lax.axis_index(AXIS).astype(jnp.int32)
                 return _topn_select_append(
                     emit_ring, sums, maxs, mins, counts, nz, v,
@@ -1752,6 +1810,15 @@ class WindowOperator(ReuseRule):
         the other half of the sub-batching tax next to the zero-end
         cond skip."""
         return min(MIN_FIRE_PAD, _next_pow2(max(n_ends, 1)))
+
+    def _count_fire(self, width: int) -> None:
+        """One top-n fire dispatched at a static width of ``width`` window
+        ends: ``fires``, and ``fires_direct`` where its program reads the
+        window's live columns (``reads_live_columns``, known when the
+        program is built)."""
+        self.prof["fires"] += 1
+        if reads_live_columns(width):
+            self.prof["fires_direct"] += 1
 
     def _topn_cap(self, w: int) -> int:
         """Winner-buffer capacity: n rows per window plus generous tie
@@ -2815,10 +2882,12 @@ class WindowOperator(ReuseRule):
         self.phases.phase("window.h2d")
         dbuf = jnp.asarray(buf)
         self.phases.phase("window.fire_dispatch")
+        fire_pad = self._fire_pad_bucket(len(ends_f))
         self.state, self.emit_ring.live, token = self._fused_step(
             self.state, self._ensure_ring(), dbuf, used,
-            sel_cap=self._topn_cap(MIN_FIRE_PAD),
-            fire_pad=self._fire_pad_bucket(len(ends_f)))
+            sel_cap=self._topn_cap(MIN_FIRE_PAD), fire_pad=fire_pad)
+        if ends_f:   # without an end the step's cond skips the fire
+            self._count_fire(fire_pad)
         # the kernel's ring-head row is the step's token
         # (_note_dispatch announces it): the throttle's wait is a
         # consume of that in-flight copy, and its head words stand in
@@ -2916,6 +2985,7 @@ class WindowOperator(ReuseRule):
                 self.emit_ring.live = self._ring_topn(
                     self.state, self._ensure_ring(), params, used,
                     sel_cap=self._topn_cap(Wp), **width)
+                self._count_fire(width.get("fire_pad", len(ends_padded)))
             else:
                 buf = self._fire_pack(
                     self.state, params, used, out_cap=self._fire_cap(Wp))

@@ -2032,14 +2032,16 @@ class Driver:
                 final[f"profile.op{nid}.{k}"] = final.get(
                     f"profile.op{nid}.{k}", 0.0) + v
                 if k in ("scan_pane_moves", "scan_ranges",
-                         "assign_records", "assign_memo_hits"):
+                         "assign_records", "assign_memo_hits",
+                         "fires", "fires_direct"):
                     # once more beside the leaf they explain: whatever
                     # reads profile.phase.* (bench artifacts, the
                     # benchmark's detail line) then shows whether
                     # window.key_scan ran on the pane cursor's cheap
-                    # path, over how many record ranges at once, and how
+                    # path, over how many record ranges at once, how
                     # many of the general lane's records the directory's
-                    # memo served
+                    # memo served, and how many of the top-n fires read
+                    # their window's live columns
                     final[f"profile.phase.{k}"] = final.get(
                         f"profile.phase.{k}", 0.0) + v
         return JobResult(job_name, final)

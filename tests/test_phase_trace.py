@@ -131,7 +131,7 @@ class TestJobPhases:
         assert op_keys <= {"drain_fetch", "drain_fetches", "drain_skips",
                            "drain_landed", "drain_waited", "preagg_batches", "scan_pane_moves",
                            "scan_ranges", "assign_records",
-                           "assign_memo_hits"}
+                           "assign_memo_hits", "fires", "fires_direct"}
         fetch = sum(v for k, v in res.metrics.items()
                     if k.startswith("profile.op")
                     and k.endswith(".drain_fetch"))

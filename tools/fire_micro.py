@@ -1,18 +1,23 @@
-"""Micro-check of the fire's compaction: device ms a call, on the chip.
+"""Micro-check of the top-n fire: device ms a call, on the chip.
 
 Times the top-n fire program (``ops/window.py`` ``ring_append_topn_kernel``:
-``jit_ring_append_topn_kernel`` in a trace) with its compaction swapped
-(``first_true_indices``: which candidates of the rows x W grid were
-selected, in row-major order, padded to ``sel_cap``), at three grids:
+``jit_ring_append_topn_kernel`` in a trace) with one of its parts swapped,
+at these grids:
 
 - ``large``: the large-keys cells' fire, 16,777,217 rows x 12 ring
   columns, one window end (``fire_pad`` 1), about half the rows counting;
+  ``large_w2`` / ``large_w4`` / ``large_w8``: the same state at the
+  widths a catch-up or the end-of-input flush takes there, every end real;
 - ``small_w1`` / ``small_w64``: the small-state cells' 32,769 rows x 8
-  columns at one end and at the full 64;
+  columns at one end and at the full 64 (one real, 63 padding);
 
 and ``fire_pack_kernel`` (no top-n; run by no cell) at 32,769 rows x 4
-ends with ``out_cap`` 131,072. Variants (the operator chooses between
-``scan`` and ``sort`` by the static shapes, see ``first_true_indices``):
+ends with ``out_cap`` 131,072. Two axes of variants, each run with the
+tree's choice on the other axis.
+
+THE COMPACTION (``first_true_indices``: which candidates of the rows x W
+grid were selected, in row-major order, padded to ``sel_cap``; the
+operator chooses between ``scan`` and ``sort`` by the static shapes):
 
 - ``argsort``: the expression the fire had before PR 40, a stable
   argsort of the negated mask (it lives on in
@@ -25,14 +30,30 @@ ends with ``out_cap`` 131,072. Variants (the operator chooses between
   blocks' prefix sums, then a prefix sum inside the ``cap`` blocks that
   hold a winner (not for ``fire_pack``: ``cap`` x 1,024 cells).
 
+WHAT RUNS BEFORE IT (``fire_kernel``'s count lane + ``top_values``;
+named ``<counts>+<threshold>``; the operator takes ``masked`` at one
+window end and ``prefix`` at any wider fire (``reads_live_columns``,
+which a variant here overrides), ``max`` for a top 1; not for
+``fire_pack``, which ranks nothing):
+
+- counts ``prefix``: ``prefix_sum_counts``, the expression every fire
+  had before PR 44: a roll of the whole ring, its prefix sum and two
+  columns of that; ``masked``: one masked reduction over the ring axis,
+  each window's live columns alone passing the mask;
+- threshold ``top_k``: ``lax.top_k`` over every candidate, as before
+  PR 44; ``max``: a reduction.
+
 Per grid and variant: ms a call on the host clock (median of ``--reps``
 calls that end in ``block_until_ready``), device ms a call of the
 program and its ``--top`` ops from a ``jax.profiler`` trace of those
-calls, and whether the emit ring it returns equals the ``argsort``
-variant's, element for element. One JSON line; traces go under
-``chiprun_out/fire_micro/``.
+calls, peak device memory so far, and whether the emit ring it returns
+equals the grid's first variant's (``argsort``, with ``prefix+top_k``
+the whole of the old fire), element for element. One JSON line, also
+written to ``--out``; traces go under ``chiprun_out/fire_micro/``.
 
-    chiprun -- python tools/fire_micro.py [--reps 6] [--grids large,fire_pack]
+    chiprun -- python tools/fire_micro.py [--reps 6] [--grids large]
+        [--compactions scan] [--before prefix+max,masked+max]
+        [--out chiprun_out/pr44/micro.json]
     python tools/fire_micro.py --cpu      # the sandbox: answers only, small
 """
 from __future__ import annotations
@@ -57,6 +78,7 @@ from flink_tpu.ops import window as W  # noqa: E402
 from flink_tpu.ops.aggregates import count  # noqa: E402
 from flink_tpu.state.keyed import PaneState  # noqa: E402
 from test_fire_compaction import argsort_compaction  # noqa: E402
+from test_fire_reduction import top_k_values  # noqa: E402
 
 PPW = 5             # Q5: a 10 s window of 2 s panes
 BLOCK = 1024
@@ -78,9 +100,14 @@ def blocks_compaction(flat, cap):
     return jnp.where(b < nb, bb * BLOCK + off, k)
 
 
-VARIANTS = [("argsort", argsort_compaction),
-            ("scan", W.searched_true_indices),
-            ("sort", W.sorted_true_indices), ("blocks", blocks_compaction)]
+COMPACTIONS = {"argsort": argsort_compaction,
+               "scan": W.searched_true_indices,
+               "sort": W.sorted_true_indices, "blocks": blocks_compaction}
+COUNTS = {"prefix": lambda width: False, "masked": lambda width: True}
+THRESHOLDS = {"top_k": top_k_values, "max": W.top_values}
+BEFORE = ["prefix+top_k", "prefix+max", "masked+top_k", "masked+max"]
+# what the fire traces: swapped for a variant, put back at the end
+SWAPPED = ("first_true_indices", "reads_live_columns", "top_values")
 
 
 def make_counts(rows: int, ring: int, seed: int):
@@ -124,17 +151,32 @@ def time_program(step, args, reps: int, top: int, trace_dir: str, name: str):
     return np.asarray(out), res
 
 
-def run_grid(grid: str, rows: int, ring: int, n_ends: int, pack: bool,
-             args, out_dir: str):
+def variants_of(pack: bool, args):
+    """(name, {module attribute: stand-in}) per run of a grid: the
+    compactions under the tree's counts and threshold, then what runs
+    before the compaction under the tree's compaction."""
+    wanted = lambda given, names: [
+        n for n in names if not given or n in given.split(",")]
+    runs = [(name, {"first_true_indices": COMPACTIONS[name]})
+            for name in wanted(args.compactions, COMPACTIONS)
+            if not (pack and name == "blocks")]
+    for name in ([] if pack else wanted(args.before, BEFORE)):
+        counts, thresh = name.split("+")
+        runs.append((name, {"reads_live_columns": COUNTS[counts],
+                            "top_values": THRESHOLDS[thresh]}))
+    return runs
+
+
+def run_grid(grid: str, rows: int, ring: int, n_ends: int, real_ends: int,
+             pack: bool, args, out_dir: str, tree: dict):
     counts = make_counts(rows, ring, args.seed)
     state = PaneState(None, None, None, counts)
     used = jnp.ones(rows, bool).at[rows - 1].set(False)
-    params = params_for(ring, n_ends if pack else 1)
+    params = params_for(ring, real_ends)
     res, want = {}, None
-    for name, compaction in VARIANTS:
-        if pack and name == "blocks":
-            continue
-        W.first_true_indices = compaction     # read when the jit traces
+    for name, swap in variants_of(pack, args):
+        for attr in SWAPPED:                  # read when the jit traces
+            setattr(W, attr, swap.get(attr, tree[attr]))
         if pack:
             def fire(s, p, u):
                 return W.fire_pack_kernel(
@@ -158,7 +200,9 @@ def run_grid(grid: str, rows: int, ring: int, n_ends: int, pack: bool,
         if want is None:
             want = got
             r["rows_fired"] = int(got[0, 0])
-        r["equal_to_argsort"] = bool(np.array_equal(got, want))
+        r["equal_to_first"] = bool(np.array_equal(got, want))
+        stats = jax.devices()[0].memory_stats() or {}
+        r["peak_bytes_so_far"] = stats.get("peak_bytes_in_use")
         res[name] = r
         print(f"# {grid}/{name}: {json.dumps(r)}", file=sys.stderr, flush=True)
     return res
@@ -173,6 +217,12 @@ def main() -> int:
                     help="small grids: the answers only")
     ap.add_argument("--grids", default="",
                     help="comma list of grids (default: all)")
+    ap.add_argument("--compactions", default="",
+                    help=f"comma list of {list(COMPACTIONS)} (default: all)")
+    ap.add_argument("--before", default="",
+                    help=f"comma list of {BEFORE} (default: all)")
+    ap.add_argument("--out", default="",
+                    help="a file the JSON line is written to as well")
     args = ap.parse_args()
     big, small = ((8 * 64 + 1, 32 * 8 + 1) if args.cpu
                   else (128 * 131072 + 1, 128 * 256 + 1))
@@ -180,21 +230,31 @@ def main() -> int:
     dev = jax.devices()[0]
     out = {"reps": args.reps, "seed": args.seed,
            "device": {"platform": dev.platform, "kind": dev.device_kind}}
-    chosen = W.first_true_indices
+    tree = {attr: getattr(W, attr) for attr in SWAPPED}
     try:
-        for grid, rows, ring, n_ends, pack in [
-                ("large", big, 12, 1, False),
-                ("small_w1", small, 8, 1, False),
-                ("small_w64", small, 8, 64, False),
-                ("fire_pack", small, 8, 4, True)]:
+        for grid, rows, ring, n_ends, real_ends, pack in [
+                ("large", big, 12, 1, 1, False),
+                ("large_w2", big, 12, 2, 2, False),
+                ("large_w4", big, 12, 4, 4, False),
+                ("large_w8", big, 12, 8, 6, False),
+                ("small_w1", small, 8, 1, 1, False),
+                ("small_w64", small, 8, 64, 1, False),
+                ("fire_pack", small, 8, 4, 4, True)]:
             if args.grids and grid not in args.grids.split(","):
                 continue
             out[grid] = {"rows": rows, "ring": ring, "ends": n_ends,
-                         **run_grid(grid, rows, ring, n_ends, pack, args,
-                                    out_dir)}
+                         "real_ends": real_ends,
+                         **run_grid(grid, rows, ring, n_ends, real_ends, pack,
+                                    args, out_dir, tree)}
     finally:
-        W.first_true_indices = chosen
-    print(json.dumps(out))
+        for attr, fn in tree.items():
+            setattr(W, attr, fn)
+    line = json.dumps(out)
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "w") as f:
+            f.write(line + "\n")
+    print(line)
     return 0
 
 
