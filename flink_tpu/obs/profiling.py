@@ -46,11 +46,20 @@ def _device_plane(name: str) -> bool:
     return "tpu" in n or "gpu" in n or "device" in n or "/xla" in n
 
 
-def summarize_trace_dir(trace_dir: str, top: int = 40) -> Dict[str, Any]:
+# the spans under which the ingest loop sends a batch's device step out
+# (its phase clock names them): a summary keeps them whatever their rank
+STEP_SPANS = ("window.step_dispatch", "window.fire_dispatch")
+
+
+def summarize_trace_dir(trace_dir: str, top: int = 40,
+                        keep=STEP_SPANS) -> Dict[str, Any]:
     """Reduce the newest ``*.trace.json.gz`` under ``trace_dir`` to a
     per-op summary: for every trace plane, op name → {total_ms, count},
-    device planes first, each plane's ops sorted by total time. Returns
-    ``{"error": ...}`` instead of raising when nothing is parseable."""
+    device planes first, each plane's ops sorted by total time: the
+    ``top`` longest, and those named in ``keep`` wherever they rank (a
+    compile that lands inside the traced span puts some twenty names of
+    its own above a 0.2 ms dispatch). Returns ``{"error": ...}`` instead
+    of raising when nothing is parseable."""
     pattern = os.path.join(trace_dir, "**", "*.trace.json.gz")
     files = sorted(glob.glob(pattern, recursive=True),
                    key=lambda p: os.path.getmtime(p))
@@ -86,7 +95,8 @@ def summarize_trace_dir(trace_dir: str, top: int = 40) -> Dict[str, Any]:
         rows = sorted(
             ({"op": op, "total_ms": round(us / 1000.0, 3), "count": n}
              for op, (us, n) in ops.items()),
-            key=lambda r: -r["total_ms"])[:top]
+            key=lambda r: -r["total_ms"])
+        rows = rows[:top] + [r for r in rows[top:] if r["op"] in keep]
         planes.append({
             "plane": plane,
             "device": _device_plane(plane),

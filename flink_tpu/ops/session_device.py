@@ -654,6 +654,31 @@ class DeviceSessionOperator(ReuseRule):
                     "from above: a fault of this operator, not of the job")
 
     # -- time --------------------------------------------------------------
+    def may_lead_advance(self) -> bool:
+        """Asked once, when the job is built: can an advance of this
+        operator ever go ahead of its batch (``lead_advance``)? Yes:
+        what stands in the way (the hand-over to a registry) is known
+        only batch by batch."""
+        return True
+
+    def lead_advance(self, wm: int, ts: np.ndarray) -> bool:
+        """May the advance to ``wm`` go AHEAD of the batch with the
+        timestamps ``ts`` (the driver's question, put before it pushes
+        the batch that implied ``wm``)? Yes while the sessions are on
+        the device, something was folded in, the watermark moves (any
+        advance may close sessions: the device alone knows) and every
+        record is stamped above ``wm + 1``. A session the advance closes
+        has ``last + gap - 1 <= wm``, and a record joins a session up to
+        ``ts = last + gap`` (``t2 - t1 <= gap``): the one stamped
+        ``wm + 1`` may be exactly that far from a session the advance
+        would close, and in the old order extends it. Past ``wm + 1``
+        a record opens a session of its own in either order and is late
+        under neither watermark: the same rows fire."""
+        if (self._registry is not None or self._base is None
+                or wm <= self.watermark):
+            return False
+        return int(ts.min()) > wm + 1
+
     def advance_watermark(self, wm: int) -> FiredWindows:
         """Advance event time and fire every session it completes, in
         as many passes as their number needs; returns once the last

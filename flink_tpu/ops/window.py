@@ -1065,6 +1065,21 @@ def snapshot_clone_kernel(state: PaneState) -> PaneState:
 
 _JIT_SNAPSHOT_CLONE = jax.jit(snapshot_clone_kernel)
 
+
+def used_mask_kernel(next_free: jax.Array, *, slots_per_shard: int
+                     ) -> jax.Array:
+    """(rows,) bool: the rows of slots that have ever held a key, those
+    below their shard's free pointer (``KeyDirectory.ever_used_mask``,
+    made where it is read: the pointers go up, 4 bytes a shard, and not
+    a byte a slot), and False for the dump row behind them."""
+    used = (jnp.arange(slots_per_shard, dtype=jnp.int32)[None, :]
+            < next_free[:, None])
+    return jnp.concatenate([used.reshape(-1), jnp.zeros(1, bool)])
+
+
+_JIT_USED_MASK = jax.jit(used_mask_kernel,
+                         static_argnames=("slots_per_shard",))
+
 # catch-up fires are evaluated in chunks of this many windows so they
 # reuse the steady-state compiled kernels (pow2 pads: 1,2,4) and keep
 # each packed buffer bounded — device→host bandwidth is the emit ceiling
@@ -1080,6 +1095,10 @@ MAX_FIRE_CHUNK_RING = 16
 # with the slots, and at 16.8 M of them the 64 columns are 4 GB an
 # intermediate and do not compile for a 16 GB chip
 FIRE_GRID_CELLS = 1 << 21
+# the most (slot, ring column) cells a block may have for a count-only
+# batch to go up as u32 pairs, the one upload the fused step can take
+# (_process_batch_fused): a larger block never stashes a batch
+FUSED_DOMAIN_MAX = 1 << 20
 
 
 def _next_pow2(n: int) -> int:
@@ -2118,6 +2137,17 @@ class WindowOperator(ReuseRule):
             self.phases.phase("ingest.throttle")
             self.throttle()
 
+    def _may_stash(self) -> bool:
+        """Whether ANY batch of this operator can be stashed for the
+        fused step, whose launch then carries the batch, the fires and
+        the purge together (``_advance_fused``): the gates of
+        ``process_batch`` and ``_process_batch_fused`` that no batch can
+        move, read off the static shapes. The one place that knows them:
+        the stash below asks it, and so does ``may_lead_advance``."""
+        return (self._fused_step is not None and self._spill is None
+                and self.mesh_plan is None and self._preagg_lanes == ()
+                and self.layout.slots * self.plan.ring <= FUSED_DOMAIN_MAX)
+
     def _process_batch_fused(self, keys: np.ndarray, ts: np.ndarray) -> bool:
         """Count-only ingest via codec.cc ingest_fused_scan: ONE C pass
         does the key→slot directory probe AND the pane/late/refire/
@@ -2234,13 +2264,13 @@ class WindowOperator(ReuseRule):
             return True
         domain = self.layout.slots * self.plan.ring
         cap = _next_pow2(max(res.npairs, 256))
-        if cmax < 0xFFF and domain <= (1 << 20):
+        if cmax < 0xFFF and domain <= FUSED_DOMAIN_MAX:
             # u32 pack emitted straight from C, with fused-step header
             # space reserved up front: the pending advance fills it and
             # dispatches apply+fire+clear as ONE program with ONE upload
             buf = ingest_fused_finalize_u32_native(
                 res, self._preagg_ws, FUSED_HDR, cap)
-            if self._fused_step is not None and self._stash_u32 is None:
+            if self._may_stash() and self._stash_u32 is None:
                 self._stash_u32 = buf
                 return True
             buf, step = buf[FUSED_HDR:], self._preagg_u32
@@ -2643,6 +2673,55 @@ class WindowOperator(ReuseRule):
         with self.phases.span("window.fire_dispatch"):
             return self._advance_watermark(wm)
 
+    def may_lead_advance(self) -> bool:
+        """Asked once, when the job is built: can an advance of this
+        operator ever go ahead of its batch (``lead_advance``)? Only
+        where the batch and the advance are launches of their own: one
+        device, event time, no spill store, and no batch ever rides the
+        fused step (``_may_stash``: there the stash, the fires and the
+        purge are ONE launch; led, the fire takes a launch and a clear
+        of its own and the drain's delivery queues behind the batch's
+        push. Tried on the chip, PR 45: the fused lane leading read
+        17.75 / 18.52 / 17.92 ms p50 beside 18.00 / 18.54 / 18.45 in
+        ``q5_hostfed_paced`` (``input_to_fire`` 5.19 -> 1.61 ms,
+        ``push_wait`` 0.09 -> 2.71) and 174.35 / 178.14 M events/s
+        beside 174.15 / 176.10 in ``q5_hostfed_replay``: inside the
+        cells' noise until the push lock is narrower, ``PERF.md``
+        section 6). The shapes that decide it move
+        one way only (a ring that grows takes the fused lane away, never
+        brings it), so a job that answers no keeps today's order
+        throughout."""
+        return not (self.mesh_plan is not None or self._spill is not None
+                    or self.uses_processing_time or self._may_stash())
+
+    def lead_advance(self, wm: int, ts: np.ndarray) -> bool:
+        """May the advance to ``wm`` go AHEAD of the batch with the
+        timestamps ``ts`` (the driver's question, put before it pushes
+        the batch that implied ``wm``)? Yes where the two commute and the
+        advance has something to do:
+
+        - ``may_lead_advance``: the same answer for every batch of a job;
+        - something was folded in already, and ``wm`` passes a window
+          end, a purge horizon or a pending re-fire: an advance that
+          does nothing is not worth a pass over ``ts``;
+        - every record of the batch is stamped above ``wm``: it lies in
+          windows that end after ``wm`` (a window's newest timestamp is
+          at least ``ts``) and in panes the purge leaves alone, and is
+          late under neither watermark, so the same rows fire, the same
+          panes are cleared and the same state results in either order
+          (a key whose newest pane the purge takes and which this batch
+          names again is released and inserted anew where it would have
+          survived: its rows are equal)."""
+        if (not self.may_lead_advance() or self._min_pane_seen is None
+                or wm <= self.watermark):
+            return False
+        if not (self._refire
+                or self._fired_below_end is None
+                or self.plan.fire_frontier(wm) > self._fired_below_end
+                or self.plan.first_dead_pane(wm) > self._cleared_below):
+            return False
+        return int(ts.min()) > wm
+
     def _advance_watermark(self, wm: int) -> "FiredWindows":
         self.run_pending_release()    # one purge, one release
         self.state_version += 1
@@ -2735,6 +2814,7 @@ class WindowOperator(ReuseRule):
         the drain decodes and delivers meanwhile). Whatever needs the
         directory up to date runs it first: the next batch, the next
         advance, a snapshot, ``quiesce``."""
+        fired.purged = True
         if self._releases:
             self._release_pending = True
             self._release_cohort = getattr(fired, "cohort", None)
@@ -3253,18 +3333,22 @@ class WindowOperator(ReuseRule):
         packed fire avoids)."""
         nk = self.directory.slots_ever_used()
         if getattr(self, "_used_pushed", -1) != nk:
-            n_rows = self.layout.rows * (
-                self.mesh_plan.n_devices if self.mesh_plan else 1)
-            used = np.zeros(n_rows, dtype=bool)
             # every slot that EVER held a key: a released slot's rows
             # are identities (_release_dead_keys) and fire nothing, so
             # the mask need not follow the keys that come and go, only
             # the allocator's high-water marks
-            ever = self.directory.ever_used_mask()
             if self.mesh_plan is None:
-                used[:len(ever)] = ever
-                self._used_dev = jnp.asarray(used)
+                # made on the device from the shards' free pointers: at
+                # 16.8 M slots the host's mask took ~9 ms to make and
+                # upload, on the way of every fire behind an allocation
+                d = self.directory
+                self._used_dev = _JIT_USED_MASK(
+                    jnp.asarray(d.free_pointers().astype(np.int32)),
+                    slots_per_shard=d.slots_per_shard)
             else:
+                used = np.zeros(
+                    self.layout.rows * self.mesh_plan.n_devices, bool)
+                ever = self.directory.ever_used_mask()
                 used[self._row_of_slots(np.nonzero(ever)[0])] = True
                 self._used_dev = jax.device_put(
                     used, self.mesh_plan.row_sharding())
@@ -3516,6 +3600,9 @@ class FiredWindows(Mapping):
         # the fire's record (WindowOperator._fire_cohort), None for a
         # batch that fired no window end
         self.cohort = cohort
+        # the advance that made this batch moved the purge horizon
+        # (WindowOperator._defer_release)
+        self.purged = False
         self._data = data
         self._fetch = fetch
         self._op = op

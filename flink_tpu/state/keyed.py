@@ -454,12 +454,16 @@ class KeyDirectory:
         """(local_slots,) bool — which slots have ever held a key (those
         below their shard's free pointer). Grows only; what the device
         needs to tell pane rows from rows nothing ever wrote."""
-        lo, hi = self.shard_lo, self.shard_hi
         return (np.arange(self.slots_per_shard)[None, :]
-                < self._next_free[lo:hi, None]).reshape(-1)
+                < self.free_pointers()[:, None]).reshape(-1)
+
+    def free_pointers(self) -> np.ndarray:
+        """(local shards,) the slots of each shard that have ever held a
+        key: ``ever_used_mask`` in 8 bytes a shard."""
+        return self._next_free[self.shard_lo:self.shard_hi]
 
     def slots_ever_used(self) -> int:
-        return int(self._next_free[self.shard_lo:self.shard_hi].sum())
+        return int(self.free_pointers().sum())
 
     def num_keys(self) -> int:
         return self._n_keys

@@ -119,6 +119,24 @@ def assert_equal_to_reference(cmp_):
 
 # -- (a) the directory and its tables --------------------------------------
 
+def test_the_used_mask_made_on_the_device_is_the_directorys():
+    """PR 45: the fire's used-rows mask is made on the device from the
+    shards' free pointers (``used_mask_kernel``); the directory's own
+    mask, which a mesh still uploads, is the reference: equal slot for
+    slot, the dump row behind them False, and made anew only when a
+    never-used slot was taken."""
+    op = q5_operator(256)
+    for data, ts in stream(3):
+        op.process_batch(data["auction"], ts, {})
+        mask = op._used_mask_device()
+        got = np.asarray(mask)
+        assert got.shape == (op.layout.rows,) and got.dtype == bool
+        assert (got[:-1] == op.directory.ever_used_mask()).all()
+        assert got[:-1].sum() == op.directory.slots_ever_used() > 0
+        assert not got[-1]
+        assert op._used_mask_device() is mask       # nothing new: kept
+
+
 def tables():
     native = native_codec.NativeHashTable.create(16)
     return [_NumpyHashTable(16)] + ([native] if native is not None else [])

@@ -179,6 +179,48 @@ class TestFireRecords:
             queued += f["t_queued"] is not None
         assert queued >= len(fires) // 2
 
+    def test_a_led_advance_fires_ahead_of_its_batchs_key_scan(
+            self, monkeypatch):
+        """PR 45: on the general lane the watermark pass a batch implies
+        goes AHEAD of the batch's push. Its stamps keep their order
+        (``t_input <= t_fire <= t_queued``), and ``t_fire`` lies before
+        the batch's ``window.key_scan`` opens, where the old order's lies
+        behind it."""
+        scans = []
+        switch = PhaseClock._switch
+
+        def spy(clock, name, attrs):
+            out = switch(clock, name, attrs)
+            if name == "window.key_scan":
+                scans.append(out[2])
+            return out
+
+        monkeypatch.setattr(PhaseClock, "_switch", spy)
+        # 8 x 16,384 slots x 11 ring columns: no batch rides the fused step
+        conf = {"state.slots-per-shard": 16384}
+        q5_job(n_batches=8, auctions=3000, **conf)      # compiles
+        scans.clear()
+        res, rows, _driver = q5_job(n_batches=40, auctions=3000, **conf)
+        m = res.metrics
+        assert rows and 1 <= m["wm.advances_led"] < m["wm.advances"]
+        led = set()
+        for f in m["trace.fires"]:
+            assert f["t_input"] <= f["t_fire"], f
+            if f["t_queued"] is not None:
+                assert f["t_fire"] <= f["t_queued"], f
+            # the batch handed over at t_input is keyed in the first
+            # key scan that opens after it (the flush has none)
+            later = [t for t in scans if t > f["t_input"]]
+            if later and f["t_fire"] < later[0]:
+                led.add(f["t_fire"])
+        assert 1 <= len(led) <= m["wm.advances_led"]
+
+    def test_the_fused_lane_leads_no_advance(self, job):
+        m = job[0].metrics
+        assert m["wm.advances_led"] == 0 < m["wm.advances"]
+        assert m["profile.phase.wm_advances_led"] == 0
+        assert m["profile.phase.wm_advances"] == m["wm.advances"]
+
     def test_t_queued_is_left_out_where_the_fetch_began_before_it(self, job):
         driver = job[2]
         kept = list(driver._fires)
@@ -705,3 +747,29 @@ def test_nested_events_change_no_reading_of_the_benchmarks():
     for trace in (plain, nested):
         gaps = dict(trace.labelled_gaps(trace.busiest()))
         assert gaps == {"window.key_scan": pytest.approx(1150.0 / 1e9)}
+
+
+def test_a_trace_summary_keeps_the_step_spans_whatever_their_rank(tmp_path):
+    """``summarize_trace_dir`` lists a plane's longest ops; the spans the
+    loop sends a step out under stay in the list when a compile inside
+    the traced span puts fifty longer names above them."""
+    import gzip
+    import json
+
+    from flink_tpu.obs.profiling import STEP_SPANS, summarize_trace_dir
+    events = [{"ph": "M", "name": "process_name", "pid": 1,
+               "args": {"name": "/host:CPU"}}]
+    events += [{"ph": "X", "pid": 1, "name": f"llvm.pass.{i}", "dur": 900 + i}
+               for i in range(50)]
+    events += [{"ph": "X", "pid": 1, "name": n, "dur": 100}
+               for n in STEP_SPANS + ("window.pack",)]
+    d = tmp_path / "plugins"
+    d.mkdir()
+    with gzip.open(d / "x.trace.json.gz", "wt") as f:
+        json.dump({"traceEvents": events}, f)
+    ops = summarize_trace_dir(str(tmp_path), top=40)["planes"][0]["ops"]
+    names = [o["op"] for o in ops]
+    assert len(names) == 42 and set(STEP_SPANS) <= set(names)
+    assert "window.pack" not in names and names[0] == "llvm.pass.49"
+    assert len(summarize_trace_dir(str(tmp_path), top=40, keep=())[
+        "planes"][0]["ops"]) == 40
