@@ -7,7 +7,7 @@ rows collected by a sink — on one TPU chip in ONE process, at the sizes
 the repo commits, and checks every committed row against a plain numpy
 reference kept in this file:
 
-- ``host_fed``: ``confs/bench_q5_host_fed.conf`` (2^20, K=1),
+- ``host_fed``: ``confs/bench_q5_host_fed.conf`` (2^20-record batches),
   ``bid_stream`` — records cross the host-device link, as in every
   deployment;
 - ``sum_lane``: count + ``sum_of("price")`` on the same bids under the
@@ -141,14 +141,6 @@ def load_conf(name: str, overrides: dict):
     return conf
 
 
-def batch_shape(conf):
-    """(logical batch size, sub-batches) of a job conf."""
-    from flink_tpu.config import PipelineOptions
-
-    return (int(conf.get(PipelineOptions.MICROBATCH_SIZE)),
-            int(conf.get(PipelineOptions.SUB_BATCHES)))
-
-
 def collecting_sink():
     from flink_tpu.api.sinks import FnSink
 
@@ -271,13 +263,14 @@ def timed_plane(name: str, watch: CompileWatch, warm, measured) -> dict:
 
 
 def job_setup(conf_file, overrides, nexmark, n_batches):
-    """(job conf, generator config, sub-batches) of one plane."""
+    """(job conf, generator config) of one plane."""
+    from flink_tpu.config import PipelineOptions
     from flink_tpu.nexmark.generator import NexmarkConfig
 
     conf = load_conf(conf_file, overrides)
-    batch, k = batch_shape(conf)
+    batch = int(conf.get(PipelineOptions.MICROBATCH_SIZE))
     return conf, NexmarkConfig(batch_size=batch, n_batches=n_batches,
-                               **nexmark), k
+                               **nexmark)
 
 
 def q5_plane(name, conf_file, overrides, nexmark, n_batches,
@@ -285,7 +278,7 @@ def q5_plane(name, conf_file, overrides, nexmark, n_batches,
     """One Q5 plane: warm-up, measured run, counters, rows against the
     reference. ``inspect(env, pane_counts)`` adds a plane's own checks
     on the finished job (the mesh's state placement)."""
-    conf, cfg, k = job_setup(conf_file, overrides, nexmark, n_batches)
+    conf, cfg = job_setup(conf_file, overrides, nexmark, n_batches)
 
     def measured():
         res, rows, env = run_q5(conf, cfg)
@@ -298,7 +291,7 @@ def q5_plane(name, conf_file, overrides, nexmark, n_batches,
                 f"{len(expect)}; first differences "
                 f"{sorted(set(rows) ^ set(expect))[:6]}")
         return {"conf": conf_file, "events": cfg.batch_size * n_batches,
-                "batches": n_batches, "sub_batches": k,
+                "batches": n_batches,
                 "rows": len(rows), "matched": True, **counters,
                 "phase_s": phase_seconds(res.metrics),
                 **(inspect(env, pane_counts) if inspect else {}),
@@ -311,8 +304,8 @@ def sum_lane_plane(overrides, nexmark, n_batches, watch) -> dict:
     import numpy as np
 
     name = "sum_lane"
-    conf, cfg, _ = job_setup("bench_q5_host_fed.conf", overrides, nexmark,
-                             n_batches)
+    conf, cfg = job_setup("bench_q5_host_fed.conf", overrides, nexmark,
+                          n_batches)
     n_auctions = cfg.num_active_auctions
 
     def measured():
@@ -368,7 +361,7 @@ def traced_run(trace_dir, overrides, nexmark, n_batches,
     """One host-fed Q5 under the existing pipeline.profile-dir seam
     (obs/profiling.py): the summary must show the job's step dispatch,
     and on a chip a device plane with its ops."""
-    conf, cfg, _ = job_setup(
+    conf, cfg = job_setup(
         "bench_q5_host_fed.conf",
         {**overrides, "pipeline.profile-dir": trace_dir}, nexmark, n_batches)
     res, _, _ = run_q5(conf, cfg)

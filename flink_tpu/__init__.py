@@ -42,8 +42,8 @@ def configure_compile_cache() -> str:
     return path
 
 
-# every entry point (bench.py, chip_smoke.py, python -m flink_tpu, the
-# cluster runner) imports this package before it compiles anything
+# every entry point (benchmark/run.py, chip_smoke.py, python -m flink_tpu,
+# the cluster runner) imports this package before it compiles anything
 configure_compile_cache()
 
 from flink_tpu.config import Configuration

@@ -664,58 +664,6 @@ def dcn_overlap_unsafe(plan, config) -> Iterable[Finding]:
             "tolerates loss")
 
 
-@config_rule("SUBBATCH_INVALID", "error",
-             fix="pick a divisor of pipeline.microbatch-size")
-def subbatch_invalid(plan, config) -> Iterable[Finding]:
-    """pipeline.sub-batches misconfigurations the driver would reject
-    at build (or that silently defeat the feature): a count below 1, a
-    count that does not divide pipeline.microbatch-size (sub-batches
-    are EQUAL slices of the logical batch — ragged configured slices
-    would compile extra kernel buckets and skew the fire cadence), or
-    an explicit emit deferral at logical-batch scale (>= 100ms) that
-    re-serializes fire visibility to full-batch cadence — the
-    emit-defer floor sub-batching exists to get under."""
-    from flink_tpu.config import PipelineOptions
-
-    try:
-        k = int(config.get(PipelineOptions.SUB_BATCHES))
-    except (TypeError, ValueError):
-        yield _f(
-            "pipeline.sub-batches does not parse as an integer",
-            fix="set an integer >= 1 that divides "
-                "pipeline.microbatch-size (1 = no sub-batching)")
-        return
-    if k < 1:
-        yield _f(
-            f"pipeline.sub-batches={k} is below 1 — the driver rejects "
-            "the job at build",
-            fix="set pipeline.sub-batches >= 1 (1 = the exact "
-                "single-dispatch path)")
-        return
-    mb = int(config.get(PipelineOptions.MICROBATCH_SIZE))
-    if mb % k:
-        yield _f(
-            f"pipeline.sub-batches={k} does not divide "
-            f"pipeline.microbatch-size={mb} — sub-batches are equal "
-            "slices of the logical batch; the driver rejects this at "
-            "build",
-            fix=f"pick a divisor of {mb} (powers of two divide the "
-                "default sizes), or adjust pipeline.microbatch-size")
-    if k > 1:
-        defer = int(config.get(PipelineOptions.EMIT_DEFER_MS))
-        if defer >= 100:
-            yield _f(
-                f"pipeline.emit-defer={defer}ms with "
-                f"pipeline.sub-batches={k} violates the emit-defer "
-                "floor: the drain defers each fired sub-batch past the "
-                "sub-batch cadence, re-serializing emit visibility to "
-                "logical-batch latency — the exact tax sub-batching "
-                "removes",
-                fix="leave pipeline.emit-defer on auto (-1: no age "
-                    "floor, the drain waits for the rows' landing) or "
-                    "set it well below the sub-batch wall time")
-
-
 @config_rule("CHECKPOINT_IN_BATCH", "error",
              fix="drop checkpointing config or run in streaming mode")
 def checkpoint_in_batch(plan, config) -> Iterable[Finding]:

@@ -1349,7 +1349,7 @@ def repo_root() -> str:
         os.path.abspath(__file__))))
 
 
-DEFAULT_LINT_PATHS = ("flink_tpu", "tools", "bench.py", "bench_micro.py")
+DEFAULT_LINT_PATHS = ("flink_tpu", "tools", "chip_smoke.py")
 
 
 def lint_paths(paths: Optional[Sequence[str]] = None,

@@ -430,7 +430,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                "`analyze --json`).")
     lint.add_argument("paths", nargs="*", metavar="PATH",
                       help="files or directories (default: the shipped "
-                           "flink_tpu tree + tools + bench scripts)")
+                           "flink_tpu tree + tools + chip_smoke.py)")
     lint.add_argument("--json", action="store_true",
                       help="one JSON object per finding")
     lint.add_argument("--plane", default=None, metavar="NAME",

@@ -260,23 +260,6 @@ class PipelineOptions:
         "while recent emit p99 exceeds the target and growing it back "
         "toward the source batch size while p99 sits under half the "
         "target. 0 = off (source batch size rules, maximum throughput).")
-    SUB_BATCHES = ConfigOption(
-        "pipeline.sub-batches", 1,
-        "Chained sub-batch device programs per LOGICAL microbatch (the "
-        "fire/emit decoupling knob): K > 1 splits each "
-        "logical batch into K equal sub-batch steps with watermark "
-        "advances, fire dispatches, and drain deliveries interleaved at "
-        "sub-batch boundaries — a fired window's rows become "
-        "host-visible at sub-batch cadence (~batch_wall/K) instead of "
-        "full-batch cadence, while source positions and checkpoint "
-        "checks stay at the logical-batch granularity. Must divide "
-        "pipeline.microbatch-size (the plan analyzer rejects "
-        "misconfigurations at submit, SUBBATCH_INVALID). 1 = one "
-        "step per logical batch. Committed output is "
-        "byte-identical across K for exact lane monoids (counts, "
-        "min/max, integer sums — the same contract as host.parallelism"
-        "); float sums may differ in last-bit rounding because the "
-        "device folds K partial batches instead of one.")
     PROFILE_DIR = ConfigOption(
         "pipeline.profile-dir", "",
         "When set, the driver wraps pipeline.profile-steps WARM logical "

@@ -339,9 +339,8 @@ class DcnExchange:
                          meta: Dict[str, Any]) -> Tuple[List[Any],
                                                         List[Dict]]:
         """The v0 wire, unchanged: serial send-then-recv per peer,
-        8-byte length + blobformat payload. Kept as the benchmark
-        baseline (`bench_micro.py bench_dcn` codec axis) — its cost IS
-        the number the binary plane is measured against."""
+        8-byte length + blobformat payload (``codec="legacy"``: what a
+        fleet that has not all moved to the binary plane speaks)."""
         for j, s in self._out.items():
             faults.fire("dcn.send", exc=ConnectionError, peer=j)
             raw = blobformat.encode(

@@ -175,31 +175,11 @@ class Driver:
         # latency target is set, ingest re-chunks source batches; the
         # chunk halves while recent emit p99 overshoots the target and
         # regrows while it sits under half of it
-        from flink_tpu.config import PipelineOptions as _PO
-
-        self._debloat_target = float(config.get(_PO.TARGET_LATENCY))
+        self._debloat_target = float(
+            config.get(PipelineOptions.TARGET_LATENCY))
         self._debloat_chunk: Optional[int] = None
         self._debloat_min = 4096
         self._debloat_seen = 0  # histogram count at last control step
-        # sub-batch fire/emit decoupling: K > 1 runs
-        # each logical batch as K chained sub-batch device steps with
-        # watermark advances + fire dispatches interleaved at sub-batch
-        # boundaries, so fired rows become host-visible at ~batch_wall/K
-        # cadence. Source positions / checkpoint checks stay at
-        # logical-batch granularity. K=1 takes none of it (every
-        # sub-batch branch is guarded on K > 1).
-        self._sub_batches = int(config.get(_PO.SUB_BATCHES))
-        if self._sub_batches < 1:
-            raise ValueError(
-                f"pipeline.sub-batches must be >= 1, got "
-                f"{self._sub_batches}")
-        mb = int(config.get(_PO.MICROBATCH_SIZE))
-        if mb % self._sub_batches:
-            raise ValueError(
-                f"pipeline.sub-batches ({self._sub_batches}) must "
-                f"divide pipeline.microbatch-size ({mb}) — sub-batches "
-                "are equal slices of the logical batch (the plan "
-                "analyzer flags this at submit: SUBBATCH_INVALID)")
         g.gauge("debloat_chunk",
                 lambda: float(self._debloat_chunk or 0))
         # where the ingest loop and the drain thread spend their time
@@ -311,8 +291,7 @@ class Driver:
     def _build_ops(self) -> None:
         num_shards = self.config.get(StateOptions.NUM_KEY_SHARDS)
         slots = self.config.get(StateOptions.SLOTS_PER_SHARD)
-        base_inflight = int(
-            self.config.get(PipelineOptions.MAX_INFLIGHT_STEPS))
+        inflight = int(self.config.get(PipelineOptions.MAX_INFLIGHT_STEPS))
         # session resource shares (runtime/session.py): the dispatcher
         # stamps session.concurrent-jobs = K (the STATIC slot-
         # proportional denominator: jobs of this quota that fit one
@@ -327,15 +306,7 @@ class Driver:
         self._session_share = max(
             1, int(self.config.get(SessionOptions.CONCURRENT_JOBS)))
         if self._session_share > 1:
-            base_inflight = max(
-                1, base_inflight // self._session_share)
-        # sub-batching dispatches K steps per logical batch, each 1/K
-        # the records: scale the in-flight credit so pipeline depth
-        # measured in LOGICAL batches (and therefore in bytes queued on
-        # the transport) is unchanged — emit polls read only landed
-        # ring copies, so the deeper sub-step queue never parks a drain
-        # behind in-flight compute.
-        inflight = base_inflight * self._sub_batches
+            inflight = max(1, inflight // self._session_share)
         xcap = self.config.get(PipelineOptions.EXCHANGE_CAPACITY)
         if xcap < 0:
             raise ValueError(
@@ -562,10 +533,10 @@ class Driver:
         may return other timestamps) to window or session operators that
         can answer (``lead_advance``), whose lane lets an advance lead at
         all (``may_lead_advance``: the fused lane, a mesh and processing
-        time do not) and which feed no stateful operator in turn;
-        sub-batches and the DCN plane run loops of their own. A job with
-        none runs the loop in today's order to the letter."""
-        if (len(self.plan.sources) != 1 or self._sub_batches > 1
+        time do not) and which feed no stateful operator in turn; the
+        DCN plane runs a loop of its own. A job with none runs the loop
+        in today's order to the letter."""
+        if (len(self.plan.sources) != 1
                 or self.plan.runtime_mode == "batch"
                 or int(self.config.get(ClusterOptions.NUM_PROCESSES)) > 1):
             return ()
@@ -1020,15 +991,7 @@ class Driver:
         checkpoint barrier DRAINS the one in-flight step first
         (``cluster.dcn-overlap-drain``) so the cut still covers every
         routed record — disabling the drain is the analyzer-flagged
-        at-most-once trade (DCN_OVERLAP_UNSAFE).
-
-        ``pipeline.sub-batches`` = K > 1: this process's merged share is
-        pushed as K contiguous slices with fire dispatches between them
-        (``_push_dcn_merged``) — dispatch granularity shrinks K-fold
-        while the GLOBAL watermark still advances once per rendezvous
-        (the clock is fleet consensus; a sub-step advance would need a
-        sub-step rendezvous), so committed rows stay byte-identical to
-        K=1."""
+        at-most-once trade (DCN_OVERLAP_UNSAFE)."""
         from flink_tpu.exchange.partitioners import hybrid_route
 
         cfg = self.config
@@ -1217,7 +1180,11 @@ class Driver:
             md = {k: np.concatenate([p["data"][k] for p in parts])
                   for k in parts[0]["data"]}
             mts = np.concatenate([p["ts"] for p in parts])
-            self._push_dcn_merged(sid, md, mts)
+            with self._loop_push:
+                self.metrics["records_in"] += len(mts)
+                self.metrics["batches"] += 1
+                self._push_downstream(
+                    sid, (md, mts, np.ones(len(mts), bool)))
             self._throttle_ops()
             self._eps_meter.mark(len(mts))
         ph("ingest.bookkeeping")
@@ -1246,40 +1213,6 @@ class Driver:
         # at different steps and the set would not be a consistent cut
         sp_req = (not absorb) and all(bool(m.get("sp")) for m in metas)
         return all(bool(m["done"]) for m in metas), ckpt_req, sp_req
-
-    def _push_dcn_merged(self, sid: int, md, mts) -> None:
-        """Push this process's merged exchange share downstream — as
-        ONE batch at K=1 (the exact pre-sub-batch path), or as K
-        contiguous slices with a fire-dispatch pass between them at
-        ``pipeline.sub-batches`` = K > 1, so device dispatch granularity
-        and fire/drain cadence shrink K-fold cross-host too. Record
-        order is untouched (slices are contiguous) and the global
-        watermark is applied by the CALLER after the whole push, so
-        late classification — and committed rows — are byte-identical
-        across K."""
-        nrec = len(mts)
-        valid = np.ones(nrec, bool)
-        k = self._sub_batches
-        if k <= 1 or nrec <= k:
-            with self._loop_push:
-                self.metrics["records_in"] += nrec
-                self.metrics["batches"] += 1
-                self._push_downstream(sid, (md, mts, valid))
-            return
-        with self._loop_push:
-            self.metrics["records_in"] += nrec
-            self.metrics["batches"] += 1
-        sub = -(-nrec // k)  # ceil: ragged tails allowed cross-host
-        for lo in range(0, nrec, sub):
-            hi = min(lo + sub, nrec)
-            self.phases.phase("ingest.route")
-            with self._loop_push:
-                self._push_downstream(
-                    sid, ({kk: v[lo:hi] for kk, v in md.items()},
-                          mts[lo:hi], valid[lo:hi]))
-            self.phases.phase("wm.advance")
-            self._advance_time()
-            self._check_drain_error()
 
     def _enumerate_owned(self, sid: int, n_splits: int) -> List[int]:
         """Which split indices THIS runner reads (ref: FLIP-27
@@ -1874,27 +1807,17 @@ class Driver:
                         continue
                     data, ts = nxt
                     ts = np.asarray(ts, np.int64)
-                    # where an operator may lead (never under
-                    # sub-batches), the split's generator learns the
-                    # batch's newest timestamp BEFORE the push (nothing
-                    # reads it until the source's watermark is
-                    # recombined): the watermark the batch implies is
-                    # known while its records are still in hand
+                    # where an operator may lead, the split's
+                    # generator learns the batch's newest timestamp
+                    # BEFORE the push (nothing reads it until the
+                    # source's watermark is recombined): the watermark
+                    # the batch implies is known while its records are
+                    # still in hand
                     lead = bool(self._lead_ops) and len(splits_alive) == 1
                     if lead and self._note_max_ts(sid, split_ix, ts):
                         self._lead_advance(sid, splits_alive, ts)
-                    if self._sub_batches > 1:
-                        # sub-batch fire/emit decoupling:
-                        # K equal slices, each followed by a watermark
-                        # advance + fire dispatch, so fired rows reach
-                        # the drain at sub-batch cadence. Position /
-                        # eps / max-ts accounting stays below, at
-                        # logical-batch granularity.
-                        self._ingest_host_subbatched(
-                            sid, split_ix, splits_alive, data, ts)
-                    else:
-                        for data_c, ts_c in self._debloat_split(data, ts):
-                            self._push_source_chunk(sid, data_c, ts_c)
+                    for data_c, ts_c in self._debloat_split(data, ts):
+                        self._push_source_chunk(sid, data_c, ts_c)
                     if not lead:
                         self._note_max_ts(sid, split_ix, ts)
                     self._advance_position(sid, split_ix, data, ts)
@@ -2040,14 +1963,11 @@ class Driver:
         final = dict(self.metrics)
         final.update(self.registry.snapshot())
         # the per-phase breakdown (dispatch/throttle/drain/advance/fire)
-        # under the ONE shared accounting (phase_breakdown) — bench
-        # artifacts embed these next to profile_top_ops so control-
-        # plane wins are attributed, not asserted
+        # under the ONE shared accounting (phase_breakdown)
         for k, v in self.phase_breakdown().items():
             final[f"profile.phase.{k}"] = round(v, 6)
         # the leaves themselves: seconds, count and longest interval
         # each, and the run's longest — every value a number
-        # (bench.py's _phase_summary calls float() on each)
         final["profile.phase.loop_wall_s"] = round(self._loop_wall_s, 6)
         leaves = self.phases.snapshot()
         for leaf, st in leaves.items():
@@ -2098,8 +2018,8 @@ class Driver:
                          "assign_records", "assign_memo_hits",
                          "fires", "fires_direct"):
                     # once more beside the leaf they explain: whatever
-                    # reads profile.phase.* (bench artifacts, the
-                    # benchmark's detail line) then shows whether
+                    # reads profile.phase.* (the benchmark's detail
+                    # line) then shows whether
                     # window.key_scan ran on the pane cursor's cheap
                     # path, over how many record ranges at once, how
                     # many of the general lane's records the directory's
@@ -2322,9 +2242,9 @@ class Driver:
         self.phases.phase("ingest.bookkeeping")
 
     def _push_source_chunk(self, sid: int, data_c, ts_c) -> None:
-        """Push ONE ingest chunk downstream (the hot-loop body shared
-        by the plain and sub-batched paths): link-quiet handshake,
-        locked push + metrics, backpressure wait OUTSIDE the lock."""
+        """Push ONE ingest chunk downstream (the hot-loop body):
+        link-quiet handshake, locked push + metrics, backpressure wait
+        OUTSIDE the lock."""
         ph = self.phases.phase
         # yield the transport to a drain fetch in progress (see
         # _link_lock): blocks only while one is active
@@ -2400,35 +2320,6 @@ class Driver:
             self._out_wm[sid] = min(
                 self._wm_gens[sid][i].current() for i in owned)
 
-    def _ingest_host_subbatched(self, sid: int, split_ix: int,
-                                splits_alive, data, ts) -> None:
-        """Sub-batching (pipeline.sub-batches = K > 1): the
-        logical batch is pushed as K equal slices, and after EACH slice
-        the watermark clock advances and fires dispatch — a fired
-        window's rows become host-visible at sub-batch cadence instead
-        of waiting out the whole logical batch. Record order is
-        untouched (slices are contiguous), so watermark semantics and
-        committed rows match the K=1 run; only fire GROUPING is finer.
-        Position advance and throughput accounting stay with the
-        caller, at logical-batch granularity."""
-        ph = self.phases.phase
-        n = len(ts)
-        sub = max(1, -(-n // self._sub_batches))  # ceil: ragged tails
-        gens = self._wm_gens[sid]
-        for lo in range(0, n, sub):
-            hi = min(lo + sub, n)
-            data_s = {k: v[lo:hi] for k, v in data.items()}
-            ts_s = ts[lo:hi]
-            for data_c, ts_c in self._debloat_split(data_s, ts_s):
-                self._push_source_chunk(sid, data_c, ts_c)
-            if len(ts_s):
-                gens[split_ix].on_batch(int(ts_s.max()))
-            self._recombine_source_wm(sid, splits_alive)
-            ph("wm.advance")
-            self._advance_time()
-            ph("ingest.bookkeeping")
-            self._check_drain_error()
-
     def _advance_position(self, sid: int, split_ix: int, data, ts) -> None:
         """One consumed source batch: the SOURCE defines what the next
         replay position is (api/sources.py position_after — batch
@@ -2441,8 +2332,7 @@ class Driver:
     # -- data plane ------------------------------------------------------
     def phase_breakdown(self) -> Dict[str, float]:
         """Cumulative per-phase wall seconds of this run — ONE
-        accounting shared by the bench artifacts (per-trial
-        ``phase_breakdown``), the JobResult (``profile.phase.*``), and
+        accounting shared by the JobResult (``profile.phase.*``) and
         the web-UI backpressure gauge. Each phase is the sum of the
         leaves of the run's phase clock that ``PHASE_LEAVES`` gives it:
           source   — waiting for the source iterator's next()
@@ -2724,7 +2614,7 @@ class Driver:
                 t_push0 = time.perf_counter()
             # the fire cohorts whose rows this delivery makes visible at
             # the sink. Emit-ring fires: every cohort the drain's fetch
-            # made host-visible (one poll coalesces several sub-batch
+            # made host-visible (one poll coalesces several
             # fires; each keeps its OWN dispatch stamp); a pack fire: its
             # own; other operators' emissions have none
             if ring_origin:

@@ -2,8 +2,8 @@
 
 Written for the one installation there is (jax 0.9.0): ``jax.shard_map``
 and ``jax.experimental.mesh_utils.create_hybrid_device_mesh`` both exist;
-every shard_map consumer (ops/window.py, exchange parity tests,
-bench_micro) imports it from here.
+every shard_map consumer (ops/window.py, exchange parity tests)
+imports it from here.
 """
 from __future__ import annotations
 

@@ -52,11 +52,6 @@ def pytest_configure(config):
         "parallel byte-identical parity gates on the sessions, "
         "windowAll, and spill golden pipelines (tier-1)")
     config.addinivalue_line(
-        "markers", "subbatch: sub-batch fire/emit decoupling "
-        "(pipeline.sub-batches) — K-parity gates on the golden Q5/"
-        "sessions pipelines, checkpoint/restore across a sub-batch "
-        "boundary, chaos at K=4, and the CLI smoke (tier-1)")
-    config.addinivalue_line(
         "markers", "session: session-cluster runtime mode (flink_tpu/"
         "runtime/session.py) — slot quotas, FIFO admission queue, fair "
         "drain scheduling, autoscaler, per-job isolation, multi-tenant "

@@ -1,6 +1,6 @@
 """Golden Q5 entry point for the analyzer surfaces — ``python -m
 flink_tpu analyze --entry runner_job_q5:build --explain`` walks the
-same pipeline shape bench.py's headline measures (nexmark bid stream →
+same pipeline shape the benchmark's Q5 cells measure (nexmark bid stream →
 keyBy(auction) → 10s/1s sliding COUNT → device top-1 → rename → sink),
 so the --explain facts in tests/test_dataflow.py are facts about THE
 golden plan, not a toy."""

@@ -596,11 +596,20 @@ def test_a_mesh_keeps_the_old_order():
     assert window_op(driver).mesh_plan is not None
 
 
-def test_sub_batches_keep_the_old_order():
-    rows, m, driver = run_job(build_pack, in_order(),
-                              **{"pipeline.sub-batches": 4})
-    assert rows
-    old_order(m, driver)
+@pytest.mark.parametrize("job", ["pack_count_and_sum", "q11_sessions",
+                                 "q5_general_lane"])
+def test_a_conf_that_still_sets_the_removed_sub_batches_key_leads(job):
+    """``pipeline.sub-batches`` went with its loop (PR 46): the key is
+    unknown, the job runs the one loop there is and leads like any
+    other, one step a batch (it kept the old order at 4, in 4 slices)."""
+    build, conf = JOBS[job]
+    plain, m_plain, _ = run_job(build, in_order(), **conf)
+    rows, m, driver = run_job(build, in_order(),
+                              **{**conf, "pipeline.sub-batches": 4})
+    assert rows == plain != []
+    assert len(driver._lead_ops) == 1
+    assert m["wm.advances_led"] == m_plain["wm.advances_led"] > 0
+    assert m["batches"] == m_plain["batches"] == N_BATCHES
 
 
 def test_global_agg_keeps_the_old_order():

@@ -219,16 +219,6 @@ def _host_parallelism_invalid(tmp_path):
     return env.analyze()
 
 
-@seed("SUBBATCH_INVALID")
-def _subbatch_indivisible(tmp_path):
-    # 3 does not divide the configured microbatch size (256); the
-    # emit-defer-floor arm (explicit defer >= 100ms at K > 1) fires on
-    # the same rule and is covered in tests/test_subbatch.py
-    return analyze_config(Configuration({
-        "pipeline.microbatch-size": 256,
-        "pipeline.sub-batches": 3}))
-
-
 @seed("DCN_OVERLAP_UNSAFE")
 def _dcn_overlap_without_drain(tmp_path):
     # the loss-tolerant perf trade made silently: overlapped cross-host

@@ -8,15 +8,21 @@ import os
 import numpy as np
 import pytest
 
+from flink_tpu import faults
 from flink_tpu.api.environment import StreamExecutionEnvironment
 from flink_tpu.api.sinks import TransactionalCollectSink
 from flink_tpu.api.sources import GeneratorSource
 from flink_tpu.api.windowing import SlidingEventTimeWindows, TumblingEventTimeWindows
 from flink_tpu.checkpoint.storage import FsCheckpointStorage
 from flink_tpu.config import Configuration
+from flink_tpu.nexmark.generator import NexmarkConfig, bid_stream
+from flink_tpu.nexmark.queries import q5_hot_items
 from flink_tpu.ops import aggregates
 from flink_tpu.ops.window import WindowOperator
+from flink_tpu.runtime.supervisor import run_with_recovery
 from flink_tpu.time.watermarks import WatermarkStrategy
+
+from test_chaos import replayable
 
 
 def make_conf(tmp_path, extra=None):
@@ -345,3 +351,130 @@ class TestTwoPhaseCommitRecovery:
             assert kk not in got, f"duplicate emission for {kk}"
             got[kk] = int(r["count"])
         assert got == golden_counts(n_batches)
+
+Q5_CFG = dict(batch_size=4096, n_batches=6, events_per_ms=100,
+              num_active_auctions=500, hot_ratio=4)
+
+
+class TestHostFedQ5Checkpoint:
+    """Host-fed Q5 (``bid_stream``) under checkpointing: recovery from
+    a checkpoint continues exactly once, source positions count batches
+    (never more than the source has), and a checkpoint whose
+    ``sub_factors`` field records a factor other than 1 (written by the
+    removed device-chained source, whose positions counted slices of a
+    batch) is refused by name. The field is input from outside the
+    program: an old checkpoint may carry it, empty or holding 1s."""
+
+    N_BATCHES = Q5_CFG["n_batches"]
+
+    def _build(self, sink):
+        def build_env(conf):
+            env = StreamExecutionEnvironment(conf)
+            q5_hot_items(env, bid_stream(NexmarkConfig(**Q5_CFG)),
+                         sink, window_ms=2000, slide_ms=500,
+                         out_of_orderness_ms=100)
+            return env
+        return build_env
+
+    @staticmethod
+    def _view(sink):
+        return [tuple(sorted(r.items())) for r in sink.committed]
+
+    def _conf(self, tmp_path, name, extra=None):
+        c = {
+            "state.num-key-shards": 16, "state.slots-per-shard": 64,
+            "pipeline.microbatch-size": Q5_CFG["batch_size"],
+            "execution.checkpointing.dir": str(tmp_path / name),
+            "execution.checkpointing.interval": 1,
+            "restart-strategy.type": "fixed-delay",
+            "restart-strategy.fixed-delay.attempts": 20,
+            "restart-strategy.fixed-delay.delay": 1,
+        }
+        c.update(extra or {})
+        return Configuration(c)
+
+    @pytest.mark.parametrize("factors", [None, 1], ids=["empty", "ones"])
+    def test_restore_continues_identically(self, tmp_path, factors,
+                                           monkeypatch):
+        """The checkpoints are in the format PR 27's tree wrote on a
+        host-fed job: a ``sub_factors`` field, empty (what it wrote) or
+        holding an explicit 1 per source."""
+        from flink_tpu.runtime.driver import Driver
+
+        snapshot = Driver._snapshot
+
+        def snapshot_as_the_parent_wrote_it(driver, *args, **kwargs):
+            payload = snapshot(driver, *args, **kwargs)
+            assert "sub_factors" not in payload
+            payload["sub_factors"] = (
+                {} if factors is None
+                else {sid: factors for sid in payload["sources"]})
+            return payload
+
+        monkeypatch.setattr(Driver, "_snapshot",
+                            snapshot_as_the_parent_wrote_it)
+
+        golden_sink = TransactionalCollectSink()
+        self._build(golden_sink)(
+            self._conf(tmp_path, "golden-ckpt")).execute("q5-golden")
+        golden = self._view(golden_sink)
+        assert golden
+
+        # the SECOND checkpoint write fails, however many the run gets
+        # round to: a checkpoint begins only once the one before it is
+        # durable and the run ends with one, so there are always two,
+        # and the recovery restores the first
+        sink = TransactionalCollectSink()
+        plan = (faults.FaultPlan(seed=77)
+                .rule("checkpoint.storage.write", "raise", count=1,
+                      after=1))
+        with plan.activate(), replayable(plan):
+            run_with_recovery(
+                self._build(sink), self._conf(tmp_path, "chaos-ckpt"),
+                job_name="q5-chaos")
+        assert self._view(sink) == golden
+        assert len(plan.log) == 1, "the checkpoint fault never fired"
+
+        # positions count batches (never more than the source has), and
+        # a completed checkpoint cut the stream before its end: the
+        # recovery resumed mid-stream
+        seen, mid = 0, 0
+        for root, job in (("golden-ckpt", "q5-golden"),
+                          ("chaos-ckpt", "q5-chaos")):
+            storage = FsCheckpointStorage(
+                str(tmp_path / root), job_id=job)
+            for h in storage.list_complete():
+                seen += 1
+                payload = FsCheckpointStorage.load(h)
+                assert len(payload["sub_factors"]) == (
+                    0 if factors is None else len(payload["sources"]))
+                for pos in payload["sources"].values():
+                    assert all(0 <= int(p) <= self.N_BATCHES
+                               for p in pos.values()), pos
+                    mid += sum(1 for p in pos.values()
+                               if 0 < int(p) < self.N_BATCHES)
+        assert seen > 0, "no completed checkpoints"
+        assert mid > 0, "no checkpoint cut the stream mid-way"
+
+    def test_restore_refuses_sub_batch_positions(self, tmp_path):
+        """A checkpoint that records a factor other than 1 for a source
+        holds positions counted in slices of a batch: restoring it
+        names the field instead of reading them as batch positions."""
+        conf = self._conf(tmp_path, "ckpt")
+        self._build(TransactionalCollectSink())(conf).execute("q5-old")
+        storage = FsCheckpointStorage(str(tmp_path / "ckpt"),
+                                      job_id="q5-old")
+        latest = storage.latest()
+        payload = FsCheckpointStorage.load(latest)
+        for added_by_load in ("op_file_versions", "op_file_compression",
+                              "op_files", "op_aux_paths"):
+            payload.pop(added_by_load, None)
+        payload["sub_factors"] = {sid: 4 for sid in payload["sources"]}
+        old = storage.save(latest.checkpoint_id + 1, payload,
+                           savepoint=True)
+
+        env = self._build(TransactionalCollectSink())(self._conf(
+            tmp_path, "ckpt2",
+            extra={"execution.checkpointing.restore": old.path}))
+        with pytest.raises(ValueError, match="'sub_factors'"):
+            env.execute("q5-restore-old")

@@ -367,16 +367,17 @@ class TestGoldenNegatives:
         assert cenv.analyze() == []
 
     def test_bench_headline_conf_and_pipeline_zero_findings(self):
-        """The bench Q5 pipeline under its committed conf
-        (bench_q5_host_fed) analyzes clean — host-fed source, declared
-        schema, sub-batch grammar."""
-        import bench
+        """The benchmark's Q5 pipeline under its committed conf
+        (confs/bench_q5_host_fed.conf, read as the benchmark and
+        chip_smoke.py read it) analyzes clean — host-fed source,
+        declared schema."""
         from flink_tpu.nexmark.generator import NexmarkConfig, bid_stream
         from flink_tpu.nexmark.queries import q5_hot_items
         from flink_tpu.api.sinks import FnSink
 
-        conf = bench.job_confs()["bench_q5_host_fed"]
-        env = StreamExecutionEnvironment(Configuration(dict(conf)))
+        conf = Configuration.from_file(
+            os.path.join(REPO, "confs", "bench_q5_host_fed.conf"))
+        env = StreamExecutionEnvironment(conf)
         cfg = NexmarkConfig(batch_size=1 << 20, n_batches=2,
                             events_per_ms=100,
                             num_active_auctions=10_000, hot_ratio=4)
@@ -385,29 +386,46 @@ class TestGoldenNegatives:
         assert env.analyze() == []
 
 
-# -- committed bench confs: staleness + cold-subprocess analyze -------------
+# -- committed confs: who reads them + cold-subprocess analyze --------------
 
 class TestBenchConfGate:
-    def test_committed_confs_match_bench(self):
-        """confs/*.conf are GENERATED from bench.job_confs() — drift in
-        either direction fails here (regenerate with
-        `python bench.py --dump-confs confs`)."""
-        import bench
+    def test_every_committed_conf_has_a_reader(self):
+        """confs/ holds what the benchmark's configurations
+        (benchmark/configs/*.json: "conf", a probe's "conf") and
+        chip_smoke.py read by name, and nothing else: a file nobody
+        reads cannot pile up there again, and a name somebody reads
+        is there."""
+        import re
 
-        confs = bench.job_confs()
-        assert confs, "bench.job_confs() is empty"
-        on_disk = {f[:-5] for f in os.listdir(os.path.join(REPO, "confs"))
-                   if f.endswith(".conf")}
-        assert on_disk == set(confs), (
-            f"confs/ out of sync: disk {sorted(on_disk)} vs bench "
-            f"{sorted(confs)}")
-        for name, conf in confs.items():
-            path = os.path.join(REPO, "confs", f"{name}.conf")
-            with open(path, "r", encoding="utf-8") as f:
-                committed = f.read()
-            assert committed == bench.render_conf(name, conf), (
-                f"{path} is stale — run `python bench.py --dump-confs "
-                "confs`")
+        cfg_dir = os.path.join(REPO, "benchmark", "configs")
+        read = set()
+        for f in sorted(os.listdir(cfg_dir)):
+            if f.endswith(".json"):
+                with open(os.path.join(cfg_dir, f), encoding="utf-8") as fh:
+                    read |= set(re.findall(r'"conf"\s*:\s*"([^"]+)"',
+                                           fh.read()))
+        assert read, "no benchmark configuration names a conf"
+        with open(os.path.join(REPO, "chip_smoke.py"),
+                  encoding="utf-8") as fh:
+            smoke = fh.read()
+        on_disk = sorted(os.listdir(os.path.join(REPO, "confs")))
+        assert on_disk, "confs/ is empty"
+        for f in on_disk:
+            assert f in read or f'"{f}"' in smoke, (
+                f"confs/{f} is read by no benchmark/configs/*.json and "
+                "not by chip_smoke.py — delete it or name its reader")
+        assert read <= set(on_disk), sorted(read - set(on_disk))
+
+    def test_the_benchmark_conf_holds_its_four_settings(self):
+        """Every cell of the benchmark builds its job from this file:
+        its settings are the cell's, to the letter (PR 46 took the
+        header and the line of a removed option out, nothing else)."""
+        conf = Configuration.from_file(
+            os.path.join(REPO, "confs", "bench_q5_host_fed.conf"))
+        assert conf.to_dict() == {"analysis.fail-on": "off",
+                                  "pipeline.microbatch-size": "1048576",
+                                  "state.num-key-shards": "128",
+                                  "state.slots-per-shard": "256"}
 
     def test_every_committed_conf_cold_analyzes_clean(self):
         """Tier-1 dogfood: `python -m flink_tpu analyze <conf>` from a

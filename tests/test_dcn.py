@@ -351,11 +351,9 @@ class TestTier5TwoProcessQ5:
         assert _collect(tmp_path, 2) == golden
 
 
-class TestDcnSubBatchAndOverlap:
-    """Cross-host contract of pipeline.sub-batches (the rendezvous is
-    per-LOGICAL-batch; K slices the local push only, so committed rows
-    are identical across K) and of cluster.dcn-overlap on/off (the
-    barrier moves, the consensus does not)."""
+class TestDcnOverlap:
+    """Cross-host contract of cluster.dcn-overlap on/off (the barrier
+    moves, the consensus does not)."""
 
     N_BATCHES = 8
     B = 64
@@ -434,7 +432,7 @@ class TestDcnSubBatchAndOverlap:
                      np.asarray(b["window_end"]).tolist(),
                      np.asarray(b["count"]).tolist())) if b else None)))
             try:
-                env.execute(f"subbatch-p{pid}")
+                env.execute(f"overlap-p{pid}")
             except BaseException as e:  # surfaced by the caller
                 errs[pid] = e
 
@@ -447,18 +445,6 @@ class TestDcnSubBatchAndOverlap:
         for pid, e in enumerate(errs):
             assert e is None, f"p{pid} failed: {e!r}"
         return [sorted(r) for r in per_pid]
-
-    def test_sub_batches_no_longer_rejected_and_byte_identical(self):
-        """K=4 cross-host runs (was a hard NotImplementedError at the
-        driver) and every process emits EXACTLY the rows its K=1 twin
-        does — the global watermark still advances once per rendezvous,
-        so fire content, ownership, and late classification are
-        untouched by the sub-batch slicing."""
-        golden = self._golden()
-        k1 = self._two_proc({"pipeline.sub-batches": 1})
-        k4 = self._two_proc({"pipeline.sub-batches": 4})
-        assert sorted(k1[0] + k1[1]) == golden
-        assert k4 == k1  # per-process byte-identity, not just the union
 
     def test_overlap_without_drain_completes_and_matches(self, tmp_path):
         """The analyzer-warned loss mode (overlap on, barrier drain

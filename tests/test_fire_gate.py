@@ -14,8 +14,9 @@ The contract under test:
   opportunistic drain poll that provably has nothing to fetch skips
   the device round trip (prof["drain_skips"]) — and a later
   row-carrying fire re-arms the fetch.
-- ``pipeline.fire-gate`` and ``pipeline.readiness`` are gone: a conf
-  that still sets them is told so as for any unknown key
+- ``pipeline.fire-gate``, ``pipeline.readiness`` and (PR 46)
+  ``pipeline.sub-batches`` are gone: a conf that still sets them is
+  told so as for any unknown key
   (CONFIG_KEY_UNKNOWN, warn), by the analyzer, the CLI and the submit
   gate, and the job runs as it does without them.
 """
@@ -47,13 +48,12 @@ def _capture_sink():
     return cat, FnSink(cap)
 
 
-def _conf(k, extra=None):
+def _conf(extra=None):
     conf = {
         "analysis.fail-on": "off",
         "pipeline.microbatch-size": 1024,
         "state.num-key-shards": 128,
         "state.slots-per-shard": 64,
-        "pipeline.sub-batches": k,
     }
     conf.update(extra or {})
     return conf
@@ -75,7 +75,7 @@ class TestHostFedLateRefire:
 
     N_KEYS = 16
     TOP = 4
-    N = 1024   # a batch the fused scan takes, whole or as two halves
+    N = 1024   # a batch the fused scan takes
 
     @staticmethod
     def _gen(split, i):
@@ -94,9 +94,9 @@ class TestHostFedLateRefire:
         return {"auction": keys.astype(np.int64),
                 "price": np.ones(n, np.int64)}, ts.astype(np.int64)
 
-    def _run(self, k, extra=None):
+    def _run(self, extra=None):
         cat, sink = _capture_sink()
-        env = StreamExecutionEnvironment(Configuration(_conf(k, extra)))
+        env = StreamExecutionEnvironment(Configuration(_conf(extra)))
         stream = env.from_source(
             GeneratorSource(self._gen),
             WatermarkStrategy.for_bounded_out_of_orderness(0))
@@ -106,7 +106,7 @@ class TestHostFedLateRefire:
                .count()
                .top(self.TOP, by="count"))
         top.add_sink(sink)
-        env.execute(f"late-refire-k{k}")
+        env.execute("late-refire")
         return cat(), env
 
     def _golden_emissions(self):
@@ -132,8 +132,7 @@ class TestHostFedLateRefire:
 
         return [top(1_000, 1), top(1_000, 3), top(3_000, 4)]
 
-    @pytest.mark.parametrize("k", [1, 2])
-    def test_refire_survives_gating(self, k, monkeypatch):
+    def test_refire_survives_gating(self, monkeypatch):
         from flink_tpu.ops.window import WindowOperator
 
         fused = []   # (window ends handed over, the fused step took them)
@@ -145,7 +144,7 @@ class TestHostFedLateRefire:
             return out
 
         monkeypatch.setattr(WindowOperator, "_advance_fused", spy)
-        got, _ = self._run(k)
+        got, _ = self._run()
         rows = list(zip(got["window_end"].tolist(), got["key"].tolist(),
                         got["count"].tolist()))
         first, refire, last = self._golden_emissions()
@@ -153,9 +152,9 @@ class TestHostFedLateRefire:
         # refire is not observable and this test is vacuous
         assert first != refire
         n1, n2 = len(first), len(first) + len(refire)
-        assert sorted(rows[:n1]) == first, k
-        assert sorted(rows[n1:n2]) == refire, k
-        assert sorted(rows[n2:]) == last, k
+        assert sorted(rows[:n1]) == first
+        assert sorted(rows[n1:n2]) == refire
+        assert sorted(rows[n2:]) == last
         # and both firings of window 1000 rode the gated fused step:
         # the first beside the empty window 2000, the refire alone
         assert [n for n, took in fused if took and n] == [2, 1], fused
@@ -246,18 +245,19 @@ class TestCoalescedReadback:
 
 
 class TestRemovedOptions:
-    """``pipeline.fire-gate`` and ``pipeline.readiness`` no longer
-    exist: a conf that still sets them gets the treatment of any key
-    outside the option grammar."""
+    """``pipeline.fire-gate``, ``pipeline.readiness`` and
+    ``pipeline.sub-batches`` no longer exist: a conf that still sets
+    them gets the treatment of any key outside the option grammar."""
 
-    OLD = {"pipeline.fire-gate": False, "pipeline.readiness": "probe"}
+    OLD = {"pipeline.fire-gate": False, "pipeline.readiness": "probe",
+           "pipeline.sub-batches": 4}
 
     @pytest.mark.parametrize("key", sorted(OLD))
     def test_analyzer_reports_an_unknown_key(self, key):
         from flink_tpu.analysis import analyze_config
 
         fs = analyze_config(Configuration(
-            {key: self.OLD[key], "pipeline.sub-batches": 4}))
+            {key: self.OLD[key], "pipeline.microbatch-size": 1024}))
         (f,) = fs
         assert (f.rule, f.severity) == ("CONFIG_KEY_UNKNOWN", "warn")
         assert repr(key) in f.message
@@ -270,7 +270,8 @@ class TestRemovedOptions:
         conf = tmp_path / "old.conf"
         conf.write_text("pipeline.fire-gate: false\n"
                         "pipeline.readiness: probe\n"
-                        "pipeline.sub-batches: 4\n")
+                        "pipeline.sub-batches: 4\n"
+                        "pipeline.microbatch-size: 1024\n")
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         root = os.path.join(os.path.dirname(__file__), "..")
 
@@ -289,13 +290,35 @@ class TestRemovedOptions:
 
     def test_driver_runs_the_job_and_records_the_findings(self):
         # through the submit gate (analysis.fail-on at its default):
-        # the job is admitted, runs as it does without the two keys,
-        # and the findings land on the driver
+        # the job is admitted, runs as it does without the three keys
+        # (one step a batch: nothing slices it), and the findings land
+        # on the driver
         t = TestHostFedLateRefire()
-        golden, _ = t._run(1, {"analysis.fail-on": "error"})
-        got, env = t._run(1, {**self.OLD, "analysis.fail-on": "error"})
+        golden, plain = t._run({"analysis.fail-on": "error"})
+        got, env = t._run({**self.OLD, "analysis.fail-on": "error"})
         _assert_identical_in_order(golden, got, "removed options")
+        assert (env._driver.metrics["batches"]
+                == plain._driver.metrics["batches"] == 4)
         unknown = [f for f in env._driver.analysis_findings
                    if f.rule == "CONFIG_KEY_UNKNOWN"]
-        assert len(unknown) == 2 and all(
+        assert len(unknown) == len(self.OLD) == 3 and all(
             f.severity == "warn" for f in unknown)
+
+    @pytest.mark.parametrize("extra, credit", [
+        ({"pipeline.max-inflight-steps": 6}, 6),
+        ({"pipeline.max-inflight-steps": 6,
+          "session.concurrent-jobs": 3}, 2),
+        ({"pipeline.max-inflight-steps": 6,
+          "pipeline.sub-batches": 4}, 6),
+    ], ids=["configured", "a-session-share", "the-dead-key-set"])
+    def test_the_in_flight_credit_is_the_configured_one(self, extra,
+                                                        credit):
+        # pipeline.max-inflight-steps, divided by the session's share
+        # where one is set, and nothing multiplied in (the slices of a
+        # batch used to multiply it)
+        from flink_tpu.ops.window import WindowOperator
+
+        _, env = TestHostFedLateRefire()._run(extra)
+        (op,) = [op for op in env._driver._ops.values()
+                 if isinstance(op, WindowOperator)]
+        assert op.max_inflight_steps == credit

@@ -117,7 +117,7 @@ class TestJobPhases:
         for leaf, ms in per_leaf.items():
             # no single interval is longer than the leaf's total
             assert ms <= 1e3 * m[f"profile.phase.{leaf}"] + 1e-3, leaf
-        # bench.py's _phase_summary calls float() on every such value
+        # every such value is a number
         for k, v in m.items():
             if k.startswith("profile.phase."):
                 float(v)

@@ -4,6 +4,7 @@ right line, and trace-static idioms (shape reads, len(), `is None`,
 static_argnums) prove it stays quiet — the false-positive budget of
 the dogfood gate is ZERO, so the negatives matter as much as the
 positives (tier-1)."""
+import os
 import textwrap
 
 import pytest
@@ -14,6 +15,7 @@ from flink_tpu.analysis.pylints import (
     LINT_RULES,
     lint_paths,
     lint_source,
+    repo_root,
 )
 
 pytestmark = pytest.mark.analysis
@@ -461,7 +463,11 @@ class TestLintPaths:
 
     def test_default_paths_cover_the_shipped_surface(self):
         assert "flink_tpu" in DEFAULT_LINT_PATHS
-        assert "bench.py" in DEFAULT_LINT_PATHS
+        assert "tools" in DEFAULT_LINT_PATHS
+        assert "chip_smoke.py" in DEFAULT_LINT_PATHS
+        root = repo_root()
+        for p in DEFAULT_LINT_PATHS:  # nothing named that is gone
+            assert os.path.exists(os.path.join(root, p)), p
 
 
 # -- one seeded violation per catalog rule ----------------------------------

@@ -493,6 +493,21 @@ class TestDriverPlaneMerge:
                                      "num_shards": NS,
                                      "shard_range": [0, NS]}
 
+    @pytest.mark.parametrize("factors", [None, {"src": 1}, {"src": 4}],
+                             ids=["absent", "ones", "a-factor-of-4"])
+    def test_sub_factors_ride_through_to_the_restore(self, factors):
+        """The field is an old checkpoint's word on how its source
+        positions count; the merge hands it on as it found it, so the
+        driver's restore can still refuse a factor other than 1."""
+        payloads = self._payloads()
+        for p in payloads:
+            if factors is None:
+                del p["sub_factors"]
+            else:
+                p["sub_factors"] = dict(factors)
+        merged = _merge(payloads, 0, 1, {"p": "process"})
+        assert merged["sub_factors"] == (factors or {})
+
     def test_empty_set_rejected(self):
         with pytest.raises(RescaleError, match="empty"):
             _merge([], 0, 1, {})
