@@ -65,6 +65,7 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 import numpy as np
 
 from flink_tpu.analysis.core import Finding, plan_rule
+from flink_tpu.ops.aggregates import EVENT_TIME_FIELD
 
 Schema = Optional[Dict[str, str]]  # field -> numpy dtype name; None = top
 
@@ -317,6 +318,12 @@ def _state_facts(node, config) -> Tuple[str, str, Optional[int]]:
                 "one accumulator per key, never expires — bounded by "
                 "key cardinality (state.num-key-shards × "
                 "state.slots-per-shard)", _lane_bytes(wt.aggregate))
+    if kind == "keyed_join":
+        return ("bounded",
+                "both sides kept per key, never expires (no window, no "
+                "TTL) — bounded by key cardinality (state.num-key-shards "
+                "× state.slots-per-shard); 9 int32 words a slot on the "
+                "device lane", 36)
     if kind == "join":
         return ("bounded",
                 "both sides buffered within window lifetime + lateness "
@@ -376,6 +383,9 @@ def _wm_facts(node, in_wm: List[str]) -> Tuple[str, str]:
         return "event", "fired at the event watermark"
     if kind == "global_agg":
         return "event", "upsert rows stamped at the emission watermark"
+    if kind == "keyed_join":
+        return "event", ("changelog rows stamped with their key's newest "
+                         "event time in the mini-batch")
     # chains/partitions/unions/sinks/async_io/broadcast: pass-through
     if not in_wm:
         return "event", ""
@@ -477,6 +487,13 @@ def _propagate(plan, config) -> PlanFacts:
                               "reads right-side field", out)
             nf.schema = node.out_schema
             nf.schema_note = "declared by the lowering" if nf.schema else ""
+        elif node.kind == "keyed_join":
+            wt = node.window_transform
+            _check_fields(nf, nf.in_schema, (
+                wt.side_field, wt.left_key, wt.right_key, wt.until_field,
+                wt.carry_field, wt.value_field), "joins over field", out)
+            nf.schema = node.out_schema
+            nf.schema_note = "declared by the lowering"
         elif node.kind in ("window", "evicting_window", "count_window",
                            "session", "process", "cep", "global_agg"):
             # the keyBy exchange folds into the op; whether the key
@@ -484,7 +501,9 @@ def _propagate(plan, config) -> PlanFacts:
             _check_fields(nf, nf.in_schema, [node.key_field],
                           "keys by field", out)
             agg = getattr(node.window_transform, "aggregate", None)
-            agg_fields = getattr(agg, "fields", None)
+            # the event time is the operator's to hand a lane, no column
+            agg_fields = [f for f in getattr(agg, "fields", None) or ()
+                          if f != EVENT_TIME_FIELD]
             if agg_fields:
                 _check_fields(nf, nf.in_schema, agg_fields,
                               "aggregates over field", out)
@@ -512,7 +531,8 @@ def _propagate(plan, config) -> PlanFacts:
         # forward them)
         wt = getattr(node, "window_transform", None)
         if (node.kind in ("global_agg", "session")
-                and getattr(wt, "retract", False)):
+                and getattr(wt, "retract", False)) \
+                or node.kind == "keyed_join":
             nf.changelog = True
         elif node.kind in ("chain", "partition", "union", "sink"):
             nf.changelog = any(u.changelog for u in ups)

@@ -70,6 +70,14 @@ class DataStream:
 
         return self._append(MapTransformation(name, (self.transform,), fn=op, kind="filter"))
 
+    def where_equals(self, field: str, value: int) -> "ViewStream":
+        """The rows whose ``field`` equals ``value``: a VIEW of this
+        stream (the suite's ``CREATE VIEW bid AS SELECT .. FROM datagen
+        WHERE event_type = 2``). Used as a stream it is that filter;
+        two views of one stream over one field can be joined without
+        either being made (``JoinBuilder.right_time_within_left``)."""
+        return ViewStream(self, field, value)
+
     def flat_map(self, fn: Callable, name: str = "flat_map") -> "DataStream":
         """``fn(data, ts, valid) -> (data', ts', valid')`` with any output
         length (ref: DataStream.flatMap → StreamFlatMap). Ingest chains
@@ -195,6 +203,25 @@ class DataStream:
     def _append(self, t: Transformation) -> "DataStream":
         self.env._register(t)
         return DataStream(self.env, t)
+
+
+class ViewStream(DataStream):
+    """``parent.where_equals(field, value)``; its filter is appended
+    only when something reads the view as a stream."""
+
+    def __init__(self, parent: DataStream, field: str, value: int) -> None:
+        self.env = parent.env
+        self.parent, self.field, self.value = parent, field, value
+        self._transform: Optional[Transformation] = None
+
+    @property
+    def transform(self) -> Transformation:
+        if self._transform is None:
+            field, value = self.field, self.value
+            self._transform = self.parent.filter(
+                lambda d: np.asarray(d[field]) == value,
+                name=f"view_{field}_{value}").transform
+        return self._transform
 
 
 class KeyedStream(DataStream):
@@ -579,6 +606,54 @@ class JoinBuilder:
 
     def window(self, assigner: WindowAssigner) -> "WindowedJoin":
         return WindowedJoin(self, assigner)
+
+    def right_time_within_left(self, until: str) -> "UnboundedJoin":
+        """No window: the join keeps both sides for ever, and a right
+        row matches the left row of its key when its event time lies
+        between the left row's event time and the left row's ``until``
+        column, both ends inclusive (``r.rowtime BETWEEN l.rowtime AND
+        l.until``). The left side has ONE row a key. Both sides must be
+        views of one stream over one field (``where_equals``)."""
+        return UnboundedJoin(self, until)
+
+
+class UnboundedJoin:
+    def __init__(self, builder: JoinBuilder, until: str):
+        self.b, self.until = builder, until
+
+    def max(self, field: str, carry: str, result_field: Optional[str] = None,
+            name: str = "keyed_join") -> DataStream:
+        """``SELECT l.key, l.carry, MAX(r.field) .. GROUP BY l.key,
+        l.carry`` as a CHANGELOG: after every microbatch, for each key
+        whose maximum appeared or changed in it, ``+I``, or ``-U`` (the
+        value last emitted) and ``+U`` (``records.OP_FIELD``); columns
+        ``key``, ``carry``, ``result_field`` (default ``max_<field>``).
+        Both sides' state lives on the device (ops/join_device.py; the
+        factory chooses the lane, ops/join_host.py otherwise). A
+        stateful consumer (``running_aggregate`` over the changelog)
+        folds it one mini-batch at a time."""
+        from flink_tpu.graph.transformations import KeyedJoinTransformation
+
+        left, right = self.b._left, self.b._right
+        if not (isinstance(left, ViewStream) and isinstance(right, ViewStream)
+                and left.parent is right.parent
+                and left.field == right.field
+                and left.value != right.value):
+            raise NotImplementedError(
+                "an unbounded join takes two views of ONE stream over one "
+                "field (stream.where_equals(field, a).join(stream"
+                ".where_equals(field, b))); two streams of their own "
+                "would need a two-input keyed operator that is not built")
+        env = left.env
+        t = KeyedJoinTransformation(
+            name, (left.parent.transform,), side_field=left.field,
+            left_value=left.value, right_value=right.value,
+            left_key=self.b._left_key or "key",
+            right_key=self.b._right_key or "key", until_field=self.until,
+            carry_field=carry, value_field=field,
+            result_field=result_field or f"max_{field}")
+        env._register(t)
+        return DataStream(env, t)
 
 
 class WindowedJoin:

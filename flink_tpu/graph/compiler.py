@@ -29,6 +29,7 @@ from flink_tpu.graph.transformations import (
     CepTransformation,
     CountWindowAggregateTransformation,
     GlobalAggregateTransformation,
+    KeyedJoinTransformation,
     KeyedProcessTransformation,
     PartitionTransformation,
     SessionAggregateTransformation,
@@ -107,7 +108,7 @@ class ExecutionPlan:
 # so the batch driver owns its submit/poll cycle at a stage head.
 STAGE_HEAD_KINDS = frozenset((
     "window", "session", "join", "count_window", "window_all",
-    "process", "cep", "evicting_window", "global_agg",
+    "process", "cep", "evicting_window", "global_agg", "keyed_join",
     "broadcast_connect", "async_io",
 ))
 
@@ -190,6 +191,11 @@ def _op_out_schema(node: ExecNode) -> Optional[Dict[str, str]]:
             if getattr(wt, "retract", False):
                 out["__op__"] = "int8"  # records.OP_FIELD changelog lane
             return out
+        if node.kind == "keyed_join":
+            # ops/join_host.py changelog_rows
+            return {"key": "int64", wt.carry_field: "int64",
+                    wt.result_field: "int64", "__op__": "int8",
+                    "__minibatch__": "int64"}
         if node.kind == "join":
             out = {"key": "int64", "window_start": "int64",
                    "window_end": "int64"}
@@ -340,6 +346,13 @@ def compile_job(
             up = node_for(t.inputs[0])
             n = new_node("global_agg", t.name, window_transform=t,
                          key_field=t.key_field, keyed_input=keyed_in(t))
+            nodes[up].downstream.append(n.id)
+        elif isinstance(t, KeyedJoinTransformation):
+            # the key is worked out per row from the side's own column
+            # (the driver's split), so no keyBy precedes it
+            up = node_for(t.inputs[0])
+            n = new_node("keyed_join", t.name, window_transform=t,
+                         keyed_input=True)
             nodes[up].downstream.append(n.id)
         elif isinstance(t, SessionAggregateTransformation):
             up = node_for(t.inputs[0])

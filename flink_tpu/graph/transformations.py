@@ -194,6 +194,28 @@ class WindowJoinTransformation(Transformation):
 
 
 @dataclasses.dataclass(eq=False)
+class KeyedJoinTransformation(Transformation):
+    """Unbounded equi-join of two VIEWS of one stream (rows told apart
+    by ``side_field``, as the NEXmark suite's ``auction`` and ``bid``
+    views of its one ``datagen`` table are), the left side one row a
+    key, the right side reduced (``MAX(value_field)``) under ``r.rowtime
+    BETWEEN l.rowtime AND l.until_field``; emits the result's changelog
+    a mini-batch (ref: table-runtime StreamingJoinOperator feeding a
+    GroupAggFunction, both keeping state without a TTL; see
+    ops/join_host.py). The one input is the views' common stream."""
+
+    side_field: str = "side"
+    left_value: int = 0
+    right_value: int = 1
+    left_key: str = "key"
+    right_key: str = "key"
+    until_field: str = "until"
+    carry_field: str = "carry"
+    value_field: str = "value"
+    result_field: str = "max_value"
+
+
+@dataclasses.dataclass(eq=False)
 class SessionAggregateTransformation(Transformation):
     """Keyed session windows (ref: EventTimeSessionWindows +
     MergingWindowSet) — session lanes on the device, or the host's span

@@ -1,8 +1,9 @@
 """NEXMark query pipelines over the DataStream API.
 
-ref: BASELINE.json configs — Q5 sliding hot items, Q7 tumbling highest
-bid, Q8 tumbling new-user join, Q11 user sessions, Q17 auction
-statistics (the unbounded GROUP BY); semantics per the nexmark/nexmark
+ref: BASELINE.json configs — Q4 average price for a category (the
+unbounded join), Q5 sliding hot items, Q7 tumbling highest bid, Q8
+tumbling new-user join, Q11 user sessions, Q17 auction statistics (the
+unbounded GROUP BY); semantics per the nexmark/nexmark
 query definitions (SQL in the external repo; validated shapes in
 SURVEY §7).
 """
@@ -222,3 +223,68 @@ def q17_auction_stats(
 Q17_COLUMNS = ("auction", "day", "total_bids", "rank1_bids", "rank2_bids",
                "rank3_bids", "min_price", "max_price", "avg_price",
                "sum_price", "last_bid_ms")
+
+
+# the suite's ``datagen`` table tells its rows apart by ``event_type``
+# (ddl_views.sql: person 0, auction 1, bid 2)
+EVENT_AUCTION, EVENT_BID = 1, 2
+Q4_COLUMNS = ("category", "avg_final", "sum_final", "auctions",
+              "last_event_ms")
+
+
+def q4_category_avg(
+    env: StreamExecutionEnvironment,
+    events,
+    sink: Sink,
+) -> DataStream:
+    """Q4: average price for a category (nexmark-flink ``queries/
+    q4.sql``: ``SELECT Q.category, AVG(Q.final) FROM (SELECT
+    MAX(B.price) AS final, A.category FROM auction A, bid B WHERE A.id =
+    B.auction AND B.dateTime BETWEEN A.dateTime AND A.expires GROUP BY
+    A.id, A.category) Q GROUP BY Q.category``).
+
+    ``events`` is the suite's one ``datagen`` table: a row is an auction
+    (``event_type`` 1: ``auction_id``, ``auction_category``,
+    ``auction_expires``) or a bid (2: ``bid_auction``, ``bid_price``),
+    its ``dateTime`` the row's timestamp; ``auction`` and ``bid`` are
+    its two views (ddl_views.sql). Inner query: the UNBOUNDED join of
+    the two views on the auction id, both sides kept without a TTL (a
+    bid that comes before its auction waits for it), reduced to ``final
+    = MAX(price)`` per auction under the predicate, leaving as a
+    changelog (ops/join_device.py; the factory chooses the lane). Outer
+    query: the changelog folded per category with exact integer lanes
+    (a -U row takes out what its +I / +U put in), one row a category a
+    microbatch: ``avg_final = sum_final // auctions`` (the sink's
+    BIGINT), ``sum_final`` and ``auctions`` (the accumulator AVG
+    holds), and ``last_event_ms``, the row's event time: the newest
+    ``dateTime`` among the rows of the keys whose ``final`` appeared or
+    changed in that microbatch."""
+    table = env.from_source(
+        events, WatermarkStrategy.for_monotonous_timestamps())
+    auction = table.where_equals("event_type", EVENT_AUCTION)
+    bid = table.where_equals("event_type", EVENT_BID)
+    final = (
+        auction.join(bid).where("auction_id").equal_to("bid_auction")
+        .right_time_within_left(until="auction_expires")
+        .max("bid_price", carry="auction_category", result_field="final",
+             name="q4_join")
+    )
+    avg = (
+        final.key_by("auction_category")
+        .running_aggregate(aggregates.multi(
+            aggregates.changelog_int_sum_of(
+                "final", "sum_final", count_field="auctions",
+                avg_field="avg_final"),
+            aggregates.latest_event_time("last_event_ms",
+                                         since_last_row=True)),
+            name="q4_category_avg")
+    )
+
+    def rename(data):
+        out = {"category": data["key"]}
+        out.update({k: data[k] for k in Q4_COLUMNS[1:]})
+        return out
+
+    out = avg.map(rename, name="q4_rename")
+    out.add_sink(sink)
+    return out

@@ -99,6 +99,12 @@ class LaneAggregate:
     # before ``finalize`` and in a snapshot
     time_lanes: Tuple[Tuple[int, ...], Tuple[int, ...],
                       Tuple[int, ...]] = ((), (), ())
+    # per family the lanes that hold "since the key's last row": the
+    # host operator of the unwindowed aggregation puts them back to
+    # their identity once a row has left (ops/global_agg.py; the device
+    # lane does not run them: ``device_lane_fits``)
+    emission_lanes: Tuple[Tuple[int, ...], Tuple[int, ...],
+                          Tuple[int, ...]] = ((), (), ())
 
     @property
     def typed(self) -> bool:
@@ -110,9 +116,12 @@ class LaneAggregate:
         (e.g. count() over a batch with no data fields). Integer lanes:
         per family a tuple of (B,) columns."""
         if self.typed:
+            # host columns stay host columns (the host operator folds a
+            # changelog on the drain's thread: no trip to the device)
+            xp = np if isinstance(valid, np.ndarray) else jnp
             return tuple(
-                tuple(jnp.where(valid, c.astype(dt),
-                                jnp.asarray(lane_identity(fam, dt), dt))
+                tuple(xp.where(valid, c.astype(dt),
+                               xp.asarray(lane_identity(fam, dt), dt))
                       for c, dt in zip(cols, dts))
                 for fam, cols, dts in zip(LANE_FAMILIES, self.lift(data),
                                           self.lane_dtypes))
@@ -359,11 +368,14 @@ def int_min_of(field: str, result_field: Optional[str] = None
 
 
 @_cached
-def latest_event_time(result_field: str = "last_ts") -> LaneAggregate:
+def latest_event_time(result_field: str = "last_ts",
+                      since_last_row: bool = False) -> LaneAggregate:
     """The newest event time among a key's records (the row's rowtime,
     which Flink carries beside the row): an int32 max lane over the
     offsets the operator provides under ``EVENT_TIME_FIELD``; the
-    result is the timestamp itself (``time_lanes``)."""
+    result is the timestamp itself (``time_lanes``).
+    ``since_last_row``: among the records folded in since the key's
+    last row left, the rowtime of THAT row (``emission_lanes``)."""
 
     def lift(data: Arrays):
         return (), (data[EVENT_TIME_FIELD].astype(jnp.int32),), ()
@@ -374,7 +386,9 @@ def latest_event_time(result_field: str = "last_ts") -> LaneAggregate:
     return LaneAggregate(0, 1, 0, lift, finalize, name="latest_event_time",
                          fields=(EVENT_TIME_FIELD,),
                          lane_dtypes=_typed(maxs=["int32"]),
-                         time_lanes=((), (0,), ()))
+                         time_lanes=((), (0,), ()),
+                         emission_lanes=((), (0,), ()) if since_last_row
+                         else ((), (), ()))
 
 
 @_cached
@@ -472,16 +486,18 @@ def _multi_typed(aggs, sw: int, mw: int, nw: int) -> LaneAggregate:
     dtypes = tuple(
         tuple(dt for a in aggs if a.typed for dt in a.lane_dtypes[i])
         for i in range(3))
-    times, at = ([], [], []), [0, 0, 0]
+    times, since, at = ([], [], []), ([], [], []), [0, 0, 0]
     for a, w in zip(aggs, widths):
         for i in range(3):
             times[i].extend(at[i] + j for j in a.time_lanes[i])
+            since[i].extend(at[i] + j for j in a.emission_lanes[i])
             at[i] += w[i]
     return LaneAggregate(
         sw, mw, nw, lift, finalize, name="+".join(a.name for a in aggs),
         fields=_merged_fields(aggs, "fields"), lane_dtypes=dtypes,
         narrow_fields=_merged_fields(aggs, "narrow_fields"),
-        time_lanes=tuple(tuple(t) for t in times))
+        time_lanes=tuple(tuple(t) for t in times),
+        emission_lanes=tuple(tuple(t) for t in since))
 
 
 # ---------------------------------------------------------------------------
@@ -567,6 +583,40 @@ def changelog_avg_of(field: str,
     return LaneAggregate(2, 0, 0, lift, finalize,
                          name=f"changelog_avg({field})",
                          fields=(field, OP_FIELD))
+
+
+@_cached
+def changelog_int_sum_of(field: str, result_field: Optional[str] = None,
+                         count_field: Optional[str] = None,
+                         avg_field: Optional[str] = None) -> LaneAggregate:
+    """SUM(field) over a changelog stream of an integer column, exact
+    at 64 bits: a -U / -D row takes out exactly what its +I / +U put
+    in. ``count_field``: the rows the sum stands for (+1 / -1 a row: the
+    built-in count lane counts retractions too); ``avg_field``: their
+    integer quotient, SQL's AVG over a BIGINT column. Two int64 sum
+    lanes; numpy in, numpy out (``lift_masked``)."""
+    from flink_tpu.records import OP_DELETE, OP_FIELD, OP_UPDATE_BEFORE
+
+    out = result_field or f"sum_{field}"
+
+    def lift(data: Arrays):
+        ops = data[OP_FIELD]
+        sign = 1 - 2 * ((ops == OP_UPDATE_BEFORE)
+                        | (ops == OP_DELETE)).astype(jnp.int64)
+        return (data[field].astype(jnp.int64) * sign, sign), (), ()
+
+    def finalize(sums, maxs, mins, counts):
+        res = {out: sums[0]}
+        if count_field is not None:
+            res[count_field] = sums[1]
+        if avg_field is not None:
+            res[avg_field] = sums[0] // np.maximum(sums[1], 1)
+        return res
+
+    return LaneAggregate(2, 0, 0, lift, finalize,
+                         name=f"changelog_int_sum({field})",
+                         fields=(field, OP_FIELD),
+                         lane_dtypes=_typed(["int64", "int64"]))
 
 
 def changelog_max_of(field: str, result_field: Optional[str] = None) -> None:

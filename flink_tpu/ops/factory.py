@@ -124,3 +124,31 @@ def _window_factory(node, ctx: OperatorBuildContext):
 
 
 register_operator_factory("window", _window_factory)
+
+
+def _keyed_join_factory(node, ctx: OperatorBuildContext):
+    """The unbounded keyed join: the ONE place its lane is chosen, by
+    what the job is: both sides' state on the device where one device
+    holds it, the host operator of the same semantics under a mesh or
+    past the slots an int32 cell key holds (``join.on_host`` 1)."""
+    from flink_tpu.ops.join_device import (
+        DeviceKeyedJoinOperator, device_lane_fits)
+    from flink_tpu.ops.join_host import HostKeyedJoinOperator
+
+    t = node.window_transform
+    fields = {"until_field": t.until_field, "carry_field": t.carry_field,
+              "value_field": t.value_field, "result_field": t.result_field}
+    if device_lane_fits(mesh=ctx.mesh_plan is not None,
+                        slots=ctx.num_shards * ctx.slots_per_shard):
+        op = DeviceKeyedJoinOperator(
+            num_shards=ctx.num_shards, slots_per_shard=ctx.slots_per_shard,
+            max_inflight_steps=ctx.max_inflight_steps, **fields)
+        # as the window factory: the loop throttles outside its push lock
+        op.external_throttle = True
+        return op
+    return HostKeyedJoinOperator(
+        num_shards=ctx.num_shards, slots_per_shard=ctx.slots_per_shard,
+        **fields)
+
+
+register_operator_factory("keyed_join", _keyed_join_factory)

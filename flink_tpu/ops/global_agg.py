@@ -213,6 +213,7 @@ class GlobalAggregateOperator:
             # timestamp (the process-function emission contract,
             # driver _emit_fired)
             out["__ts__"] = np.full(len(sl), wm, np.int64)
+            self._restart_emission_lanes(sl)
             return FiredWindows(data=out)
         # retract mode: fired BEFORE any emission bookkeeping mutates,
         # so an injected failure here leaves (prev_*, emitted) exactly
@@ -250,6 +251,16 @@ class GlobalAggregateOperator:
         self.prev_mins[sl] = self.mins[sl]
         self.emitted[sl] = True
         return FiredWindows(data=out)
+
+    def _restart_emission_lanes(self, sl: np.ndarray) -> None:
+        """A lane that holds "since the key's last row"
+        (``LaneAggregate.emission_lanes``) starts anew for the keys
+        whose row has just left."""
+        for fam, arr, lanes, dts in zip(
+                LANE_FAMILIES, (self.sums, self.maxs, self.mins),
+                self.agg.emission_lanes, lane_layout(self.agg)):
+            for j in lanes:
+                arr[sl, j] = lane_identity(fam, dts[j])
 
     # -- time plane ------------------------------------------------------
 

@@ -73,9 +73,11 @@ def device_lane_fits(*, agg: Any, retract: bool, mesh: bool,
                      slots: int) -> bool:
     """Whether an unwindowed aggregation keeps its accumulators on the
     device: no retract rows (the -U row needs the accumulators as last
-    emitted), one device, a lane aggregate, and slots that int32 cell
+    emitted), one device, a lane aggregate without lanes that start
+    anew with every row (``emission_lanes``), and slots that int32 cell
     keys hold. Everything else keeps ``GlobalAggregateOperator``."""
     return (not retract and not mesh and isinstance(agg, LaneAggregate)
+            and not any(agg.emission_lanes)
             and 0 < int(slots) < (1 << 30))
 
 

@@ -36,6 +36,11 @@ MIN_TS = np.int64(np.iinfo(np.int64).min)
 # refires). Insert-only streams carry no ``__op__`` column at all, so
 # the plane costs nothing until a retract-producing op creates it.
 OP_FIELD = "__op__"
+# the number of the mini-batch a changelog row left its operator in
+# (ascending within a delivery): the driver hands a stateful consumer
+# the rows one mini-batch at a time and drops the column
+# (runtime/driver.py _minibatches; ops/join_host.py)
+MINIBATCH_FIELD = "__minibatch__"
 OP_DTYPE = np.int8
 OP_INSERT = 0         # +I  first result for its key
 OP_UPDATE_BEFORE = 1  # -U  retraction of the previously emitted row

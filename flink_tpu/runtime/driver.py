@@ -16,7 +16,9 @@ exactly-once cheap here.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import threading
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
@@ -35,6 +37,7 @@ from flink_tpu.graph.compiler import (
     ExecutionPlan,
 )
 from flink_tpu.obs.tracing import PhaseClock
+from flink_tpu.records import MINIBATCH_FIELD
 from flink_tpu.time.watermarks import LONG_MIN, WatermarkTracker, make_generator
 
 # fire cohorts kept for JobResult "trace.fires" (and its records: the
@@ -206,6 +209,10 @@ class Driver:
         # re-armed by, a later run on the same Driver.
         self._drain_discard = [False]
         self._stateless_cache: Dict[int, bool] = {}
+        self._drain_ok_cache: Dict[int, bool] = {}
+        # the drain thread of the run (``_drain_entry``): a stateful
+        # operator it folds rows into hands ITS rows on in line
+        self._drain_ident: Optional[int] = None
         # batch (bounded) mode: open blocking-edge writers, keyed by
         # (from_node, to_node); _push diverts matching edges into the
         # shuffle spool instead of the consumer. Always a dict (empty
@@ -2445,7 +2452,12 @@ class Driver:
             keys = np.asarray(data[n.key_field], np.int64)
             dev_data = {k: v for k, v in data.items()
                         if np.asarray(v).dtype != object}
-            op.process_batch(keys, ts, dev_data, valid)
+            # on the drain's thread (a changelog folded into its GROUP
+            # BY inside drain.deliver) the fold is a detail of that leaf
+            with (self.phases.detail("fold")
+                  if threading.get_ident() == self._drain_ident
+                  else contextlib.nullcontext()):
+                op.process_batch(keys, ts, dev_data, valid)
             if n.kind in ("count_window", "process", "cep",
                           "evicting_window", "global_agg", "session"):
                 # these emit per-step, not (only) per-watermark
@@ -2454,6 +2466,20 @@ class Driver:
                 fired = op.take_fired()
                 if fired is not None:
                     self._emit_fired(nid, fired)
+        elif n.kind == "keyed_join":
+            # the split of the one stream into its two views (the open
+            # leaf is ingest.route): which side a row is, and its key
+            # from that side's own column
+            op = self._ops[nid]
+            t = n.window_transform
+            side = np.asarray(data[t.side_field])
+            left = side == t.left_value
+            keys = np.where(left, data[t.left_key], data[t.right_key])
+            valid = valid & (left | (side == t.right_value))
+            op.process_batch(keys, ts, left, data, valid)
+            fired = op.take_fired()
+            if fired is not None:
+                self._emit_fired(nid, fired)
         elif n.kind == "join":
             op = self._ops[nid]
             t = n.window_transform
@@ -2594,7 +2620,8 @@ class Driver:
         stamp = time.perf_counter()
         if cohort is not None:
             cohort["t_queued"] = stamp
-        if self._emit_q is not None and self._stateless_downstream(nid):
+        if (self._emit_q is not None and self._drain_may_deliver(nid)
+                and threading.get_ident() != self._drain_ident):
             self._emit_q.put((nid, fired, stamp))
             if not getattr(fired, "rowless", False):
                 self._drain_wake.set()
@@ -2633,8 +2660,9 @@ class Driver:
                       if nrec else np.zeros(0, np.int64))
             if nrec:
                 self.metrics["fired_windows"] += nrec
-                valid = np.ones(nrec, bool)
-                self._push_downstream(nid, (out, ts, valid))
+                for part, part_ts in _minibatches(out, ts):
+                    self._push_downstream(
+                        nid, (part, part_ts, np.ones(len(part_ts), bool)))
             # latency marker: fire dispatch → delivered at sink (ref:
             # streaming/runtime/streamrecord/LatencyMarker.java), read
             # off the fire records; an emission without one is stamped
@@ -2671,6 +2699,42 @@ class Driver:
                        for we in c["window_ends"])
         return out[-FIRE_RECORDS:]
 
+    def _drain_may_deliver(self, nid: int) -> bool:
+        """Whether the drain thread may deliver ``nid``'s fired rows:
+        nothing stateful below it, or (a device operator that feeds a
+        stateful one: the join's changelog into its GROUP BY) below it
+        only HOST aggregations of an unwindowed GROUP BY that nothing
+        else feeds and whose own rows meet nothing stateful. Such an
+        operator's state is touched by pushes alone (no watermark pass
+        advances it, no throttle, a snapshot comes after the drain's
+        flush), and every push into it is this delivery, under
+        ``_push_lock``: one thread at a time. Its rows leave in the
+        same delivery (``_emit_fired`` on the drain's thread is in
+        line)."""
+        if nid not in self._drain_ok_cache:
+            from flink_tpu.ops.global_agg import GlobalAggregateOperator
+
+            ok = self._stateless_downstream(nid)
+            if not ok and hasattr(self._ops.get(nid), "emit_ring"):
+                ok, stack, seen = True, list(
+                    self.plan.node(nid).downstream), set()
+                while ok and stack:
+                    d = stack.pop()
+                    if d in seen:
+                        continue
+                    seen.add(d)
+                    n = self.plan.node(d)
+                    if n.kind not in STAGE_HEAD_KINDS:
+                        stack.extend(n.downstream)
+                    else:
+                        ok = (isinstance(self._ops.get(d),
+                                         GlobalAggregateOperator)
+                              and all(u == nid or u in seen
+                                      for u in self._upstream[d])
+                              and self._stateless_downstream(d))
+            self._drain_ok_cache[nid] = ok
+        return self._drain_ok_cache[nid]
+
     def _stateless_downstream(self, nid: int) -> bool:
         """True iff nothing stateful (window/session/join) is reachable
         below nid — the async-drain safety condition."""
@@ -2699,6 +2763,7 @@ class Driver:
         and the fair-drain gate membership across the loop's lifetime."""
         from flink_tpu import faults
 
+        self._drain_ident = threading.get_ident()
         gate = self._drain_gate
         if gate is not None:
             gate.register(self._gate_token)
@@ -2706,11 +2771,11 @@ class Driver:
             with faults.job_scope(getattr(self, "_fault_scope", None)):
                 self._drain_loop()
         finally:
+            self._drain_ident = None    # the id may be another thread's next
             if gate is not None:
                 gate.unregister(self._gate_token)
 
     def _drain_loop(self) -> None:
-        import contextlib
         import queue as _q
 
         from flink_tpu.ops.window import FiredWindows
@@ -2869,6 +2934,22 @@ class Driver:
             finally:
                 self._flush_req.clear()
         self._check_drain_error()
+
+
+def _minibatches(out: Dict[str, np.ndarray], ts: np.ndarray):
+    """``(rows, their timestamps)`` one mini-batch at a time: a
+    changelog's rows carry the number of the mini-batch they left their
+    operator in (``records.MINIBATCH_FIELD``, ascending), and one
+    delivery may hold several. A stateful consumer then emits once a
+    mini-batch, as behind ``table.exec.mini-batch``'s marker. The
+    column goes no further."""
+    seq = out.pop(MINIBATCH_FIELD, None)
+    if seq is None or seq[0] == seq[-1]:
+        yield out, ts
+        return
+    cuts = [0, *(np.flatnonzero(np.diff(seq)) + 1).tolist(), len(seq)]
+    for lo, hi in zip(cuts[:-1], cuts[1:]):
+        yield {k: v[lo:hi] for k, v in out.items()}, ts[lo:hi]
 
 
 @dataclasses.dataclass
