@@ -2680,14 +2680,16 @@ class WindowOperator(ReuseRule):
         device, event time, no spill store, and no batch ever rides the
         fused step (``_may_stash``: there the stash, the fires and the
         purge are ONE launch; led, the fire takes a launch and a clear
-        of its own and the drain's delivery queues behind the batch's
-        push. Tried on the chip, PR 45: the fused lane leading read
-        17.75 / 18.52 / 17.92 ms p50 beside 18.00 / 18.54 / 18.45 in
-        ``q5_hostfed_paced`` (``input_to_fire`` 5.19 -> 1.61 ms,
-        ``push_wait`` 0.09 -> 2.71) and 174.35 / 178.14 M events/s
+        of its own. Tried on the chip in PR 45, when the drain's
+        delivery still queued behind the batch's push: the fused lane
+        leading read 17.75 / 18.52 / 17.92 ms p50 beside 18.00 / 18.54 /
+        18.45 in ``q5_hostfed_paced`` (``input_to_fire`` 5.19 -> 1.61
+        ms, ``push_wait`` 0.09 -> 2.71) and 174.35 / 178.14 M events/s
         beside 174.15 / 176.10 in ``q5_hostfed_replay``: inside the
-        cells' noise until the push lock is narrower, ``PERF.md``
-        section 6). The shapes that decide it move
+        cells' noise. Since PR 48 the loop holds the push lock through
+        no batch (``Driver._find_drain_reach``), so that wait is gone
+        and the trial is worth making again: ROADMAP S2 (c), D19). The
+        shapes that decide it move
         one way only (a ring that grows takes the fused lane away, never
         brings it), so a job that answers no keeps today's order
         throughout."""

@@ -91,25 +91,65 @@ def _fired_or_purged(fired) -> bool:
     return not rowless or getattr(fired, "purged", False)
 
 
+class _PushLock:
+    """The delivery lock: a plain lock that knows which thread holds it,
+    so that a thread already delivering under it (the drain in its poll,
+    a stateful consumer's rows leaving in line inside that poll) does
+    not take it a second time (``_LoopHold``)."""
+
+    __slots__ = ("_lock", "_owner")
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._owner: Optional[int] = None
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        got = self._lock.acquire(blocking, timeout)
+        if got:
+            self._owner = threading.get_ident()
+        return got
+
+    def release(self) -> None:
+        self._owner = None
+        self._lock.release()
+
+    def locked(self) -> bool:
+        return self._lock.locked()
+
+    def held_by_caller(self) -> bool:
+        return self._owner == threading.get_ident()
+
+
 class _LoopHold:
-    """``with`` a lock on the ingest loop's thread, adding up what the
-    loop waited for it (``waited_s``): two clock reads around an acquire
-    that did not succeed at once, none where it did."""
+    """``with`` the delivery lock on a thread that is not inside the
+    drain's poll (the ingest loop's), adding up what it waited for it
+    (``waited_s``: two clock reads around an acquire that did not
+    succeed at once, none where it did) and how often it took it
+    (``takes``). A thread that holds the lock already goes on."""
 
-    __slots__ = ("_lock", "waited_s")
+    __slots__ = ("_lock", "_nested", "waited_s", "takes")
 
-    def __init__(self, lock) -> None:
+    def __init__(self, lock: _PushLock) -> None:
         self._lock = lock
+        self._nested = 0    # written by the lock's holder alone
         self.waited_s = 0.0
+        self.takes = 0
 
     def __enter__(self) -> None:
+        if self._lock.held_by_caller():
+            self._nested += 1
+            return
         if not self._lock.acquire(blocking=False):
             t0 = time.perf_counter()
             self._lock.acquire()
             self.waited_s += time.perf_counter() - t0
+        self.takes += 1
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        self._lock.release()
+        if self._nested:
+            self._nested -= 1
+        else:
+            self._lock.release()
 
 
 class JobCancelledError(RuntimeError):
@@ -249,13 +289,23 @@ class Driver:
         self._emit_defer_s = max(
             0, self.config.get(PipelineOptions.EMIT_DEFER_MS)) / 1000.0
 
-        # serializes downstream pushes from the ingest thread and the
-        # drain thread (shared sinks + metrics are single-writer at a
-        # time; the expensive materialization stays outside the lock)
-        self._push_lock = threading.Lock()
+        # the delivery lock: one writer at a time in what BOTH threads
+        # can reach, and nowhere else. The drain holds it for a poll's
+        # whole delivery (chains, partitions, sinks, a host GROUP BY
+        # that only its delivery feeds: _drain_may_deliver); the loop's
+        # thread takes it only on entering a node such a delivery can
+        # enter (_drain_reach, in _push) and where it delivers fired
+        # rows in line (_emit_fired_sync), never through an operator's
+        # process_batch or advance_watermark: those are the loop's
+        # alone, and what an operator shares with the drain (the ring,
+        # the decode, the reuse rule's marks) it guards itself. Under it
+        # on both threads: every sink.write, records_out, fired_windows,
+        # the emit-latency histogram
+        self._push_lock = _PushLock()
         # the loop thread's way to it: what the loop loses to the
         # drain's delivery is profile.phase.push_wait_s (the drain's own
-        # wait is its detail drain/push_wait)
+        # wait is its detail drain/push_wait), how often it took the
+        # lock at all push.loop_lock_takes
         self._loop_push = _LoopHold(self._push_lock)
         # fair drain scheduling (session-cluster mode): co-resident
         # jobs' drain fetches take round-robin turns on the process-
@@ -272,6 +322,7 @@ class Driver:
             self._drain_gate = drain_gate()
         self._build_ops()
         self._lead_ops = self._find_lead_ops()
+        self._drain_reach = self._find_drain_reach()
         # plan-time HBM budgeting: dense static layouts make the device
         # footprint computable BEFORE the first step — fail at build
         # with a breakdown, not mid-run in the XLA allocator (ref:
@@ -566,6 +617,31 @@ class Driver:
                 return ()
         return tuple(heads)
 
+    def _find_drain_reach(self) -> frozenset:
+        """The nodes a delivery on the drain's thread can enter, read
+        off the plan when the job is built: everything below an
+        operator whose fired rows the drain may deliver
+        (``_drain_may_deliver``), through a host GROUP BY that only
+        such a delivery feeds and on below it. These, and nothing else,
+        are what the loop's thread takes ``_push_lock`` for (``_push``):
+        a sink or chain that a source path AND a fired path reach, a
+        blocking edge into such a node. Where the drain delivers
+        anything, every sink is of the set, since ``records_out`` is one
+        counter all sinks share; a plan no operator of which the drain
+        serves has an empty set, and its loop takes the lock nowhere."""
+        reach: set = set()
+        stack = [d for nid in self._ops if self._drain_may_deliver(nid)
+                 for d in self.plan.node(nid).downstream]
+        while stack:
+            nid = stack.pop()
+            if nid not in reach:
+                reach.add(nid)
+                stack.extend(self.plan.node(nid).downstream)
+        if reach:
+            reach.update(nid for nid, n in self.plan.nodes.items()
+                         if n.kind == "sink")
+        return frozenset(reach)
+
     # -- checkpointing ---------------------------------------------------
     def _setup_checkpointing(self, job_name: str):
         from flink_tpu.checkpoint.coordinator import CheckpointCoordinator
@@ -822,11 +898,10 @@ class Driver:
         # barrier part 1: in-flight async-I/O batches are NOT in the
         # snapshot (their source positions already advanced) — drain
         # them downstream first so the checkpoint covers their effects
-        with self._loop_push:
-            for nid, op in self._ops.items():
-                if self.plan.node(nid).kind == "async_io":
-                    for b in op.poll(drain=True):
-                        self._push_downstream(nid, b)
+        for nid, op in self._ops.items():
+            if self.plan.node(nid).kind == "async_io":
+                for b in op.poll(drain=True):
+                    self._push_downstream(nid, b)
         # barrier: staged epoch must be complete. A fire in flight is
         # waited for here, on the loop
         with self.phases.span("ingest.checkpoint_flush"):
@@ -1187,11 +1262,10 @@ class Driver:
             md = {k: np.concatenate([p["data"][k] for p in parts])
                   for k in parts[0]["data"]}
             mts = np.concatenate([p["ts"] for p in parts])
-            with self._loop_push:
-                self.metrics["records_in"] += len(mts)
-                self.metrics["batches"] += 1
-                self._push_downstream(
-                    sid, (md, mts, np.ones(len(mts), bool)))
+            self.metrics["records_in"] += len(mts)
+            self.metrics["batches"] += 1
+            self._push_downstream(
+                sid, (md, mts, np.ones(len(mts), bool)))
             self._throttle_ops()
             self._eps_meter.mark(len(mts))
         ph("ingest.bookkeeping")
@@ -1546,7 +1620,7 @@ class Driver:
         self._emit_q = queue.Queue()
         self._drain_discard = [False]  # fresh cell per run (see __init__)
         self.phases = PhaseClock()
-        self._loop_push.waited_s = 0.0
+        self._loop_push.waited_s, self._loop_push.takes = 0.0, 0
         for op in self._ops.values():
             op.phases = self.phases
         if self._coordinator is not None:
@@ -1996,9 +2070,14 @@ class Driver:
         final["profile.phase.longest_ms"] = worst["longest_ms"]
         final["profile.phase.longest_at_s"] = worst["longest_at_s"]
         # what the loop lost to the drain's delivery: its waits for
-        # _push_lock, which lie inside whichever leaf was open
+        # _push_lock, which lie inside whichever leaf was open, and how
+        # often it took the lock at all (a node the drain's delivery
+        # can enter too, an in-line delivery): a job total, and once
+        # more where the benchmark's detail line reads
         final["profile.phase.push_wait_s"] = round(
             self._loop_push.waited_s, 6)
+        final["push.loop_lock_takes"] = final[
+            "profile.phase.push_lock_takes"] = self._loop_push.takes
         # the watermark passes that fired or purged, and those that went
         # ahead of their batch: once more where the benchmark's detail
         # line reads (no dot in the name: a leaf's has one)
@@ -2178,11 +2257,10 @@ class Driver:
                     break
                 data, ts = nxt
                 ts = np.asarray(ts, np.int64)
-                with self._loop_push:
-                    self.metrics["records_in"] += len(ts)
-                    self.metrics["batches"] += 1
-                    self._push_downstream(
-                        sid, (dict(data), ts, np.ones(len(ts), bool)))
+                self.metrics["records_in"] += len(ts)
+                self.metrics["batches"] += 1
+                self._push_downstream(
+                    sid, (dict(data), ts, np.ones(len(ts), bool)))
                 self._throttle_ops()
                 ph("ingest.bookkeeping")
                 self._advance_position(sid, split_ix, data, ts)
@@ -2214,17 +2292,16 @@ class Driver:
                 if self._cancel is not None and self._cancel.is_set():
                     raise JobCancelledError(job_name)
                 self._t_input = self.phases.phase("ingest.route")
-                with self._loop_push:
-                    self.metrics["shuffle_records_replayed"] = (
-                        self.metrics.get("shuffle_records_replayed", 0)
-                        + len(ts))
-                    self._push(v, (data, ts, np.ones(len(ts), bool)),
-                               from_node=u)
-                    if n.kind == "async_io":
-                        # keep enrichment results flowing mid-stage —
-                        # nothing else polls between wave finalizes
-                        for b in op.poll():
-                            self._push_downstream(v, b)
+                self.metrics["shuffle_records_replayed"] = (
+                    self.metrics.get("shuffle_records_replayed", 0)
+                    + len(ts))
+                self._push(v, (data, ts, np.ones(len(ts), bool)),
+                           from_node=u)
+                if n.kind == "async_io":
+                    # keep enrichment results flowing mid-stage —
+                    # nothing else polls between wave finalizes
+                    for b in op.poll():
+                        self._push_downstream(v, b)
                 self._throttle_ops()
                 self.phases.phase("ingest.bookkeeping")
                 self._check_drain_error()
@@ -2250,8 +2327,12 @@ class Driver:
 
     def _push_source_chunk(self, sid: int, data_c, ts_c) -> None:
         """Push ONE ingest chunk downstream (the hot-loop body):
-        link-quiet handshake, locked push + metrics, backpressure wait
-        OUTSIDE the lock."""
+        link-quiet handshake, the push and the loop's own counters,
+        backpressure wait. Under no lock: the operators the records
+        reach are this thread's alone, and ``_push`` takes
+        ``_push_lock`` itself where the drain's delivery can enter too
+        (``_drain_reach``), so a fired row is delivered while its
+        successor batch is keyed, packed and uploaded."""
         ph = self.phases.phase
         # yield the transport to a drain fetch in progress (see
         # _link_lock): blocks only while one is active
@@ -2260,12 +2341,9 @@ class Driver:
             pass
         ph("ingest.route")   # the operator below opens its window.* phases
         valid = np.ones(len(ts_c), bool)
-        with self._loop_push:
-            self.metrics["records_in"] += len(ts_c)
-            self.metrics["batches"] += 1
-            self._push_downstream(sid, (dict(data_c), ts_c, valid))
-        # backpressure wait OUTSIDE the lock: the drain thread must be
-        # able to deliver while ingest blocks on the device pipeline
+        self.metrics["records_in"] += len(ts_c)
+        self.metrics["batches"] += 1
+        self._push_downstream(sid, (dict(data_c), ts_c, valid))
         self._throttle_ops()
         ph("ingest.bookkeeping")
 
@@ -2404,6 +2482,18 @@ class Driver:
             self._push(d, batch, from_node=nid)
 
     def _push(self, nid: int, batch: Batch, from_node: int) -> None:
+        """``batch`` into node ``nid``: under ``_push_lock`` where the
+        drain's delivery can enter the node too (``_drain_reach``: the
+        lock is taken at the first such node on the way down and held
+        for what lies below it, which is of the set as well), under no
+        lock anywhere else."""
+        if nid in self._drain_reach:
+            with self._loop_push:
+                self._route(nid, batch, from_node)
+        else:
+            self._route(nid, batch, from_node)
+
+    def _route(self, nid: int, batch: Batch, from_node: int) -> None:
         if self._batch_capture:
             # bounded mode: a blocking edge diverts into its shuffle
             # spool — the consumer sees nothing until its wave replays
@@ -2510,16 +2600,17 @@ class Driver:
     # -- time plane ------------------------------------------------------
     def _advance_time(self, final: bool = False, only=None,
                       led: bool = False) -> None:
-        """One watermark pass under the push lock and then, the lock let
-        go and the fired cohorts with the drain (``t_queued``), the
-        releases that the purging advances left pending
-        (``WindowOperator._defer_release``): the long part of such an
-        advance holds back neither the cohort nor the drain's delivery.
-        On the loop's thread, as every other write of a key directory.
+        """One watermark pass and then, the fired cohorts with the drain
+        (``t_queued``), the releases that the purging advances left
+        pending (``WindowOperator._defer_release``): the long part of
+        such an advance does not hold back the cohort. Under no lock:
+        ``advance_watermark`` is the loop's thread's alone, as every
+        other write of a key directory; what the pass hands on takes
+        ``_push_lock`` where it must (``_emit_fired_sync`` in line,
+        ``_push`` for async I/O's results).
         ``led``: the pass goes ahead of the batch that implied its
         watermark (``_lead_advance``)."""
-        with self._loop_push:
-            acted = self._propagate_watermarks(final=final, only=only)
+        acted = self._propagate_watermarks(final=final, only=only)
         if acted:
             self.metrics["wm.advances"] += 1
             self.metrics["wm.advances_led"] += led
@@ -2599,12 +2690,15 @@ class Driver:
 
     def _emit_fired(self, nid: int, fired) -> None:
         """Route fired windows downstream. When the downstream subtree is
-        stateless (chains/sinks only), materialization happens on the
-        drain thread — the device→host fetch leaves the hot loop, the
-        way the reference hands buffers to Netty's IO thread off the
-        mailbox thread (ref: PipelinedSubpartition.notifyDataAvailable).
-        Stateful downstream (a second window stage) keeps the in-line
-        path so operator state is touched by one thread only."""
+        stateless (chains/sinks only), materialization and delivery
+        happen on the drain thread — the device→host fetch leaves the
+        hot loop, the way the reference hands buffers to Netty's IO
+        thread off the mailbox thread (ref: PipelinedSubpartition
+        .notifyDataAvailable), and the delivery runs beside the loop's
+        next batch: the loop holds ``_push_lock`` only where that
+        delivery can reach (``_drain_reach``). Stateful downstream (a
+        second window stage) keeps the in-line path so operator state
+        is touched by one thread only."""
         cohort = getattr(fired, "cohort", None)
         if cohort is not None:
             # the operator stamped t_fire at its dispatch; the batch that
@@ -2631,48 +2725,52 @@ class Driver:
     def _emit_fired_sync(self, nid: int, fired, stamp: float,
                          t_push0: Optional[float] = None) -> None:
         """``t_push0``: when the drain took ``_push_lock`` for the poll
-        this delivery belongs to; in line the loop holds it all along,
-        and the stamp is the rows' arrival in hand."""
+        this delivery belongs to. In line (no stamp given) the rows are
+        brought to hand first and the lock is taken for the delivery
+        alone, where the stamp then lies; a thread that holds it (the
+        drain inside its poll: a host GROUP BY's rows leaving in line)
+        goes on."""
         ring_origin = getattr(fired, "_ring", False)
         attrs = {"ring": fired._ring_no} if ring_origin else {}
         with self.phases.span("drain.deliver", **attrs):
             out = dict(fired)  # materializes lazy FiredWindows
-            if t_push0 is None:
-                t_push0 = time.perf_counter()
-            # the fire cohorts whose rows this delivery makes visible at
-            # the sink. Emit-ring fires: every cohort the drain's fetch
-            # made host-visible (one poll coalesces several
-            # fires; each keeps its OWN dispatch stamp); a pack fire: its
-            # own; other operators' emissions have none
-            if ring_origin:
-                cohorts = self._ops[nid].take_delivered_fires()
-            else:
-                cohorts = [c for c in (getattr(fired, "cohort", None),)
-                           if c is not None]
-            if "__ts__" in out:
-                # process-function emissions: explicit per-row timestamps
-                ts = np.asarray(out.pop("__ts__"), np.int64)
-                nrec = len(ts)
-            else:
-                nrec = len(out.get("window_end", ()))  # windowed schemas
-                # (keyed rows also carry "key"; windowAll rows don't)
-                ts = (np.asarray(out["window_end"], np.int64) - 1
-                      if nrec else np.zeros(0, np.int64))
-            if nrec:
-                self.metrics["fired_windows"] += nrec
-                for part, part_ts in _minibatches(out, ts):
-                    self._push_downstream(
-                        nid, (part, part_ts, np.ones(len(part_ts), bool)))
-            # latency marker: fire dispatch → delivered at sink (ref:
-            # streaming/runtime/streamrecord/LatencyMarker.java), read
-            # off the fire records; an emission without one is stamped
-            # where it was handed to the drain
-            now = time.perf_counter()
-            for c in cohorts:
-                c["t_push0"], c["t_sink"] = t_push0, now
-                self._lat_hist.update((now - c["t_fire"]) * 1000.0)
-            if nrec and not cohorts and not ring_origin:
-                self._lat_hist.update((now - stamp) * 1000.0)
+            with self._loop_push:
+                if t_push0 is None:
+                    t_push0 = time.perf_counter()
+                # the fire cohorts whose rows this delivery makes visible at
+                # the sink. Emit-ring fires: every cohort the drain's fetch
+                # made host-visible (one poll coalesces several
+                # fires; each keeps its OWN dispatch stamp); a pack fire: its
+                # own; other operators' emissions have none
+                if ring_origin:
+                    cohorts = self._ops[nid].take_delivered_fires()
+                else:
+                    cohorts = [c for c in (getattr(fired, "cohort", None),)
+                               if c is not None]
+                if "__ts__" in out:
+                    # process-function emissions: explicit per-row timestamps
+                    ts = np.asarray(out.pop("__ts__"), np.int64)
+                    nrec = len(ts)
+                else:
+                    nrec = len(out.get("window_end", ()))  # windowed schemas
+                    # (keyed rows also carry "key"; windowAll rows don't)
+                    ts = (np.asarray(out["window_end"], np.int64) - 1
+                          if nrec else np.zeros(0, np.int64))
+                if nrec:
+                    self.metrics["fired_windows"] += nrec
+                    for part, part_ts in _minibatches(out, ts):
+                        self._push_downstream(
+                            nid, (part, part_ts, np.ones(len(part_ts), bool)))
+                # latency marker: fire dispatch → delivered at sink (ref:
+                # streaming/runtime/streamrecord/LatencyMarker.java), read
+                # off the fire records; an emission without one is stamped
+                # where it was handed to the drain
+                now = time.perf_counter()
+                for c in cohorts:
+                    c["t_push0"], c["t_sink"] = t_push0, now
+                    self._lat_hist.update((now - c["t_fire"]) * 1000.0)
+                if nrec and not cohorts and not ring_origin:
+                    self._lat_hist.update((now - stamp) * 1000.0)
 
     def fire_records(self) -> List[Dict[str, Any]]:
         """One record per window end of each fire cohort (the newest
@@ -2684,8 +2782,12 @@ class Driver:
         pending, and the cohort is handed to the drain),
         ``t_fetch0`` (the drain began to want its rows: its wait for
         their landing began, under no lock), ``t_ready`` (they had
-        landed), ``t_fetch1`` (the rows are host arrays), ``t_push0`` (the delivery holds
-        ``_push_lock``), ``t_sink`` (``sink.write`` returned). A stamp
+        landed), ``t_fetch1`` (the rows are host arrays), ``t_push0``
+        (the delivery holds ``_push_lock``: what lies between is the
+        drain's way to the lock and its wait for another delivery or
+        for the loop inside a node both threads reach, never for a
+        batch's key scan, pack and upload), ``t_sink`` (``sink.write``
+        returned). A stamp
         the cohort never reached is ``None``, and so is ``t_queued``
         where an EARLIER poll's fetch took the rows (a newer ring
         version had landed) before the cohort was queued."""
@@ -2707,10 +2809,13 @@ class Driver:
         else feeds and whose own rows meet nothing stateful. Such an
         operator's state is touched by pushes alone (no watermark pass
         advances it, no throttle, a snapshot comes after the drain's
-        flush), and every push into it is this delivery, under
-        ``_push_lock``: one thread at a time. Its rows leave in the
+        flush), and every push into it enters through ``_push`` at a
+        node of ``_drain_reach``, under ``_push_lock`` whichever
+        thread makes it: one thread at a time. Its rows leave in the
         same delivery (``_emit_fired`` on the drain's thread is in
-        line)."""
+        line). The loop's thread holds that lock nowhere else, so
+        this rule is also what says where the loop must take it
+        (``_find_drain_reach``)."""
         if nid not in self._drain_ok_cache:
             from flink_tpu.ops.global_agg import GlobalAggregateOperator
 

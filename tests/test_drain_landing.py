@@ -21,7 +21,9 @@ event, never on the clock.
 - (j) under the fair gate the waiting tenant does not hold the turn;
 - (k) a batch of markers without rows hurries no one: the drain holds it
   until a marker that may carry rows, a barrier or a stop (or the
-  announce cadence, which the other cases set to nothing).
+  announce cadence, which the other cases set to nothing);
+- (l) an abort while the drain stands before ``_push_lock``: the re-check
+  under the lock delivers nothing (PR 48).
 """
 import queue
 import threading
@@ -338,6 +340,32 @@ class TestBarrierAndStop:
         assert h.stop(discard=True)     # never landed: the thread is gone
         assert not h.sink.rows and not gate.landed.is_set()
         assert h.driver._emit_q.unfinished_tasks == 0
+
+    def test_an_abort_while_the_drain_stands_before_the_lock_delivers_nothing(
+            self, h):
+        """The re-check under ``_push_lock``: the rows have landed and are
+        host arrays, the drain waits for the lock (another delivery, or
+        the loop inside a node both threads reach), the run aborts
+        meanwhile: once the drain has the lock it delivers nothing."""
+        d = h.driver
+        assert d._push_lock.acquire(timeout=WAIT_S)
+        try:
+            fired = h.fire()
+            gate = h.gate_newest(landed=True)
+            h.queue(fired)
+            # fetched and decoded: what is left is the lock
+            for _ in range(int(WAIT_S / 0.001)):
+                if fired.cohort.get("t_fetch1") is not None:
+                    break
+                threading.Event().wait(0.001)
+            assert gate.asks and not h.sink.rows
+            assert d._emit_q.unfinished_tasks == 1
+            d._drain_discard[0] = True
+        finally:
+            d._push_lock.release()
+        assert joined(d._emit_q)
+        assert not h.sink.rows and fired.cohort.get("t_sink") is None
+        assert h.stop(discard=True)
 
     def test_a_stop_during_the_wait_delivers_what_was_queued(self, h):
         fired = h.fire()

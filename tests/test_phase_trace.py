@@ -215,6 +215,71 @@ class TestFireRecords:
                 led.add(f["t_fire"])
         assert 1 <= len(led) <= m["wm.advances_led"]
 
+    def test_a_led_fires_delivery_does_not_wait_out_the_next_key_scan(
+            self, monkeypatch):
+        """PR 48: the loop holds ``_push_lock`` through no batch's push,
+        so ``t_push0 - t_fetch1`` of a led advance's rows does not hold
+        the ``window.key_scan`` of the batch behind it. The scan is held
+        open here (its ``assign`` waits, once, until the sink has the
+        rows or a bound has passed): the delivery's ``t_sink`` precedes
+        that leaf's end, and the drain's wait for the lock is a fraction
+        of the leaf."""
+        from flink_tpu.api.sinks import CollectSink as Sink
+        from flink_tpu.runtime.driver import Driver
+        from flink_tpu.state.keyed import KeyDirectory
+
+        scans, sunk, waited = [], threading.Event(), []
+        switch, assign, write = (PhaseClock._switch, KeyDirectory.assign,
+                                 Sink.write)
+        loop = threading.get_ident()
+
+        def spy(clock, name, attrs):
+            prev, t_open, now = out = switch(clock, name, attrs)
+            if (prev == "window.key_scan" != name
+                    and threading.get_ident() == loop):
+                scans.append((t_open, now))
+            return out
+
+        def held_assign(directory, keys, *a, **k):
+            # the first scan behind a fire that has left for the drain
+            if leds and not waited:
+                t0 = time.perf_counter()
+                waited.append((t0, sunk.wait(10.0)))
+            return assign(directory, keys, *a, **k)
+
+        def sink_write(sink, batch):
+            write(sink, batch)
+            if leds:
+                sunk.set()
+
+        conf = {"state.slots-per-shard": 16384}     # no batch is stashed
+        q5_job(n_batches=8, auctions=3000, **conf)          # compiles
+        leds = []
+        emit = Driver._emit_fired
+
+        def emit_fired(driver, nid, fired):
+            if getattr(fired, "cohort", None) is not None:
+                leds.append(fired.cohort)
+            return emit(driver, nid, fired)
+
+        monkeypatch.setattr(PhaseClock, "_switch", spy)
+        monkeypatch.setattr(KeyDirectory, "assign", held_assign)
+        monkeypatch.setattr(Sink, "write", sink_write)
+        monkeypatch.setattr(Driver, "_emit_fired", emit_fired)
+        res, rows, _driver = q5_job(n_batches=40, auctions=3000, **conf)
+        m = res.metrics
+        assert rows and m["wm.advances_led"] >= 1
+        (t_wait, in_time), = waited
+        assert in_time              # the rows came while the scan stood open
+        scan, = [(a, b) for a, b in scans if a <= t_wait <= b]
+        first = leds[0]
+        # led: it fired ahead of the scan of the batch that completed it
+        assert first["t_input"] <= first["t_fire"] < scan[0]
+        assert not [a for a, _ in scans if first["t_input"] < a < scan[0]]
+        assert scan[0] < first["t_sink"] < scan[1]
+        assert first["t_push0"] - first["t_fetch1"] < (scan[1] - scan[0]) / 2
+        assert m["push.loop_lock_takes"] == 0
+
     def test_the_fused_lane_leads_no_advance(self, job):
         m = job[0].metrics
         assert m["wm.advances_led"] == 0 < m["wm.advances"]
