@@ -19,15 +19,44 @@ at the source's widths (``init_groupagg_state`` says why one array).
 Per batch ONE program (``groupagg_apply_kernel``): the batch is
 combined per slot (``ops/window.py`` ``combine_cells``: a sort by slot
 with the lifted lanes as payload, run heads, one segmented scan a lane,
-the heads brought to the front), then the DISTINCT slots are merged
-into the donated state, ``merge_chunk`` of them a trip: gather, reduce,
-write back with sorted unique indices, and the merged rows written into
-the batch's emit buffer in the same trip. So the emission is part of
-the batch's program: it needs no watermark and no pass over the slots.
-The buffer's first ``emit_cap`` rows are a small array of their own
-whose copy to the host starts at the dispatch; a batch that touched
-more keys leaves the rest in the full buffer, which the drain reads in
-further passes of ``emit_cap`` rows, none lost and none twice.
+the heads brought to the front), each distinct slot's fresh row (the
+slot, its records, each lane's reduction of them) is written into the
+batch's emit buffer, ``merge_chunk`` of them a trip, with no word of
+the state read, and then the DISTINCT slots are merged into the donated
+state one of two ways. A gather and a scatter are paid by the call and
+by the index, hardly by the word (``init_groupagg_state``), a
+``dynamic_update_slice`` by the byte; and a slot the batch's own
+``assign`` handed out for the first time holds the identity, so its
+merged strip IS its fresh row, and such slots are neighbours: with no
+slot ever released a shard's first-time keys take the slots from its
+free pointer up. So:
+
+- **dense blocks.** The host lists those runs, cut into pieces
+  (``fresh_pieces``: the free pointers before and after ``assign``);
+  the kernel writes a piece only where the batch's cells bear it out
+  (its slots are all named, so they are neighbours among the distinct
+  slots too: a slot whose every record was refused for a lane overflow
+  fails its piece): one block of the emit buffer into one block of the
+  state, read-modify-write, a trip a piece;
+- **gather trips.** Every other cell (a key that recurs, a piece that
+  failed or was not listed): gather its strip, reduce it with the fresh
+  row, write both back with sorted unique indices.
+
+The dense write and the ONE small trip (``sparse_chunk``) for the cells
+it leaves are taken where they pay: the batch has more cells than one
+``merge_chunk`` trip and the cells in no piece fit the small trip (the
+suite's batch: 68,400 of 68,500 keys are new, five trips of 16,384
+become 128 blocks and one trip of 2,048). Any other batch, one whose
+keys mostly recur among them, takes ``merge_chunk`` trips over all its
+cells and no block. The header counts both (``groupagg.rows_dense``,
+``groupagg.dense_pieces``, ``profile.opN.apply_trips``).
+
+So the emission is part of the batch's program: it needs no watermark
+and no pass over the slots. The buffer's first ``emit_cap`` rows are a small
+array of their own whose copy to the host starts at the dispatch; a
+batch that touched more keys leaves the rest in the full buffer, which
+the drain reads in further passes of ``emit_cap`` rows, none lost and
+none twice.
 
 The rows of a batch leave as ``WindowOperator``'s fired rows do: a
 ``FiredWindows`` with a cohort (``take_fired``) through the driver's
@@ -35,10 +64,11 @@ drain, which waits for the landing under no lock
 (``EmitRing.await_landing``), decodes slot -> key, puts the 64-bit
 words together and finalizes on the host (``avg = sum // count``).
 
-What the host does per record: ``KeyDirectory.assign`` and the pack. A
-value a lane cannot hold (a ``narrow_fields`` value or an event-time
-offset beyond 32 bits) is refused with its record and counted
-(``groupagg.lane_overflow``), never wrapped.
+What the host does per record: ``KeyDirectory.assign`` and the pack;
+per batch, the table of its fresh runs. A value a lane cannot hold (a
+``narrow_fields`` value or an event-time offset beyond 32 bits) is
+refused with its record and counted (``groupagg.lane_overflow``), never
+wrapped.
 """
 from __future__ import annotations
 
@@ -62,7 +92,9 @@ from flink_tpu.state.keyed import KeyDirectory, account_full_drop
 from flink_tpu.time.watermarks import LONG_MIN
 
 I32 = np.iinfo(np.int32)
-HEAD_WORDS = 8      # a batch's header: [distinct slots, records, trips, 0..]
+# a batch's header: [distinct slots, records, gather trips, rows written
+# dense, pieces written, 0..]
+HEAD_WORDS = 8
 # rows of a batch's emit buffer whose copy starts at the dispatch; None:
 # ``apply_chunk`` of the batch, an eighth of it (tests patch a number)
 EMIT_CAP: Optional[int] = None
@@ -126,14 +158,62 @@ _FILL = jax.jit(lambda ident, slots: jnp.broadcast_to(
 
 
 def merge_chunk(batch: int) -> int:
-    """Distinct slots ONE trip of the merge loop takes, from the batch's
-    shape alone. A strip gather and scatter cost the chip 3.3 ms at
-    16,384 slots and 17 at 131,072, whether a slot is a batch's or the
-    chunk's padding (``init_groupagg_state``), so the chunk is small: a
-    sixty-fourth of the batch (the suite's 2^20 bids name ~68,500 keys:
-    five trips of 16,384, a sixth of them padding), the whole of a small
-    batch."""
+    """Distinct slots ONE gather trip of a batch takes where the batch
+    has no dense write to make (``groupagg_apply_kernel``), from the
+    batch's shape alone. A strip gather and scatter cost the chip 3.3 ms
+    at 16,384 slots and 17 at 131,072, whether a slot is a batch's or
+    the chunk's padding (``init_groupagg_state``), so the chunk is
+    small: a sixty-fourth of the batch (a 2^20 batch whose ~68,500 keys
+    all recur: five trips of 16,384, a sixth of them padding), the whole
+    of a small batch."""
     return max(batch // 64, min(batch, 1024))
+
+
+def sparse_chunk(batch: int) -> int:
+    """Distinct slots the ONE gather trip takes that follows a batch's
+    dense write: the cells in no verified piece, by their positions
+    among the batch's cells. Such a trip gathers and scatters the emit
+    buffer's rows as well as the state's strips (its cells are not
+    neighbours), all four paid by the index, so it is an eighth of
+    ``merge_chunk``: 2,048 of a 2^20 batch, whose ~80 recurring keys
+    and a failed piece or two fit many times over."""
+    return max(merge_chunk(batch) // 8, min(batch, 1024))
+
+
+def piece_width(batch: int, pieces: int, slots: int) -> int:
+    """Slots of ONE piece of a batch's fresh runs, the block a dense
+    write moves, from the upload's size and the table's length (twice
+    the directory's shards) alone: a power of two near an eighth of the
+    records a shard takes of a full batch (2^20 over 128 shards: 1,024,
+    which holds the ~534 first-time keys a shard gets of the suite's
+    batch in one piece), within ``merge_chunk`` and the state."""
+    per_shard = max(1, 2 * batch // max(pieces, 1))
+    return min(merge_chunk(batch), slots,
+               max(128, 1 << (max(per_shard // 8, 1).bit_length() - 1)))
+
+
+def fresh_pieces(before: np.ndarray, after: np.ndarray,
+                 slots_per_shard: int, batch: int) -> np.ndarray:
+    """The slots the ``assign`` of a batch (``batch`` records as
+    uploaded) handed out for the first time, as the ``(2, length)``
+    int32 table ``groupagg_apply_kernel`` takes: (first slot, slots) of
+    each piece, ascending, then zeros; two entries a shard. Shard h's
+    free pointer went ``before[h] -> after[h]``: with no slot ever
+    released those are consecutive slots nothing was folded into. A run
+    longer than ``piece_width`` is cut; pieces past the table's end are
+    not listed (their cells take the gather trip)."""
+    runs = after - before
+    length = 2 * len(runs)
+    width = piece_width(batch, length, len(runs) * slots_per_shard)
+    per = -(-runs // width)
+    shard = np.repeat(np.arange(len(runs)), per)[:length]
+    rank = (np.arange(int(per.sum()))
+            - np.repeat(np.cumsum(per) - per, per))[:length]
+    table = np.zeros((2, length), np.int32)
+    table[0, :len(shard)] = (shard * slots_per_shard + before[shard]
+                             + rank * width)
+    table[1, :len(shard)] = np.minimum(width, runs[shard] - rank * width)
+    return table
 
 
 def _words(v: jax.Array) -> List[jax.Array]:
@@ -156,10 +236,34 @@ def _lane(words: jax.Array, at: int, dtype: str) -> jax.Array:
     return words[at]
 
 
+def _merge_strips(state: jax.Array, fresh: jax.Array, mine: jax.Array,
+                  layout, slots: int) -> Tuple[jax.Array, jax.Array]:
+    """Fold the rows ``fresh`` ``(1 + words, n)`` (slot, then what the
+    batch alone gives the slot; slots ascending where ``mine``) into the
+    strips the state holds for their slots: gather, reduce, write back
+    with sorted unique indices. Returns the state and the merged rows."""
+    k = fresh[0]
+    held = state[:, jnp.where(mine, k, 0)]
+    words, at = [k, held[0] + fresh[1]], 1
+    for (_, op, _), dts in zip(LANE_OPS, layout):
+        for dt in dts:
+            words.extend(_words(op(_lane(held, at, dt),
+                                   _lane(fresh, at + 1, dt))))
+            at += 2 if dt == "int64" else 1
+    merged = jnp.stack(words)
+    # padding takes distinct slots past the last: uniqueness is true
+    pad = slots + jnp.arange(k.shape[0], dtype=jnp.int32)
+    state = state.at[:, jnp.where(mine, k, pad)].set(
+        merged[1:], indices_are_sorted=True, unique_indices=True,
+        mode="drop")
+    return state, merged
+
+
 def groupagg_apply_kernel(
     state: jax.Array,       # (words, slots) i32: init_groupagg_state
     slot: jax.Array,        # (B,) i32; < 0 = the record takes part in nothing
     data: Dict[str, jax.Array],
+    pieces: jax.Array,      # (2, R) i32: fresh_pieces
     *,
     agg: LaneAggregate,
     slots: int,
@@ -167,53 +271,123 @@ def groupagg_apply_kernel(
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fold one batch into the accumulators and gather the merged rows
     of the slots it touched. Returns the state, the int32 header
-    [distinct slots, valid records, trips, 0...], the first ``cap``
-    rows ``(1 + words, cap)`` and all of them ``(1 + words, B +
-    chunk)``: row j (j < distinct slots, slots ascending) is its slot,
-    then the slot's accumulators as the state holds them; the rows past
-    them hold nothing."""
+    [distinct slots, valid records, gather trips, rows written dense,
+    pieces written, 0...], the first ``cap`` rows ``(1 + words, cap)``
+    and all of them ``(1 + words, B + chunk)``: row j (j < distinct
+    slots, slots ascending) is its slot, then the slot's accumulators
+    as the state holds them; the rows past them hold nothing.
+
+    A cell is merged one of two ways (module docstring). ``pieces`` is
+    the host's word on which slots are first-time ones; the kernel
+    writes a piece dense only where the batch's own cells bear it out:
+    the piece's slots are ``n`` neighbours among the distinct slots, in
+    the state's range, above every piece listed before it."""
     batch = slot.shape[0]
     valid = slot >= 0
     rows = jnp.where(valid, slot, 0)
     layout = lane_layout(agg)
+    n_words = lane_words(agg)
     lifted = agg.lift_masked(data, valid)
     lanes = {fam: lane for fam, lane, dts in zip(
         LANE_FAMILIES, lifted, layout) if dts}
     cells, starts, scans, n_cells, n_records = combine_cells(
         slots, rows, jnp.zeros_like(rows), valid, lanes)
 
-    chunk = merge_chunk(batch)
-    lane_i = jnp.arange(chunk, dtype=jnp.int32)
+    chunk, few = merge_chunk(batch), sparse_chunk(batch)
+    width = piece_width(batch, pieces.shape[1], slots)
     cells = jnp.concatenate([cells, jnp.full(chunk, NO_CELL, jnp.int32)])
     starts = jnp.concatenate([starts, jnp.zeros(chunk, jnp.int32)])
-    out = jnp.zeros((lane_words(agg), batch + chunk), jnp.int32)
+    out = jnp.zeros((n_words, batch + chunk), jnp.int32)
 
-    def trip(carry):
-        state, out, done, trips = carry
+    # what the batch alone gives each distinct slot, the row of a slot
+    # nothing was folded into before: no word of the state is read
+    def fresh_trip(carry):
+        out, done = carry
         k = lax.dynamic_slice(cells, (done,), (chunk,))
         s = lax.dynamic_slice(starts, (done,), (chunk + 1,))
-        mine = done + lane_i < n_cells
-        held = state[:, jnp.where(mine, k, 0)]
         last = jnp.maximum(s[1:] - 1, 0)
-        words, at = [held[0] + (s[1:] - s[:-1])], 1
+        words = [k, s[1:] - s[:-1]]
         for (fam, op, _), dts in zip(LANE_OPS, layout):
             for dt, scan in zip(dts, scans.get(fam, ())):
-                words.extend(_words(op(_lane(held, at, dt), scan[last])))
-                at += 2 if dt == "int64" else 1
-        merged = jnp.stack(words)
-        # padding takes distinct slots past the last: uniqueness is true
-        state = state.at[:, jnp.where(mine, k, slots + lane_i)].set(
+                words.extend(_words(op(
+                    jnp.asarray(lane_identity(fam, dt), dt), scan[last])))
+        out = lax.dynamic_update_slice(
+            out, jnp.stack(words), (jnp.int32(0), done))
+        return out, done + chunk
+
+    out, _ = lax.while_loop(
+        lambda c: c[1] < n_cells, fresh_trip, (out, jnp.int32(0)))
+
+    # the pieces the cells bear out: distinct ascending slots whose
+    # first is ``first`` and whose n-th is ``first + n - 1`` are those n
+    first, length = pieces[0], pieces[1]
+    at = jnp.searchsorted(cells, first).astype(jnp.int32)
+    end = first + length
+    above = jnp.concatenate([jnp.zeros(1, jnp.int32), lax.cummax(end)[:-1]])
+    ok = ((length > 0) & (length <= width) & (first >= above)
+          & (first < slots) & (end <= slots)
+          & (cells[at] == first) & (cells[at + length - 1] == end - 1))
+    took = jnp.where(ok, length, 0)
+    rest = n_cells - jnp.sum(took, dtype=jnp.int32)
+    # a dense write and its one gather trip pay where they stand in for
+    # several gather trips and the cells they leave fit that one trip
+    dense = (n_cells > chunk) & (rest <= few)
+    took = jnp.where(dense, took, 0)
+    lane_p = jnp.arange(width, dtype=jnp.int32)
+
+    def piece_trip(r, state):
+        # read-modify-write of the block that holds the piece: a short
+        # piece leaves its neighbours, and the state's last columns
+        # push the block's start back and the piece along it
+        col = jnp.minimum(first[r], slots - width)
+        shift = first[r] - col
+        new = jnp.roll(lax.dynamic_slice(
+            out, (jnp.int32(0), at[r]), (n_words, width))[1:], shift, axis=1)
+        old = lax.dynamic_slice(
+            state, (jnp.int32(0), col), (n_words - 1, width))
+        put = (lane_p >= shift) & (lane_p < shift + took[r])
+        return lax.dynamic_update_slice(
+            state, jnp.where(put, new, old), (jnp.int32(0), col))
+
+    listed = jnp.max(jnp.where(
+        took > 0, jnp.arange(1, took.shape[0] + 1, dtype=jnp.int32), 0))
+    state = lax.fori_loop(0, listed, piece_trip, state)
+
+    def few_trip(state, out):
+        # the j-th cell in no piece lies behind the pieces that have at
+        # most j such cells before them
+        j = jnp.arange(few, dtype=jnp.int32)
+        before = at - (jnp.cumsum(took, dtype=jnp.int32) - took)
+        p = j + jnp.sum(jnp.where(
+            before[None, :] <= j[:, None], took[None, :], 0), axis=1,
+            dtype=jnp.int32)
+        mine = j < rest
+        state, merged = _merge_strips(
+            state, out[:, jnp.where(mine, p, 0)], mine, layout, slots)
+        out = out.at[:, jnp.where(mine, p, batch + chunk + j)].set(
             merged, indices_are_sorted=True, unique_indices=True,
             mode="drop")
-        out = lax.dynamic_update_slice(
-            out, jnp.concatenate([k[None], merged]), (jnp.int32(0), done))
-        return state, out, done + jnp.sum(mine, dtype=jnp.int32), trips + 1
+        return state, out
 
-    state, out, _, trips = lax.while_loop(
-        lambda c: c[2] < n_cells, trip,
-        (state, out, jnp.int32(0), jnp.int32(0)))
-    head = jnp.zeros(HEAD_WORDS, jnp.int32).at[:3].set(
-        jnp.stack([n_cells, n_records, trips]))
+    state, out = lax.cond(dense & (rest > 0), few_trip,
+                          lambda state, out: (state, out), state, out)
+
+    def full_trip(carry):
+        state, out, done = carry
+        mine = done + jnp.arange(chunk, dtype=jnp.int32) < n_cells
+        state, merged = _merge_strips(
+            state, lax.dynamic_slice(out, (jnp.int32(0), done),
+                                     (n_words, chunk)), mine, layout, slots)
+        out = lax.dynamic_update_slice(out, merged, (jnp.int32(0), done))
+        return state, out, done + chunk
+
+    state, out, done = lax.while_loop(
+        lambda c: ~dense & (c[2] < n_cells), full_trip,
+        (state, out, jnp.int32(0)))
+    trips = jnp.where(dense, (rest > 0).astype(jnp.int32), done // chunk)
+    head = jnp.zeros(HEAD_WORDS, jnp.int32).at[:5].set(jnp.stack([
+        n_cells, n_records, trips, jnp.sum(took, dtype=jnp.int32),
+        jnp.sum(took > 0, dtype=jnp.int32)]))
     return state, head, out[:, :cap], out
 
 
@@ -366,7 +540,8 @@ class DeviceGroupAggOperator:
         # event-time lanes hold ``ts - _base`` (the first batch's earliest)
         self._base: Optional[int] = None
         self._pending: Optional[FiredWindows] = None
-        self.counters = {"rows_emitted": 0, "emit_passes": 0, "batches": 0}
+        self.counters = {"rows_emitted": 0, "emit_passes": 0, "batches": 0,
+                         "rows_dense": 0, "dense_pieces": 0}
 
     # -- ingest ------------------------------------------------------------
     def process_batch(self, keys, ts, data: Dict[str, np.ndarray],
@@ -387,6 +562,7 @@ class DeviceGroupAggOperator:
                 if self._base is None and any(self.agg.time_lanes):
                     self._base = int(ts.min())
             with detail("assign"):
+                fresh_from = self.directory.free_pointers().copy()
                 slots = self.directory.assign(keys)
                 self.prof["assign_records"] = self.directory.assign_records
                 self.prof["assign_memo_hits"] = \
@@ -412,14 +588,18 @@ class DeviceGroupAggOperator:
                 cols = {k: np.concatenate(
                     [v, np.zeros((size - n,) + v.shape[1:], v.dtype)])
                     for k, v in cols.items()}
+            pieces = fresh_pieces(
+                fresh_from, self.directory.free_pointers(),
+                self.directory.slots_per_shard, size)
             ph("window.h2d")
             dslot = jnp.asarray(slot32)
             ddata = {k: jnp.asarray(v) for k, v in cols.items()}
+            dpieces = jnp.asarray(pieces)
             ph("window.step_dispatch")
             cap = min(EMIT_CAP or apply_chunk(size), size)
             self.state, head, rows, full = _JIT_GROUPAGG_APPLY(
-                self.state, dslot, ddata, agg=self.agg, slots=self.slots,
-                cap=cap)
+                self.state, dslot, ddata, dpieces, agg=self.agg,
+                slots=self.slots, cap=cap)
             ring = self.emit_ring
             cohort = {"window_ends": [int(ts.max()) + 1],
                       "t_fire": time.perf_counter()}
@@ -503,10 +683,13 @@ class DeviceGroupAggOperator:
             t_ready = wanted.t_landed if wanted else time.perf_counter()
             parts, wms = [], []
             for (head, rows), (tail, wm) in zip(bufs, tails):
-                n, records, trips = (int(x) for x in np.asarray(head)[:3])
+                n, records, trips, dense, pieces = (
+                    int(x) for x in np.asarray(head)[:5])
                 self.prof["apply_cells"] += n
                 self.prof["apply_records"] += records
                 self.prof["apply_trips"] += trips
+                self.counters["rows_dense"] += dense
+                self.counters["dense_pieces"] += pieces
                 cap = rows.shape[1]
                 parts.append(np.asarray(rows)[:, :min(n, cap)])
                 parts.extend(np.asarray(tail[:, lo:min(lo + cap, n)])

@@ -6,6 +6,7 @@ device and updates pane state once per distinct cell
 records, through both uploads (``apply_kernel``'s packed int32,
 ``apply_kernel_split``'s three bytes)."""
 import math
+import re
 
 import jax.numpy as jnp
 import numpy as np
@@ -221,3 +222,33 @@ def test_the_large_keys_apply_makes_no_pass_over_the_state(one_chip):
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 4 * 4 * rows
     assert memory.alias_size_in_bytes >= 4 * rows * RING   # donated, in place
+
+
+def test_the_upsert_apply_updates_its_state_in_place(one_chip):
+    """``groupagg_apply_kernel`` at ``q17_upserts_paced``'s 33,554,432
+    slots (a small batch and a one-lane aggregate: the compile takes
+    seconds, and what could copy the state, the dense blocks' loop, the
+    conditional around the small gather trip and the gather loop, is
+    there whatever the batch): the donated accumulators are aliased to
+    the result and the temporaries are those of the batch, not a copy
+    of the state (PERF.md, PR 49)."""
+    import jax
+
+    from flink_tpu.ops import aggregates, groupagg_device as G
+
+    agg, slots, batch = aggregates.int_max_of("price"), 128 * 262144, 4096
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=one_chip)
+
+    words = G.state_words(agg)
+    compiled = G._JIT_GROUPAGG_APPLY.lower(
+        shape(words, slots), shape(batch), {"price": shape(batch)},
+        shape(2, 256), agg=agg, slots=slots, cap=1024).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4 * words * slots
+    assert memory.temp_size_in_bytes < 4 * slots // 8
+    text = compiled.as_text()
+    assert "conditional(" in text and text.count(" while(") >= 4
+    assert f"s32[{words},{slots}]" in text
+    assert not re.search(rf"= s32\[{words},{slots}\]\S* copy\(", text)

@@ -600,3 +600,199 @@ def test_a_float_job_runs_on_the_device_lane_too():
         assert last[k][0] == c
         assert last[k][1] == pytest.approx(s / c, rel=1e-5)
         assert last[k][2] == pytest.approx(mx, rel=1e-6)
+
+
+# -- the two ways a cell is merged: dense blocks and gather trips -------------
+
+def _keys_by_shard(op, per_shard, lo):
+    """``per_shard[h]`` distinct keys from ``lo`` up that hash to shard
+    h, shuffled, and the next unused key."""
+    want = np.asarray(per_shard)
+    got = [[] for _ in want]
+    k = lo
+    while any(len(g) < w for g, w in zip(got, want)):
+        cand = np.arange(k, k + 4096, dtype=np.int64)
+        for h, c in zip(op.directory.shard_of(cand).tolist(), cand.tolist()):
+            if len(got[h]) < want[h]:
+                got[h].append(c)
+        k += 4096
+    keys = np.asarray([c for g in got for c in g], np.int64)
+    return np.random.default_rng(lo).permutation(keys), k
+
+
+def _records(keys, i, size, float_prices=False):
+    """A batch of ``size`` records that names every key of ``keys``."""
+    rng = np.random.default_rng(1000 + i)
+    ks = np.concatenate([keys, rng.choice(keys, size - len(keys))])
+    rng.shuffle(ks)
+    price = 10.0 ** (rng.random(size) * 6.0) * 100.0
+    price = (price.astype(np.float32).astype(np.float64) if float_prices
+             else np.rint(price))
+    ts = T0 + i * 1000 + np.sort(rng.integers(0, 1000, size))
+    return ks.astype(np.int64), ts.astype(np.int64), (
+        price if float_prices else price.astype(np.int64))
+
+
+def _expected_merge(op, before, keys, refused, size):
+    """(rows dense, pieces, gather trips) of the batch just folded in,
+    by a per-piece Python loop over what the directory handed out."""
+    G = groupagg_device
+    sps = op.directory.slots_per_shard
+    named = op.directory.assign(keys)           # every key is known now
+    named = set(named[(named >= 0) & ~refused].tolist())
+    n_pieces = 2 * op.directory.num_shards
+    width = G.piece_width(size, n_pieces, op.slots)
+    pieces = [(h * sps + lo, min(width, after - lo))
+              for h, (b, after) in enumerate(zip(
+                  before.tolist(), op.directory.free_pointers().tolist()))
+              for lo in range(b, after, width)][:n_pieces]
+    held = [n for first, n in pieces
+            if all(s in named for s in range(first, first + n))]
+    rest = len(named) - sum(held)
+    chunk = G.merge_chunk(size)
+    if len(named) > chunk and rest <= G.sparse_chunk(size):
+        return sum(held), len(held), int(rest > 0)
+    return 0, 0, -(-len(named) // chunk)
+
+
+def _merge_case(case):
+    """``(agg maker, slots a shard, [(per-shard new keys, old keys,
+    refused keys)] a batch)``: old keys are drawn from those folded in
+    before, refused ones (all of a new key's records carry a price 32
+    bits cannot hold) from the batch's new ones."""
+    even = lambda n: [n] * 8                                    # noqa: E731
+    return {
+        "all_new": (q17_agg, 2048, [(even(190), 0, 0), (even(150), 0, 0)]),
+        "all_recurring": (q17_agg, 2048, [(even(190), 0, 0),
+                                          (even(0), 1500, 0)]),
+        "mixed": (q17_agg, 2048, [(even(190), 0, 0), (even(170), 300, 0),
+                                  (even(80), 1200, 0)]),
+        "long_runs_and_unlisted_pieces": (
+            q17_agg, 2048, [([700, 300, 300, 300, 100, 100, 100, 100], 0, 0),
+                            ([130, 260, 30, 400, 400, 300, 0, 200], 200, 0)]),
+        "refused_fresh_slot": (q17_agg, 2048, [(even(190), 0, 3),
+                                               (even(150), 100, 2)]),
+        "last_columns_and_full_shards": (
+            q17_agg, 256, [(even(150), 0, 0), (even(150), 300, 0),
+                           (even(10), 1100, 0)]),
+        "float": (LANE_JOBS["float_sum_max"][0], 2048, [
+            (even(190), 0, 0), (even(170), 300, 0), (even(0), 1500, 0)]),
+    }[case]
+
+
+MERGE_CASES = ["all_new", "all_recurring", "mixed",
+               "long_runs_and_unlisted_pieces", "refused_fresh_slot",
+               "last_columns_and_full_shards", "snapshot_restore", "float"]
+
+
+@pytest.mark.parametrize("case", MERGE_CASES)
+def test_dense_blocks_and_gather_trips_give_the_reference_rows(
+        case, monkeypatch):
+    """Every way a batch's cells are merged (first-time runs written as
+    dense blocks, the other cells in one small gather trip; or, where
+    that cannot pay, every cell in gather trips as before) against the
+    host operator row for row, against the same operator with no piece
+    ever listed word for word, and the header's counters against a
+    per-piece loop."""
+    restore = case == "snapshot_restore"
+    make, sps, plan = _merge_case("mixed" if restore else case)
+    is_float = case == "float"
+    size = 4096
+
+    def ops():
+        kw = dict(num_shards=8, slots_per_shard=sps)
+        trio = (DeviceGroupAggOperator(make(), **kw),
+                DeviceGroupAggOperator(make(), **kw),
+                GlobalAggregateOperator(make(), **kw))
+        for op in trio:
+            op.allow_drops = True
+        return trio
+
+    dev, plain, host = ops()
+    # the twin is told of no first-time slot: every cell takes a gather trip
+    listed = groupagg_device.fresh_pieces
+    monkeypatch.setattr(
+        groupagg_device, "fresh_pieces",
+        lambda *a: (listed(*a) if fold_on is dev
+                    else np.zeros_like(listed(*a))))
+    folded, next_key, seen, since = np.zeros(0, np.int64), 10_000, [], 0
+    for i, (per_shard, n_old, n_refused) in enumerate(plan):
+        if restore and i == 1:
+            # both lanes' snapshots, each restored on the device lane
+            snaps = [dev.snapshot_state(), host.snapshot_state()]
+            (dev, plain, host), since = ops(), len(seen)
+            dev.restore_state(snaps[1])
+            plain.restore_state(snaps[0])
+            host.restore_state(snaps[0])
+        new, next_key = _keys_by_shard(dev, per_shard, next_key)
+        rng = np.random.default_rng(50 + i)
+        old = rng.choice(folded, n_old, replace=False)
+        keys, ts, price = _records(np.concatenate([new, old]), i, size,
+                                   float_prices=is_float)
+        refused = np.isin(keys, new[:n_refused])
+        price[refused] = 2**31 + 5
+        folded = np.union1d(folded, new)
+        before = dev.directory.free_pointers().copy()
+        rows = []
+        for fold_on in (dev, plain, host):
+            fold_on.process_batch(keys, ts, {"price": price})
+            rows.append(dict(fold_on.take_fired()))
+        a, b, c = rows
+        assert list(a) == list(b) == list(c)
+        for f in a:
+            # the float32 sum against the host's float64: to its eighth digit
+            assert np.array_equal(a[f], b[f]), f
+            if is_float and f.startswith("sum"):
+                np.testing.assert_allclose(a[f], c[f], rtol=1e-5)
+            else:
+                assert a[f].dtype == c[f].dtype
+                assert np.array_equal(a[f], c[f]), f
+        dense, pieces, trips = _expected_merge(dev, before, keys, refused,
+                                               size)
+        seen.append((dense, pieces, trips, len(a["key"])))
+        counters = dev.state_counters()
+        assert counters["groupagg.rows_dense"] == sum(s[0] for s in seen[since:])
+        assert counters["groupagg.dense_pieces"] == sum(s[1] for s in seen[since:])
+        assert dev.prof["apply_trips"] == sum(s[2] for s in seen[since:])
+        assert counters["groupagg.rows_emitted"] == sum(s[3] for s in seen[since:])
+        assert plain.state_counters()["groupagg.rows_dense"] == 0
+        assert (counters["groupagg.lane_overflow"]
+                == host.state_counters()["groupagg.lane_overflow"])
+        assert dev.records_dropped_full == host.records_dropped_full
+    # the state word for word, and the snapshot value for value
+    assert np.array_equal(np.asarray(dev.state), np.asarray(plain.state))
+    if not is_float:
+        s1, s2 = dev.snapshot_state(), host.snapshot_state()
+        for f in ("counts", "sums", "maxs", "mins"):
+            assert np.array_equal(s1[f], s2[f]), f
+    # what each case is there for
+    dense, pieces, trips, cells = (np.asarray(x) for x in zip(*seen))
+    chunk = groupagg_device.merge_chunk(size)
+    assert (cells[:2] > chunk).all()
+    if case == "all_new":
+        assert (dense == cells).all() and (trips == 0).all()
+    if case in ("all_recurring", "float"):
+        assert dense[-1] == 0 and trips[-1] == -(-cells[-1] // chunk) == 2
+    if case in ("mixed", "snapshot_restore", "float"):
+        assert 0 < dense[1] < cells[1] and trips[1] == 1
+    if case == "mixed":
+        assert dense[2] == 0 and trips[2] == 2      # too many for one trip
+    if case == "long_runs_and_unlisted_pieces":
+        width = groupagg_device.piece_width(size, 16, dev.slots)
+        assert pieces[0] == 16 and dense[0] == 700 + 300 + 300 + 300 + 100
+        assert 700 > 5 * width and trips[0] == 1
+        # batch 1: the 16 pieces end inside shard 5; shard 7 is not listed
+        assert pieces[1] == 16 and trips[1] == 1
+        assert dense[1] == 130 + 260 + 30 + 400 + 400 + 2 * width
+    if case == "refused_fresh_slot":
+        # a piece with a slot no record names fails its check, whole
+        assert ((13 <= pieces) & (pieces < 16)).all() and (trips == 1).all()
+        assert dev.lane_overflow > 0
+    if case == "last_columns_and_full_shards":
+        assert dev.records_dropped_full > 0
+        assert (dev.directory.free_pointers() == sps).all()
+        assert pieces[1] == 8 and dense[1] == 8 * (sps - 150)
+        # a batch of one gather trip as it is has nothing to save
+        assert cells[2] <= chunk and dense[2] == 0 and trips[2] == 1
+        # the last piece's block starts before it: the state ends there
+        assert sps - 150 < groupagg_device.piece_width(size, 16, dev.slots)
