@@ -174,6 +174,8 @@ def _bind(lib, i64p, f32p) -> None:
     lib.ht_longest_run.argtypes = [ctypes.c_void_p]
     lib.slot_panes_note.restype = None
     lib.slot_panes_note.argtypes = [ctypes.c_int64, i64p, i64p, u8p, i64p]
+    lib.ts_order_stats.restype = None
+    lib.ts_order_stats.argtypes = [i64p, ctypes.c_int64, ctypes.c_int64, i64p]
     lib.hash_keys.restype = None
     lib.hash_keys.argtypes = [i64p, ctypes.c_int64, i64p]
     lib.crc32_zlib.restype = ctypes.c_uint32
@@ -381,6 +383,9 @@ def hash_keys_native(keys: np.ndarray) -> Optional[np.ndarray]:
 #: records between two looks of ``NativeHashTable.assign``'s memo at its
 #: own hit share (codec.cc MEMO_STRETCH)
 MEMO_STRETCH = 4096
+#: stretches the memo sits out after one in which it did not pay, before
+#: it looks again (codec.cc MEMO_REST)
+MEMO_REST = 7
 
 
 class NativeHashTable:
@@ -462,8 +467,9 @@ class NativeHashTable:
         from the directory's allocator arrays (updated in place, with
         ``_alloc_slots``' outcome to the slot) and entered. Behind a
         memo of the call's own that serves a record whose key a record
-        shortly before it had, and steps aside where a stretch of
-        ``MEMO_STRETCH`` records shows it does not pay. Returns ``(slots,
+        shortly before it had, and steps aside for ``MEMO_REST``
+        stretches where a stretch of ``MEMO_STRETCH`` records shows it
+        does not pay. Returns ``(slots,
         slots handed out, of them reclaimed ones, memo hits, records
         that consulted the memo)``."""
         keys = np.ascontiguousarray(keys, np.int64)
@@ -504,6 +510,18 @@ def slot_panes_note_native(slots: np.ndarray, panes: np.ndarray,
         np.ascontiguousarray(panes, np.int64),
         np.ascontiguousarray(valid).view(np.uint8), newest)
     return True
+
+
+def ts_order_stats(ts: np.ndarray, seen: int) -> Tuple[int, int, int]:
+    """``(records stamped below seen, the oldest, the newest)`` of a
+    batch's int64 timestamps (not empty): one pass in C, three in numpy
+    where the library is unavailable."""
+    lib = _load()
+    if lib is None:
+        return int(np.count_nonzero(ts < seen)), int(ts.min()), int(ts.max())
+    out = np.empty(3, np.int64)
+    lib.ts_order_stats(np.ascontiguousarray(ts, np.int64), len(ts), seen, out)
+    return int(out[0]), int(out[1]), int(out[2])
 
 
 def slot_panes_note_pairs_native(pairs: np.ndarray, ring: int, pane_lo: int,

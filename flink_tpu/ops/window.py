@@ -48,6 +48,7 @@ from jax.sharding import PartitionSpec as P
 
 from flink_tpu.api.windowing import WindowAssigner
 from flink_tpu.hostsync import ready_wait
+from flink_tpu.native_codec import ts_order_stats
 from flink_tpu.obs.tracing import PhaseClock
 from flink_tpu.utils.jaxcompat import shard_map
 from flink_tpu.ops.aggregates import LaneAggregate, require_float_lanes
@@ -1703,6 +1704,11 @@ class WindowOperator(ReuseRule):
         self._refire: set[int] = set()
         self._min_pane_seen: Optional[int] = None
         self._max_pane_seen: Optional[int] = None
+        # the newest timestamp folded in on the general lane
+        # (_count_disorder), and the window ends fired a second time for
+        # a record that came after their first fire
+        self._max_ts_seen: Optional[int] = None
+        self.refire_ends: int = 0
         self.late_records: int = 0
         self.exchange_overflow: int = 0
         # what the keyed exchange of a mesh did over the job: steps
@@ -1947,17 +1953,31 @@ class WindowOperator(ReuseRule):
             panes = self.plan.pane_of(ts)
 
             dead = self._cleared_below
-            late_mask = valid & (panes < dead)
-            n_late = int(np.count_nonzero(late_mask))
-            if n_late:
-                self.late_records += n_late
-                valid = valid & ~late_mask
-                whole = False
+            # the batch's oldest and newest record, and how many lie
+            # behind the newest folded in before: one native pass. The
+            # lowest and the highest pane follow (a pane is monotone in
+            # the timestamp), and a batch whose lowest pane is alive has
+            # no late record: the mask is made only where it has
+            seen = self._max_ts_seen
+            order = (ts_order_stats(ts, LONG_MIN if seen is None else seen)
+                     if whole else None)
+            if order is None or self.plan.pane_of(order[1]) < dead:
+                late_mask = valid & (panes < dead)
+                n_late = int(np.count_nonzero(late_mask))
+                if n_late:
+                    self.late_records += n_late
+                    valid = valid & ~late_mask
+                    whole, order = False, None
 
-            if whole or valid.any():
-                pv = panes if whole else panes[valid]
-                mn = int(pv.min())
-                mx = int(pv.max())
+            some = whole or bool(valid.any())
+            if some:
+                if order is None:
+                    order = ts_order_stats(
+                        ts[valid], LONG_MIN if seen is None else seen)
+                behind, oldest, newest = order
+                mn = int(self.plan.pane_of(oldest))
+                mx = int(self.plan.pane_of(newest))
+                self._count_disorder(behind, oldest, newest, mx - mn + 1)
                 prev_min = self._min_pane_seen
                 prev_max = self._max_pane_seen
                 if prev_min is None or mn < prev_min:
@@ -1989,12 +2009,16 @@ class WindowOperator(ReuseRule):
             # with updated contents (ref: EventTimeTrigger.onElement
             # fires immediately for late elements within allowed
             # lateness)
-            if self._fired_below_end is not None:
+            # (the batch's lowest valid pane says whether any record can
+            # be one: in a stream inside its watermark's bound none is)
+            if (some and self._fired_below_end is not None
+                    and mn < self._fired_below_end):
                 late_ok = valid & (panes < self._fired_below_end)
-                if late_ok.any():
-                    self._refire.update(self.plan.late_refire_ends(
-                        panes[late_ok], self._fired_below_end,
-                        self.watermark))
+                self.prof["refire_probe_records"] += int(
+                    np.count_nonzero(late_ok))
+                self._refire.update(self.plan.late_refire_ends(
+                    panes[late_ok], self._fired_below_end,
+                    self.watermark))
 
         with detail("assign"):
             slots = self.directory.assign(keys)
@@ -2131,6 +2155,27 @@ class WindowOperator(ReuseRule):
             # so holding them would read deleted buffers
             self._note_dispatch(self.state.counts[0, 0])
         self._throttle_unless_external()
+
+    def _count_disorder(self, behind: int, oldest: int, newest: int,
+                        panes_spanned: int) -> None:
+        """What a batch's valid records say of the stream's order, on the
+        general lane (``ts_order_stats`` counted them): ``behind`` of
+        them are stamped below the newest timestamp the operator had
+        folded in before the batch (``disorder_records``), the farthest
+        by ``disorder_max_ms``; ``batch_panes`` sums the panes a batch's
+        records span."""
+        prof, seen = self.prof, self._max_ts_seen
+        prof["batch_panes"] += panes_spanned
+        prof["disorder_records"] += behind
+        # (a lane's first batch makes the counters: they read a number
+        # from then on, 0 where nothing was counted)
+        prof["refire_probe_records"] += 0
+        if behind and seen - oldest > prof["disorder_max_ms"]:
+            prof["disorder_max_ms"] = float(seen - oldest)
+        else:
+            prof["disorder_max_ms"] += 0
+        if seen is None or newest > seen:
+            self._max_ts_seen = newest
 
     def _throttle_unless_external(self) -> None:
         if not self.external_throttle:
@@ -2722,7 +2767,13 @@ class WindowOperator(ReuseRule):
                 or self.plan.fire_frontier(wm) > self._fired_below_end
                 or self.plan.first_dead_pane(wm) > self._cleared_below):
             return False
-        return int(ts.min()) > wm
+        # of the advances with something to do, those the batch's oldest
+        # record lets lead (a stream in order: all; one that is not, as
+        # long as its disorder stays inside the watermark's bound)
+        self.prof["advances_with_work"] += 1
+        led = int(ts.min()) > wm
+        self.prof["advances_led"] += led
+        return led
 
     def _advance_watermark(self, wm: int) -> "FiredWindows":
         self.run_pending_release()    # one purge, one release
@@ -2741,6 +2792,7 @@ class WindowOperator(ReuseRule):
         frontier = self.plan.fire_frontier(wm)
         if self._fired_below_end is None or frontier > self._fired_below_end:
             self._fired_below_end = frontier
+        self.refire_ends += len(self._refire)
         self._refire.clear()
         # fused path: the pending ingest stash + these fires + the purge
         # ride ONE device dispatch with ONE upload
@@ -3433,6 +3485,8 @@ class WindowOperator(ReuseRule):
             "refire": sorted(self._refire),
             "late_records": self.late_records,
             "records_dropped_full": self.records_dropped_full,
+            "max_ts_seen": self._max_ts_seen,
+            "refire_ends": self.refire_ends,
         }
         if aux_files:
             out["__aux_files__"] = aux_files
@@ -3500,6 +3554,8 @@ class WindowOperator(ReuseRule):
         self._refire = set(snap["refire"])
         self.late_records = snap["late_records"]
         self.records_dropped_full = snap.get("records_dropped_full", 0)
+        self._max_ts_seen = snap.get("max_ts_seen")
+        self.refire_ends = snap.get("refire_ends", 0)
         # pre-restore device steps are from a dead timeline (their
         # in-flight tokens included — a stale token's ring head must
         # never be folded into the restored timeline's facts)

@@ -2024,7 +2024,8 @@ class Driver:
             self._metrics_server.close()
         for nid, op in self._ops.items():
             for counter in ("late_records", "records_dropped_full",
-                            "exchange_overflow", "records_spilled"):
+                            "exchange_overflow", "records_spilled",
+                            "refire_ends"):
                 if hasattr(op, counter):
                     self.metrics[counter] = (
                         self.metrics.get(counter, 0) + getattr(op, counter))

@@ -10,8 +10,9 @@ the placeholders resolved in numpy), and a directory on the numpy table
 is held to the same, call for call.
 
 The memo's RULE is held by its counters, never by time: keys without
-locality read no hit and lose the memo within one stretch of the call;
-the suite's own order is served nine records in ten.
+locality read no hit and lose the memo within one stretch, for the next
+``MEMO_REST``; locality that comes back has the memo back; the suite's
+own order is served nine records in ten.
 """
 import numpy as np
 import pytest
@@ -20,7 +21,7 @@ import flink_tpu  # noqa: F401 — x64 before other jax users
 from benchmark.configs import nexmark_q5_large_keys as large
 from benchmark.traffic_kinds.constant_rate import Schedule
 from flink_tpu import native_codec
-from flink_tpu.native_codec import MEMO_STRETCH
+from flink_tpu.native_codec import MEMO_REST, MEMO_STRETCH
 from flink_tpu.state.keyed import KeyDirectory, _NumpyHashTable
 
 pytestmark = pytest.mark.skipif(
@@ -263,29 +264,60 @@ def test_the_suites_order_is_served_by_the_memo():
 
 
 def test_keys_without_locality_lose_the_memo_within_a_stretch():
+    """... and pay its compare for one stretch in ``MEMO_REST + 1``."""
     rng = np.random.default_rng(8)
     keys = rng.permutation(1 << 16).astype(np.int64)
     d = KeyDirectory(8, 1 << 14)
     d.assign(keys)
-    assert memo_counts(d) == (len(keys), MEMO_STRETCH, 0)
+    stretches = len(keys) // MEMO_STRETCH
+    looked = -(-stretches // (MEMO_REST + 1)) * MEMO_STRETCH
+    assert looked == 2 * MEMO_STRETCH
+    assert memo_counts(d) == (len(keys), looked, 0)
     # known keys, no locality: the same
     d.assign(keys[::-1].copy())
-    assert memo_counts(d) == (2 * len(keys), 2 * MEMO_STRETCH, 0)
+    assert memo_counts(d) == (2 * len(keys), 2 * looked, 0)
 
 
-def test_the_memo_steps_aside_for_the_call_and_not_for_the_next():
-    """Locality that ends mid-batch: the memo serves the first part and
-    leaves at the end of the first stretch without it; a new call
-    starts with it again."""
+@pytest.mark.parametrize("rests", [1, 3])
+def test_the_memo_steps_aside_for_a_rest_and_not_for_the_call(rests):
+    """Locality that ends mid-batch and comes back (a batch whose first
+    rows were held back from seconds ago, ISSUE 50): the memo serves the
+    first part, leaves at the end of the first stretch without it, looks
+    again every ``MEMO_REST + 1`` stretches, and serves the last part
+    from the first stretch on that it looks at; a new call starts with
+    it."""
     rng = np.random.default_rng(9)
     local = np.repeat(np.arange(64, dtype=np.int64), 2 * MEMO_STRETCH // 64)
-    scattered = rng.permutation(1 << 15).astype(np.int64) + 1000
-    d = KeyDirectory(8, 1 << 13)
+    # whole rests: the stretch after the last one is the first of
+    # ``local`` again
+    scattered = rng.permutation(
+        rests * (MEMO_REST + 1) * MEMO_STRETCH).astype(np.int64) + 1000
+    d = KeyDirectory(8, 1 << 15)
     d.assign(np.concatenate([local, scattered, local]))
     records, looks, hits = memo_counts(d)
     assert records == 4 * MEMO_STRETCH + len(scattered)
-    assert looks == 3 * MEMO_STRETCH
-    assert hits == len(local) - 64
+    assert looks == (2 + rests + 2) * MEMO_STRETCH
+    # what it held before the rest is still true: the second part's
+    # first records hit too, but where a scattered key took the entry
+    assert 2 * len(local) - 128 <= hits <= 2 * len(local) - 64
     d.assign(local)
     assert memo_counts(d) == (records + len(local), looks + len(local),
                               hits + len(local) - 64)
+
+
+def test_held_back_rows_first_leave_the_memo_to_the_rest_of_the_batch():
+    """A tenth of a batch held back 0-3 s, offered first
+    (``nexmark_q5_delayed`` at the rehearsal's density): the rows after
+    them are served as the suite's own order is."""
+    from benchmark.configs import nexmark_q5_delayed as delayed
+
+    p = {**PARAMS, "events_per_ms": 16, "prob_delayed": 0.1,
+         "occasional_delay_ms": 3000, "delay_seed": 3}
+    n = 1 << 17
+    pool = delayed.make_pool(SEED, n, p)
+    d = KeyDirectory(8, 1 << 15)
+    keys = pool[pool.arrivals.steady_from + 2]["auction"]
+    d.assign(keys)
+    records, looks, hits = memo_counts(d)
+    assert records == n
+    assert looks > 0.85 * n and hits > 0.75 * n
